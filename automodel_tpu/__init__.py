@@ -9,6 +9,4 @@ NamedSharding — the TPU-native analog of the reference's DTensor/FSDP2 stack
 (reference: nemo_automodel/components/distributed/mesh.py:42).
 """
 
-from automodel_tpu.utils import jax_compat as _jax_compat  # noqa: F401  (installs old-jax shims)
-
 __version__ = "0.1.0"

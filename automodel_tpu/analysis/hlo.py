@@ -66,7 +66,21 @@ _CUSTOM_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
 _ALIAS_ENTRY_RE = re.compile(
     r"\{([\d, ]*)\}:\s*\((\d+),\s*\{([\d, ]*)\},\s*([\w-]+)\)"
 )
-_UPCAST_RE = re.compile(r"= f32\[[^\]]*\]\S* convert\(bf16\[")
+# `%y = f32[..] convert(%x)`: jaxlib 0.9.0 prints operands by name only, so
+# the operand's dtype comes from its own definition (names are unique in a
+# module); older text carried it inline (`convert(bf16[..] %x)`)
+_DEF_RE = re.compile(r"%([\w.\-]+) = (\w+)\[")
+_F32_CONVERT_RE = re.compile(
+    r"= f32\[[^\]]*\]\S* convert\((?:(\w+)\[[^\]]*\]\S* )?%([\w.\-]+)\)"
+)
+
+
+def _count_bf16_upcasts(txt: str) -> int:
+    defs = dict(_DEF_RE.findall(txt))
+    return sum(
+        (inline or defs.get(name)) == "bf16"
+        for inline, name in _F32_CONVERT_RE.findall(txt)
+    )
 
 
 @dataclasses.dataclass
@@ -204,7 +218,7 @@ def analyze_compiled(compiled, entry: str = "", mesh_axes: dict | None = None) -
         collectives=collectives,
         collective_groups=groups,
         ops=ops,
-        convert_upcasts=len(_UPCAST_RE.findall(txt)),
+        convert_upcasts=_count_bf16_upcasts(txt),
         custom_calls=custom_calls,
         host_callbacks=host_callbacks,
         donation=donation,

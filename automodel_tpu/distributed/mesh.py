@@ -178,7 +178,7 @@ class MeshContext:
         return NamedSharding(self.mesh, PartitionSpec())
 
     def __enter__(self):
-        self._ctx = jax.sharding.use_mesh(self.mesh)
+        self._ctx = jax.set_mesh(self.mesh)
         self._ctx.__enter__()
         return self
 

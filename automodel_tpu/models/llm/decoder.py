@@ -795,7 +795,8 @@ def attention_block(h, lp, cfg: TransformerConfig, positions, segment_ids, inv_f
 
     When the mesh has cp > 1 the sequence dim is sharded and attention runs
     as ring attention over the cp axis (parallel/cp.py); otherwise the
-    backend dispatcher in ops/attention.py picks flash (TPU) or XLA.
+    backend dispatcher in ops/attention.py picks flash (TPU) or XLA, and
+    runs flash per shard of the mesh.
 
     `manual=True` = running INSIDE a full-mesh shard_map (the pp pipeline):
     GSPMD constraints are inert there, so tensor parallelism is explicit —
@@ -807,7 +808,8 @@ def attention_block(h, lp, cfg: TransformerConfig, positions, segment_ids, inv_f
         from automodel_tpu.models.llm.mla import mla_attention_block
 
         return mla_attention_block(
-            h, lp, cfg, positions, segment_ids, inv_freq, constrain, sliding_window, mesh_ctx
+            h, lp, cfg, positions, segment_ids, inv_freq, constrain, sliding_window,
+            None if manual else mesh_ctx,
         )
     D = cfg.resolved_head_dim
     B, S, _ = h.shape
@@ -869,6 +871,7 @@ def attention_block(h, lp, cfg: TransformerConfig, positions, segment_ids, inv_f
             scale=cfg.attn_scale,
             sinks=sinks,
             impl=cfg.attn_impl,
+            mesh_ctx=None if manual else mesh_ctx,
         )
     attn = attn.reshape(B, S, cfg.num_heads * D)
     from automodel_tpu.ops.quant import matmul as _mm

@@ -469,6 +469,7 @@ def mla_attention_block(h, lp, cfg, positions, segment_ids, inv_freq, constrain,
             logits_soft_cap=cfg.attn_soft_cap,
             scale=scale,
             impl=cfg.attn_impl,
+            mesh_ctx=mesh_ctx,
         )
     attn = attn.reshape(B, S, n * dv)
     h = h + _dense(attn, {"kernel": lp["o_proj"]["kernel"]}, cfg.linear_precision)

@@ -2,7 +2,7 @@
 
 Every number the serving stack emits (engine `serve_batch` stats, router
 per-replica balance, frontend TTFT/ITL/shed/goodput, resilience
-retry/rollback totals, bench probe failures) lands on ONE registry so a
+retry/rollback totals, attention kernel fallbacks) lands on ONE registry so a
 single snapshot answers "what has this process done so far". Two export
 faces:
 
@@ -302,12 +302,13 @@ METRIC_CATALOG = (
     ("resilience_wasted_steps_total", "counter", "train steps redone after rollback"),
     # observability itself
     ("flight_recorder_dumps_total", "counter", "flight-recorder dumps written (labeled by reason)"),
-    # bench environment probes
-    ("bench_probe_failures_total", "counter", "failed accelerator probes (labeled by reason)"),
+    # attention dispatch (ops/attention.py resolve_kernel_impl; trace time,
+    # process-global registry)
+    ("attention_reference_fallbacks_total", "counter", "impl='auto' call sites compiled to the XLA reference on a TPU (labeled by op and reason)"),
 )
 
 #: Process-global registry for components without an engine in hand
-#: (resilience counters, bench probes). Engine/router/frontend metrics use
+#: (resilience counters, attention dispatch). Engine/router/frontend metrics use
 #: the per-`Observability` registry instead so tests stay hermetic.
 _DEFAULT = MetricsRegistry()
 

@@ -129,8 +129,6 @@ def serve_step_cost(engine) -> dict | None:
         plan = engine.empty_plan()
         lowered = engine.lower_step(plan)
         cost = lowered.compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else None
         if not cost:
             return None
         return {

@@ -8,7 +8,11 @@ TPU backends:
 - "xla":    einsum + masked softmax reference path (CPU-testable, and the
             correctness oracle for the Pallas kernels).
 - "flash":  Pallas flash-attention kernel (ops/pallas/flash_attention.py).
-- "auto":   flash on TPU, xla elsewhere.
+            Strict: a call the kernel cannot take raises.
+- "auto":   flash on TPU, xla elsewhere. On a TPU, a call the kernel cannot
+            take runs the reference, logged once per reason and counted on
+            the process metrics registry (`attention_reference_fallbacks_
+            total`), so a benchmark can assert which implementation ran.
 
 Supports GQA (num_q_heads a multiple of num_kv_heads), causal and
 bidirectional masks, packed-sequence segment ids (the THD/cu_seqlens analog,
@@ -34,6 +38,43 @@ NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def resolve_kernel_impl(
+    impl: str, kernel: str, unsupported: str | None, op: str
+) -> str:
+    """The one dispatch rule of the attention ops: returns `kernel` (the
+    Pallas implementation's name) or "xla". `unsupported` is the reason the
+    kernel cannot take this call, or None. Runs at trace time, so the
+    fallback count ticks once per traced call site, not per step."""
+    if impl == "xla":
+        return "xla"
+    if impl == kernel:
+        if unsupported is not None:
+            raise NotImplementedError(
+                f"{op}: impl={impl!r} cannot be honoured: {unsupported}"
+            )
+        return kernel
+    if impl != "auto":
+        raise ValueError(f"Unknown {op} impl '{impl}'")
+    if not _on_tpu():
+        return "xla"
+    if unsupported is None:
+        return kernel
+    from automodel_tpu.observability.metrics import default_registry
+
+    # trace-time by design: one tick per compiled call site that resolved
+    # to the reference
+    counter = default_registry().counter(
+        "attention_reference_fallbacks_total", op=op, reason=unsupported
+    )
+    if counter.value == 0:
+        logger.warning(
+            "%s: impl='auto' runs the XLA reference on this TPU: %s",
+            op, unsupported,
+        )
+    counter.inc()
+    return "xla"
 
 
 def make_attention_mask(
@@ -126,15 +167,27 @@ def dot_product_attention(
     scale: float | None = None,
     sinks: jnp.ndarray | None = None,
     impl: AttnImpl = "auto",
+    mesh_ctx=None,
 ) -> jnp.ndarray:
-    """Main attention entry. Shapes: q (B,S,Hq,D); k,v (B,T,Hkv,D)."""
-    resolved = impl
-    if impl == "auto":
-        resolved = "flash" if _on_tpu() else "xla"
-    if resolved == "flash":
-        from automodel_tpu.ops.pallas.flash_attention import flash_attention
+    """Main attention entry. Shapes: q (B,S,Hq,D); k,v (B,T,Hkv,D).
 
-        try:
+    `mesh_ctx` (a GSPMD caller on a multi-device mesh with cp == 1): the
+    flash kernel is a Mosaic custom call with no partitioning rule, so it
+    runs inside the shared attention shard_map (parallel/cp.py) — batch on
+    the data axes, heads on tp — and every chip computes its own shard."""
+    from automodel_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+        flash_unsupported_reason,
+    )
+
+    sharded = mesh_ctx is not None and mesh_ctx.num_devices > 1
+    if sharded and mesh_ctx.sizes["cp"] != 1:
+        raise ValueError("cp > 1 attention goes through parallel/cp.py")
+    unsupported = flash_unsupported_reason(q, k)
+    if unsupported is None and sharded:
+        unsupported = _shard_unsupported_reason(q, k, mesh_ctx)
+    if resolve_kernel_impl(impl, "flash", unsupported, "attention") == "flash":
+        def flash(q, k, v, positions, segment_ids, sinks=None):
             return flash_attention(
                 q, k, v,
                 causal=causal,
@@ -145,20 +198,41 @@ def dot_product_attention(
                 scale=scale,
                 sinks=sinks,
             )
-        except NotImplementedError:
-            resolved = "xla"
-    if resolved == "xla":
-        mask = make_attention_mask(
-            q.shape[1], k.shape[1],
-            causal=causal,
-            q_segment_ids=segment_ids,
-            kv_segment_ids=segment_ids,
-            q_positions=positions,
-            kv_positions=positions,
-            sliding_window=sliding_window,
+
+        if not sharded:
+            return flash(q, k, v, positions, segment_ids, sinks)
+        from automodel_tpu.parallel.cp import shard_map_attention
+
+        B, S = q.shape[:2]
+        if positions is None:
+            positions = jnp.arange(S, dtype=jnp.int32)[None, :]
+        if segment_ids is None:
+            segment_ids = jnp.zeros((B, S), jnp.int32)
+        return shard_map_attention(
+            flash, mesh_ctx, q, k, v,
+            jnp.broadcast_to(positions, (B, S)),
+            jnp.broadcast_to(segment_ids, (B, S)), sinks,
         )
-        return xla_attention(
-            q, k, v, mask=mask, scale=scale,
-            logits_soft_cap=logits_soft_cap, sinks=sinks,
-        )
-    raise ValueError(f"Unknown attention impl '{impl}'")
+    mask = make_attention_mask(
+        q.shape[1], k.shape[1],
+        causal=causal,
+        q_segment_ids=segment_ids,
+        kv_segment_ids=segment_ids,
+        q_positions=positions,
+        kv_positions=positions,
+        sliding_window=sliding_window,
+    )
+    return xla_attention(
+        q, k, v, mask=mask, scale=scale,
+        logits_soft_cap=logits_soft_cap, sinks=sinks,
+    )
+
+
+def _shard_unsupported_reason(q, k, mesh_ctx) -> str | None:
+    """Why the attention shard_map cannot split this call, or None."""
+    batch, tp = mesh_ctx.batch_size_divisor, mesh_ctx.sizes["tp"]
+    if q.shape[0] % batch:
+        return f"batch {q.shape[0]} not divisible by the data axes ({batch})"
+    if q.shape[2] % tp or k.shape[2] % tp:
+        return f"heads ({q.shape[2]}/{k.shape[2]}) not divisible by tp={tp}"
+    return None

@@ -8,16 +8,18 @@ cache lives in fixed-size pages of a global pool, indexed per token through
 a page table. Nothing is padded per request and no dense (B, T) cache is
 ever materialized.
 
-Two backends, dispatched like ops/attention.py's flash path:
+Two backends, chosen by the one dispatch rule of ops/attention.py
+(`resolve_kernel_impl`: explicit "pallas" is strict, "auto" on a TPU logs
+and counts every call it hands to the reference):
 
 - XLA reference (this file): gather each token's pages from the pool and
   run masked softmax attention — pure gather/einsum, runs (and is tested)
   under `JAX_PLATFORMS=cpu`, and is the correctness oracle for the kernel.
 - Pallas TPU kernel (`ops/pallas/ragged_paged_attention.py`): streams pages
   through VMEM with the page table as a scalar-prefetch BlockSpec index map
-  (no gathered (T, P, page, ...) intermediate in HBM); raises
-  NotImplementedError for unsupported features (sliding windows, sinks) so
-  this dispatcher can fall back to the reference.
+  (no gathered (T, P, page, ...) intermediate in HBM). It covers neither
+  sliding windows nor sinks (`_gqa_unsupported_reason` /
+  `_mla_unsupported_reason` below state its rules).
 
 Layouts (see serving/kv_pages.py for the pool):
 
@@ -189,8 +191,9 @@ def _annotate_tp(x, mesh_ctx, dim: int):
     return jax.lax.with_sharding_constraint(x, mesh_ctx.sharding(*axes))
 
 
-def _pallas_gqa_shard_map(mesh_ctx):
-    """shard_map wrapper for the Pallas GQA kernel under tp>1: each rank
+def _pallas_gqa_tp(mesh_ctx, q, k_pages, v_pages, page_tables, positions, *,
+                   scale, soft_cap):
+    """The Pallas GQA kernel under tp>1, inside a shard_map: each rank
     runs the SAME kernel on its local head slice — q/k/v/out shard the
     head dim, page tables and positions replicate, and the grid/BlockSpec
     machinery (scalar-prefetch page indexing, online softmax) is untouched
@@ -201,34 +204,37 @@ def _pallas_gqa_shard_map(mesh_ctx):
         paged_attention_kernel,
     )
 
-    def wrapped(q, k_pages, v_pages, page_tables, positions, *,
-                scale, soft_cap, window, sinks):
-        tp = mesh_ctx.sizes["tp"]
-        if q.shape[1] % tp or k_pages.shape[2] % tp:
-            raise NotImplementedError(
-                f"heads ({q.shape[1]}/{k_pages.shape[2]}) not divisible by "
-                f"tp={tp} — falling back to the XLA reference"
-            )
-        heads = P(None, "tp", None)
-        pages = P(None, None, "tp", None)
-        args = (q, k_pages, v_pages, page_tables, positions)
-        in_specs = (heads, pages, pages, P(None, None), P(None))
-        if sinks is not None:
-            args += (sinks,)
-            in_specs += (P("tp"),)
+    heads = P(None, "tp", None)
+    pages = P(None, None, "tp", None)
 
-        def body(q, k, v, pt, pos, *s):
-            return paged_attention_kernel(
-                q, k, v, pt, pos, scale=scale, soft_cap=soft_cap,
-                window=window, sinks=s[0] if s else None,
-            )
+    def body(q, k, v, pt, pos):
+        return paged_attention_kernel(
+            q, k, v, pt, pos, scale=scale, soft_cap=soft_cap,
+        )
 
-        return jax.shard_map(
-            body, mesh=mesh_ctx.mesh, in_specs=in_specs, out_specs=heads,
-            check_vma=False,
-        )(*args)
+    return jax.shard_map(
+        body, mesh=mesh_ctx.mesh,
+        in_specs=(heads, pages, pages, P(None, None), P(None)),
+        out_specs=heads, check_vma=False,
+    )(q, k_pages, v_pages, page_tables, positions)
 
-    return wrapped
+
+def _gqa_unsupported_reason(q, k_pages, window, sinks, quant, tp):
+    """Why the Pallas GQA kernels cannot take this call, or None."""
+    Hq, Hkv = q.shape[1], k_pages.shape[2]
+    if window is not None:
+        return "sliding windows"
+    if sinks is not None:
+        return "attention sinks"
+    if Hq % Hkv != 0:
+        return f"GQA needs Hq % Hkv == 0 (got {Hq} % {Hkv})"
+    if tp > 1 and quant:
+        # scales replicate while heads shard; the quantized kernel has no
+        # shard_map wrapper
+        return "tp-sharded int8 pages"
+    if tp > 1 and (Hq % tp or Hkv % tp):
+        return f"heads ({Hq}/{Hkv}) not divisible by tp={tp}"
+    return None
 
 
 def ragged_paged_attention(
@@ -243,65 +249,66 @@ def ragged_paged_attention(
     k_scales=None,
     v_scales=None,
 ):
-    """GQA entry. impl: "xla" | "pallas" | "auto" (pallas on TPU, with a
-    shape/feature-based fallback to the reference — the flash dispatch
-    pattern of ops/attention.py). With a `mesh_ctx` (tp>1) the reference
-    path carries head-sharding annotations and the Pallas kernel runs
-    inside a shard_map over the tp axis (rank-local head slices). With
+    """GQA entry. impl: "xla" | "pallas" | "auto" (pallas on TPU where the
+    kernel covers the call). With a `mesh_ctx` (tp>1) the reference path
+    carries head-sharding annotations and the Pallas kernel runs inside a
+    shard_map over the tp axis (rank-local head slices). With
     `k_scales`/`v_scales` ((N, ps) per-row scales) the pages are int8 and
     the quantized kernel/reference dequantizes per page."""
+    from automodel_tpu.ops.attention import resolve_kernel_impl
+
     scale = scale if scale is not None else float(q.shape[-1]) ** -0.5
     quant = k_scales is not None
-    resolved = impl
-    if impl == "auto":
-        resolved = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if resolved == "pallas":
-        try:
-            if _tp_size(mesh_ctx) > 1:
-                if quant:
-                    # scales replicate while heads shard; the quantized
-                    # kernel has no shard_map wrapper yet — the annotated
-                    # XLA reference serves the tp>1 quantized path
-                    raise NotImplementedError(
-                        "tp-sharded quantized paged attention → XLA path"
-                    )
-                return _pallas_gqa_shard_map(mesh_ctx)(
-                    q, k_pages, v_pages, page_tables, positions,
-                    scale=scale, soft_cap=soft_cap, window=window,
-                    sinks=sinks,
-                )
-            if quant:
-                from automodel_tpu.ops.pallas.ragged_paged_attention import (
-                    paged_attention_quant_kernel,
-                )
-
-                return paged_attention_quant_kernel(
-                    q, k_pages, v_pages, k_scales, v_scales,
-                    page_tables, positions,
-                    scale=scale, soft_cap=soft_cap, window=window,
-                    sinks=sinks,
-                )
+    tp = _tp_size(mesh_ctx)
+    unsupported = _gqa_unsupported_reason(
+        q, k_pages, window, sinks, quant, tp
+    )
+    if resolve_kernel_impl(
+        impl, "pallas", unsupported, "paged_attention"
+    ) == "pallas":
+        if tp > 1:
+            return _pallas_gqa_tp(
+                mesh_ctx, q, k_pages, v_pages, page_tables, positions,
+                scale=scale, soft_cap=soft_cap,
+            )
+        if quant:
             from automodel_tpu.ops.pallas.ragged_paged_attention import (
-                paged_attention_kernel,
+                paged_attention_quant_kernel,
             )
 
-            return paged_attention_kernel(
-                q, k_pages, v_pages, page_tables, positions,
-                scale=scale, soft_cap=soft_cap, window=window, sinks=sinks,
+            return paged_attention_quant_kernel(
+                q, k_pages, v_pages, k_scales, v_scales,
+                page_tables, positions, scale=scale, soft_cap=soft_cap,
             )
-        except NotImplementedError:
-            resolved = "xla"
-    if resolved == "xla":
-        q = _annotate_tp(q, mesh_ctx, 1)              # head axis
-        k_pages = _annotate_tp(k_pages, mesh_ctx, 2)
-        v_pages = _annotate_tp(v_pages, mesh_ctx, 2)
-        out = ragged_paged_attention_xla(
-            q, k_pages, v_pages, page_tables, positions,
-            scale=scale, window=window, soft_cap=soft_cap, sinks=sinks,
-            k_scales=k_scales, v_scales=v_scales,
+        from automodel_tpu.ops.pallas.ragged_paged_attention import (
+            paged_attention_kernel,
         )
-        return _annotate_tp(out, mesh_ctx, 1)
-    raise ValueError(f"Unknown paged attention impl '{impl}'")
+
+        return paged_attention_kernel(
+            q, k_pages, v_pages, page_tables, positions,
+            scale=scale, soft_cap=soft_cap,
+        )
+    q = _annotate_tp(q, mesh_ctx, 1)              # head axis
+    k_pages = _annotate_tp(k_pages, mesh_ctx, 2)
+    v_pages = _annotate_tp(v_pages, mesh_ctx, 2)
+    out = ragged_paged_attention_xla(
+        q, k_pages, v_pages, page_tables, positions,
+        scale=scale, window=window, soft_cap=soft_cap, sinks=sinks,
+        k_scales=k_scales, v_scales=v_scales,
+    )
+    return _annotate_tp(out, mesh_ctx, 1)
+
+
+def _mla_unsupported_reason(window, tp):
+    """Why the Pallas MLA kernels cannot take this call, or None."""
+    if window is not None:
+        return "sliding windows"
+    if tp > 1:
+        # the latent rank r is the sharded dim: the score contraction
+        # reduces over r across ranks, which a rank-local online softmax
+        # cannot express
+        return "latent-sharded MLA (tp > 1)"
+    return None
 
 
 def ragged_paged_mla_attention(
@@ -317,47 +324,35 @@ def ragged_paged_mla_attention(
     """MLA (absorbed latent-cache) entry; same dispatch contract as the GQA
     one. Returns latent-space outputs (T, n, r). Under tp>1 the latent rank
     r is the sharded dim (q_abs/c_pages/out; the tiny shared rope head
-    replicates) — the score contraction reduces over r across ranks, which
-    the Pallas kernel's rank-local online softmax cannot express, so the
-    sharded MLA path always takes the annotated XLA reference."""
-    resolved = impl
-    if impl == "auto":
-        resolved = "pallas" if jax.default_backend() == "tpu" else "xla"
-    quant = c_scales is not None
-    if resolved == "pallas":
-        try:
-            if _tp_size(mesh_ctx) > 1:
-                raise NotImplementedError(
-                    "latent-sharded MLA paged attention needs the "
-                    "cross-rank score reduction — XLA reference only"
-                )
-            if quant:
-                from automodel_tpu.ops.pallas.ragged_paged_attention import (
-                    paged_mla_attention_quant_kernel,
-                )
+    replicates) and the annotated XLA reference serves the call."""
+    from automodel_tpu.ops.attention import resolve_kernel_impl
 
-                return paged_mla_attention_quant_kernel(
-                    q_abs, q_rope, c_pages, kr_pages, c_scales, kr_scales,
-                    page_tables, positions,
-                    scale=scale, window=window,
-                )
+    unsupported = _mla_unsupported_reason(window, _tp_size(mesh_ctx))
+    if resolve_kernel_impl(
+        impl, "pallas", unsupported, "paged_mla_attention"
+    ) == "pallas":
+        if c_scales is not None:
             from automodel_tpu.ops.pallas.ragged_paged_attention import (
-                paged_mla_attention_kernel,
+                paged_mla_attention_quant_kernel,
             )
 
-            return paged_mla_attention_kernel(
-                q_abs, q_rope, c_pages, kr_pages, page_tables, positions,
-                scale=scale, window=window,
+            return paged_mla_attention_quant_kernel(
+                q_abs, q_rope, c_pages, kr_pages, c_scales, kr_scales,
+                page_tables, positions, scale=scale,
             )
-        except NotImplementedError:
-            resolved = "xla"
-    if resolved == "xla":
-        q_abs = _annotate_tp(q_abs, mesh_ctx, 2)      # latent-rank axis
-        c_pages = _annotate_tp(c_pages, mesh_ctx, 2)
-        out = ragged_paged_mla_attention_xla(
-            q_abs, q_rope, c_pages, kr_pages, page_tables, positions,
-            scale=scale, window=window,
-            c_scales=c_scales, kr_scales=kr_scales,
+        from automodel_tpu.ops.pallas.ragged_paged_attention import (
+            paged_mla_attention_kernel,
         )
-        return _annotate_tp(out, mesh_ctx, 2)
-    raise ValueError(f"Unknown paged attention impl '{impl}'")
+
+        return paged_mla_attention_kernel(
+            q_abs, q_rope, c_pages, kr_pages, page_tables, positions,
+            scale=scale,
+        )
+    q_abs = _annotate_tp(q_abs, mesh_ctx, 2)      # latent-rank axis
+    c_pages = _annotate_tp(c_pages, mesh_ctx, 2)
+    out = ragged_paged_mla_attention_xla(
+        q_abs, q_rope, c_pages, kr_pages, page_tables, positions,
+        scale=scale, window=window,
+        c_scales=c_scales, kr_scales=kr_scales,
+    )
+    return _annotate_tp(out, mesh_ctx, 2)

@@ -401,6 +401,7 @@ def _flash_fwd_impl(q, k, v, sinks, qpos, qwin, qseg, kpos, kseg,
             pltpu.VMEM((BQ, Dv), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(qpos, qwin, qseg, kpos, kseg, q, k, v)
 
     lse_row = lse[..., 0]                                # (B, Hq, S)
@@ -481,6 +482,7 @@ def _flash_bwd(causal_mode, has_window, skip_window, soft_cap, scale,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
         interpret=_interpret(),
+        name="flash_attention_dq",
     )(qpos, qwin, qseg, kpos, kseg, q, k, v, dout, lse_b, delta_b)
 
     dk, dv = pl.pallas_call(
@@ -512,6 +514,7 @@ def _flash_bwd(causal_mode, has_window, skip_window, soft_cap, scale,
             pltpu.VMEM((BK, Dv), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_attention_dkv",
     )(qpos, qwin, qseg, kpos, kseg, q, k, v, dout, lse_b, delta_b)
 
     dsinks = None
@@ -524,6 +527,20 @@ def _flash_bwd(causal_mode, has_window, skip_window, soft_cap, scale,
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_unsupported_reason(q, k) -> str | None:
+    """Why the kernel cannot take q (B,S,Hq,D) against k (B,T,Hkv,D), or
+    None. The single statement of its shape rules."""
+    S, Hq, Dq = q.shape[1:]
+    T, Hkv, Dk = k.shape[1:]
+    if Dq != Dk:
+        return f"q/k head_dim mismatch ({Dq} vs {Dk})"
+    if Hq % Hkv != 0:
+        return f"GQA needs Hq % Hkv == 0 (got {Hq} % {Hkv})"
+    if S % LANE or T % LANE:
+        return f"seq lens ({S}, {T}) are not multiples of {LANE}"
+    return None
 
 
 def flash_attention(
@@ -551,20 +568,15 @@ def flash_attention(
     (out, lse) where lse is (B, Hq, S) fp32 (NEG_INF for fully-masked rows)
     and is differentiable.
 
-    Raises NotImplementedError for unsupported shapes so the dispatcher in
-    ops/attention.py can fall back to the XLA path.
+    Raises NotImplementedError for the shapes `flash_unsupported_reason`
+    names; the dispatcher in ops/attention.py asks it first.
     """
+    reason = flash_unsupported_reason(q, k)
+    if reason is not None:
+        raise NotImplementedError(f"flash_attention: {reason}")
     B, S, Hq, Dq = q.shape
-    _, T, Hkv, Dk = k.shape
+    _, T, Hkv, _ = k.shape
     Dv = v.shape[-1]
-    if Dq != Dk:
-        raise NotImplementedError("flash_attention: q/k head_dim mismatch")
-    if Hq % Hkv != 0:
-        raise NotImplementedError("flash_attention: GQA needs Hq % Hkv == 0")
-    if _pick_block(S, 512) == 0 or _pick_block(T, 512) == 0:
-        raise NotImplementedError(
-            f"flash_attention: seq lens ({S}, {T}) need a 128-multiple block"
-        )
     scale = scale if scale is not None else float(Dq) ** -0.5
 
     asym = kv_positions is not None or kv_segment_ids is not None

@@ -14,13 +14,13 @@ pool's trash page).
 Covers the serving engine's hot path: GQA (kv-head sharing via reshape, no
 KV repeat) and absorbed-MLA (scores latent + rope parts summed in one
 accumulator, output in latent space). Sliding windows and attention sinks
-raise NotImplementedError so the dispatcher falls back to the XLA
-reference — decode for windowed/sinked models is bandwidth-bound on pages
-it must read anyway, so the reference path costs little there.
+are not covered: the dispatcher (ops/paged_attention.py) states the rules
+and never calls in here with them.
 
-Head dims are zero-padded to the 128 lane width host-side (pad lanes add
-zero logits / zero value columns — exact). Runs on CPU via interpret mode
-for unit-test parity against the XLA reference.
+GQA head dims that are not lane (128) multiples are zero-padded host-side
+(pad lanes add zero logits / zero value columns — exact), which copies the
+pool on every call; the MLA latent/rope pages go in as they are. Runs on
+CPU via interpret mode for unit-test parity against the XLA reference.
 """
 
 from __future__ import annotations
@@ -117,21 +117,10 @@ def paged_attention_kernel(
     *,
     scale: float,
     soft_cap: float | None = None,
-    window=None,
-    sinks=None,
 ):
-    """GQA ragged paged attention; q (T, Hq, D), pages (N, ps, Hkv, D[v]).
-
-    Raises NotImplementedError for features the kernel does not cover so
-    `ops/paged_attention.py` can fall back to the XLA reference."""
-    if window is not None:
-        raise NotImplementedError("paged kernel: sliding windows → XLA path")
-    if sinks is not None:
-        raise NotImplementedError("paged kernel: attention sinks → XLA path")
+    """GQA ragged paged attention; q (T, Hq, D), pages (N, ps, Hkv, D[v])."""
     T, Hq, D = q.shape
     N, ps, Hkv, Dv = v_pages.shape
-    if Hq % Hkv != 0:
-        raise NotImplementedError("paged kernel: GQA needs Hq % Hkv == 0")
     P = page_tables.shape[1]
     G = Hq // Hkv
 
@@ -163,6 +152,7 @@ def paged_attention_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, Hq, Dvp), q.dtype),
         interpret=_interpret(),
+        name="paged_attention_gqa",
     )(page_tables.astype(jnp.int32), positions.astype(jnp.int32), qp, kp, vp)
     return out[..., :Dv]
 
@@ -173,8 +163,8 @@ def _gqa_quant_kernel(
     q_ref,     # (1, Hq, D)
     k_ref,     # (1, ps, Hkv, D)  int8
     v_ref,     # (1, ps, Hkv, Dv) int8
-    ks_ref,    # (1, ps) f32 per-row K scales of THIS page
-    vs_ref,    # (1, ps) f32 per-row V scales
+    ks_ref,    # (1, 1, ps) f32 per-row K scales of THIS page
+    vs_ref,    # (1, 1, ps) f32 per-row V scales
     out_ref,   # (1, Hq, Dv)
     m_scr, l_scr, acc_scr,
     *,
@@ -204,8 +194,8 @@ def _gqa_quant_kernel(
         q = q_ref[0]                              # (Hq, D)
         k = k_ref[0].astype(jnp.float32)          # (ps, Hkv, D)
         v = v_ref[0].astype(jnp.float32)          # (ps, Hkv, Dv)
-        ks = ks_ref[...].reshape(1, 1, page_size)  # per-slot K scales
-        vs = vs_ref[...].reshape(1, 1, page_size)
+        ks = ks_ref[...]                          # (1, 1, ps) per-slot K scales
+        vs = vs_ref[...]
         Hq, D = q.shape
         ps, Hkv, Dv = v.shape
         qg = q.reshape(Hkv, groups, D).astype(jnp.float32)
@@ -256,20 +246,11 @@ def paged_attention_quant_kernel(
     *,
     scale: float,
     soft_cap: float | None = None,
-    window=None,
-    sinks=None,
 ):
     """GQA ragged paged attention over int8 pages with (N, ps) per-row
-    scales; same contract (and NotImplementedError fallbacks) as
-    `paged_attention_kernel`."""
-    if window is not None:
-        raise NotImplementedError("paged kernel: sliding windows → XLA path")
-    if sinks is not None:
-        raise NotImplementedError("paged kernel: attention sinks → XLA path")
+    scales; same contract as `paged_attention_kernel`."""
     T, Hq, D = q.shape
     N, ps, Hkv, Dv = v_pages.shape
-    if Hq % Hkv != 0:
-        raise NotImplementedError("paged kernel: GQA needs Hq % Hkv == 0")
     P = page_tables.shape[1]
     G = Hq // Hkv
 
@@ -289,8 +270,8 @@ def paged_attention_quant_kernel(
             pl.BlockSpec((1, Hq, Dp), lambda t, j, pt, pos: (t, 0, 0)),
             pl.BlockSpec((1, ps, Hkv, Dp), lambda t, j, pt, pos: (pt[t, j], 0, 0, 0)),
             pl.BlockSpec((1, ps, Hkv, Dvp), lambda t, j, pt, pos: (pt[t, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps), lambda t, j, pt, pos: (pt[t, j], 0)),
-            pl.BlockSpec((1, ps), lambda t, j, pt, pos: (pt[t, j], 0)),
+            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
+            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Hq, Dvp), lambda t, j, pt, pos: (t, 0, 0)),
         scratch_shapes=[
@@ -304,10 +285,15 @@ def paged_attention_quant_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, Hq, Dvp), q.dtype),
         interpret=_interpret(),
+        name="paged_attention_gqa_int8",
     )(
         page_tables.astype(jnp.int32), positions.astype(jnp.int32),
         qp, kp, vp,
-        k_scales.astype(jnp.float32), v_scales.astype(jnp.float32),
+        # (N, 1, ps): a (1, ps) block of an (N, ps) array breaks Mosaic's
+        # rule that a block's second-to-last dim is a multiple of 8 or the
+        # whole axis
+        k_scales.astype(jnp.float32)[:, None, :],
+        v_scales.astype(jnp.float32)[:, None, :],
     )
     return out[..., :Dv]
 
@@ -381,48 +367,39 @@ def paged_mla_attention_kernel(
     q_abs, q_rope, c_pages, kr_pages, page_tables, positions,
     *,
     scale: float,
-    window=None,
 ):
     """Absorbed-MLA ragged paged attention; returns latent outputs (T, n, r)."""
-    if window is not None:
-        raise NotImplementedError("paged MLA kernel: sliding windows → XLA path")
     T, n, r = q_abs.shape
     N, ps, _ = c_pages.shape
-    P = page_tables.shape[1]
-
-    qa = _pad_last(q_abs, LANE)
-    qr = _pad_last(q_rope, LANE)
-    cp = _pad_last(c_pages, LANE)
-    krp = _pad_last(kr_pages, LANE)
-    rp, drp = qa.shape[-1], qr.shape[-1]
+    P, dr = page_tables.shape[1], q_rope.shape[-1]
 
     kernel = functools.partial(_mla_kernel, scale=scale, page_size=ps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T, P),
         in_specs=[
-            pl.BlockSpec((1, n, rp), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, n, drp), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, ps, rp), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, ps, drp), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
+            pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
+            pl.BlockSpec((1, n, dr), lambda t, j, pt, pos: (t, 0, 0)),
+            pl.BlockSpec((1, ps, r), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
+            pl.BlockSpec((1, ps, dr), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, n, rp), lambda t, j, pt, pos: (t, 0, 0)),
+        out_specs=pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((n, LANE), jnp.float32),
             pltpu.VMEM((n, LANE), jnp.float32),
-            pltpu.VMEM((n, rp), jnp.float32),
+            pltpu.VMEM((n, r), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, n, rp), q_abs.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, n, r), q_abs.dtype),
         interpret=_interpret(),
+        name="paged_attention_mla",
     )(
         page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-        qa, qr, cp, krp,
+        q_abs, q_rope, c_pages, kr_pages,
     )
-    return out[..., :r]
 
 
 def _mla_quant_kernel(
@@ -431,8 +408,8 @@ def _mla_quant_kernel(
     qr_ref,    # (1, n, dr)
     c_ref,     # (1, ps, r)  int8
     kr_ref,    # (1, ps, dr) int8
-    cs_ref,    # (1, ps) f32 per-row latent scales of THIS page
-    krs_ref,   # (1, ps) f32 per-row rope scales
+    cs_ref,    # (1, 1, ps) f32 per-row latent scales of THIS page
+    krs_ref,   # (1, 1, ps) f32 per-row rope scales
     out_ref,   # (1, n, r)
     m_scr, l_scr, acc_scr,
     *,
@@ -461,8 +438,8 @@ def _mla_quant_kernel(
         qr = qr_ref[0].astype(jnp.float32)    # (n, dr)
         c = c_ref[0].astype(jnp.float32)      # (ps, r)
         kr = kr_ref[0].astype(jnp.float32)    # (ps, dr)
-        cs = cs_ref[...].reshape(1, page_size)
-        krs = krs_ref[...].reshape(1, page_size)
+        cs = cs_ref[0]                        # (1, ps)
+        krs = krs_ref[0]
         n = qa.shape[0]
         ps = c.shape[0]
         s = jax.lax.dot_general(
@@ -503,50 +480,43 @@ def paged_mla_attention_quant_kernel(
     page_tables, positions,
     *,
     scale: float,
-    window=None,
 ):
     """Absorbed-MLA ragged paged attention over int8 latent/rope pages
     with (N, ps) per-row scales; same contract as
     `paged_mla_attention_kernel`."""
-    if window is not None:
-        raise NotImplementedError("paged MLA kernel: sliding windows → XLA path")
     T, n, r = q_abs.shape
     N, ps, _ = c_pages.shape
-    P = page_tables.shape[1]
-
-    qa = _pad_last(q_abs, LANE)
-    qr = _pad_last(q_rope, LANE)
-    cp = _pad_last(c_pages, LANE)
-    krp = _pad_last(kr_pages, LANE)
-    rp, drp = qa.shape[-1], qr.shape[-1]
+    P, dr = page_tables.shape[1], q_rope.shape[-1]
 
     kernel = functools.partial(_mla_quant_kernel, scale=scale, page_size=ps)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T, P),
         in_specs=[
-            pl.BlockSpec((1, n, rp), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, n, drp), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, ps, rp), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, ps, drp), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, ps), lambda t, j, pt, pos: (pt[t, j], 0)),
-            pl.BlockSpec((1, ps), lambda t, j, pt, pos: (pt[t, j], 0)),
+            pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
+            pl.BlockSpec((1, n, dr), lambda t, j, pt, pos: (t, 0, 0)),
+            pl.BlockSpec((1, ps, r), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
+            pl.BlockSpec((1, ps, dr), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
+            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
+            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, n, rp), lambda t, j, pt, pos: (t, 0, 0)),
+        out_specs=pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((n, LANE), jnp.float32),
             pltpu.VMEM((n, LANE), jnp.float32),
-            pltpu.VMEM((n, rp), jnp.float32),
+            pltpu.VMEM((n, r), jnp.float32),
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, n, rp), q_abs.dtype),
+        out_shape=jax.ShapeDtypeStruct((T, n, r), q_abs.dtype),
         interpret=_interpret(),
+        name="paged_attention_mla_int8",
     )(
         page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-        qa, qr, cp, krp,
-        c_scales.astype(jnp.float32), kr_scales.astype(jnp.float32),
+        q_abs, q_rope, c_pages, kr_pages,
+        # (N, 1, ps) for the same block-shape rule as the GQA int8 kernel
+        c_scales.astype(jnp.float32)[:, None, :],
+        kr_scales.astype(jnp.float32)[:, None, :],
     )
-    return out[..., :r]

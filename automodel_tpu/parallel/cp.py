@@ -188,12 +188,13 @@ class BlockDiagContextParallelSharder:
         return out
 
 
-def _cp_shard_map_attention(inner_fn, mesh_ctx, q, k, v, positions,
-                            segment_ids, sinks):
-    """Shared shard_map wrapper for the CP attention variants: batch on the
-    data axes, sequence on cp, heads on tp; sinks (per-q-head) ride the tp
-    axis. `inner_fn(q, k, v, positions, segment_ids, sinks=None)` runs
-    per-shard."""
+def shard_map_attention(inner_fn, mesh_ctx, q, k, v, positions,
+                        segment_ids, sinks):
+    """The one shard_map wrapper of attention: batch on the data axes,
+    sequence on cp, heads on tp; sinks (per-q-head) ride the tp axis.
+    `inner_fn(q, k, v, positions, segment_ids, sinks=None)` runs per-shard.
+    Serves the CP variants below and, on cp == 1 meshes, the flash kernel
+    (ops/attention.py) — a Mosaic call GSPMD cannot partition."""
     batch = ("dp_replicate", "dp_shard", "ep")
     qkv_spec = P(batch, "cp", "tp", None)
     tok_spec = P(batch, "cp")
@@ -247,7 +248,7 @@ def local_cp_attention(
             sinks=sinks, impl=attn_impl,
         )
 
-    return _cp_shard_map_attention(
+    return shard_map_attention(
         fn, mesh_ctx, q, k, v, positions, segment_ids, sinks
     )
 
@@ -292,18 +293,6 @@ def _partial_attention_xla(q, k, v, qpos, kpos, qseg, kseg, *, scale, soft_cap, 
     return o, lse
 
 
-def _flash_ring_ok(q, k) -> bool:
-    from automodel_tpu.ops.pallas.flash_attention import _pick_block
-
-    S, T = q.shape[1], k.shape[1]
-    return (
-        _pick_block(S, 512) > 0
-        and _pick_block(T, 512) > 0
-        and q.shape[2] % k.shape[2] == 0
-        and q.shape[-1] == k.shape[-1]
-    )
-
-
 def ring_attention(
     q, k, v,
     positions, segment_ids,
@@ -322,8 +311,9 @@ def ring_attention(
     segment_ids (B, S_loc) in GLOBAL coordinates (survive any layout).
 
     Each step computes local-q × visiting-kv attention — through the Pallas
-    flash kernel in position-causal mode when shapes allow (reference: TE ring
-    wiring, moe/parallelizer.py:749-800), else the XLA oracle — and merges
+    flash kernel in position-causal mode (reference: TE ring wiring,
+    moe/parallelizer.py:749-800) or the XLA oracle, chosen by the dispatch
+    rule of ops/attention.py (`resolve_kernel_impl`) — and merges
     (out, lse) partials with a running logsumexp. The merge is plain JAX, so
     the whole ring differentiates through the flash kernel's lse-aware VJP.
     gpt-oss sinks join once at the end: out *= sigmoid(lse_final - sink).
@@ -336,10 +326,15 @@ def ring_attention(
     if segment_ids is None:
         segment_ids = jnp.zeros((B, S), jnp.int32)
 
-    use_flash = attn_impl in ("auto", "flash") and _flash_ring_ok(q, k)
-    if use_flash:
-        from automodel_tpu.ops.pallas.flash_attention import flash_attention
+    from automodel_tpu.ops.attention import resolve_kernel_impl
+    from automodel_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+        flash_unsupported_reason,
+    )
 
+    if resolve_kernel_impl(
+        attn_impl, "flash", flash_unsupported_reason(q, k), "ring_attention"
+    ) == "flash":
         def partial_step(k_blk, v_blk, kpos, kseg):
             o, lse = flash_attention(
                 q, k_blk, v_blk,
@@ -410,6 +405,6 @@ def ring_dot_product_attention(
             scale=scale, sinks=sinks, attn_impl=attn_impl,
         )
 
-    return _cp_shard_map_attention(
+    return shard_map_attention(
         fn, mesh_ctx, q, k, v, positions, segment_ids, sinks
     )

@@ -187,10 +187,6 @@ def pipeline_layers(
             # p <= t < p + M; off-window ticks recompute clipped garbage that
             # must not leak into the aux/stat accumulators
             valid = jnp.logical_and(t >= p_idx, t - p_idx < M)
-            # aux_acc is carried as shape (1,), not a scalar: jax 0.4.37's
-            # shard_map linearization mis-promotes scalar scan residuals
-            # (broadcast-in-dim shape mismatch under grad); any rank>=1
-            # carry avoids the bug
             aux_acc = aux_acc + jnp.where(valid, aux, 0.0)
             ex_acc = jax.tree.map(
                 lambda a, e: a + jnp.where(valid, e, jnp.zeros_like(e)),
@@ -213,7 +209,7 @@ def pipeline_layers(
         init_stream = (jnp.zeros_like(h_mb[0]), pos_mb[0], seg_mb[0])
         (_, outputs, aux_acc, ex_acc), _ = lax.scan(
             tick,
-            (init_stream, jnp.zeros_like(h_mb), jnp.zeros((1,), jnp.float32),
+            (init_stream, jnp.zeros_like(h_mb), jnp.zeros((), jnp.float32),
              ex0),
             jnp.arange(T),
         )
@@ -231,7 +227,7 @@ def pipeline_layers(
         # shard routes its own tokens → mean over the (data shard,
         # microbatch) chunks (replicated over tp already — tp ranks see
         # identical tokens)
-        aux_acc = lax.psum(aux_acc[0], data_axes + ("pp",)) / n_chunks
+        aux_acc = lax.psum(aux_acc, data_axes + ("pp",)) / n_chunks
         ex_acc = jax.tree.map(lambda e: lax.psum(e, data_axes), ex_acc)
         return outputs, aux_acc, ex_acc
 
@@ -493,8 +489,6 @@ def pipeline_train_1f1b(
             def fwd(xx, pp_, hh_):
                 y, aux, ex = stage(xx, pp_, pos, seg)
                 sa = aux * scale
-                # cond operands stay explicit arrays — 0.4.37 shard_map
-                # linearization mishandles captured/scalar cond residuals
                 s = lax.cond(
                     is_last,
                     lambda yy, hh: head_loss_fn(yy, hh, lab).astype(jnp.float32),

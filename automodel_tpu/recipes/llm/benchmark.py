@@ -60,7 +60,7 @@ class BenchmarkRecipe(TrainFinetuneRecipeForNextTokenPrediction):
             "metric": "benchmark_step_seconds",
             "steps_timed": len(times),
             "step_seconds": round(step_s, 4),
-            **{k: round(v, 3) for k, v in perf.items()},
+            **{k: v if v is None else round(v, 3) for k, v in perf.items()},
         }
         self.metric_logger.log(summary)
         print(json.dumps(summary))

@@ -106,12 +106,28 @@ logger = logging.getLogger(__name__)
 
 class ServeRecipe(TrainFinetuneRecipeForNextTokenPrediction):
     """Reuses the train chassis (model build + checkpoint load + dataloader
-    + loggers); replaces the train loop with a continuous-batching serve."""
+    + loggers); replaces the train loop with a continuous-batching serve.
+    Serving holds the weights ONCE, in `model.dtype`: no fp32 masters, no
+    optimizer state, no train step."""
+
+    fp32_master_weights = False
 
     def setup(self) -> None:
+        if self.cfg.get("checkpoint.restore_from", None):
+            raise ValueError(
+                "llm_serve has no train state to restore an orbax training "
+                "checkpoint into; export it (checkpoint.save_consolidated) "
+                "and serve it through model.pretrained_path"
+            )
         self.cfg.set("checkpoint.enabled", False)
         self.cfg.set("auto_resume", False)
         super().setup()
+
+    def _build_optimizer(self) -> None:
+        """The chassis' optimizer stage, which serving does not have: keep
+        the weights, build no optimizer state and no train step."""
+        self.params = self._init_params
+        del self._init_params
 
     def _requests(self, serving, serve_cfg):
         """Dataset rows → ragged Request stream (pad-stripped prompts,
@@ -188,6 +204,54 @@ class ServeRecipe(TrainFinetuneRecipeForNextTokenPrediction):
         }})
         return {"requests": reqs, "stats": stats}
 
+    def _build_server(self, serve_cfg):
+        """The engine, or router of engines, that `serving.mesh` and
+        `serving.disaggregation` ask for. It TAKES the chassis' weights:
+        they arrive sharded over every visible device, each engine replica
+        re-device_puts them onto its own serving mesh slice (no hop through
+        host memory), and the recipe keeps no reference, so the chassis'
+        copy is freed once the engines hold theirs. serving.mesh={replicas,
+        tp,ep} picks the pod shape; the default 1x1x1 is the single-chip
+        engine on a trivial mesh of the SAME code path."""
+        from automodel_tpu.serving import (
+            DisaggRouter,
+            ReplicaRouter,
+            ServingEngine,
+        )
+
+        params, self.params = self.params, None
+        if self.peft_cfg is not None:
+            from automodel_tpu.peft.lora import merge_lora
+
+            params = merge_lora(self.base_params, params, self.peft_cfg)
+            self.base_params = None
+        serve_mesh = self.typed.serving_mesh
+        disagg = self.typed.serving_disaggregation
+        logger.info("serving with %s, mesh=%s", serve_cfg, serve_mesh)
+        if disagg.enabled:
+            # mesh=None → every replica meshless on the default device
+            # (fused same-device transfers; the hermetic smoke mode). Any
+            # non-trivial serving.mesh carves one tp*ep slice per replica
+            # class member and transfers take the cross-slice split path.
+            mesh_arg = (
+                serve_mesh
+                if serve_mesh.replicas > 1 or serve_mesh.tp > 1
+                or serve_mesh.ep > 1 else None
+            )
+            return DisaggRouter(
+                params, self.model_cfg, serve_cfg, disagg, mesh=mesh_arg,
+                resilience=self.typed.serving_resilience,
+            )
+        if serve_mesh.replicas > 1:
+            return ReplicaRouter(
+                params, self.model_cfg, serve_cfg, serve_mesh,
+                resilience=self.typed.serving_resilience,
+            )
+        return ServingEngine(
+            params, self.model_cfg, serve_cfg,
+            mesh_ctx=serve_mesh.build_contexts()[0],
+        )
+
     def run_train_validation_loop(self) -> None:
         from automodel_tpu.serving import ServingConfig, ServingEngine
 
@@ -212,23 +276,10 @@ class ServeRecipe(TrainFinetuneRecipeForNextTokenPrediction):
             kv_cache_dtype=(get("kv_cache_dtype", None) or None),
             serve_precision=(get("serve_precision", None) or None),
         )
-        params = self.train_state.params
-        if self.peft_cfg is not None:
-            from automodel_tpu.peft.lora import merge_lora
-
-            params = merge_lora(self.base_params, params, self.peft_cfg)
-        # the chassis' mesh-sharded params flow STRAIGHT into the sharded
-        # step (no de-shard hop through host memory — PR 2's single-chip
-        # workaround is gone): each engine replica re-device_puts them onto
-        # its own serving mesh slice. serving.mesh={replicas,tp,ep} picks
-        # the pod shape; the default 1x1x1 is the single-chip engine on a
-        # trivial mesh of the SAME code path.
-        serve_mesh = self.typed.serving_mesh
         reqs = self._requests(node, serve_cfg)
-        logger.info(
-            "serving %d requests (%s, mesh=%s)", len(reqs), serve_cfg,
-            serve_mesh,
-        )
+        # the engine (or router) stays on the recipe after the run: callers
+        # read its compile-once counter and compiled step from it
+        self.server = server = self._build_server(serve_cfg)
         # serving counters get their own JSONL (training.jsonl stays a
         # train-loss trail for the golden/parity tooling)
         from automodel_tpu.loggers.metric_logger import MetricLogger
@@ -236,72 +287,30 @@ class ServeRecipe(TrainFinetuneRecipeForNextTokenPrediction):
         serve_logger = MetricLogger(
             os.path.join(cfg.get("run_dir", "."), "serving.jsonl")
         )
-        disagg = self.typed.serving_disaggregation
+        obs = server.obs
         online_node = node.get("online") if node is not None else None
         online = (
             bool(online_node.get("enabled", False))
             if online_node is not None else False
         )
-        if disagg.enabled:
-            from automodel_tpu.serving import DisaggRouter
+        if online:
+            from automodel_tpu import serving
 
-            # mesh=None → every replica meshless on the default device
-            # (fused same-device transfers; the hermetic smoke mode). Any
-            # non-trivial serving.mesh carves one tp*ep slice per replica
-            # class member and transfers take the cross-slice split path.
-            mesh_arg = (
-                serve_mesh
-                if serve_mesh.replicas > 1 or serve_mesh.tp > 1
-                or serve_mesh.ep > 1 else None
+            frontend_cls = {
+                serving.DisaggRouter: serving.DisaggOnlineFrontend,
+                serving.ReplicaRouter: serving.OnlineRouter,
+                ServingEngine: serving.OnlineFrontend,
+            }[type(server)]
+            res = self._serve_online(
+                frontend_cls(server, self.typed.serving_online),
+                reqs, online_node, serve_logger,
             )
-            router = DisaggRouter(
-                params, self.model_cfg, serve_cfg, disagg, mesh=mesh_arg,
-                resilience=self.typed.serving_resilience,
+        elif isinstance(server, ServingEngine):
+            res = server.serve_batch(
+                reqs, metric_logger=serve_logger, log_every=16,
             )
-            obs = router.obs
-            if online:
-                from automodel_tpu.serving import DisaggOnlineFrontend
-
-                res = self._serve_online(
-                    DisaggOnlineFrontend(router, self.typed.serving_online),
-                    reqs, online_node, serve_logger,
-                )
-            else:
-                res = router.serve_batch(reqs, metric_logger=serve_logger)
-        elif serve_mesh.replicas > 1:
-            from automodel_tpu.serving import ReplicaRouter
-
-            router = ReplicaRouter(
-                params, self.model_cfg, serve_cfg, serve_mesh,
-                resilience=self.typed.serving_resilience,
-            )
-            obs = router.obs
-            if online:
-                from automodel_tpu.serving import OnlineRouter
-
-                res = self._serve_online(
-                    OnlineRouter(router, self.typed.serving_online),
-                    reqs, online_node, serve_logger,
-                )
-            else:
-                res = router.serve_batch(reqs, metric_logger=serve_logger)
         else:
-            ctx = serve_mesh.build_contexts()[0]
-            engine = ServingEngine(
-                params, self.model_cfg, serve_cfg, mesh_ctx=ctx
-            )
-            obs = engine.obs
-            if online:
-                from automodel_tpu.serving import OnlineFrontend
-
-                res = self._serve_online(
-                    OnlineFrontend(engine, self.typed.serving_online),
-                    reqs, online_node, serve_logger,
-                )
-            else:
-                res = engine.serve_batch(
-                    reqs, metric_logger=serve_logger, log_every=16,
-                )
+            res = server.serve_batch(reqs, metric_logger=serve_logger)
         if obs.enabled:
             # end-of-run exports: Perfetto/JSONL trace, the Prometheus
             # snapshot, and the TTFT/ITL attribution block (phase

@@ -116,6 +116,11 @@ def _dataclass_from_cfg(cls, node, **extra):
 
 
 class TrainFinetuneRecipeForNextTokenPrediction(BaseRecipe):
+    #: the trainer holds fp32 master weights whatever `model.dtype` says;
+    #: a chassis user that never takes an optimizer step (llm_serve) clears
+    #: this and gets its weights in `model.dtype` from the start
+    fp32_master_weights = True
+
     def __init__(self, cfg: ConfigNode):
         super().__init__(cfg)
         self.is_moe = False
@@ -323,6 +328,7 @@ class TrainFinetuneRecipeForNextTokenPrediction(BaseRecipe):
         cfg = self.cfg
         mcfg = cfg.get("model")
         dtype = _DTYPES[mcfg.get("dtype", "bfloat16")]
+        param_dtype = jnp.float32 if self.fp32_master_weights else dtype
         overrides = dict(
             dtype=dtype,
             remat_policy=mcfg.get("remat_policy", "full"),
@@ -424,7 +430,7 @@ class TrainFinetuneRecipeForNextTokenPrediction(BaseRecipe):
                 **self.model_spec.adapter_kwargs,
             )
             params = adapter.from_hf(self._hf_reader, shardings=self.param_shardings)
-            params = jax.tree.map(lambda p: jnp.asarray(p, jnp.float32), params)
+            params = jax.tree.map(lambda p: jnp.asarray(p, param_dtype), params)
             if getattr(self.model_cfg, "dsa_index_topk", None) is not None:
                 # V3-style checkpoints predate DSA — backfill fresh indexers
                 from automodel_tpu.models.llm.mla import init_indexer
@@ -452,8 +458,14 @@ class TrainFinetuneRecipeForNextTokenPrediction(BaseRecipe):
                 )
             logger.info("loaded pretrained weights from %s", self._hf_reader._dir)
         else:
+            from automodel_tpu.models.common.layers import cast_params
+
+            # the cast is inside the jit, so no fp32 copy of the model
+            # ever exists when param_dtype is narrower
             init_fn = jax.jit(
-                lambda key: module.init(self.model_cfg, key),
+                lambda key: cast_params(
+                    module.init(self.model_cfg, key), param_dtype
+                ),
                 out_shardings=self.param_shardings,
             )
             params = init_fn(self.rng.next_key())
@@ -845,7 +857,7 @@ class TrainFinetuneRecipeForNextTokenPrediction(BaseRecipe):
                 "grad_norm": metrics["grad_norm"],
                 "lr": metrics.get("lr", 0.0),
                 "num_label_tokens": n_tokens,
-                **{k: round(v, 4) for k, v in perf.items()},
+                **{k: v if v is None else round(v, 4) for k, v in perf.items()},
             }
             if "tokens_per_expert" in metrics:
                 tpe = np.asarray(metrics["tokens_per_expert"])
@@ -861,7 +873,13 @@ class TrainFinetuneRecipeForNextTokenPrediction(BaseRecipe):
             first_record = False
             self.metric_logger.log(record)
             for t in self.trackers:
-                t.log({k: v for k, v in record.items() if k not in ("step", "ts")}, step=step)
+                t.log(
+                    {
+                        k: v for k, v in record.items()
+                        if k not in ("step", "ts") and v is not None
+                    },
+                    step=step,
+                )
 
             if self.rollback is not None and not nonfinite and self.rollback.due(step):
                 self.rollback.snapshot(step, self.train_state)

@@ -17,7 +17,7 @@ import time
 
 logger = logging.getLogger(__name__)
 
-# floor for the probe window: with an already-expired deadline the wait must
+# floor for the commit wait: with an already-expired deadline the wait must
 # not block meaningfully, but a 0-second wait would race the daemon thread's
 # startup and report an already-committed save as missing
 _MIN_PROBE_S = 0.25

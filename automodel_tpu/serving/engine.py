@@ -295,16 +295,11 @@ class ServingEngine:
                 jnp.asarray(windows[off : off + L], jnp.int32)
             )
             off += L
+        # windows ride the layer scan as traced per-layer values, so one
+        # windowed layer hands every layer's `window` to the paged op — and
+        # the op's dispatch rule (ops/paged_attention.py) then runs the XLA
+        # reference for the whole model, as it does for sinks
         self._any_window = any(windows)
-        self._has_sinks = any(
-            "sinks" in self.params.get(k, {}) for k, *_ in self._stacks
-        )
-        # the Pallas kernel covers the windowless/sinkless hot path; traced
-        # per-layer windows and sinks take the XLA reference (static choice —
-        # one compiled step either way)
-        self._attn_impl = (
-            "xla" if (self._any_window or self._has_sinks) else "auto"
-        )
         self._inv_freq = rope_frequencies(
             cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
         )
@@ -567,8 +562,7 @@ class ServingEngine:
             out_lat = ragged_paged_mla_attention(
                 q_abs[0], q_rope[0], pool_k, pool_v,
                 b["pt_tok"], b["pos"],
-                scale=scale, window=window, impl=self._attn_impl,
-                mesh_ctx=self._mesh, **scales_kw,
+                scale=scale, window=window, mesh_ctx=self._mesh, **scales_kw,
             )
             attn = jnp.einsum("tnr,rnd->tnd", out_lat, w_uv)
             attn = attn.reshape(1, -1, n * dv)
@@ -604,7 +598,7 @@ class ServingEngine:
             q[0], pool_k, pool_v, b["pt_tok"], b["pos"],
             scale=scale, window=window,
             soft_cap=cfg.attn_soft_cap, sinks=lp.get("sinks"),
-            impl=self._attn_impl, mesh_ctx=self._mesh, **scales_kw,
+            mesh_ctx=self._mesh, **scales_kw,
         )
         T = attn.shape[0]
         attn = attn.reshape(1, T, cfg.num_heads * attn.shape[-1])

@@ -137,7 +137,7 @@ async def drive_load(frontend, cfg: LoadTestConfig) -> dict:
         "shed": len(shed),
         "shed_rate": round(len(shed) / max(len(submitted), 1), 4),
         # terminal status per stream (the TokenStream finish_reason
-        # taxonomy) + how many streams survived a replica death
+        # classes) + how many streams survived a replica death
         "finish_reasons": reasons,
         "recovered": len(recovered),
         # recovered-request TTFT penalty: how much the re-prefill detour

@@ -157,7 +157,7 @@ def test_ring_flash_kernel_parity():
     kernel (position-causal mode, interpret on CPU) — fwd + grads vs cp=1."""
     cp = 2
     ctx = MeshConfig(cp=cp, dp_shard=4).build()
-    S = 256  # S_loc = 128 per rank → _flash_ring_ok holds
+    S = 256  # S_loc = 128 per rank: a lane multiple, as the kernel needs
     q, k, v = _qkv(jax.random.key(5), B=4, S=S, Hq=2, Hkv=1, D=128)
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (4, S))
 

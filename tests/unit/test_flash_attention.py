@@ -129,6 +129,55 @@ def test_unsupported_shapes_raise():
     q = jnp.zeros((1, 100, 4, 64))  # seq not 128-divisible
     with pytest.raises(NotImplementedError):
         flash_attention(q, q, q)
+    # ...and through the dispatcher an explicit impl="flash" is strict: it
+    # raises, it does not quietly run the XLA reference
+    from automodel_tpu.ops.attention import dot_product_attention
+
+    with pytest.raises(NotImplementedError, match="not multiples of 128"):
+        dot_product_attention(q, q, q, impl="flash")
+    np.testing.assert_allclose(  # "auto" off-TPU is the reference by rule
+        np.asarray(dot_product_attention(q, q, q, impl="auto")),
+        np.asarray(_oracle(q, q, q)),
+    )
+
+
+def test_auto_fallback_on_tpu_is_counted(monkeypatch):
+    """On a TPU, impl="auto" handing a call to the XLA reference ticks the
+    process registry once per traced call site, labeled with the reason."""
+    from automodel_tpu.observability.metrics import default_registry
+    from automodel_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    counter = default_registry().counter(
+        "attention_reference_fallbacks_total", op="attention",
+        reason="seq lens (100, 100) are not multiples of 128",
+    )
+    before = counter.value
+    q = jnp.ones((1, 100, 4, 64))
+    out = attention.dot_product_attention(q, q, q, impl="auto")
+    assert counter.value == before + 1
+    np.testing.assert_allclose(np.asarray(out), np.asarray(_oracle(q, q, q)))
+
+
+def test_flash_runs_per_shard_on_a_mesh():
+    """cp == 1 mesh: the kernel runs inside the attention shard_map (batch
+    on the data axes, heads on tp) and matches the unsharded oracle; a
+    batch the data axes do not divide is refused by name."""
+    from automodel_tpu.distributed import MeshConfig
+    from automodel_tpu.ops.attention import dot_product_attention
+
+    ctx = MeshConfig(dp_shard=2, ep=2, tp=2).build()
+    q, k, v = _rand_qkv(jax.random.key(11), B=4, S=128, Hq=4, Hkv=2, D=128)
+    out = jax.jit(
+        lambda q, k, v: dot_product_attention(
+            q, k, v, impl="flash", mesh_ctx=ctx
+        )
+    )(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_oracle(q, k, v)), rtol=2e-4, atol=2e-4
+    )
+    with pytest.raises(NotImplementedError, match="not divisible by the data axes"):
+        dot_product_attention(q[:3], k[:3], v[:3], impl="flash", mesh_ctx=ctx)
 
 
 @pytest.mark.slow

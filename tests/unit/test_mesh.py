@@ -69,3 +69,19 @@ def test_with_logical_constraint_in_jit():
     x = np.zeros((8, 16, 4), np.float32)
     y = f(x)
     assert y.shape == x.shape
+
+
+def test_mesh_context_manager_sets_the_ambient_mesh():
+    """`with ctx:` is jax.set_mesh: a bare PartitionSpec resolves against
+    the mesh inside the block."""
+    import jax.numpy as jnp
+
+    ctx = MeshConfig(tp=2).build()
+    with ctx:
+        y = jax.jit(
+            lambda x: jax.lax.with_sharding_constraint(
+                x * 2, PartitionSpec("dp_shard", "tp")
+            )
+        )(jnp.ones((8, 8)))
+    assert y.sharding.spec == PartitionSpec("dp_shard", "tp")
+    assert y.sharding.mesh.shape["tp"] == 2

@@ -129,24 +129,22 @@ def test_pallas_mla_kernel_matches_reference():
     assert np.asarray(got)[2].max() == 0.0  # pad row
 
 
-def test_dispatch_falls_back_for_kernel_unsupported_features():
-    """Windows/sinks raise NotImplementedError from the kernel entry so the
-    dispatcher (impl='pallas') silently takes the XLA path — the flash
-    dispatch contract."""
+def test_dispatch_is_strict_about_kernel_unsupported_features():
+    """Windows/sinks are outside the kernel: an explicit impl='pallas'
+    raises instead of quietly running the reference; 'auto' (off-TPU: the
+    reference by rule) still serves the call."""
     q, keys, values, kp, vp, pt, pos = _paged_setup(seed=5)
-    from automodel_tpu.ops.pallas.ragged_paged_attention import (
-        paged_attention_kernel,
-    )
-
-    with pytest.raises(NotImplementedError):
-        paged_attention_kernel(q, kp, vp, pt, pos, scale=0.25, window=jnp.int32(4))
-    with pytest.raises(NotImplementedError):
-        paged_attention_kernel(
-            q, kp, vp, pt, pos, scale=0.25,
+    with pytest.raises(NotImplementedError, match="sliding windows"):
+        ragged_paged_attention(
+            q, kp, vp, pt, pos, scale=0.25, window=jnp.int32(4), impl="pallas",
+        )
+    with pytest.raises(NotImplementedError, match="attention sinks"):
+        ragged_paged_attention(
+            q, kp, vp, pt, pos, scale=0.25, impl="pallas",
             sinks=jnp.zeros((q.shape[1],), jnp.float32),
         )
     got = ragged_paged_attention(
-        q, kp, vp, pt, pos, scale=0.25, window=jnp.int32(4), impl="pallas",
+        q, kp, vp, pt, pos, scale=0.25, window=jnp.int32(4), impl="auto",
     )
     want = ragged_paged_attention_xla(q, kp, vp, pt, pos, scale=0.25, window=jnp.int32(4))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)

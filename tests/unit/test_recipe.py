@@ -63,7 +63,8 @@ def test_recipe_train_checkpoints_and_metrics(tmp_path):
     assert [r["step"] for r in records] == [1, 2, 3, 4]
     for r in records:
         assert np.isfinite(r["loss"]) and np.isfinite(r["grad_norm"])
-        assert "tps" in r and "mfu_pct" in r
+        # a CPU has no peak FLOP/s to hold the run to: the key stays, null
+        assert r["tps"] > 0 and r["mfu_pct"] is None
     assert sorted(
         int(d) for d in os.listdir(tmp_path / "ckpt") if d.isdigit()
     ) == [2, 4]
