@@ -82,30 +82,36 @@ def moe_forward(
     token_mask: jnp.ndarray | None = None,  # (B, S) bool
     mesh_ctx=None,
     forced_indices: jnp.ndarray | None = None,  # (B*S, K) routing replay
+    scope: str = "moe",  # named-scope prefix: the serve step passes its own
 ) -> tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Returns (out (B,S,H), aux_loss scalar, stats). stats["indices"] is
-    the (T,K) selection — capture it for routing replay (R3)."""
+    the (T,K) selection — capture it for routing replay (R3). The three
+    sublayers run under `<scope>.route`, `.experts` and `.shared` named
+    scopes (metadata only), so a profiler trace tells them apart."""
     B, S, H = x.shape
     flat = x.reshape(B * S, H)
     flat_mask = token_mask.reshape(B * S) if token_mask is not None else None
-    weights, indices, aux_loss, stats = gate_forward(
-        params["gate"], cfg, flat, flat_mask, forced_indices
-    )
+    with jax.named_scope(f"{scope}.route"):
+        weights, indices, aux_loss, stats = gate_forward(
+            params["gate"], cfg, flat, flat_mask, forced_indices
+        )
     stats = {**stats, "indices": indices}
-    if cfg.dispatcher == "dropless":
-        if mesh_ctx is not None and mesh_ctx.sizes["ep"] > 1:
-            routed = experts_forward_dropless_ep(
-                params["experts"], cfg, flat, weights, indices, mesh_ctx
-            )
+    with jax.named_scope(f"{scope}.experts"):
+        if cfg.dispatcher == "dropless":
+            if mesh_ctx is not None and mesh_ctx.sizes["ep"] > 1:
+                routed = experts_forward_dropless_ep(
+                    params["experts"], cfg, flat, weights, indices, mesh_ctx
+                )
+            else:
+                routed = experts_forward_dropless(params["experts"], cfg, flat, weights, indices)
         else:
-            routed = experts_forward_dropless(params["experts"], cfg, flat, weights, indices)
-    else:
-        capacity = compute_capacity(cfg, B * S)
-        dispatch, combine = dispatch_tensors(cfg, indices, weights, capacity)
-        routed = experts_forward(params["experts"], cfg, flat, dispatch, combine, constrain)
+            capacity = compute_capacity(cfg, B * S)
+            dispatch, combine = dispatch_tensors(cfg, indices, weights, capacity)
+            routed = experts_forward(params["experts"], cfg, flat, dispatch, combine, constrain)
     out = routed
     if cfg.n_shared_experts > 0:
         from automodel_tpu.moe.experts import shared_expert_forward
 
-        out = out + shared_expert_forward(params["shared"], cfg, flat)
+        with jax.named_scope(f"{scope}.shared"):
+            out = out + shared_expert_forward(params["shared"], cfg, flat)
     return out.reshape(B, S, H).astype(x.dtype), aux_loss, stats

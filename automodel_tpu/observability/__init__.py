@@ -8,12 +8,14 @@
                lifecycle digest, flight-recorder ring.
 - timeline.py: per-request phase timelines → TTFT/ITL attribution
                (queue vs prefill vs transfer vs step vs backpressure).
-- profiler.py: `jax.profiler` windowed capture for train + serve paths,
-               compiled cost analysis → MFU / bandwidth estimates.
+- profiler.py: `jax.profiler` windowed capture for train + serve paths.
 
 The `Observability` bundle is what the engines thread through: metrics
-are ALWAYS live (plain float adds, negligible), tracing/profiling/flight
-recording only when `ObservabilityConfig.enabled`. Nothing in this
+are ALWAYS live (plain float adds, negligible), and so are the spans'
+profiler annotations (trace.py: whenever a profiler session runs, the
+program's spans are in its trace); the tracer's in-memory buffer, the
+profiler captures and the flight recorder only when
+`ObservabilityConfig.enabled`. Nothing in this
 package may be referenced from jit-reachable code — the tracer records
 host wall clocks and the registry mutates Python floats, either of which
 inside a jitted function is a tracing-time no-op at best and a host-sync
@@ -25,7 +27,10 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
+import weakref
 from typing import Optional
+
+import jax
 
 from automodel_tpu.observability.metrics import (
     LATENCY_MS_BUCKETS,
@@ -54,19 +59,32 @@ from automodel_tpu.observability.profiler import (
     Profiler,
     ProfilingConfig,
     ServeProfiler,
-    annotate,
-    serve_step_cost,
-    step_efficiency,
 )
 
 logger = logging.getLogger(__name__)
+
+#: the registries of the live bundles: the process's ONE `jax.monitoring`
+#: listener (it cannot be taken off again) ticks the compile counters of each
+_COMPILE_WATCHERS: "weakref.WeakSet[MetricsRegistry]" = weakref.WeakSet()
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
+    if not event.endswith("backend_compile_duration"):
+        return
+    for reg in list(_COMPILE_WATCHERS):
+        reg.counter("jax_backend_compiles_total").inc()
+        reg.counter("jax_backend_compile_seconds_total").inc(seconds)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 @dataclasses.dataclass(frozen=True)
 class ObservabilityConfig:
     """`serving.observability` YAML section. Everything defaults off;
-    with `enabled: false` the serve path is byte-identical to a build
-    without this package."""
+    with `enabled: false` the tracer keeps no buffer (its spans still
+    reach a running profiler session) and the jitted step is the same
+    program either way."""
 
     enabled: bool = False
     #: trace export prefix: writes <trace_path>.trace.json (Perfetto) and
@@ -90,8 +108,8 @@ class Observability:
 
     `registry` is always a real `MetricsRegistry` — counters cost one
     float add, so they stay on unconditionally and offline/online stats
-    mirror onto them. `tracer` is the null tracer unless enabled, so the
-    hot serve loop pays two attribute lookups when tracing is off.
+    mirror onto them. `tracer` is the null tracer unless enabled: no
+    buffer, spans are profiler annotations only.
     """
 
     def __init__(self, cfg: ObservabilityConfig | None = None, *,
@@ -99,6 +117,9 @@ class Observability:
         self.cfg = cfg or ObservabilityConfig()
         self.enabled = bool(self.cfg.enabled)
         self.registry = registry if registry is not None else MetricsRegistry()
+        # a serving process's contract is one step program: a compilation
+        # after warm-up belongs on an operator's /metrics
+        _COMPILE_WATCHERS.add(self.registry)
         self.tracer = (
             Tracer(ring_len=self.cfg.flight_recorder_len)
             if self.enabled else NULL_TRACER
@@ -195,13 +216,10 @@ __all__ = [
     "ServeProfiler",
     "TraceEvent",
     "Tracer",
-    "annotate",
     "attribute_itl",
     "attribute_ttft",
     "attribution_summary",
     "build_timelines",
     "default_registry",
-    "serve_step_cost",
-    "step_efficiency",
     "validate_chrome_trace",
 ]

@@ -302,6 +302,10 @@ METRIC_CATALOG = (
     ("resilience_wasted_steps_total", "counter", "train steps redone after rollback"),
     # observability itself
     ("flight_recorder_dumps_total", "counter", "flight-recorder dumps written (labeled by reason)"),
+    # the process's compilations (observability/__init__.py: one
+    # jax.monitoring listener; a persistent-cache hit is not a compilation)
+    ("jax_backend_compiles_total", "counter", "XLA backend compilations in this process"),
+    ("jax_backend_compile_seconds_total", "counter", "seconds spent in XLA backend compilations"),
     # attention dispatch (ops/attention.py resolve_kernel_impl; trace time,
     # process-global registry)
     ("attention_reference_fallbacks_total", "counter", "impl='auto' call sites compiled to the XLA reference on a TPU (labeled by op and reason)"),

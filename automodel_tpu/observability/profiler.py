@@ -1,21 +1,15 @@
-"""Profiling — step-windowed `jax.profiler` capture + compiled cost analysis.
+"""Profiling — step-windowed `jax.profiler` capture.
 
-Home of the former `utils/profiling.py` (train-path `Profiler`, kept
-API-compatible; `utils.profiling` remains as a deprecation shim) plus the
-serving-path additions:
-
+- `Profiler` — the train path's capture of a window of steps.
 - `ServeProfiler` — windowed `jax.profiler` capture for the serve loop,
   triggered either by a fixed step range (`profile_window: [start, n]` in
   the `serving.observability` config) or by a latency-spike predicate
   (`itl_spike_ms`): the first step whose measured device time crosses the
   threshold starts the capture, so the trace you get is the trace of the
-  anomaly, not of a lucky warm step.
-- `serve_step_cost` / `step_efficiency` — `compiled.cost_analysis()`
-  FLOPs/bytes for the engine's jitted step via AOT lowering (does NOT
-  touch the jit call cache, so compile-once assertions still hold),
-  joined with measured step wall time into achieved-FLOP/s and
-  bandwidth figures, and MFU / bandwidth-utilization when hardware peaks
-  are known.
+  anomaly, not of a lucky warm step. A capture holds the program's own
+  spans (`serve.step.upload`, `serve.frontend.intake`, ...: trace.py
+  mirrors every span into a running profiler session) beside the device's
+  ops, and each device op carries its `serve.*` named scope.
 """
 
 from __future__ import annotations
@@ -68,9 +62,6 @@ class Profiler:
             self.done = True
 
 
-annotate = jax.named_scope  # the NVTX-range analog for model code
-
-
 class ServeProfiler:
     """Serving-path windowed capture. One capture per run: either the
     fixed `window = (start_step, num_steps)` or the first step whose
@@ -117,48 +108,3 @@ class ServeProfiler:
     def close(self) -> None:
         if self._active:
             self._stop()
-
-
-def serve_step_cost(engine) -> dict | None:
-    """FLOPs/bytes of the engine's compiled serve step via AOT
-    `lower().compile().cost_analysis()`. AOT compilation is cached
-    separately from the jit call cache, so `step_cache_size()` (the
-    compile-once counter) is unaffected. Returns None when the backend
-    does not expose a cost model."""
-    try:
-        plan = engine.empty_plan()
-        lowered = engine.lower_step(plan)
-        cost = lowered.compile().cost_analysis()
-        if not cost:
-            return None
-        return {
-            "flops": float(cost.get("flops", 0.0)),
-            "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
-        }
-    except Exception as e:  # pragma: no cover - backend-dependent
-        logger.debug("serve step cost analysis unavailable: %s", e)
-        return None
-
-
-def step_efficiency(cost: dict | None, step_s: float, *,
-                    peak_flops: float | None = None,
-                    peak_bytes_per_s: float | None = None) -> dict:
-    """Join static cost with one measured step time. Achieved rates are
-    always reported; MFU / bandwidth-utilization only when the hardware
-    peaks are known (None on CPU fallback runs)."""
-    out = {"step_ms": step_s * 1e3}
-    if not cost or step_s <= 0:
-        return out
-    gflops_s = cost["flops"] / step_s / 1e9
-    gbytes_s = cost["bytes_accessed"] / step_s / 1e9
-    out.update({
-        "flops_per_step": cost["flops"],
-        "bytes_per_step": cost["bytes_accessed"],
-        "achieved_gflops_per_s": gflops_s,
-        "achieved_gbytes_per_s": gbytes_s,
-    })
-    if peak_flops:
-        out["mfu"] = cost["flops"] / step_s / peak_flops
-    if peak_bytes_per_s:
-        out["bw_util"] = cost["bytes_accessed"] / step_s / peak_bytes_per_s
-    return out

@@ -1,13 +1,25 @@
 """Span/event tracer for the serving stack — host-side, dual-clock.
 
-Every event carries BOTH clocks: wall time (`time.perf_counter`, exported
-in microseconds for Perfetto) and the engine step clock (`step`), because
+One tracer, two sinks. A span ALWAYS opens a `jax.profiler.TraceAnnotation`
+named `serve.<name>` for its lifetime (stats: `engine_step` and the span's
+int args), so whenever a profiler session is running — the benchmark's
+`--trace 1`, `ServeProfiler`'s window or spike capture, a hand-started
+trace — the program's spans lie in the `/host:CPU` plane on the profiler's
+own clock, beside the device's ops. `ObservabilityConfig.enabled` only
+decides whether the second sink, the in-memory buffer below (exports,
+flight recorder, timelines), is kept. Instants are not mirrored
+(`request.commit` fires dozens of times a step).
+
+Every in-memory event carries BOTH clocks: wall time (`time.perf_counter`,
+exported in microseconds for Perfetto) and a step clock (`step`), because
 serving questions come in both flavors — "how many milliseconds did the
 KV handoff take" and "how many steps did this request wait in the
-admission queue". Spans (`ph == "X"`) time engine phases (host plan build
-vs device step vs absorb, KV transfers); instants (`ph == "i"`) mark
-request lifecycle transitions (submit → admit → first_token → commit →
-done/shed/cancel/expire).
+admission queue". Spans (`ph == "X"`) time engine phases (intake, plan,
+upload, dispatch, read-back, absorb, emit, KV transfers) and are stamped
+with the ENGINE's step (`engine.steps_run`), so one turn's spans join on
+it; instants (`ph == "i"`) mark request lifecycle transitions (submit →
+admit → first_token → commit → done/shed/cancel/expire) on their track's
+own clock.
 
 Three export faces:
 
@@ -33,6 +45,11 @@ from __future__ import annotations
 import hashlib
 import json
 import time
+
+from jax.profiler import TraceAnnotation
+
+#: prefix of every span's name in the profiler's trace
+PROFILER_PREFIX = "serve."
 
 
 class TraceEvent:
@@ -61,10 +78,17 @@ class TraceEvent:
         return d
 
 
-class _SpanCtx:
-    """Reusable-shape context manager: records one X event on exit."""
+def _annotation(name, step, args):
+    """The profiler-side sink of one span (span args are ints)."""
+    return TraceAnnotation(PROFILER_PREFIX + name, engine_step=step, **args)
 
-    __slots__ = ("_tr", "_name", "_track", "_step", "_rid", "_args", "_t0")
+
+class _SpanCtx:
+    """One span into both sinks: the profiler's annotation for its
+    lifetime, one X event in the tracer's buffer on exit."""
+
+    __slots__ = ("_tr", "_name", "_track", "_step", "_rid", "_args", "_t0",
+                 "_ann")
 
     def __init__(self, tr, name, track, step, rid, args):
         self._tr = tr
@@ -74,10 +98,18 @@ class _SpanCtx:
         self._rid = rid
         self._args = args
         self._t0 = 0.0
+        self._ann = _annotation(name, step, args)
 
     def __enter__(self):
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def set_metadata(self, **args):
+        """Args known only once the span's work is done (the rows of the
+        plan `step.plan` returned); same name as `TraceAnnotation`'s."""
+        self._args.update(args)
+        self._ann.set_metadata(**args)
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
@@ -85,27 +117,15 @@ class _SpanCtx:
             self._name, "X", self._t0, t1 - self._t0,
             self._step, self._track, self._rid, self._args,
         ))
-        return False
-
-
-class _NullCtx:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CTX = _NullCtx()
+        return self._ann.__exit__(*exc)
 
 
 class NullTracer:
-    """The disabled tracer: every call is a constant-time no-op, so the
-    instrumented hot loops cost two attribute lookups when tracing is off
-    and the serve-step HLO stays byte-identical (nothing device-side ever
-    depends on tracing either way)."""
+    """The tracer without a buffer: an instant is a no-op and a span is
+    the profiler's annotation alone (well under a microsecond when no
+    profiler session is running), so the instrumented hot loops cost a
+    few microseconds a step when tracing is off and nothing device-side
+    ever depends on tracing either way."""
 
     enabled = False
     events = ()
@@ -114,7 +134,7 @@ class NullTracer:
         pass
 
     def span(self, name, *, track="engine", step=-1, rid=-1, **args):
-        return _NULL_CTX
+        return _annotation(name, step, args)
 
 
 NULL_TRACER = NullTracer()

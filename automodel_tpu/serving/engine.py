@@ -59,6 +59,7 @@ through loggers/metric_logger.MetricLogger when one is passed).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 
 import jax
@@ -68,7 +69,6 @@ import numpy as np
 from automodel_tpu.inference.generate import (
     _dense_mlp,
     _embed,
-    _moe_mlp,
     mla_absorbed_inputs,
 )
 from automodel_tpu.inference.sampling import filter_logits
@@ -275,13 +275,12 @@ class ServingEngine:
         # through PR 1's EP shard_map machinery (dropless dispatch + expert
         # A2A INSIDE the step) instead of the single-shard dropless path.
         if self.is_moe:
-            moe_fn = _moe_mlp
-            if mesh_ctx is not None and mesh_ctx.sizes["ep"] > 1:
-                moe_fn = self._moe_mlp_ep
             self._stacks = []
             if cfg.first_k_dense > 0:
                 self._stacks.append(("dense_layers", _dense_mlp, cfg.first_k_dense))
-            self._stacks.append(("moe_layers", moe_fn, cfg.num_moe_layers))
+            self._stacks.append(
+                ("moe_layers", self._moe_mlp, cfg.num_moe_layers)
+            )
         else:
             L = jax.tree.leaves(self.params["layers"])[0].shape[0]
             self._stacks = [("layers", _dense_mlp, L)]
@@ -497,19 +496,23 @@ class ServingEngine:
             for stack in pool
         ]
 
-    def _moe_mlp_ep(self, h, lp, cfg):
-        """MoE block under ep>1: PR 1's dropless EP dispatch (sort + ragged
-        GEMM + expert A2A confined to this step) via the shard_map wrapper —
-        the flat token batch shards over ep, expert weights enter sharded on
-        ep only. Routing is deterministic in the logits, so EP changes
-        where experts run, never which tokens they see."""
+    def _moe_mlp(self, h, lp, cfg):
+        """MoE block of the step. Dropless dispatch always: the capacity
+        dispatcher's bound depends on the token population, so a
+        capacity-trained config would drop differently at serve time;
+        dropless is exact for any population. Under ep>1 that is PR 1's
+        EP dispatch (sort + ragged GEMM + expert A2A confined to this
+        step) via the shard_map wrapper — the flat token batch shards over
+        ep, expert weights enter sharded on ep only. Routing is
+        deterministic in the logits, so EP changes where experts run,
+        never which tokens they see."""
         from automodel_tpu.moe.layer import moe_forward
 
         moe_cfg = dataclasses.replace(cfg.moe, dispatcher="dropless")
         x = rms_norm(h, lp["post_attn_norm"]["scale"], cfg.rms_norm_eps,
                      cfg.zero_centered_norm)
         moe_out, _aux, _stats = moe_forward(
-            lp["moe"], moe_cfg, x, mesh_ctx=self._mesh
+            lp["moe"], moe_cfg, x, mesh_ctx=self._mesh, scope="serve.moe"
         )
         return h + moe_out
 
@@ -542,19 +545,21 @@ class ServingEngine:
                 pool_k, pool_v, s_c, s_kr = cache
                 qc, c_rows = quantize_kv_rows(c_kv[0])
                 qkr, kr_rows = quantize_kv_rows(k_rope[0])
-                pool_k = pool_k.at[b["page"], b["off"]].set(qc)
-                pool_v = pool_v.at[b["page"], b["off"]].set(qkr)
-                s_c = s_c.at[b["page"], b["off"]].set(c_rows)
-                s_kr = s_kr.at[b["page"], b["off"]].set(kr_rows)
+                with jax.named_scope("serve.pool_write"):
+                    pool_k = pool_k.at[b["page"], b["off"]].set(qc)
+                    pool_v = pool_v.at[b["page"], b["off"]].set(qkr)
+                    s_c = s_c.at[b["page"], b["off"]].set(c_rows)
+                    s_kr = s_kr.at[b["page"], b["off"]].set(kr_rows)
                 scales_kw = dict(c_scales=s_c, kr_scales=s_kr)
             else:
                 pool_k, pool_v = cache
-                pool_k = pool_k.at[b["page"], b["off"]].set(
-                    c_kv[0].astype(pool_k.dtype)
-                )
-                pool_v = pool_v.at[b["page"], b["off"]].set(
-                    k_rope[0].astype(pool_v.dtype)
-                )
+                with jax.named_scope("serve.pool_write"):
+                    pool_k = pool_k.at[b["page"], b["off"]].set(
+                        c_kv[0].astype(pool_k.dtype)
+                    )
+                    pool_v = pool_v.at[b["page"], b["off"]].set(
+                        k_rope[0].astype(pool_v.dtype)
+                    )
             scale = (
                 cfg.attn_scale if cfg.attn_scale is not None
                 else (dn + dr) ** -0.5
@@ -577,19 +582,21 @@ class ServingEngine:
             pool_k, pool_v, s_k, s_v = cache
             qk, k_rows = quantize_kv_rows(k[0])
             qv, v_rows = quantize_kv_rows(v[0])
-            pool_k = pool_k.at[b["page"], b["off"]].set(qk)
-            pool_v = pool_v.at[b["page"], b["off"]].set(qv)
-            s_k = s_k.at[b["page"], b["off"]].set(k_rows)
-            s_v = s_v.at[b["page"], b["off"]].set(v_rows)
+            with jax.named_scope("serve.pool_write"):
+                pool_k = pool_k.at[b["page"], b["off"]].set(qk)
+                pool_v = pool_v.at[b["page"], b["off"]].set(qv)
+                s_k = s_k.at[b["page"], b["off"]].set(k_rows)
+                s_v = s_v.at[b["page"], b["off"]].set(v_rows)
             scales_kw = dict(k_scales=s_k, v_scales=s_v)
         else:
             pool_k, pool_v = cache
-            pool_k = pool_k.at[b["page"], b["off"]].set(
-                k[0].astype(pool_k.dtype)
-            )
-            pool_v = pool_v.at[b["page"], b["off"]].set(
-                v[0].astype(pool_v.dtype)
-            )
+            with jax.named_scope("serve.pool_write"):
+                pool_k = pool_k.at[b["page"], b["off"]].set(
+                    k[0].astype(pool_k.dtype)
+                )
+                pool_v = pool_v.at[b["page"], b["off"]].set(
+                    v[0].astype(pool_v.dtype)
+                )
         scale = (
             cfg.attn_scale if cfg.attn_scale is not None
             else cfg.resolved_head_dim ** -0.5
@@ -613,7 +620,12 @@ class ServingEngine:
         return h + attn_out, (pool_k, pool_v)
 
     def _step_impl(self, params, pool, b):
-        cfg, sc = self.cfg, self.serve_cfg
+        cfg = self.cfg
+        # The serve.* named scopes are metadata only: they name each op's
+        # sublayer in a profiler trace and add no instruction. An op under
+        # serve.layers and under none of its sublayers is the scan's own
+        # slicing of the stacked operands and its write-back.
+        #
         # per-token page-table rows: pads index slot 0's table but their
         # position is -1, so they attend to nothing
         b = dict(b)
@@ -621,35 +633,48 @@ class ServingEngine:
         # copy-on-write splits first (≤ 1 per slot; idle entries copy the
         # trash page onto itself): a slot about to append into a page some
         # other table or the radix tree still reads gets a private copy
-        pool = jax.tree.map(
-            lambda a: a.at[:, b["cow_dst"]].set(a[:, b["cow_src"]]), pool
-        )
+        with jax.named_scope("serve.cow"):
+            pool = jax.tree.map(
+                lambda a: a.at[:, b["cow_dst"]].set(a[:, b["cow_src"]]), pool
+            )
         # under a mesh: pool pinned to its pages-global / heads-sharded
         # layout through the COW block and the scans; hidden replicated so
         # every tp reduction lives inside the layer stack (no-ops off-mesh)
         pool = self._constrain_pool(pool)
-        h = _embed(params, cfg, b["tok"][None])  # (1, T, H)
+        with jax.named_scope("serve.embed"):
+            h = _embed(params, cfg, b["tok"][None])  # (1, T, H)
         h = self._constrain_rep(h)
 
         new_pool = []
         for (pkey, mlp_fn, L), stack, wins in zip(
             self._stacks, pool, self._stack_windows
         ):
-            def one_layer(carry, xs, mlp_fn=mlp_fn):
+            mlp_scope = "serve.mlp" if mlp_fn is _dense_mlp else "serve.moe"
+
+            def one_layer(carry, xs, mlp_fn=mlp_fn, mlp_scope=mlp_scope):
                 (h,) = carry
                 lp, cache, win = xs
-                h, cache = self._attn(h, lp, win, cache, b)
-                h = mlp_fn(h, lp, cfg)
+                with jax.named_scope("serve.attn"):
+                    h, cache = self._attn(h, lp, win, cache, b)
+                with jax.named_scope(mlp_scope):
+                    h = mlp_fn(h, lp, cfg)
                 return (self._constrain_rep(h),), cache
 
             # the stack's cache arrays ((k, v) fp, (k, v, sk, sv) int8)
             # scan over their shared layer axis alongside the params
-            (h,), stack = jax.lax.scan(
-                one_layer, (h,), (params[pkey], tuple(stack), wins)
-            )
+            with jax.named_scope("serve.layers"):
+                (h,), stack = jax.lax.scan(
+                    one_layer, (h,), (params[pkey], tuple(stack), wins)
+                )
             new_pool.append(stack)
         new_pool = self._constrain_pool(new_pool)
 
+        with jax.named_scope("serve.head"):
+            return self._head(params, new_pool, h, b)
+
+    def _head(self, params, new_pool, h, b):
+        """Final norm, unembed of the sample rows, sampling, log-softmax."""
+        cfg = self.cfg
         h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps,
                      cfg.zero_centered_norm)
         if self._spec is not None:
@@ -817,22 +842,29 @@ class ServingEngine:
         reg.counter("serve_steps_total").inc()
         reg.counter("serve_plan_tokens_total").inc(plan.n_tokens)
         reg.counter("serve_plan_samples_total").inc(plan.n_samples)
-        with self.obs.tracer.span(
-            "step.run", track=self.track, step=self.steps_run,
-            n_tokens=plan.n_tokens, n_samples=plan.n_samples,
-        ):
-            batch = self._plan_batch(plan)
+        # step.run's three children split what the host does around the
+        # device's step: the plan's uploads, the enqueue of the jitted
+        # step (a compile lands here), and the read-back, which blocks
+        # until the device is done. All four carry this step's number.
+        span = functools.partial(
+            self.obs.tracer.span, track=self.track, step=self.steps_run
+        )
+        with span("step.run", rows=plan.n_tokens, samples=plan.n_samples):
+            with span("step.upload"):
+                batch = self._plan_batch(plan)
             # the StepPlan upload above is the ONE sanctioned host→device
             # copy per step; with guard_transfers the step invocation runs
             # under transfer_guard("disallow") so any other transfer raises
-            if self.serve_cfg.guard_transfers:
-                with jax.transfer_guard("disallow"):
+            with span("step.dispatch"):
+                if self.serve_cfg.guard_transfers:
+                    with jax.transfer_guard("disallow"):
+                        out = self._step(self.params, self.pool, batch)
+                else:
                     out = self._step(self.params, self.pool, batch)
-            else:
-                out = self._step(self.params, self.pool, batch)
             self.pool = out[0]
             self.steps_run += 1
-            return tuple(np.asarray(x) for x in out[1:])
+            with span("step.readback"):
+                return tuple(np.asarray(x) for x in out[1:])
 
     def empty_plan(self) -> StepPlan:
         """A zero-work StepPlan with the engine's fixed shapes — shape
@@ -876,12 +908,13 @@ class ServingEngine:
         timing covers run_step ONLY (upload + jitted step + readback),
         not the host-side scheduler bookkeeping, so latency counters stay
         comparable with the pre-router serve loop's."""
+        step = self.steps_run  # what run_step stamps on this turn's spans
         t0 = time.perf_counter()
         out = self.run_step(plan)
         dt = time.perf_counter() - t0
         self.obs.observe_step(self.steps_run, dt * 1e3)
         with self.obs.tracer.span(
-            "step.absorb", track=self.track, step=self.steps_run
+            "step.absorb", track=self.track, step=step
         ):
             n_new = self.absorb_outputs(sched, plan, out, step_idx)
         return n_new, dt
@@ -919,8 +952,10 @@ class ServingEngine:
         with live admission between calls."""
         with self.obs.tracer.span(
             "step.plan", track=self.track, step=self.steps_run
-        ):
+        ) as span:
             plan = sched.schedule(step_idx)
+            if plan is not None:
+                span.set_metadata(rows=plan.n_tokens, samples=plan.n_samples)
         if plan is None:
             return None, 0, 0.0
         n_new, dt = self.run_and_absorb(sched, plan, step_idx)

@@ -377,16 +377,30 @@ class OnlineFrontend:
     async def _drive(self) -> None:
         loop = asyncio.get_running_loop()
         while True:
-            self._apply_cancels()
-            self._drain_arrivals()
-            self._shed_waiting()
-            if self._closed:
-                if not self.cfg.drain:
-                    self._abort_resident()
-                if not self.sched.has_work:
-                    break
-            self._apply_backpressure()
-            plan = self.sched.schedule(self.step_idx)
+            # every span of a turn carries the number `engine.run_step`
+            # will stamp on this turn's `step.run` (idle turns advance
+            # `step_idx` but not it), and none is held across an `await`:
+            # other coroutines run on this thread meanwhile
+            span = functools.partial(
+                self.obs.tracer.span, track=self.name,
+                step=self.engine.steps_run,
+            )
+            with span("frontend.intake"):
+                self._apply_cancels()
+                self._drain_arrivals()
+                self._shed_waiting()
+                if self._closed:
+                    if not self.cfg.drain:
+                        self._abort_resident()
+                    if not self.sched.has_work:
+                        break
+                self._apply_backpressure()
+            with span("step.plan") as plan_span:
+                plan = self.sched.schedule(self.step_idx)
+                if plan is not None:
+                    plan_span.set_metadata(
+                        rows=plan.n_tokens, samples=plan.n_samples
+                    )
             if plan is None:
                 # deadline expiry inside schedule() may have evicted work
                 self._emit()
@@ -437,9 +451,10 @@ class OnlineFrontend:
             dt = time.perf_counter() - t0
             self.obs.observe_step(self.step_idx, dt * 1e3)
             self._sha.update(np.ascontiguousarray(out[0]).tobytes())
-            n_new = self.engine.absorb_outputs(
-                self.sched, plan, out, self.step_idx
-            )
+            with span("step.absorb"):
+                n_new = self.engine.absorb_outputs(
+                    self.sched, plan, out, self.step_idx
+                )
             self.steps_run += 1
             if n_new:
                 itl = dt / n_new
@@ -451,8 +466,9 @@ class OnlineFrontend:
                     itl if self.itl_ewma_s is None
                     else d * self.itl_ewma_s + (1 - d) * itl
                 )
-            self._emit()
-            self._advance()
+            with span("frontend.emit"):
+                self._emit()
+                self._advance()
 
     def _advance(self) -> None:
         self.step_idx += 1

@@ -4,8 +4,9 @@ A chip machine starts cold and compiling is a large part of a cold run, so
 `cli/app.py:main`, `chip_smoke.py` and `bench.py` each call
 `enable_compile_cache()` once, before anything compiles. The cache's path
 is part of its key, so it never moves: where `JAX_COMPILATION_CACHE_DIR`
-is set JAX reads it itself and this module sets nothing; otherwise the
-cache lives in `.jax_cache/` at the root of the checkout.
+is set JAX reads it itself and this module sets no other; otherwise the
+cache lives in `.jax_cache/` at the root of the checkout. Either way the
+key includes each op's metadata (see below).
 """
 
 from __future__ import annotations
@@ -37,6 +38,14 @@ def enable_compile_cache() -> str | None:
     """
     if _pinned_to_cpu():
         return None
+    # JAX leaves an op's metadata (its `jax.named_scope` path among it) out
+    # of the cache key by default, so a program compiled before a scope was
+    # added would be served from the cache to the code that added it, the
+    # scope gone from every profiler trace: the serve step's `serve.*`
+    # scopes are what its per-layer readings are cut by. The price: an edit
+    # that moves a traced line (source locations are metadata too)
+    # recompiles the programs traced through it, once.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

@@ -9,9 +9,15 @@
 - serving integration: tracing ON changes neither the greedy token stream
   nor the compile count; two identical online load_test runs produce the
   SAME digest; disagg TTFT attribution components sum to the measured
-  TTFT exactly; an injected serve-step crash dumps the flight recorder.
+  TTFT exactly; an injected serve-step crash dumps the flight recorder;
+- the two sinks: under a profiler session the online loop's spans lie in
+  the profiler's own trace whether or not the in-memory buffer is kept,
+  joined per turn by `engine_step`; the jitted step names its sublayers
+  (`serve.*` scopes) without one more instruction; the process's
+  compilations are counted.
 """
 
+import asyncio
 import json
 import os
 
@@ -43,7 +49,7 @@ from automodel_tpu.serving import (
     ServingConfig,
     ServingEngine,
 )
-from automodel_tpu.serving.frontend import FrontendConfig
+from automodel_tpu.serving.frontend import FrontendConfig, OnlineFrontend
 from automodel_tpu.serving.load_test import LoadTestConfig, run_load_test
 
 CFG = TransformerConfig(
@@ -187,9 +193,18 @@ def test_flight_ring_is_bounded():
 def test_null_tracer_is_inert():
     assert NULL_TRACER.events == ()
     NULL_TRACER.instant("request.submit", rid=0)
-    with NULL_TRACER.span("step.run", step=3):
-        pass
+    with NULL_TRACER.span("step.run", step=3) as span:
+        span.set_metadata(rows=8)  # the one span API, buffer or none
     assert NULL_TRACER.events == ()
+
+
+def test_span_metadata_set_inside_lands_in_the_event():
+    tr = Tracer()
+    with tr.span("step.plan", step=4) as span:
+        span.set_metadata(rows=7, samples=2)
+    (ev,) = tr.events
+    assert (ev.name, ev.step, ev.args) == ("step.plan", 4,
+                                           {"rows": 7, "samples": 2})
 
 
 def test_digest_excludes_timing_and_stream_edges():
@@ -219,7 +234,8 @@ def test_tracing_on_off_parity_and_compile_once(params):
     neither the greedy token stream nor the number of compiled step
     signatures, and the trace actually recorded the run."""
     reqs = lambda: _reqs([5, 9, 3], seed0=10)  # noqa: E731
-    base = ServingEngine(params, CFG, _sc()).serve_batch(reqs())
+    base_eng = ServingEngine(params, CFG, _sc())
+    base = base_eng.serve_batch(reqs())
     sc = _sc(observability=ObservabilityConfig(enabled=True))
     eng = ServingEngine(params, CFG, sc)
     res = eng.serve_batch(reqs())
@@ -227,8 +243,27 @@ def test_tracing_on_off_parity_and_compile_once(params):
     assert res["stats"]["compiled_signatures"] == 1
     assert base["stats"]["compiled_signatures"] == 1
     names = {e.name for e in eng.obs.tracer.events}
-    assert {"step.plan", "step.run", "step.absorb", "request.submit",
+    assert {"step.plan", "step.run", "step.upload", "step.dispatch",
+            "step.readback", "step.absorb", "request.submit",
             "request.admit", "request.first_token", "request.done"} <= names
+    # one step clock: every span of a turn carries the number its
+    # step.run carries, and step.run's children nest inside it
+    spans = [e for e in eng.obs.tracer.events if e.ph == "X"]
+    runs = {e.step: e for e in spans if e.name == "step.run"}
+    assert sorted(runs) == list(range(eng.steps_run))
+    for name in ("step.absorb", "step.upload", "step.dispatch",
+                 "step.readback"):
+        assert sorted(e.step for e in spans if e.name == name) == sorted(runs)
+    # (a turn that could plan nothing has a step.plan without rows)
+    assert sorted(e.step for e in spans if e.name == "step.plan"
+                  and "rows" in e.args) == sorted(runs)
+    for e in spans:
+        if e.name in ("step.upload", "step.dispatch", "step.readback"):
+            run = runs[e.step]
+            assert run.ts <= e.ts and e.ts + e.dur <= run.ts + run.dur
+    assert all(set(r.args) == {"rows", "samples"} for r in runs.values())
+    # with neither sink nothing is recorded
+    assert base_eng.obs.tracer is NULL_TRACER and NULL_TRACER.events == ()
     reg = eng.obs.registry.snapshot()
     assert reg["serve_steps_total"] > 0
     assert reg["serve_new_tokens_total"] == sum(
@@ -337,3 +372,185 @@ def test_observability_export_writes_both_faces(tmp_path):
     assert len(open(paths["jsonl"]).read().splitlines()) == 2
     # disabled bundles export nothing
     assert Observability(None).export(str(tmp_path / "x")) == {}
+
+
+# -- the two sinks: profiler session and in-memory buffer --------------------
+
+TURN_SPANS = ("frontend.intake", "step.plan", "step.absorb", "frontend.emit")
+RUN_SPANS = ("step.upload", "step.dispatch", "step.readback")
+
+
+def _online(eng, lens=(5, 9, 3), max_new=6):
+    """Drive a tiny OnlineFrontend over `eng`; returns each request's tokens."""
+
+    async def main():
+        fe = OnlineFrontend(eng, FrontendConfig(idle_sleep_s=0.0002))
+        fe.start()
+
+        async def one(req):
+            return [t async for t in fe.submit(req)]
+
+        outs = await asyncio.gather(
+            *[one(r) for r in _reqs(lens, seed0=90, max_new=max_new)])
+        await fe.close()
+        return outs
+
+    return asyncio.run(main())
+
+
+def _profiled(tmp_path, fn):
+    """Run `fn` under a profiler session; returns (its result, the
+    `serve.*` host events as (name, line, start_ns, end_ns, stats))."""
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = list(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    events, n_line = [], 0
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for ln in plane.lines:
+            n_line += 1
+            events += [
+                (e.name, n_line, e.start_ns, e.start_ns + e.duration_ns,
+                 dict(e.stats))
+                for e in ln.events if e.name.startswith("serve.")]
+    return out, events
+
+
+@pytest.fixture(scope="module")
+def online_untraced(params, tmp_path_factory):
+    """The online loop under a profiler session, observability disabled."""
+    eng = ServingEngine(params, CFG, _sc())
+    outs, events = _profiled(
+        tmp_path_factory.mktemp("prof_off"), lambda: _online(eng))
+    return eng, outs, events
+
+
+def test_online_spans_reach_the_profiler_with_observability_off(online_untraced):
+    """Per engine step: one serve.step.run with upload / dispatch / readback
+    nested in it on the executor thread's line, and the frontend's four
+    spans on the loop's line, all with that step's `engine_step`."""
+    eng, _outs, events = online_untraced
+    assert eng.obs.tracer is NULL_TRACER and eng.steps_run > 3
+    by_step = {}
+    for name, line, t0, t1, stats in events:
+        by_step.setdefault(stats["engine_step"], []).append(
+            (name[len("serve."):], line, t0, t1, stats))
+    for step in range(eng.steps_run):
+        evs = by_step[step]
+        (run,) = [e for e in evs if e[0] == "step.run"]
+        assert set(run[4]) == {"engine_step", "rows", "samples"}
+        for name in RUN_SPANS:
+            (child,) = [e for e in evs if e[0] == name]
+            assert child[1] == run[1], "same thread line"
+            assert run[2] <= child[2] and child[3] <= run[3], name
+        # the turn that planned this step (idle turns plan nothing and
+        # carry no rows), and the one absorb / emit after it
+        loop_lines = set()
+        for name in TURN_SPANS:
+            mine = [e for e in evs if e[0] == name]
+            assert mine, name
+            loop_lines |= {e[1] for e in mine}
+        assert len(loop_lines) == 1 and run[1] not in loop_lines
+        (plan,) = [e for e in evs if e[0] == "step.plan" and "rows" in e[4]]
+        assert plan[4]["rows"] == run[4]["rows"]
+        assert plan[3] <= run[2], "planned before it ran"
+        (absorb,) = [e for e in evs if e[0] == "step.absorb"]
+        assert run[3] <= absorb[2], "absorbed after it ran"
+    # no instant is mirrored: a commit a token would swamp the trace
+    assert not [e for e in events if e[0].startswith("serve.request.")]
+
+
+def test_online_spans_with_observability_on_match_and_validate(
+        params, online_untraced, tmp_path):
+    """The same run with the buffer kept: the same spans in Tracer.events
+    AND in the profiler's trace, a valid Chrome export (no span is held
+    across an await, so each track nests), and the same greedy tokens."""
+    _eng, base_outs, base_events = online_untraced
+    eng = ServingEngine(
+        params, CFG, _sc(observability=ObservabilityConfig(enabled=True)))
+    outs, events = _profiled(tmp_path / "prof", lambda: _online(eng))
+    assert outs == base_outs
+    assert eng.step_cache_size() == 1
+    spans = [e for e in eng.obs.tracer.events if e.ph == "X"]
+    in_memory = sorted((e.name, e.step) for e in spans)
+    in_profile = sorted((n[len("serve."):], st["engine_step"])
+                        for n, _l, _a, _b, st in events)
+    assert in_memory == in_profile
+    # the untraced run made the same spans, step for step (idle turns, whose
+    # number the wall clock decides, add intake and plan spans only)
+    per_step = {"serve." + n for n in
+                RUN_SPANS + ("step.run", "step.absorb", "frontend.emit")}
+    assert sorted((n, st["engine_step"]) for n, _l, _a, _b, st in base_events
+                  if n in per_step) == sorted(
+        (n, st["engine_step"]) for n, _l, _a, _b, st in events
+        if n in per_step)
+    for name in TURN_SPANS + RUN_SPANS + ("step.run",):
+        assert any(e.name == name for e in spans), name
+    chrome = tmp_path / "online.trace.json"
+    eng.obs.tracer.export_chrome(str(chrome))
+    stats = validate_chrome_trace(str(chrome))
+    assert stats["spans"] == len(spans) and stats["tracks"] == 2
+
+
+def test_serve_step_names_its_sublayers_and_keeps_its_instructions():
+    """The compiled step carries the serve.* scopes as metadata only: the
+    baseline's instruction counts hold with them in."""
+    from automodel_tpu.analysis import compare_report, load_baseline
+    from automodel_tpu.analysis.entrypoints import ENTRY_POINTS, _configs
+    from automodel_tpu.analysis.hlo import analyze_compiled
+    from automodel_tpu.models.moe_lm import decoder as moe_decoder
+
+    compiled, mesh_axes = ENTRY_POINTS["paged_serve_step"]()
+    text = compiled.as_text()
+    for scope in ("serve.cow", "serve.embed", "serve.layers", "serve.attn",
+                  "serve.pool_write", "serve.mlp", "serve.head"):
+        assert f"/{scope}/" in text, scope
+    baselines = os.path.join(
+        os.path.dirname(__file__), "..", "..", "automodel_tpu", "analysis",
+        "baselines")
+    report = analyze_compiled(compiled, entry="paged_serve_step",
+                              mesh_axes=mesh_axes)
+    assert compare_report(
+        report, load_baseline(baselines, "paged_serve_step")) == []
+
+    _dense, moe_cfg = _configs()
+    eng = ServingEngine(
+        moe_decoder.init(moe_cfg, jax.random.key(0)), moe_cfg, _sc())
+    text = eng.lower_step().compile().as_text()
+    for scope in ("serve.layers", "serve.attn", "serve.moe/serve.moe.route",
+                  "serve.moe/serve.moe.experts", "serve.moe/serve.moe.shared",
+                  "serve.head"):
+        assert f"/{scope}/" in text, scope
+    assert "serve.mlp" not in text  # first_k_dense == 0: no dense stack
+
+
+def test_compile_counter_rises_once_for_the_step(params):
+    """`jax_backend_compiles_total` sees the step's one compilation over
+    the first step and none between the second and the tenth."""
+    eng = ServingEngine(params, CFG, _sc())
+    sched = eng.make_scheduler(arrival_gating=False)
+    for r in _reqs([6, 7, 5], seed0=110, max_new=12):
+        sched.submit(r)
+    compiles = lambda: eng.obs.registry.snapshot().get(  # noqa: E731
+        "jax_backend_compiles_total", 0.0)
+    before = compiles()
+    eng.run_one_step(sched, 0)
+    after_first = compiles()
+    assert after_first >= before + 1
+    seconds = eng.obs.registry.snapshot()["jax_backend_compile_seconds_total"]
+    assert seconds > 0.0
+    eng.run_one_step(sched, 1)
+    after_second = compiles()
+    for i in range(2, 10):
+        plan, _n, _dt = eng.run_one_step(sched, i)
+        assert plan is not None
+    assert compiles() == after_second
+    assert eng.step_cache_size() == 1
