@@ -149,9 +149,12 @@ def ep_moe_forward():
 
 def paged_serve_step():
     """The serving engine's jitted step: paged-pool reads stay gathers,
-    pool writes stay O(stacks) in-place updates, zero collectives on a
+    pool writes are in-place scatters into per-layer page arrays (no
+    dynamic-slice / dynamic-update-slice along a layer axis: the step walks
+    its layers over per-layer buffers), zero collectives on a
     single-process engine, and the pool donation must survive (the
-    aliasing table is part of the baseline). The prefix-hit path rides the
+    aliasing table, one entry per layer and page array, is part of the
+    baseline). The prefix-hit path rides the
     SAME program — COW is the bounded copy block pinned here."""
     import jax
     import jax.numpy as jnp

@@ -26,7 +26,12 @@
 - ops/paged_attention.py holds the ragged paged-attention op it runs on.
 """
 
-from automodel_tpu.serving.engine import Request, ServingConfig, ServingEngine
+from automodel_tpu.serving.engine import (
+    Request,
+    ServingConfig,
+    ServingEngine,
+    split_layer_stacks,
+)
 from automodel_tpu.serving.frontend import (
     DisaggOnlineFrontend,
     FrontendConfig,
@@ -108,5 +113,6 @@ __all__ = [
     "pack_plan",
     "pack_stop",
     "pool_identity_ok",
+    "split_layer_stacks",
     "unpack_plan",
 ]

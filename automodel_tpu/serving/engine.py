@@ -20,6 +20,11 @@ Layer math is shared with generate.py (project_qkv / mlp_inner / the MoE
 stack split); only attention differs — the ragged paged op from
 ops/paged_attention.py (XLA gather reference on CPU, Pallas kernel on TPU),
 with the MLA absorbed-decode algebra reproduced over the latent page pool.
+The layers are walked differently too: generate.py scans stacked arrays, the
+step loops in Python over PER-LAYER buffers (split_layer_stacks below splits
+the weights once, at construction; kv_pages.init_pool makes the pool per
+layer), because a scan over stacks copies every layer's operands out of them
+on every step.
 
 Sampling runs inside the jit: greedy where a slot's temperature <= 0, else
 top-k/top-p (static, engine-wide) filtered categorical with the key derived
@@ -92,7 +97,7 @@ from automodel_tpu.serving.kv_pages import (
     PageAllocator,
     apply_defrag,
     init_pool,
-    pool_axes,
+    pool_shardings,
 )
 from automodel_tpu.serving.prefix_cache import PrefixCache, PrefixCacheConfig
 from automodel_tpu.serving.scheduler import Request, Scheduler, StepPlan
@@ -199,6 +204,72 @@ def _resolve_ttft(watch: list) -> list:
     return still
 
 
+#: the keys of a decoder's parameter tree that hold its layers
+LAYER_STACKS = ("layers", "dense_layers", "moe_layers")
+
+
+@functools.partial(jax.jit, static_argnames=("dtype",))
+def _unstack(leaves, dtype):
+    """Per stacked leaf, one array per index of its leading (layer) axis,
+    floating leaves cast to `dtype` on the way."""
+    def one(leaf):
+        if jnp.issubdtype(leaf.dtype, jnp.floating):
+            leaf = leaf.astype(dtype)
+        return tuple(leaf[i] for i in range(leaf.shape[0]))
+
+    return [one(leaf) for leaf in leaves]
+
+
+def _batches(leaves, budget: int):
+    """`leaves` in order, cut into runs of at most `budget` bytes (a leaf
+    above it goes alone)."""
+    batch, held = [], 0
+    for leaf in leaves:
+        if batch and held + leaf.nbytes > budget:
+            yield batch
+            batch, held = [], 0
+        batch.append(leaf)
+        held += leaf.nbytes
+    if batch:
+        yield batch
+
+
+def split_layer_stacks(params: dict, dtype) -> dict:
+    """The parameter tree with every layer stack (`LAYER_STACKS`: a dict
+    whose leaves carry a leading layer axis) replaced by a tuple of per-layer
+    trees, floating leaves in `dtype`: the form the serve step reads, every
+    layer's weights a buffer of its own and an argument of the program, so
+    no layer is sliced out of a stack step after step.
+
+    CONSUMES the stacks it splits: the stacked leaves are split a batch at a
+    time (small leaves share a program; no batch holds more bytes than the
+    largest leaf) and their buffers deleted before the next batch's turn, so
+    the peak is one stacked leaf above the tree's own size. A second whole
+    copy does not fit beside a model that fills most of a chip. The caller's
+    tree shares those buffers and must not read its layer stacks afterwards.
+    A stack that is already a sequence of per-layer trees passes through
+    untouched, so one split tree can be handed to many engines."""
+    out = dict(params)
+    for key in LAYER_STACKS:
+        stack = params.get(key)
+        if not isinstance(stack, dict):
+            continue  # absent, or per layer already
+        leaves, treedef = jax.tree.flatten(stack)
+        num_layers = leaves[0].shape[0]
+        assert all(leaf.shape[0] == num_layers for leaf in leaves), key
+        parts = []  # per leaf, its per-layer arrays
+        for batch in _batches(leaves, max(leaf.nbytes for leaf in leaves)):
+            parts += jax.block_until_ready(_unstack(batch, dtype))
+            for leaf in batch:
+                if isinstance(leaf, jax.Array):
+                    leaf.delete()
+        out[key] = tuple(
+            treedef.unflatten([part[i] for part in parts])
+            for i in range(num_layers)
+        )
+    return out
+
+
 class ServingEngine:
     """Paged-cache continuous-batching engine for the generic decoder
     families (TransformerConfig / MoETransformerConfig, GQA or MLA). The
@@ -214,6 +285,18 @@ class ServingEngine:
         obs: Observability | None = None,
         track: str = "engine",
     ):
+        """The engine TAKES OWNERSHIP of `params`. A tree with stacked
+        layers (what `init` and a checkpoint give: `params["layers"]` a dict
+        whose leaves carry a leading layer axis) is split into per-layer
+        buffers by `split_layer_stacks`, which deletes each stacked buffer
+        as it goes; the caller's tree shares those buffers, so its layer
+        stacks are gone when this returns (hand over a copy to keep them).
+        That happens first, before the pool is allocated, so construction
+        never holds more than one stacked leaf above the steady state. A
+        tree that is split already (its stacks sequences of per-layer
+        trees) is used as it is and stays the caller's: the routers split
+        once and build every engine from the one tree. Either way
+        `self.params` is the per-layer tree, on the engine's mesh."""
         from automodel_tpu.models.moe_lm.het_moe import HetMoEConfig
 
         if isinstance(cfg, HetMoEConfig):
@@ -255,7 +338,11 @@ class ServingEngine:
         self._mesh = mesh_ctx
         if mesh_ctx is not None:
             self._validate_mesh(cfg, serve_cfg, mesh_ctx)
-        self.params = cast_params(params, cfg.dtype)
+        # the layer stacks are split first, before anything else is
+        # allocated: the split gives the stacked buffers up leaf by leaf
+        self.params = cast_params(
+            split_layer_stacks(params, cfg.dtype), cfg.dtype
+        )
         if mesh_ctx is not None:
             from automodel_tpu.parallel.sharding import logical_to_shardings
 
@@ -282,8 +369,9 @@ class ServingEngine:
                 ("moe_layers", self._moe_mlp, cfg.num_moe_layers)
             )
         else:
-            L = jax.tree.leaves(self.params["layers"])[0].shape[0]
-            self._stacks = [("layers", _dense_mlp, L)]
+            self._stacks = [
+                ("layers", _dense_mlp, len(self.params["layers"]))
+            ]
 
         n_layers = sum(L for *_, L in self._stacks)
         windows = [w or 0 for w in layer_windows(cfg, n_layers)]
@@ -294,7 +382,7 @@ class ServingEngine:
                 jnp.asarray(windows[off : off + L], jnp.int32)
             )
             off += L
-        # windows ride the layer scan as traced per-layer values, so one
+        # windows ride the layer loop as per-layer array values, so one
         # windowed layer hands every layer's `window` to the paged op — and
         # the op's dispatch rule (ops/paged_attention.py) then runs the XLA
         # reference for the whole model, as it does for sinks
@@ -315,7 +403,11 @@ class ServingEngine:
             serve_cfg.num_pages, serve_cfg.page_size,
             mesh_ctx=self._mesh, kv_cache_dtype=serve_cfg.kv_cache_dtype,
         )
-        self._pool_axes = pool_axes(cfg, serve_cfg.kv_cache_dtype)
+        # the pool's shardings, in the pool's own structure (mesh only)
+        self._pool_shardings = None if self._mesh is None else pool_shardings(
+            cfg, [L for *_, L in self._stacks], self._mesh,
+            serve_cfg.kv_cache_dtype,
+        )
         # ENGINE-LIFETIME prefix cache (SGLang-RadixAttention-style): with
         # the cache enabled, the refcounted allocator and the radix tree
         # are created ONCE here and threaded through every scheduler this
@@ -352,13 +444,8 @@ class ServingEngine:
             # pinned signature the SECOND step would see a "different"
             # pool sharding and recompile — breaking the compile-once
             # contract the cache-miss counter tests pin per replica
-            from automodel_tpu.serving.kv_pages import pool_shardings
-
             rep = self._mesh.replicated()
-            psh = pool_shardings(
-                cfg, [L for *_, L in self._stacks], self._mesh,
-                serve_cfg.kv_cache_dtype,
-            )
+            psh = self._pool_shardings
             batch_keys = [
                 "tok", "slot", "pos", "page", "off", "page_tables",
                 "sample_tok", "temp", "seed", "cow_src", "cow_dst",
@@ -449,24 +536,29 @@ class ServingEngine:
         else:
             from automodel_tpu.models.llm import decoder as mod
         specs = mod.param_specs(self.cfg)
-        if not self.is_mla:
-            return specs
 
         def _drop_heads(spec):
             return tuple(None if a == "heads" else a for a in spec)
 
-        for key in ("layers", "dense_layers", "moe_layers"):
+        def _is_spec(x):
+            return isinstance(x, tuple)
+
+        for key in LAYER_STACKS:
             ld = specs.get(key)
             if not ld:
                 continue
-            for name in ("q_proj", "q_up_proj", "o_proj"):
-                if name in ld:
-                    ld[name] = jax.tree.map(
-                        _drop_heads, ld[name],
-                        is_leaf=lambda x: isinstance(x, tuple),
-                    )
-            if "kv_up_proj" in ld:
-                ld["kv_up_proj"]["kernel"] = ("layers", "mla_latent", None)
+            if self.is_mla:
+                for name in ("q_proj", "q_up_proj", "o_proj"):
+                    if name in ld:
+                        ld[name] = jax.tree.map(
+                            _drop_heads, ld[name], is_leaf=_is_spec
+                        )
+                if "kv_up_proj" in ld:
+                    ld["kv_up_proj"]["kernel"] = ("layers", "mla_latent", None)
+            # the engine holds one tree per layer (split_layer_stacks): each
+            # gets the stack's specs less their leading "layers" axis
+            one = jax.tree.map(lambda sp: sp[1:], ld, is_leaf=_is_spec)
+            specs[key] = (one,) * len(self.params[key])
         return specs
 
     def _constrain_rep(self, x):
@@ -481,20 +573,11 @@ class ServingEngine:
         return jax.lax.with_sharding_constraint(x, self._mesh.replicated())
 
     def _constrain_pool(self, pool):
-        """Pin the per-stack pool arrays to their kv_pages.pool_axes layout
-        through the COW block and the layer scan (no-op off-mesh). Stacks
-        are tuples of 2 (fp) or 4 (int8 payloads + replicated per-page
-        scale arrays) — the axis tuples line up either way."""
+        """Pin every layer's page arrays to their kv_pages.pool_axes layout
+        through the COW block and the layer loop (no-op off-mesh)."""
         if self._mesh is None:
             return pool
-        shs = [self._mesh.sharding(*a) for a in self._pool_axes]
-        return [
-            tuple(
-                jax.lax.with_sharding_constraint(p, s)
-                for p, s in zip(stack, shs)
-            )
-            for stack in pool
-        ]
+        return jax.lax.with_sharding_constraint(pool, self._pool_shardings)
 
     def _moe_mlp(self, h, lp, cfg):
         """MoE block of the step. Dropless dispatch always: the capacity
@@ -519,7 +602,7 @@ class ServingEngine:
     # -- device step --------------------------------------------------------
     def _attn(self, h, lp, win, cache, b):
         """One attention sub-block over the paged pool; `cache` is one
-        layer's slice of a stack — (k, v) fp, or (k, v, k_scale, v_scale)
+        layer's page arrays — (k, v) fp, or (k, v, k_scale, v_scale)
         with kv_cache_dtype="int8", where new-token rows quantize IN-JIT at
         scatter time (ops/quant.quantize_kv_rows) and attention dequantizes
         behind the page gather. Returns (post-residual h, written cache).
@@ -621,10 +704,18 @@ class ServingEngine:
 
     def _step_impl(self, params, pool, b):
         cfg = self.cfg
+        # The layers are walked in a Python loop at trace time over
+        # per-layer buffers: each layer's weights (split_layer_stacks) and
+        # each layer's page arrays (kv_pages.init_pool) are arguments of the
+        # program in their own right, the page arrays donated and aliased to
+        # the outputs. So no instruction slices an operand out of a stack or
+        # writes one back along a layer axis, as a lax.scan over stacked
+        # operands made the compiler do for every layer of every step.
+        #
         # The serve.* named scopes are metadata only: they name each op's
-        # sublayer in a profiler trace and add no instruction. An op under
-        # serve.layers and under none of its sublayers is the scan's own
-        # slicing of the stacked operands and its write-back.
+        # sublayer in a profiler trace and add no instruction. What lies
+        # under serve.layers and under none of its sublayers is the
+        # hidden state's sharding constraint: nothing off-mesh.
         #
         # per-token page-table rows: pads index slot 0's table but their
         # position is -1, so they attend to nothing
@@ -635,10 +726,10 @@ class ServingEngine:
         # other table or the radix tree still reads gets a private copy
         with jax.named_scope("serve.cow"):
             pool = jax.tree.map(
-                lambda a: a.at[:, b["cow_dst"]].set(a[:, b["cow_src"]]), pool
+                lambda a: a.at[b["cow_dst"]].set(a[b["cow_src"]]), pool
             )
         # under a mesh: pool pinned to its pages-global / heads-sharded
-        # layout through the COW block and the scans; hidden replicated so
+        # layout through the COW block and the layers; hidden replicated so
         # every tp reduction lives inside the layer stack (no-ops off-mesh)
         pool = self._constrain_pool(pool)
         with jax.named_scope("serve.embed"):
@@ -646,27 +737,20 @@ class ServingEngine:
         h = self._constrain_rep(h)
 
         new_pool = []
-        for (pkey, mlp_fn, L), stack, wins in zip(
+        for (pkey, mlp_fn, _), stack, wins in zip(
             self._stacks, pool, self._stack_windows
         ):
             mlp_scope = "serve.mlp" if mlp_fn is _dense_mlp else "serve.moe"
-
-            def one_layer(carry, xs, mlp_fn=mlp_fn, mlp_scope=mlp_scope):
-                (h,) = carry
-                lp, cache, win = xs
-                with jax.named_scope("serve.attn"):
-                    h, cache = self._attn(h, lp, win, cache, b)
-                with jax.named_scope(mlp_scope):
-                    h = mlp_fn(h, lp, cfg)
-                return (self._constrain_rep(h),), cache
-
-            # the stack's cache arrays ((k, v) fp, (k, v, sk, sv) int8)
-            # scan over their shared layer axis alongside the params
+            new_stack = []
             with jax.named_scope("serve.layers"):
-                (h,), stack = jax.lax.scan(
-                    one_layer, (h,), (params[pkey], tuple(stack), wins)
-                )
-            new_pool.append(stack)
+                for lp, cache, win in zip(params[pkey], stack, wins):
+                    with jax.named_scope("serve.attn"):
+                        h, cache = self._attn(h, lp, win, cache, b)
+                    with jax.named_scope(mlp_scope):
+                        h = mlp_fn(h, lp, cfg)
+                    h = self._constrain_rep(h)
+                    new_stack.append(cache)
+            new_pool.append(tuple(new_stack))
         new_pool = self._constrain_pool(new_pool)
 
         with jax.named_scope("serve.head"):

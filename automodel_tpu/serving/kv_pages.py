@@ -12,17 +12,22 @@ page accounting on the host (`PageAllocator`), while the device arrays keep
 ONE fixed shape for the whole serving run — the engine step never reshapes
 or recompiles as requests join and leave.
 
-Device-side layouts (L = layers of a stack, N = `num_pages`, ps =
-`page_size`; allocated as N+1 pages — page index N is the TRASH page that
-pad token rows write into and padded page-table entries point at, keeping
-every gather/scatter in bounds without branching):
+Device-side layouts. The pool is PER LAYER: `pool[stack][layer]` is a tuple
+of page arrays, every one a buffer of its own with the PAGE axis first (axis
+0), so the serve step (which walks its layers in a Python loop and is
+donated every array) writes each layer's rows in place and hands the paged
+kernel the argument's own buffer: no layer is sliced out of a stack or
+written back into one. N = `num_pages`, ps = `page_size`; allocated as N+1
+pages — page index N is the TRASH page that pad token rows write into and
+padded page-table entries point at, keeping every gather/scatter in bounds
+without branching:
 
-- GQA:  k/v  (L, N+1, ps, Hkv, D)
-- MLA:  c    (L, N+1, ps, r),  kr (L, N+1, ps, dr)   (absorbed decode —
+- GQA:  (k, v)   each (N+1, ps, Hkv, D)
+- MLA:  (c, kr)  (N+1, ps, r) and (N+1, ps, dr)   (absorbed decode —
   r+dr cached floats per token instead of n*(dn+dr+dv))
 
 Quantized pools (kv_cache_dtype="int8"): the same layouts hold int8 and
-each stack gains PARALLEL per-page scale arrays (L, N+1, ps) — one f32
+each layer gains PARALLEL per-page scale arrays (N+1, ps) — one f32
 scale per cache row, stored page-major so scales travel with their pages
 through every page-axis pytree op (COW, defrag, prefix-cache adoption,
 truncate, kv_transfer handoff) without the host allocator/scheduler/radix
@@ -217,47 +222,51 @@ class PageAllocator:
 @functools.partial(jax.jit, donate_argnums=(0,))
 def apply_defrag(pool, src: jnp.ndarray):
     """Apply a defrag plan to a pool pytree: one gather along the page axis
-    (axis 1, after the layer axis) per array; the trash page stays put.
+    (axis 0) per array; the trash page stays put.
     The old pool is donated — callers rebind (`pool = apply_defrag(pool,
     src)`), and XLA may reuse the donated buffers instead of double-
     buffering the whole KV pool during compaction."""
     full = jnp.concatenate(
         [src, jnp.asarray([pool_trash_index(pool)], jnp.int32)]
     )
-    return jax.tree.map(lambda a: a[:, full], pool)
+    return jax.tree.map(lambda a: a[full], pool)
 
 
 def pool_trash_index(pool) -> int:
     """The trash page index = num_pages (pages axis is num_pages + 1)."""
-    return jax.tree.leaves(pool)[0].shape[1] - 1
+    return jax.tree.leaves(pool)[0].shape[0] - 1
 
 
-def _scale_arrays(num_layers: int, num_pages: int, page_size: int):
-    """Two per-page scale arrays (L, N+1, ps) for a quantized stack — one
-    f32 scalar per cache row, rows of a page contiguous so every page-axis
-    operation on the pool pytree (COW copy, defrag gather, transfer
-    gather/scatter) moves a page's scales with its int8 payload for free.
-    Initialized to 1.0 (identity dequant for never-written rows)."""
-    shape = (num_layers, num_pages + 1, page_size)
-    return (jnp.ones(shape, jnp.float32), jnp.ones(shape, jnp.float32))
+def _init_layer(shapes, dtype, num_pages, page_size, kv_cache_dtype):
+    """One layer's page arrays at `shapes` (the row shapes behind the
+    (N+1, ps) page axes). kv_cache_dtype="int8": int8 payloads plus two
+    per-page scale arrays (N+1, ps) — one f32 scalar per cache row, rows of
+    a page contiguous so every page-axis operation on the pool pytree (COW
+    copy, defrag gather, transfer gather/scatter) moves a page's scales with
+    its int8 payload for free; 1.0 (identity dequant) for never-written
+    rows."""
+    lead = (num_pages + 1, page_size)
+    if kv_cache_dtype is None:
+        return tuple(jnp.zeros(lead + s, dtype) for s in shapes)
+    assert kv_cache_dtype == "int8", kv_cache_dtype
+    return (
+        *(jnp.zeros(lead + s, jnp.int8) for s in shapes),
+        jnp.ones(lead, jnp.float32), jnp.ones(lead, jnp.float32),
+    )
 
 
 def init_gqa_pool(
     cfg, num_layers: int, num_pages: int, page_size: int,
     kv_cache_dtype: str | None = None,
 ):
-    """(k, v) pool arrays for one GQA stack (dtype/shapes from cfg — the
-    cache-entry shapes of inference/generate.py's `_cache_shapes`).
-    kv_cache_dtype="int8" → (k, v, k_scale, v_scale): int8 payloads at the
-    SAME shapes plus the per-page scale arrays."""
-    D = cfg.resolved_head_dim
-    shape = (num_layers, num_pages + 1, page_size, cfg.num_kv_heads, D)
-    if kv_cache_dtype is None:
-        return (jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
-    assert kv_cache_dtype == "int8", kv_cache_dtype
-    return (
-        jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-        *_scale_arrays(num_layers, num_pages, page_size),
+    """One GQA stack: a (k, v) tuple of page arrays per layer (dtype/row
+    shapes from cfg — the cache-entry shapes of inference/generate.py's
+    `_cache_shapes`). kv_cache_dtype="int8" → (k, v, k_scale, v_scale):
+    int8 payloads at the SAME shapes plus the per-page scale arrays."""
+    row = (cfg.num_kv_heads, cfg.resolved_head_dim)
+    return tuple(
+        _init_layer((row, row), cfg.dtype, num_pages, page_size, kv_cache_dtype)
+        for _ in range(num_layers)
     )
 
 
@@ -265,27 +274,21 @@ def init_mla_pool(
     cfg, num_layers: int, num_pages: int, page_size: int,
     kv_cache_dtype: str | None = None,
 ):
-    """(c, kr) pool arrays for one MLA stack (absorbed latent cache);
+    """One MLA stack: a (c, kr) tuple per layer (absorbed latent cache);
     kv_cache_dtype="int8" → (c, kr, c_scale, kr_scale)."""
-    c_shape = (num_layers, num_pages + 1, page_size, cfg.mla_kv_lora_rank)
-    kr_shape = (
-        num_layers, num_pages + 1, page_size, cfg.mla_qk_rope_head_dim,
-    )
-    if kv_cache_dtype is None:
-        return (jnp.zeros(c_shape, cfg.dtype), jnp.zeros(kr_shape, cfg.dtype))
-    assert kv_cache_dtype == "int8", kv_cache_dtype
-    return (
-        jnp.zeros(c_shape, jnp.int8), jnp.zeros(kr_shape, jnp.int8),
-        *_scale_arrays(num_layers, num_pages, page_size),
+    rows = ((cfg.mla_kv_lora_rank,), (cfg.mla_qk_rope_head_dim,))
+    return tuple(
+        _init_layer(rows, cfg.dtype, num_pages, page_size, kv_cache_dtype)
+        for _ in range(num_layers)
     )
 
 
 def pool_axes(cfg, kv_cache_dtype: str | None = None) -> tuple:
-    """Per-stack mesh-axis tuples for the two pool arrays of one stack
-    (feed each through `MeshContext.sharding(*axes)`). Page IDs stay
-    GLOBAL — layer and page axes are never sharded, so the host-side
-    allocator/scheduler/prefix-cache integer accounting composes with any
-    mesh unchanged. Only the per-page head dim is partitioned over tp:
+    """Mesh-axis tuples for the page arrays of one layer (feed each through
+    `MeshContext.sharding(*axes)`). Page IDs stay GLOBAL — the page axis is
+    never sharded, so the host-side allocator/scheduler/prefix-cache
+    integer accounting composes with any mesh unchanged. Only the per-page
+    head dim is partitioned over tp:
 
     - GQA:  k/v shard KV heads (each tp rank owns Hkv/tp heads of every
       page — the query heads of its GQA groups live on the same rank, so
@@ -300,49 +303,45 @@ def pool_axes(cfg, kv_cache_dtype: str | None = None) -> tuple:
     dequantize its local head slice.
     """
     if cfg.attention_type == "mla":
-        data = ((None, None, None, "tp"), (None, None, None, None))
+        data = ((None, None, "tp"), (None, None, None))
     else:
-        data = (
-            (None, None, None, "tp", None), (None, None, None, "tp", None),
-        )
+        data = ((None, None, "tp", None), (None, None, "tp", None))
     if kv_cache_dtype is None:
         return data
-    return data + ((None, None, None), (None, None, None))
+    return data + ((None, None), (None, None))
 
 
 def pool_shardings(
     cfg, stack_layers: list[int], mesh_ctx, kv_cache_dtype: str | None = None,
 ):
-    """Per-stack NamedSharding tuples matching `init_pool`'s structure."""
-    axes = pool_axes(cfg, kv_cache_dtype)
-    return [
-        tuple(mesh_ctx.sharding(*a) for a in axes) for _ in stack_layers
-    ]
+    """NamedShardings matching `init_pool`'s structure: per stack, per
+    layer, one per page array."""
+    layer = tuple(
+        mesh_ctx.sharding(*a) for a in pool_axes(cfg, kv_cache_dtype)
+    )
+    return [(layer,) * L for L in stack_layers]
 
 
 def init_pool(
     cfg, stack_layers: list[int], num_pages: int, page_size: int,
     mesh_ctx=None, kv_cache_dtype: str | None = None,
 ):
-    """Per-stack pool tuples for a decoder (dense decoders have one stack;
-    MoE decoders a dense prefix + MoE stack — mirrors generate.py). With a
-    `mesh_ctx` the arrays are placed mesh-sharded (`pool_axes`). With
-    kv_cache_dtype="int8" each stack carries int8 payloads plus per-page
-    scale arrays — same page axis, so COW/defrag/transfer move scales with
-    their pages and the host-side allocator never knows."""
+    """The pool of a decoder: per stack (dense decoders have one; MoE
+    decoders a dense prefix + MoE stack — mirrors generate.py) a tuple with
+    one tuple of page arrays per layer. With a `mesh_ctx` the arrays are
+    placed mesh-sharded (`pool_axes`). With kv_cache_dtype="int8" each
+    layer carries int8 payloads plus per-page scale arrays — same page
+    axis, so COW/defrag/transfer move scales with their pages and the
+    host-side allocator never knows."""
     init = init_mla_pool if cfg.attention_type == "mla" else init_gqa_pool
     pool = [
         init(cfg, L, num_pages, page_size, kv_cache_dtype)
         for L in stack_layers
     ]
     if mesh_ctx is not None:
-        pool = [
-            tuple(jax.device_put(a, s) for a, s in zip(stack, shards))
-            for stack, shards in zip(
-                pool,
-                pool_shardings(cfg, stack_layers, mesh_ctx, kv_cache_dtype),
-            )
-        ]
+        pool = jax.device_put(
+            pool, pool_shardings(cfg, stack_layers, mesh_ctx, kv_cache_dtype)
+        )
     return pool
 
 

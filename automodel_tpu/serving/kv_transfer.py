@@ -5,9 +5,10 @@ DisaggRouter, Mooncake/DistServe-style): a prefill-class replica finishes a
 prompt, the scheduler pins the request's committed pages and releases its
 slot, and this module moves those pages into a decode-class replica's pool
 — by GLOBAL page ID, with no cache-format conversion. Both pools share the
-same layout family (kv_pages.py: GQA (L, N+1, ps, Hkv, D) or absorbed-MLA
-(L, N+1, ps, r)/(L, N+1, ps, dr)); only `num_pages` may differ between the
-classes, so a transfer is a pure index copy along the pages axis.
+same layout family (kv_pages.py: per layer, GQA (N+1, ps, Hkv, D) or
+absorbed-MLA (N+1, ps, r)/(N+1, ps, dr)); only `num_pages` may differ between
+the classes, so a transfer is a pure index copy along the pages axis (axis 0
+of every array).
 
 The copy plan is HOST-side (the (src_page, dst_page) pairs the decode
 scheduler's `try_admit_handoff` returns after splicing out pages its own
@@ -20,7 +21,7 @@ radix tree already holds); the data movement is DEVICE-side, batched
   pool-sized allocation). This is the program the `kv_transfer` analysis
   baseline pins: gather/scatter only, zero collectives.
 - split path (engines on disjoint mesh slices): a jitted gather on the
-  source mesh lifts the pages into a (L, B, ...) staging block, one
+  source mesh lifts the pages into (B, ...) staging blocks, one
   `jax.device_put` hops it onto the destination placement (pages
   unsharded; the per-page head/latent dim follows the destination's tp
   cut), and a jitted donated scatter lands it. Three steps instead of
@@ -46,26 +47,26 @@ from automodel_tpu.serving.kv_pages import pool_trash_index
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def apply_transfer(dst_pool, src_pool, src_idx, dst_idx):
-    """Fused same-device page copy: dst_pool[:, dst_idx[i]] =
-    src_pool[:, src_idx[i]] for every pool array, in one program. `src_idx`
+    """Fused same-device page copy: dst_pool[dst_idx[i]] =
+    src_pool[src_idx[i]] for every pool array, in one program. `src_idx`
     / `dst_idx` are fixed-length (B,) int32; pad entries point both sides
     at their trash page (a self-overwrite of garbage). The destination
     pool is donated — callers rebind."""
     return jax.tree.map(
-        lambda d, s: d.at[:, dst_idx].set(s[:, src_idx]), dst_pool, src_pool
+        lambda d, s: d.at[dst_idx].set(s[src_idx]), dst_pool, src_pool
     )
 
 
 @jax.jit
 def _gather_pages(src_pool, src_idx):
     """Split-path stage 1: lift B pages out of the source pool."""
-    return jax.tree.map(lambda a: a[:, src_idx], src_pool)
+    return jax.tree.map(lambda a: a[src_idx], src_pool)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_pages(dst_pool, rows, dst_idx):
     """Split-path stage 3: land B staged pages in the donated dest pool."""
-    return jax.tree.map(lambda d, r: d.at[:, dst_idx].set(r), dst_pool, rows)
+    return jax.tree.map(lambda d, r: d.at[dst_idx].set(r), dst_pool, rows)
 
 
 class KVTransfer:
@@ -96,7 +97,7 @@ class KVTransfer:
         # gather → device_put hop → scatter split path
         self.fused = src_engine._mesh is None and dst_engine._mesh is None
         self.page_bytes = sum(
-            (a.size // a.shape[1]) * a.dtype.itemsize
+            (a.size // a.shape[0]) * a.dtype.itemsize
             for a in jax.tree.leaves(src_engine.pool)
         )
         self.n_pages = 0    # real (non-pad) pages moved

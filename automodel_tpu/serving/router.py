@@ -45,6 +45,7 @@ from automodel_tpu.serving.engine import (
     ServingEngine,
     _percentiles_ms,
     _resolve_ttft,
+    split_layer_stacks,
 )
 from automodel_tpu.serving.frontend import (
     FrontendConfig,
@@ -159,12 +160,16 @@ class ReplicaRouter:
         resilience: ServeResilienceConfig | None = None,
     ):
         """`params` may carry any placement (chassis-sharded arrays flow
-        straight in); each replica re-shards them onto its own slice.
+        straight in); each replica re-shards them onto its own slice. The
+        router takes ownership of `params` as an engine does
+        (`split_layer_stacks`): the layer stacks are split ONCE, here, and
+        every replica is handed the same per-layer tree.
         `draft_source_factory()` builds one draft source per replica for
         the stateful EAGLE/DFlash speculation adapters (per-request state
         must live with the replica that serves the request)."""
         self.mesh = mesh
         ctxs = mesh.build_contexts(devices)
+        params = split_layer_stacks(params, cfg.dtype)
         # ONE shared observability bundle: replicas interleave on a shared
         # registry/trace, distinguished by track name
         self.obs = Observability(serve_cfg.observability)
@@ -644,6 +649,9 @@ class DisaggRouter:
             # single-device engine needs them there anyway); the fresh
             # pools are committed alongside, below.
             params = jax.device_put(params, jax.devices()[0])
+        # split the layer stacks once (the router owns `params` as an engine
+        # does): both classes' engines share the one per-layer tree
+        params = split_layer_stacks(params, cfg.dtype)
         # ONE shared observability bundle across both replica classes
         self.obs = Observability(serve_cfg.observability)
         self.prefill = [

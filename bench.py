@@ -513,6 +513,7 @@ def _headline_prefix(accel: bool) -> dict:
         Request,
         ServingConfig,
         ServingEngine,
+        split_layer_stacks,
     )
 
     if accel:
@@ -536,7 +537,10 @@ def _headline_prefix(accel: bool) -> dict:
                    pages_per_slot=32, token_budget=16, prefill_chunk=8)
         sys_len, turn_len, agents, rounds, max_new = 24, 6, 3, 4, 8
         arrival_stride = 12
-    params = decoder.init(cfg, jax.random.key(0))
+    # split once: the engines below share the per-layer tree
+    params = split_layer_stacks(
+        decoder.init(cfg, jax.random.key(0)), cfg.dtype
+    )
     rng = np.random.default_rng(0)
     system = [int(t) for t in rng.integers(1, cfg.vocab_size, (sys_len,))]
 
@@ -611,6 +615,7 @@ def _headline_spec(accel: bool) -> dict:
         ServingConfig,
         ServingEngine,
         SpeculativeConfig,
+        split_layer_stacks,
     )
 
     if accel:
@@ -632,7 +637,10 @@ def _headline_spec(accel: bool) -> dict:
         geo = dict(page_size=8, num_pages=96, max_slots=4,
                    pages_per_slot=16, token_budget=32, prefill_chunk=8)
         lens, max_new, n_req, draft_len = (24, 16, 30, 20), 64, 8, 6
-    params = decoder.init(cfg, jax.random.key(0))
+    # split once: the engines below share the per-layer tree
+    params = split_layer_stacks(
+        decoder.init(cfg, jax.random.key(0)), cfg.dtype
+    )
     rng = np.random.default_rng(0)
     prompts = [
         [int(t) for t in rng.integers(1, cfg.vocab_size, (lens[i % len(lens)],))]
@@ -781,6 +789,7 @@ def _serve_chaos_child() -> None:
         ServingConfig,
         ServingEngine,
         pool_identity_ok,
+        split_layer_stacks,
     )
     from automodel_tpu.serving.load_test import (
         LoadTestConfig,
@@ -802,7 +811,10 @@ def _serve_chaos_child() -> None:
         mean_interarrival_steps=0.25, deadline_in=160,
         deadline_fraction=0.25, vocab=cfg.vocab_size,
     )
-    params = decoder.init(cfg, jax.random.key(0))
+    # split once: the engines below share the per-layer tree
+    params = split_layer_stacks(
+        decoder.init(cfg, jax.random.key(0)), cfg.dtype
+    )
     trace = make_trace(lt)
 
     async def drive(router):
@@ -948,6 +960,7 @@ def _headline_disagg(accel: bool) -> dict:
         Request,
         ServingConfig,
         ServingEngine,
+        split_layer_stacks,
     )
 
     if accel:
@@ -984,7 +997,10 @@ def _headline_disagg(accel: bool) -> dict:
         disagg = DisaggConfig(enabled=True, transfer_pages=8,
                               prefill_token_budget=32)
         sys_len = 24
-    params = decoder.init(cfg, jax.random.key(0))
+    # split once: the engines below share the per-layer tree
+    params = split_layer_stacks(
+        decoder.init(cfg, jax.random.key(0)), cfg.dtype
+    )
     rng = np.random.default_rng(0)
 
     def reqs():
@@ -1162,6 +1178,7 @@ def _headline_serve_online(accel: bool) -> dict:
     from automodel_tpu.models.llm.decoder import TransformerConfig
     from automodel_tpu.serving import (
         FrontendConfig, Request, ServingConfig, ServingEngine,
+        split_layer_stacks,
     )
     from automodel_tpu.serving.load_test import LoadTestConfig, run_load_test
 
@@ -1199,7 +1216,10 @@ def _headline_serve_online(accel: bool) -> dict:
             deadline_fraction=0.25, vocab=cfg.vocab_size,
             parity_check=1024,
         )
-    params = decoder.init(cfg, jax.random.key(0))
+    # split once: the engines below share the per-layer tree
+    params = split_layer_stacks(
+        decoder.init(cfg, jax.random.key(0)), cfg.dtype
+    )
     engine = ServingEngine(params, cfg, serve)
     # warmup: compile the single step signature outside the timed window
     engine.serve_batch([Request(prompt=[1, 2, 3], max_new_tokens=2)])
@@ -1372,7 +1392,7 @@ def _headline_kv_quant(accel: bool) -> dict:
     from automodel_tpu.loss import fused_linear_cross_entropy
     from automodel_tpu.models.llm import decoder
     from automodel_tpu.models.llm.decoder import TransformerConfig
-    from automodel_tpu.serving import Request, ServingConfig, ServingEngine
+    from automodel_tpu.serving import Request, ServingConfig, ServingEngine, split_layer_stacks
     from automodel_tpu.serving.kv_pages import pool_bytes
 
     if accel:
@@ -1426,6 +1446,8 @@ def _headline_kv_quant(accel: bool) -> dict:
     for _ in range(train_steps):
         key, k = jax.random.split(key)
         params, opt, ce = train_one(params, opt, k)
+    # both engines below are built from the trained tree: split it once
+    params = split_layer_stacks(params, cfg.dtype)
 
     rng = np.random.default_rng(0)
     prompts = [

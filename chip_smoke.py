@@ -393,6 +393,30 @@ def phase_serve() -> None:
     check(all(len(g["prompt_ids"]) == n_prompt for g in gens),
           f"every prompt has {n_prompt} tokens")
 
+    def restack(params):
+        """The engine's per-layer trees back on a leading layer axis, which
+        the training forward scans. Leaf by leaf and through host memory:
+        beside the weights and the pool the chip has no room for one more
+        stacked expert leaf (2.75 GiB asked, 1.60 free: PR 27), so each
+        layer's buffer is read back and given up before its stack goes in
+        (the engine is done with them)."""
+        from automodel_tpu.serving.engine import LAYER_STACKS
+
+        out = dict(params)
+        for key in LAYER_STACKS:
+            layers = params.get(key)
+            if layers is None:
+                continue
+            treedef = jax.tree.structure(layers[0])
+            stacked = []
+            for parts in zip(*(jax.tree.leaves(layer) for layer in layers)):
+                on_host = np.stack([np.asarray(part) for part in parts])
+                for part in parts:
+                    part.delete()
+                stacked.append(jnp.asarray(on_host))
+            out[key] = treedef.unflatten(stacked)
+        return out
+
     @jax.jit
     def ref_logits(params, ids):
         hidden, _aux = moe_decoder.forward(
@@ -402,11 +426,13 @@ def phase_serve() -> None:
         rows = hidden[:, n_prompt - 1 : n_prompt - 1 + n_new]
         return unembed(params, recipe.model_cfg, rows)[0]
 
+    stacked_params = restack(engine.params)
+    del engine.params
     for g in (gens[0], gens[len(gens) // 2], gens[-1]):
         ids = np.asarray(g["prompt_ids"] + g["generated_ids"], np.int32)
         padded = np.zeros((1, -(-len(ids) // 128) * 128), np.int32)
         padded[0, : len(ids)] = ids  # flash needs a multiple of 128
-        logits = ref_logits(engine.params, jnp.asarray(padded))
+        logits = ref_logits(stacked_params, jnp.asarray(padded))
         chosen = jnp.asarray(ids[n_prompt:])
         gap = logits.max(-1) - jnp.take_along_axis(
             logits, chosen[:, None], -1)[:, 0]

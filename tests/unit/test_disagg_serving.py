@@ -42,6 +42,7 @@ from automodel_tpu.serving import (
     ServingEngine,
     SpeculativeConfig,
 )
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -70,13 +71,13 @@ def _reqs(prompts, arrivals, max_new=6):
 
 
 def _mono(params, sc, requests):
-    res = ServingEngine(params, CFG, sc).serve_batch(requests)
+    res = ServingEngine(own(params), CFG, sc).serve_batch(requests)
     assert res["stats"]["compiled_signatures"] == 1, res["stats"]
     return res
 
 
 def _disagg(params, sc, dc, requests, **kw):
-    router = DisaggRouter(params, CFG, sc, dc)
+    router = DisaggRouter(own(params), CFG, sc, dc)
     res = router.serve_batch(requests, **kw)
     assert res["stats"]["compiled_signatures_prefill"] == 1, res["stats"]
     assert res["stats"]["compiled_signatures_decode"] == 1, res["stats"]
@@ -229,7 +230,7 @@ def test_engine_lifetime_cache_across_serve_batch_calls(params):
         token_budget=8, prefill_chunk=4,
         prefix_cache=PrefixCacheConfig(enabled=True),
     )
-    eng = ServingEngine(params, CFG, sc)
+    eng = ServingEngine(own(params), CFG, sc)
     first = eng.serve_batch(mk(0))
     assert first["stats"]["prefill_skipped_tokens"] == 0  # cold tree
     second_reqs = mk(1)
@@ -242,7 +243,7 @@ def test_engine_lifetime_cache_across_serve_batch_calls(params):
     # cached pages (plus the sampled ones) were ever fed
     assert second["stats"]["tokens_fed"] <= prompt_len - skipped + 1 + 4
     # parity: warm tokens equal a cold engine's on the identical request
-    cold = ServingEngine(params, CFG, sc).serve_batch(mk(1))
+    cold = ServingEngine(own(params), CFG, sc).serve_batch(mk(1))
     assert second["outputs"] == cold["outputs"]
     assert eng.step_cache_size() == 1  # both calls, one signature
     # explicit reset returns the engine to cold
@@ -266,7 +267,7 @@ def test_engine_lifetime_feeds_disagg_peers(params):
         token_budget=8, prefill_chunk=4,
         prefix_cache=PrefixCacheConfig(enabled=True),
     )
-    router = DisaggRouter(params, CFG, sc, DisaggConfig(enabled=True))
+    router = DisaggRouter(own(params), CFG, sc, DisaggConfig(enabled=True))
     router.serve_batch(mk())
     res = router.serve_batch(mk())
     assert res["stats"]["prefill_skipped_tokens"] >= len(system) - sc.page_size
@@ -278,7 +279,7 @@ def _tiny_engine(params, **over):
     geo = dict(page_size=4, num_pages=8, max_slots=2, pages_per_slot=4,
                token_budget=8)
     geo.update(over)
-    return ServingEngine(params, CFG, ServingConfig(**geo))
+    return ServingEngine(own(params), CFG, ServingConfig(**geo))
 
 
 def test_kv_transfer_moves_pages_and_chunks(params):
@@ -286,7 +287,7 @@ def test_kv_transfer_moves_pages_and_chunks(params):
     dst = _tiny_engine(params, num_pages=16)  # num_pages may differ
     # stamp recognizable values into three source pages
     src.pool = jax.tree.map(
-        lambda a: a.at[:, 2].set(1.5).at[:, 3].set(2.5).at[:, 5].set(3.5),
+        lambda a: a.at[2].set(1.5).at[3].set(2.5).at[5].set(3.5),
         src.pool,
     )
     xfer = KVTransfer(src, dst, batch_pages=2)
@@ -294,10 +295,10 @@ def test_kv_transfer_moves_pages_and_chunks(params):
     assert moved == 3
     assert xfer.n_pages == 3 and xfer.n_chunks == 2  # 2+1 under batch=2
     for leaf_dst in jax.tree.leaves(dst.pool):
-        np.testing.assert_allclose(np.asarray(leaf_dst[:, 7]), 1.5)
-        np.testing.assert_allclose(np.asarray(leaf_dst[:, 9]), 2.5)
-        np.testing.assert_allclose(np.asarray(leaf_dst[:, 1]), 3.5)
-        np.testing.assert_allclose(np.asarray(leaf_dst[:, 0]), 0.0)
+        np.testing.assert_allclose(np.asarray(leaf_dst[7]), 1.5)
+        np.testing.assert_allclose(np.asarray(leaf_dst[9]), 2.5)
+        np.testing.assert_allclose(np.asarray(leaf_dst[1]), 3.5)
+        np.testing.assert_allclose(np.asarray(leaf_dst[0]), 0.0)
     assert xfer.move([]) == 0
     assert xfer.n_chunks == 2
 

@@ -44,6 +44,7 @@ from automodel_tpu.serving import (
 )
 from automodel_tpu.serving.load_test import LoadTestConfig, run_load_test
 from automodel_tpu.speculative.serve_draft import DraftSource
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -61,7 +62,7 @@ def _engine(params, **geo):
     base = dict(page_size=4, num_pages=24, max_slots=3, pages_per_slot=6,
                 token_budget=8, prefill_chunk=4)
     base.update(geo)
-    return ServingEngine(params, CFG, ServingConfig(**base))
+    return ServingEngine(own(params), CFG, ServingConfig(**base))
 
 
 def _prompts(lens, vocab=64, seed0=0):
@@ -291,7 +292,7 @@ def test_disagg_cancel_releases_inflight_handoff_pins():
     replica's pool must return to free + cached == total."""
     params = _params()
     router = DisaggRouter(
-        params, CFG,
+        own(params), CFG,
         ServingConfig(page_size=4, num_pages=16, max_slots=2,
                       pages_per_slot=4, token_budget=8, prefill_chunk=8),
         DisaggConfig(enabled=True, prefill_replicas=1, decode_replicas=1),
@@ -374,7 +375,7 @@ def test_adaptive_draft_len_collapses_to_plain_decode():
             adaptive_threshold=0.5, adaptive_decay=0.5,
         )
         engine = ServingEngine(
-            params, CFG, ServingConfig(**geo, speculative=spec),
+            own(params), CFG, ServingConfig(**geo, speculative=spec),
             draft_source=_AlwaysWrongDraft(refs),
         )
         reqs = [
@@ -472,7 +473,7 @@ def test_disagg_autoscale_borrowed_replica_serves_prefill():
     the rids guard (its own decode work untouched), and parity holds."""
     params = _params()
     router = DisaggRouter(
-        params, CFG,
+        own(params), CFG,
         ServingConfig(page_size=4, num_pages=32, max_slots=2,
                       pages_per_slot=6, token_budget=8, prefill_chunk=4),
         DisaggConfig(

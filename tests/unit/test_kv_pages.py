@@ -60,16 +60,15 @@ def test_defrag_compacts_live_pages():
     assert a.num_free == 5
     # device-side: new page i holds old page src[i] (apply_defrag donates
     # the pool, so compare against a host snapshot taken before the call)
-    pool = (jnp.arange(2 * 9 * 2 * 1 * 1, dtype=jnp.float32).reshape(2, 9, 2, 1, 1),)
-    before = np.asarray(pool[0])
-    moved = apply_defrag(pool, src)[0]
-    for slot in (0, 2):
-        for old, new in zip(live_before[slot], a.table(slot)):
-            np.testing.assert_array_equal(
-                np.asarray(moved[:, new]), before[:, old]
-            )
-    # trash page (index num_pages) stays put
-    np.testing.assert_array_equal(np.asarray(moved[:, 8]), before[:, 8])
+    # two layers of one page array each, the page axis first
+    before = np.arange(2 * 9 * 2 * 1 * 1, dtype=np.float32).reshape(2, 9, 2, 1, 1)
+    pool = [tuple((jnp.asarray(layer),) for layer in before)]
+    for (moved,), was in zip(apply_defrag(pool, src)[0], before):
+        for slot in (0, 2):
+            for old, new in zip(live_before[slot], a.table(slot)):
+                np.testing.assert_array_equal(np.asarray(moved[new]), was[old])
+        # trash page (index num_pages) stays put
+        np.testing.assert_array_equal(np.asarray(moved[8]), was[8])
 
 
 def test_refcount_share_and_free():
@@ -177,9 +176,11 @@ def test_init_pool_shapes():
         num_heads=4, num_kv_heads=2, dtype=jnp.float32, remat_policy="none",
     )
     pool = init_pool(cfg, [2], num_pages=6, page_size=4)
-    (k, v), = pool
+    (stack,) = pool
+    assert len(stack) == 2  # one (k, v) per layer, each a buffer of its own
     D = cfg.resolved_head_dim
-    assert k.shape == (2, 7, 4, 2, D) and v.shape == k.shape  # N+1 pages
+    for k, v in stack:
+        assert k.shape == (7, 4, 2, D) and v.shape == k.shape  # N+1 pages
     assert pool_trash_index(pool) == 6
 
     import dataclasses
@@ -188,5 +189,7 @@ def test_init_pool_shapes():
         cfg, attention_type="mla", mla_kv_lora_rank=8, mla_q_lora_rank=0,
         mla_qk_nope_head_dim=4, mla_qk_rope_head_dim=4, mla_v_head_dim=4,
     )
-    (c, kr), = init_pool(mla, [2], num_pages=6, page_size=4)
-    assert c.shape == (2, 7, 4, 8) and kr.shape == (2, 7, 4, 4)
+    (stack,) = init_pool(mla, [2], num_pages=6, page_size=4)
+    assert len(stack) == 2
+    for c, kr in stack:
+        assert c.shape == (7, 4, 8) and kr.shape == (7, 4, 4)

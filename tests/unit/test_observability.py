@@ -51,6 +51,7 @@ from automodel_tpu.serving import (
 )
 from automodel_tpu.serving.frontend import FrontendConfig, OnlineFrontend
 from automodel_tpu.serving.load_test import LoadTestConfig, run_load_test
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -234,10 +235,10 @@ def test_tracing_on_off_parity_and_compile_once(params):
     neither the greedy token stream nor the number of compiled step
     signatures, and the trace actually recorded the run."""
     reqs = lambda: _reqs([5, 9, 3], seed0=10)  # noqa: E731
-    base_eng = ServingEngine(params, CFG, _sc())
+    base_eng = ServingEngine(own(params), CFG, _sc())
     base = base_eng.serve_batch(reqs())
     sc = _sc(observability=ObservabilityConfig(enabled=True))
-    eng = ServingEngine(params, CFG, sc)
+    eng = ServingEngine(own(params), CFG, sc)
     res = eng.serve_batch(reqs())
     assert res["outputs"] == base["outputs"]
     assert res["stats"]["compiled_signatures"] == 1
@@ -284,7 +285,7 @@ def test_digest_stable_across_identical_load_tests(params):
     digests = []
     for _ in range(2):
         eng = ServingEngine(
-            params, CFG, _sc(observability=ObservabilityConfig(enabled=True)),
+            own(params), CFG, _sc(observability=ObservabilityConfig(enabled=True)),
         )
         report = run_load_test(eng, lt, fc)
         assert report["completed"] == 8
@@ -299,7 +300,7 @@ def test_disagg_timeline_phases_sum_to_ttft(params):
     real (markers present, not zero-width by omission)."""
     sc = _sc(observability=ObservabilityConfig(enabled=True))
     dc = DisaggConfig(enabled=True, transfer_pages=4, prefill_token_budget=16)
-    router = DisaggRouter(params, CFG, sc, dc)
+    router = DisaggRouter(own(params), CFG, sc, dc)
     res = router.serve_batch(_reqs([5, 11, 3, 7], seed0=30))
     assert res["stats"]["handoffs"] == 4
     events = list(router.obs.tracer.events)
@@ -334,7 +335,7 @@ def test_flight_recorder_dumps_on_injected_crash(params, tmp_path):
         enabled=True, flight_recorder_len=32,
         flight_recorder_path=str(dump),
     ))
-    eng = ServingEngine(params, CFG, sc)
+    eng = ServingEngine(own(params), CFG, sc)
     with injected({"point": "serve_step", "mode": "crash", "step": 2}):
         with pytest.raises(FaultCrash):
             eng.serve_batch(_reqs([5, 7], seed0=50))
@@ -351,7 +352,7 @@ def test_flight_recorder_dumps_on_injected_crash(params, tmp_path):
 def test_observability_disabled_is_null_tracer(params):
     """Default config: the engine gets the null tracer (no events, no
     ring) while the registry still mirrors the run's stats."""
-    eng = ServingEngine(params, CFG, _sc())
+    eng = ServingEngine(own(params), CFG, _sc())
     res = eng.serve_batch(_reqs([4, 6], seed0=70))
     assert eng.obs.tracer is NULL_TRACER
     assert eng.obs.enabled is False
@@ -427,7 +428,7 @@ def _profiled(tmp_path, fn):
 @pytest.fixture(scope="module")
 def online_untraced(params, tmp_path_factory):
     """The online loop under a profiler session, observability disabled."""
-    eng = ServingEngine(params, CFG, _sc())
+    eng = ServingEngine(own(params), CFG, _sc())
     outs, events = _profiled(
         tmp_path_factory.mktemp("prof_off"), lambda: _online(eng))
     return eng, outs, events
@@ -475,7 +476,7 @@ def test_online_spans_with_observability_on_match_and_validate(
     across an await, so each track nests), and the same greedy tokens."""
     _eng, base_outs, base_events = online_untraced
     eng = ServingEngine(
-        params, CFG, _sc(observability=ObservabilityConfig(enabled=True)))
+        own(params), CFG, _sc(observability=ObservabilityConfig(enabled=True)))
     outs, events = _profiled(tmp_path / "prof", lambda: _online(eng))
     assert outs == base_outs
     assert eng.step_cache_size() == 1
@@ -535,7 +536,7 @@ def test_serve_step_names_its_sublayers_and_keeps_its_instructions():
 def test_compile_counter_rises_once_for_the_step(params):
     """`jax_backend_compiles_total` sees the step's one compilation over
     the first step and none between the second and the tenth."""
-    eng = ServingEngine(params, CFG, _sc())
+    eng = ServingEngine(own(params), CFG, _sc())
     sched = eng.make_scheduler(arrival_gating=False)
     for r in _reqs([6, 7, 5], seed0=110, max_new=12):
         sched.submit(r)

@@ -29,6 +29,7 @@ from automodel_tpu.serving import (
     ServingConfig,
     ServingEngine,
 )
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -147,7 +148,7 @@ def _ragged(seed, lens, vocab=64):
 
 
 def _serve(params, serve_cfg, prompts, arrivals, max_new=6):
-    engine = ServingEngine(params, CFG, serve_cfg)
+    engine = ServingEngine(own(params), CFG, serve_cfg)
     reqs = [Request(prompt=list(p), max_new_tokens=max_new, arrival=a)
             for p, a in zip(prompts, arrivals)]
     return engine.serve_batch(reqs)
@@ -214,7 +215,7 @@ def test_defrag_with_multiply_referenced_pages_preserves_decode():
                token_budget=8, prefill_chunk=4)
     cold = _serve(params, ServingConfig(**geo), prompts, [0, 1, 2], max_new=5)
 
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         **geo, prefix_cache=ENABLED,
     ))
     sched = engine.make_scheduler()
@@ -245,7 +246,7 @@ def test_full_hit_goes_straight_to_decode():
     rows before sampling are decode-class (one pending token)."""
     params = decoder.init(CFG, jax.random.key(0))
     (p,) = _ragged(40, [8])
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=8, prefix_cache=ENABLED,
     ))
@@ -279,7 +280,7 @@ def test_prefix_hit_admission_policy_prefers_hits_when_tight():
     (sys_p,) = _ragged(50, [16])            # 4 full pages of system prompt
     hot = sys_p + _ragged(51, [2])[0]       # needs 1 fresh page after the hit
     cold_long = _ragged(52, [16])[0]        # needs 5 pages, no hit
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         page_size=4, num_pages=9, max_slots=2, pages_per_slot=6,
         token_budget=16, prefill_chunk=16,
         prefix_cache=ENABLED, admission_policy="prefix-hit",
@@ -365,7 +366,7 @@ def test_admission_accounting_excludes_pages_the_request_would_pin():
     (donor_prompt,) = _ragged(80, [8])   # exactly 2 pages of known tokens
 
     def run(num_pages):
-        engine = ServingEngine(params, CFG, ServingConfig(
+        engine = ServingEngine(own(params), CFG, ServingConfig(
             page_size=4, num_pages=num_pages, max_slots=2, pages_per_slot=3,
             token_budget=8, prefix_cache=ENABLED,
         ))

@@ -53,6 +53,7 @@ from automodel_tpu.serving.kv_pages import (
     pool_bytes,
 )
 from automodel_tpu.serving.kv_transfer import apply_transfer
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -87,7 +88,7 @@ def _reqs(prompts, arrivals, max_new=6):
 
 
 def _serve(params, cfg, sc, requests, mesh_ctx=None):
-    eng = ServingEngine(params, cfg, sc, mesh_ctx=mesh_ctx)
+    eng = ServingEngine(own(params), cfg, sc, mesh_ctx=mesh_ctx)
     res = eng.serve_batch(requests)
     assert res["stats"]["compiled_signatures"] == 1, res["stats"]
     return eng, res
@@ -95,23 +96,25 @@ def _serve(params, cfg, sc, requests, mesh_ctx=None):
 
 # -- pool plumbing -----------------------------------------------------------
 def test_init_quant_pool_shapes_and_dtypes():
-    """Quantized stacks are 4-leaf: int8 payloads at the fp shapes plus
-    (L, N+1, ps) f32 scale planes initialized to identity dequant."""
+    """Quantized layers are 4-leaf: int8 payloads at the fp shapes plus
+    (N+1, ps) f32 scale planes initialized to identity dequant."""
     (gqa,) = init_pool(CFG, [CFG.num_layers], 8, 4, kv_cache_dtype="int8")
-    k, v, ks, vs = gqa
+    assert len(gqa) == CFG.num_layers == 2
     D = CFG.resolved_head_dim
-    assert k.shape == v.shape == (2, 9, 4, CFG.num_kv_heads, D)
-    assert k.dtype == v.dtype == jnp.int8
-    assert ks.shape == vs.shape == (2, 9, 4)
-    assert ks.dtype == vs.dtype == jnp.float32
-    assert bool(jnp.all(ks == 1.0)) and bool(jnp.all(vs == 1.0))
+    for k, v, ks, vs in gqa:
+        assert k.shape == v.shape == (9, 4, CFG.num_kv_heads, D)
+        assert k.dtype == v.dtype == jnp.int8
+        assert ks.shape == vs.shape == (9, 4)
+        assert ks.dtype == vs.dtype == jnp.float32
+        assert bool(jnp.all(ks == 1.0)) and bool(jnp.all(vs == 1.0))
 
     (mla,) = init_pool(MLA, [MLA.num_layers], 8, 4, kv_cache_dtype="int8")
-    c, kr, cs, krs = mla
-    assert c.shape == (2, 9, 4, MLA.mla_kv_lora_rank)
-    assert kr.shape == (2, 9, 4, MLA.mla_qk_rope_head_dim)
-    assert c.dtype == kr.dtype == jnp.int8
-    assert cs.shape == krs.shape == (2, 9, 4)
+    assert len(mla) == MLA.num_layers
+    for c, kr, cs, krs in mla:
+        assert c.shape == (9, 4, MLA.mla_kv_lora_rank)
+        assert kr.shape == (9, 4, MLA.mla_qk_rope_head_dim)
+        assert c.dtype == kr.dtype == jnp.int8
+        assert cs.shape == krs.shape == (9, 4)
 
     # int8 + f32-scale pool is well under half the f32 pool (>= 1.8x even
     # against a bf16 pool: 2 bytes -> 1 + 4/ps)
@@ -123,16 +126,16 @@ def test_defrag_moves_scales_with_pages():
     """apply_defrag gathers along the page axis of EVERY leaf — a moved
     page's scale rows arrive at the new page ID with its int8 payload."""
     (stack,) = init_pool(CFG, [CFG.num_layers], 4, 2, kv_cache_dtype="int8")
-    k, v, ks, vs = stack
-    k = k.at[:, 3].set(7)
-    ks = ks.at[:, 3].set(0.25)
+    stack = tuple(
+        (k.at[3].set(7), v, ks.at[3].set(0.25), vs) for k, v, ks, vs in stack
+    )
     # plan: live page 3 compacts to slot 0; rest backfilled from free pages
     src = jnp.asarray([3, 1, 2, 0], jnp.int32)
-    (k2, v2, ks2, vs2) = apply_defrag([(k, v, ks, vs)], src)[0]
-    assert bool(jnp.all(k2[:, 0] == 7))
-    assert bool(jnp.all(ks2[:, 0] == 0.25))
-    # trash page stayed put, identity scales everywhere else
-    assert bool(jnp.all(ks2[:, 1:] == 1.0))
+    for k2, v2, ks2, vs2 in apply_defrag([stack], src)[0]:
+        assert bool(jnp.all(k2[0] == 7))
+        assert bool(jnp.all(ks2[0] == 0.25))
+        # trash page stayed put, identity scales everywhere else
+        assert bool(jnp.all(ks2[1:] == 1.0))
 
 
 def test_transfer_ships_scale_planes_natively():
@@ -140,24 +143,27 @@ def test_transfer_ships_scale_planes_natively():
     the handoff never dequantizes, so adopted pages are bit-identical."""
     src = init_pool(CFG, [CFG.num_layers], 4, 2, kv_cache_dtype="int8")
     dst = init_pool(CFG, [CFG.num_layers], 4, 2, kv_cache_dtype="int8")
-    k, v, ks, vs = src[0]
-    src[0] = (k.at[:, 1].set(-5), v, ks.at[:, 1].set(0.5), vs)
+    src[0] = tuple(
+        (k.at[1].set(-5), v, ks.at[1].set(0.5), vs) for k, v, ks, vs in src[0]
+    )
     out = apply_transfer(dst, src, jnp.asarray([1], jnp.int32),
                          jnp.asarray([2], jnp.int32))
-    k2, _, ks2, _ = out[0]
-    assert bool(jnp.all(k2[:, 2] == -5))
-    assert bool(jnp.all(ks2[:, 2] == 0.5))
+    for k2, _, ks2, _ in out[0]:
+        assert bool(jnp.all(k2[2] == -5))
+        assert bool(jnp.all(ks2[2] == 0.5))
 
 
 def test_step_cow_copies_scale_rows(params):
     """The in-step COW block is a pytree copy along the page axis: the
     destination page's scale rows equal the source's after the step."""
-    eng = ServingEngine(params, CFG, ServingConfig(
+    eng = ServingEngine(own(params), CFG, ServingConfig(
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=8, **QUANT,
     ))
-    k, v, ks, vs = eng.pool[0]
-    eng.pool[0] = (k.at[:, 2].set(9), v, ks.at[:, 2].set(0.125), vs)
+    eng.pool[0] = tuple(
+        (k.at[2].set(9), v, ks.at[2].set(0.125), vs)
+        for k, v, ks, vs in eng.pool[0]
+    )
     T, S, P, trash = 8, 2, 4, 16
     batch = {key: jnp.full(T, trash if key == "page" else 0, jnp.int32)
              for key in ("tok", "slot", "pos", "page", "off")}
@@ -170,9 +176,9 @@ def test_step_cow_copies_scale_rows(params):
         cow_dst=jnp.asarray([5, trash], jnp.int32),
     )
     new_pool, _, _ = eng._step(eng.params, eng.pool, batch)
-    k2, _, ks2, _ = new_pool[0]
-    assert bool(jnp.all(k2[:, 5] == 9))
-    assert bool(jnp.all(ks2[:, 5] == 0.125))
+    for k2, _, ks2, _ in new_pool[0]:
+        assert bool(jnp.all(k2[5] == 9))
+        assert bool(jnp.all(ks2[5] == 0.125))
 
 
 # -- exact self-parity across every serving feature --------------------------
@@ -255,7 +261,7 @@ def test_quant_disagg_handoff_parity(params):
     )
     prompts = _prompts([6, 9, 4], seed0=30)
     _, mono = _serve(params, CFG, sc, _reqs(prompts, (0, 1, 3)))
-    router = DisaggRouter(params, CFG, sc, DisaggConfig(
+    router = DisaggRouter(own(params), CFG, sc, DisaggConfig(
         prefill_replicas=1, decode_replicas=1,
     ))
     res = router.serve_batch(_reqs(prompts, (0, 1, 3)))
@@ -265,7 +271,7 @@ def test_quant_disagg_handoff_parity(params):
     assert all(t.n_bytes == t.n_pages * t.page_bytes for t in transfers)
     # quantized wire bytes: >= 1.8x fewer than the same handoff in fp
     fp_sc = dataclasses.replace(sc, kv_cache_dtype=None, serve_precision=None)
-    fp_router = DisaggRouter(params, CFG, fp_sc, DisaggConfig(
+    fp_router = DisaggRouter(own(params), CFG, fp_sc, DisaggConfig(
         prefill_replicas=1, decode_replicas=1,
     ))
     fp_router.serve_batch(_reqs(prompts, (0, 1, 3)))
@@ -289,9 +295,9 @@ def test_quant_tp2_parity(params):
                       mesh_ctx=ctx)
     assert tp2["outputs"] == base["outputs"]
     # int8 payload sharded over kv heads; scale planes replicated
-    k, v, ks, vs = eng.pool[0]
-    assert k.sharding.spec[3] == "tp"
-    assert all(s is None for s in ks.sharding.spec)
+    for k, v, ks, vs in eng.pool[0]:
+        assert k.sharding.spec[2] == "tp"
+        assert all(s is None for s in ks.sharding.spec)
 
 
 def test_quant_mla_stream_compiles_once():

@@ -21,6 +21,7 @@ from automodel_tpu.inference.generate import GenerateConfig, generate
 from automodel_tpu.models.llm import decoder
 from automodel_tpu.models.llm.decoder import TransformerConfig
 from automodel_tpu.serving import Request, ServingConfig, ServingEngine
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -61,7 +62,7 @@ def test_gqa_parity_ragged_stream_compiles_once():
     joiners interleaves with running decodes; greedy tokens match the
     batch-synchronous path exactly and the step compiles exactly once."""
     params = decoder.init(CFG, jax.random.key(0))
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         page_size=4, num_pages=24, max_slots=3, pages_per_slot=6,
         token_budget=8, prefill_chunk=4,
     ))
@@ -75,7 +76,7 @@ def test_gqa_parity_ragged_stream_compiles_once():
 
 def test_mla_parity_ragged_stream_compiles_once():
     params = decoder.init(MLA, jax.random.key(0))
-    engine = ServingEngine(params, MLA, ServingConfig(
+    engine = ServingEngine(own(params), MLA, ServingConfig(
         page_size=4, num_pages=20, max_slots=3, pages_per_slot=5,
         token_budget=6, prefill_chunk=3,
     ))
@@ -88,7 +89,7 @@ def test_preempt_and_requeue_parity():
     """A pool too small for every admitted request forces recompute-style
     preemption; greedy outputs stay exact (and the requeue actually ran)."""
     params = decoder.init(CFG, jax.random.key(0))
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         page_size=2, num_pages=8, max_slots=3, pages_per_slot=6,
         token_budget=6, prefill_chunk=3,
     ))
@@ -109,7 +110,7 @@ def test_eos_stops_and_frees_pages():
         GenerateConfig(max_new_tokens=4),
     )
     eos = int(np.asarray(ref)[0, len(prompt) + 1])
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         page_size=4, num_pages=8, max_slots=2, pages_per_slot=4, token_budget=6,
     ))
     sched = engine.make_scheduler()
@@ -145,7 +146,7 @@ def test_moe_decoder_parity():
         ),
     )
     params = moe_decoder.init(cfg, jax.random.key(0))
-    engine = ServingEngine(params, cfg, ServingConfig(
+    engine = ServingEngine(own(params), cfg, ServingConfig(
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=6, prefill_chunk=3,
     ))
@@ -166,7 +167,7 @@ def test_windows_and_sinks_parity():
     params["layers"]["sinks"] = 0.5 + 0.1 * jax.random.normal(
         jax.random.key(11), params["layers"]["sinks"].shape
     )
-    engine = ServingEngine(params, cfg, ServingConfig(
+    engine = ServingEngine(own(params), cfg, ServingConfig(
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=6, prefill_chunk=3,
     ))
@@ -183,7 +184,7 @@ def test_sampling_deterministic_across_batching():
     prompt = _ragged_prompts([5], seed0=60)[0]
 
     def run(serve_cfg, extra=()):
-        engine = ServingEngine(params, CFG, serve_cfg)
+        engine = ServingEngine(own(params), CFG, serve_cfg)
         reqs = [Request(prompt=list(prompt), max_new_tokens=5,
                         temperature=0.8, seed=7)]
         reqs += [Request(prompt=list(p), max_new_tokens=4, seed=1 + i)
@@ -206,7 +207,7 @@ def test_defrag_preserves_decode():
     """Compacting the pool mid-run (tables rewritten + device gather) must
     not change subsequent decode output."""
     params = decoder.init(CFG, jax.random.key(0))
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         page_size=2, num_pages=16, max_slots=3, pages_per_slot=8,
         token_budget=6,
     ))
@@ -256,7 +257,7 @@ def test_deadline_evicts_pool_hog_from_stalled_stream():
     pool pages for the rest of its decode; the co-resident request then
     runs without further churn and its output keeps exact greedy parity."""
     params = decoder.init(CFG, jax.random.key(0))
-    engine = ServingEngine(params, CFG, ServingConfig(**_OVERLOAD))
+    engine = ServingEngine(own(params), CFG, ServingConfig(**_OVERLOAD))
     hog, blocked, blocked_prompt = _overload_stream(deadline=6)
     res = engine.serve_batch([hog, blocked])
     stats = res["stats"]
@@ -283,7 +284,7 @@ def test_no_deadline_same_stream_churns_but_completes():
     the smaller request finishes AFTER the hog despite needing 3 tokens —
     the latency cliff the per-request deadline bounds."""
     params = decoder.init(CFG, jax.random.key(0))
-    engine = ServingEngine(params, CFG, ServingConfig(**_OVERLOAD))
+    engine = ServingEngine(own(params), CFG, ServingConfig(**_OVERLOAD))
     hog, blocked, _ = _overload_stream(deadline=None)
     res = engine.serve_batch([hog, blocked])
     assert res["stats"]["timed_out"] == 0
@@ -298,7 +299,7 @@ def test_deadline_fast_forward_never_skips_a_future_arrival():
     jump PAST a future arrival — the request would be expired without ever
     getting its window to run."""
     params = decoder.init(CFG, jax.random.key(0))
-    engine = ServingEngine(params, CFG, ServingConfig(
+    engine = ServingEngine(own(params), CFG, ServingConfig(
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=8,
     ))

@@ -61,6 +61,7 @@ from automodel_tpu.serving.resilience import (
     pool_identity_ok,
     transfer_with_retry,
 )
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -191,7 +192,7 @@ def test_transfer_retry_counts_attempts_and_exhausts_loudly():
 
 def _chaos_serve(params, prompts, arrivals, max_new):
     sc = _sc(prefix_cache=PrefixCacheConfig(enabled=True))
-    router = ReplicaRouter(params, CFG, sc, ServeMeshConfig(replicas=2, tp=1))
+    router = ReplicaRouter(own(params), CFG, sc, ServeMeshConfig(replicas=2, tp=1))
     with injected(FaultSpec(point="serve_step_run.replica1", call=3)):
         res = router.serve_batch(_reqs(prompts, max_new, arrivals))
     return router, res
@@ -206,7 +207,7 @@ def test_replica_death_chaos_parity_offline(params):
     prompts = _prompts([5, 9, 3, 7, 11, 4])
     arrivals = [0, 0, 1, 2, 3, 4]
     max_new = 6
-    baseline = ServingEngine(params, CFG, _sc()).serve_batch(
+    baseline = ServingEngine(own(params), CFG, _sc()).serve_batch(
         _reqs(prompts, max_new, arrivals)
     )
 
@@ -234,7 +235,7 @@ def test_replica_death_chaos_parity_offline(params):
 
 def test_resilience_disabled_restores_fail_fast(params):
     router = ReplicaRouter(
-        params, CFG, _sc(), ServeMeshConfig(replicas=2, tp=1),
+        own(params), CFG, _sc(), ServeMeshConfig(replicas=2, tp=1),
         resilience=ServeResilienceConfig(enabled=False),
     )
     with injected(FaultSpec(point="serve_step_run.replica0", call=1)):
@@ -243,7 +244,7 @@ def test_resilience_disabled_restores_fail_fast(params):
 
 
 def test_last_replica_death_raises_named_failure(params):
-    router = ReplicaRouter(params, CFG, _sc(), ServeMeshConfig(replicas=2,
+    router = ReplicaRouter(own(params), CFG, _sc(), ServeMeshConfig(replicas=2,
                                                                tp=1))
     with injected(
         FaultSpec(point="serve_step_run.replica0", call=2),
@@ -265,7 +266,7 @@ def test_online_streams_survive_replica_death(params):
     duplicated), and the stream ends with its NORMAL finish reason,
     `recovered` marking the detour."""
     sc = _sc(prefix_cache=PrefixCacheConfig(enabled=True))
-    router = ReplicaRouter(params, CFG, sc, ServeMeshConfig(replicas=2,
+    router = ReplicaRouter(own(params), CFG, sc, ServeMeshConfig(replicas=2,
                                                             tp=1))
     prompts = _prompts([5, 9, 3, 7])
     max_new = 8
@@ -313,11 +314,11 @@ def test_prefill_class_death_degrades_to_monolithic(params):
     sc = _sc()
     prompts = _prompts([5, 9, 3, 7])
     max_new = 6
-    baseline = ServingEngine(params, CFG, sc).serve_batch(
+    baseline = ServingEngine(own(params), CFG, sc).serve_batch(
         _reqs(prompts, max_new)
     )
     router = DisaggRouter(
-        params, CFG, sc,
+        own(params), CFG, sc,
         DisaggConfig(enabled=True, transfer_pages=4,
                      prefill_token_budget=16),
     )
@@ -347,11 +348,11 @@ def test_transfer_faults_absorbed_by_retry(params):
     sc = _sc()
     prompts = _prompts([5, 9, 3])
     max_new = 6
-    baseline = ServingEngine(params, CFG, sc).serve_batch(
+    baseline = ServingEngine(own(params), CFG, sc).serve_batch(
         _reqs(prompts, max_new)
     )
     router = DisaggRouter(
-        params, CFG, sc,
+        own(params), CFG, sc,
         DisaggConfig(enabled=True, transfer_pages=4,
                      prefill_token_budget=16),
     )
@@ -375,11 +376,11 @@ def test_transfer_exhaustion_escalates_to_reprefill(params):
     sc = _sc()
     prompts = _prompts([5, 9, 3])
     max_new = 6
-    baseline = ServingEngine(params, CFG, sc).serve_batch(
+    baseline = ServingEngine(own(params), CFG, sc).serve_batch(
         _reqs(prompts, max_new)
     )
     router = DisaggRouter(
-        params, CFG, sc,
+        own(params), CFG, sc,
         DisaggConfig(enabled=True, transfer_pages=4,
                      prefill_token_budget=16),
         resilience=ServeResilienceConfig(
@@ -411,7 +412,7 @@ def test_drain_quiesce_resume_admission(params):
     """drain() stops ADMISSION while residents finish; quiesce() returns
     only once nothing is resident; resume_admission() reopens — no work
     dropped anywhere."""
-    engine = ServingEngine(params, CFG, _sc())
+    engine = ServingEngine(own(params), CFG, _sc())
     prompts = _prompts([5, 9, 4])
 
     async def run():
@@ -453,7 +454,7 @@ def test_recovery_backlog_prices_reprefill_into_shedding(params):
     `known`; admission arithmetic must count that backlog. The old
     formula (device + waiting only) admitted deadline-doomed work
     mid-recovery — this pins the corrected term."""
-    engine = ServingEngine(params, CFG, _sc())
+    engine = ServingEngine(own(params), CFG, _sc())
     fe = OnlineFrontend(engine, FAST)  # never started: pure arithmetic
     big = Request(prompt=list(range(1, 41)), max_new_tokens=4)  # 40 to re-feed
     fe._adopted.append((big, None, 0))

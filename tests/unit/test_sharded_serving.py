@@ -38,6 +38,7 @@ from automodel_tpu.serving import (
     ServingEngine,
     SpeculativeConfig,
 )
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -70,7 +71,7 @@ def _tp_ctx(tp):
 
 
 def _serve(params, cfg, mesh_ctx, sc, requests):
-    eng = ServingEngine(params, cfg, sc, mesh_ctx=mesh_ctx)
+    eng = ServingEngine(own(params), cfg, sc, mesh_ctx=mesh_ctx)
     res = eng.serve_batch(requests)
     assert res["stats"]["compiled_signatures"] == 1, res["stats"]
     return res
@@ -138,9 +139,9 @@ def test_mla_tp2_latent_sharded_parity():
     tp2 = _serve(params, MLA, _tp_ctx(2), sc, requests())
     assert tp2["outputs"] == base["outputs"]
     # the latent pool is genuinely partitioned: each rank holds r/tp
-    eng = ServingEngine(params, MLA, sc, mesh_ctx=_tp_ctx(2))
-    c_shard = eng.pool[0][0].sharding
-    assert c_shard.spec[3] == "tp", c_shard
+    eng = ServingEngine(own(params), MLA, sc, mesh_ctx=_tp_ctx(2))
+    for c, _kr in eng.pool[0]:  # every layer's own latent array
+        assert c.sharding.spec[2] == "tp", c.sharding
 
 
 def test_dp2_tp2_router_parity_balance_and_compile_once():
@@ -154,11 +155,11 @@ def test_dp2_tp2_router_parity_balance_and_compile_once():
     )
     prompts = _prompts([5, 9, 3, 7, 11, 4])
     arrivals = [0, 0, 1, 2, 3, 4]
-    base = ServingEngine(params, CFG, sc).serve_batch(
+    base = ServingEngine(own(params), CFG, sc).serve_batch(
         _reqs(prompts, arrivals)
     )
     router = ReplicaRouter(
-        params, CFG, sc, ServeMeshConfig(replicas=2, tp=2),
+        own(params), CFG, sc, ServeMeshConfig(replicas=2, tp=2),
     )
     res = router.serve_batch(_reqs(prompts, arrivals))
     st = res["stats"]
@@ -190,7 +191,7 @@ def test_router_sticky_prefix_affinity():
         ),
     ]
     router = ReplicaRouter(
-        params, CFG,
+        own(params), CFG,
         ServingConfig(
             page_size=4, num_pages=24, max_slots=3, pages_per_slot=6,
             token_budget=8, prefill_chunk=4,
@@ -244,7 +245,7 @@ def test_tp2_defrag_preserves_decode_and_sharding():
     from automodel_tpu.inference.generate import GenerateConfig, generate
 
     params = decoder.init(CFG, jax.random.key(0))
-    eng = ServingEngine(params, CFG, ServingConfig(
+    eng = ServingEngine(own(params), CFG, ServingConfig(
         page_size=2, num_pages=16, max_slots=3, pages_per_slot=8,
         token_budget=6,
     ), mesh_ctx=_tp_ctx(2))
@@ -259,7 +260,8 @@ def test_tp2_defrag_preserves_decode_and_sharding():
             eng.run_and_absorb(sched, plan, step)
             if step == 4:
                 eng.defrag(sched)
-                assert eng.pool[0][0].sharding.spec[3] == "tp"
+                for k, _v in eng.pool[0]:
+                    assert k.sharding.spec[2] == "tp"
         step += 1
     for p, req in zip(prompts, sorted(sched.finished, key=lambda r: r.rid)):
         ref = generate(
@@ -278,15 +280,15 @@ def test_mesh_validation_errors():
                        pages_per_slot=4, token_budget=4)
     with pytest.raises(ValueError, match="dp_shard=1"):
         ServingEngine(
-            params, CFG, sc,
+            own(params), CFG, sc,
             mesh_ctx=MeshConfig(dp_shard=2).build(jax.devices()[:2]),
         )
     bad_heads = dataclasses.replace(CFG, num_kv_heads=3, num_heads=3)
     with pytest.raises(ValueError, match="divisible by tp"):
-        ServingEngine(params, bad_heads, sc, mesh_ctx=_tp_ctx(2))
+        ServingEngine(own(params), bad_heads, sc, mesh_ctx=_tp_ctx(2))
     with pytest.raises(ValueError, match="MoE"):
         ServingEngine(
-            params, CFG, sc,
+            own(params), CFG, sc,
             mesh_ctx=MeshConfig(ep=2, dp_shard=1).build(jax.devices()[:2]),
         )
     with pytest.raises(ValueError, match="devices"):
@@ -314,7 +316,7 @@ def test_tp2_eagle_hidden_feedback_host_addressable():
     requests = lambda: _reqs(_prompts([5, 9], 60), [0, 1], 6)  # noqa: E731
     base = _serve(params, CFG, None, ServingConfig(**sc_kw), requests())
     eng = ServingEngine(
-        params, CFG,
+        own(params), CFG,
         ServingConfig(
             **sc_kw,
             speculative=SpeculativeConfig(

@@ -35,6 +35,7 @@ from automodel_tpu.speculative.acceptance import (
     greedy_accept_length,
     onehot_speculative_verify,
 )
+from tests.serving_params import own
 
 CFG = TransformerConfig(
     vocab_size=64, hidden_size=32, intermediate_size=48, num_layers=2,
@@ -56,7 +57,7 @@ def _ragged(seed0, lens, vocab=64):
 
 def _serve(params, geo, reqs, spec=None, prefix=None, draft_source=None):
     engine = ServingEngine(
-        params, CFG,
+        own(params), CFG,
         ServingConfig(**geo, speculative=spec, prefix_cache=prefix),
         draft_source=draft_source,
     )
@@ -494,7 +495,7 @@ def test_mla_spec_parity():
     params = decoder.init(mla, jax.random.key(0))
 
     def serve(spec):
-        engine = ServingEngine(params, mla, ServingConfig(
+        engine = ServingEngine(own(params), mla, ServingConfig(
             page_size=4, num_pages=20, max_slots=2, pages_per_slot=5,
             token_budget=10, prefill_chunk=3, speculative=spec,
         ))
