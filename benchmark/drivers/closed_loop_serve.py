@@ -48,9 +48,27 @@ class Step:
     sequence_tokens: int  # sum over the step's sequences of cached tokens
 
 
-def build_engine(run):
+def step_kernel_counts(config: dict, lowered_text: str, on_chip: bool) -> dict:
+    """{kernel name: times it stands in the lowered step}, for the names the
+    configuration's `step_kernels` lists. On the chip a configuration that
+    lists none is refused (a step is never passed unlooked-at), and so is a
+    step that lacks one it lists."""
+    names = list(config.get("step_kernels") or ())
+    counts = {name: lowered_text.count(name) for name in names}
+    if on_chip and not names:
+        raise RuntimeError(
+            "the configuration's file names no `step_kernels`: list the "
+            "kernels its lowered serve step must contain")
+    absent = [name for name, n in counts.items() if not n]
+    if on_chip and absent:
+        raise RuntimeError(f"no {', '.join(absent)} kernel in the step")
+    return counts
+
+
+def build_engine(run, ref):
     """The program's engine at the configuration's geometry, holding the
-    benchmark's weights. Returns (engine, the parameter tree's shapes)."""
+    benchmark's weights, drawn as the configuration's reference module `ref`
+    says. Returns (engine, the parameter tree's shapes)."""
     import jax
     import jax.numpy as jnp
 
@@ -67,7 +85,7 @@ def build_engine(run):
         hf, dtype=dtype, remat_policy="none", attn_impl=cfg.get("attn_impl", "auto"))
     shapes = jax.eval_shape(lambda: spec.module.init(model_cfg, jax.random.key(0)))
     params = weights.make_params(
-        run.seed, shapes, dtype, depth=cfg["published"]["num_hidden_layers"])
+        run.seed, shapes, dtype, weights.draw_for(ref, cfg))
     serving = dict(cfg["serving"])
     if run.control == "program_int8":
         # the program's own lower-precision path, switched on
@@ -221,7 +239,8 @@ def run(run) -> dict:
 
     mix, cfg = run.mix, run.config
     t_build = time.perf_counter()
-    engine, shapes = build_engine(run)
+    ref = served_check.load_reference(cfg, run.root, run.manifest["paths"])
+    engine, shapes = build_engine(run, ref)
     jax.block_until_ready(engine.params)
     run.note(engine_built_s=time.perf_counter() - t_build)
     frontend = OnlineFrontend(engine, FrontendConfig(drain=False))
@@ -232,22 +251,21 @@ def run(run) -> dict:
 
     def before_window():
         """What must hold for the window to be the cell: one compiled step,
-        no XLA fallback of the attention op, the Mosaic kernel in the step."""
+        no XLA fallback of the attention op, and in the lowered step every
+        kernel the configuration's `step_kernels` names."""
         fallbacks = {k: v for k, v in default_registry().snapshot().items()
                      if k.startswith("attention_reference_fallbacks_total")}
         text = engine.lower_step().as_text() if run.on_chip else ""
         run.note(
             step_cache_size=engine.step_cache_size(),
             attention_fallbacks=fallbacks,
-            mosaic_paged_attention_mla=text.count("paged_attention_mla"),
             ramp_steps=len(steps), ramp_requests=len(recs),
         )
         if engine.step_cache_size() != 1 or fallbacks:
             raise RuntimeError("the serve step is not the cell's: "
                                f"{engine.step_cache_size()} programs, "
                                f"fallbacks {fallbacks}")
-        if run.on_chip and "paged_attention_mla" not in text:
-            raise RuntimeError("no paged_attention_mla kernel in the step")
+        run.note(step_kernels=step_kernel_counts(cfg, text, run.on_chip))
 
     asyncio.run(drive(run, engine, frontend, source, recs, window,
                       before_window))
@@ -290,7 +308,7 @@ def run(run) -> dict:
     gc.collect()
     t_check = time.perf_counter()
     result["compared"] = served_check.check(
-        cfg, mix, run.seed, recs, logprobs, window, shapes,
+        ref, cfg, mix, run.seed, recs, logprobs, window, shapes,
         served_check.load_limits(run.workload, run.root, run.manifest["paths"]),
         control=None if run.control == "program_int8" else run.control)
     run.note(check_s=time.perf_counter() - t_check)

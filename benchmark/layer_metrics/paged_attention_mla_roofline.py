@@ -1,7 +1,8 @@
 """`paged_attention_mla`'s share of its roofline: the least time the chip
 needs for the latent-space attention of the rows and contexts the traced
-steps held (benchmark/flops.py `paged_mla_call`, one call a layer; not the
-padded rows x pages grid) over the summed device time of the kernel's events.
+steps held (`paged_mla_call` of the architecture's counts, found by
+benchmark/flops.py `counts_for`; one call a layer; not the padded rows x pages
+grid) over the summed device time of the kernel's events.
 Notes which peak bounds it."""
 
 from benchmark import flops, trace_reduce
@@ -17,9 +18,10 @@ def read(ctx):
     seconds, calls = trace_reduce.name_seconds(ctx["trace"].devices[0], KERNEL)
     if not steps or not calls:
         return None
+    counts = flops.counts_for(ctx)
     least, bounds = 0.0, {"compute": 0, "memory": 0}
     for s in steps:
-        need = flops.paged_mla_call(cfg, s.rows, s.context_tokens, s.sequence_tokens)
+        need = counts.paged_mla_call(cfg, s.rows, s.context_tokens, s.sequence_tokens)
         r = flops.roofline(need["flops"], need["bytes"], ctx["peaks"])
         least += r["least_s"] * cfg["num_hidden_layers"]
         bounds[r["bound"]] += 1

@@ -37,7 +37,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PREFIX = "serve."
 DEVICE_PLANE = "/device:TPU:0"   # one-chip cells: chip 0 is the chip
 STEP_PROGRAM = r"_step_impl"
-SUBLAYERS = ("serve.attn", "serve.mlp", "serve.moe")
 #: ops whose `op_name` the compiler drops: the TPU compiler rewrites a
 #: `ragged_dot` into kernels of its own ("ragged-dot-none", tf_op
 #: "ragged-dot-none:"), and the step has ragged dots in its routed experts
@@ -45,9 +44,6 @@ SUBLAYERS = ("serve.attn", "serve.mlp", "serve.moe")
 #: of the device's time that is.
 SCOPE_BY_NAME = ((re.compile(r"^ragged-dot"),
                   ("serve.layers", "serve.moe", "serve.moe.experts")),)
-#: what the scan of layers compiles its slicing and write-back into, by name:
-#: the cross-check of `serve_scan_copy_device_ms`
-SCAN_COPY_NAMES = r"^(dynamic-slice_bitcast_fusion|bitcast_dynamic-update-slice_fusion)"
 
 
 @dataclasses.dataclass

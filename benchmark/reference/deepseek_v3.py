@@ -29,8 +29,10 @@ Wkv_down; `n_group` = `topk_group` = 1 (Moonlight) so group-limited routing
 is the identity and is not written out; every expert is computed for every
 token and the unselected ones are weighted 0 (plain, and exact).
 
-The weights come one layer at a time from `layer_weights(stack, l)`, so a
-model whose float32 copy does not fit the device can still be followed.
+The reference asks for its own leaves: `leaf(path)` for one that has no layer
+axis (the embedding, the final norm, the head), `layer(stack, l)` for one
+layer's, so a model whose float32 copy does not fit the device can still be
+followed, and the harness names no leaf.
 
 The control (`control="int8"` or `"fp8"`) is this same reference with both
 operands of every weight matrix product (projections, experts, shared experts,
@@ -162,21 +164,24 @@ def stacks(cfg) -> list:
     return out + [("moe_layers", expert_layer, cfg["num_hidden_layers"] - k)]
 
 
-def hidden_states(cfg, ids, embedding, layer_weights, control=None):
-    """Final hidden states (B, S, H), before the last norm. `layer_weights`
-    (stack, l) -> {leaf path: float32 array}; each layer is one jitted call,
-    so only one layer's float32 weights are alive at a time."""
+def hidden_states(cfg, ids, leaf, layer, control=None):
+    """Final hidden states (B, S, H), before the last norm. `leaf(path)` makes
+    a leaf that has no layer axis, by its path in the program's tree, as it is
+    served; `layer(stack, l)` -> {leaf path: float32 array} makes one layer's.
+    Each layer is one jitted call, so only one layer's float32 weights are
+    alive at a time."""
     with jax.default_matmul_precision("highest"):
-        h = jnp.take(embedding.astype(F32), ids, axis=0)
+        h = jnp.take(leaf("embed/embedding").astype(F32), ids, axis=0)
         for stack, fn, n in stacks(cfg):
             step = jax.jit(lambda h, w, fn=fn: fn(h, w, cfg, matmul(control)))
             for l in range(n):
-                h = step(h, layer_weights(stack, l))
+                h = step(h, layer(stack, l))
         return h
 
 
-def logits_at(cfg, h_rows, final_scale, head, control=None):
+def logits_at(cfg, h_rows, leaf, control=None):
     """Float32 logits (N, V) of the chosen rows (N, H) of the hidden states."""
     with jax.default_matmul_precision("highest"):
-        x = rmsnorm(h_rows, final_scale.astype(F32), cfg["rms_norm_eps"])
-        return jax.jit(matmul(control))(x, head.astype(F32))
+        x = rmsnorm(h_rows, leaf("final_norm/scale").astype(F32),
+                    cfg["rms_norm_eps"])
+        return jax.jit(matmul(control))(x, leaf("lm_head/kernel").astype(F32))

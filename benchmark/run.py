@@ -20,7 +20,6 @@ T_START = time.time()
 import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import importlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -39,7 +38,7 @@ class Run:
     #: keys of a configuration file that are the benchmark's, not the model's
     NOT_HF_KEYS = ("source", "reduced", "assumed", "published", "stands_for",
                    "reference", "serve_dtype", "serving", "attn_impl",
-                   "architectures")
+                   "architectures", "step_kernels")
     trace_slice_s = 3.0
 
     def __init__(self, manifest: dict, workload: str, seed: int,
@@ -126,20 +125,6 @@ class Run:
         self.memory_peak_bytes = int(max(peaks))
 
 
-def load_reader(root: str, paths: list, name: str):
-    """The reader of a per-layer metric: `<path>/layer_metrics/<name>.py`."""
-    from benchmark import find_data
-
-    f = find_data(root, paths, "layer_metrics", f"{name}.py")
-    if f is None:
-        raise FileNotFoundError(f"no reader layer_metrics/{name}.py under {paths}")
-    spec = importlib.util.spec_from_file_location(
-        "benchmark_layer_metric_" + name.replace(".", "_"), f)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def metrics_of(manifest: dict, kind: str, workload: str, reported: set) -> list:
     """The manifest's metrics of `kind` that this cell is to report."""
     out = []
@@ -208,6 +193,7 @@ def main(argv=None, *, manifest_path=None, on_chip=True, root=ROOT) -> int:
 
 def finish(run: Run, manifest: dict, result: dict, devices) -> int:
     """Reduce, print the notes and the one result line."""
+    from benchmark import load_module
     from benchmark import peaks as peaks_mod
 
     window = result["window"]
@@ -248,10 +234,12 @@ def finish(run: Run, manifest: dict, result: dict, devices) -> int:
             "cell": run.cell, "steps": result["steps"], "recs": result["recs"],
             "window": window, "first_step_t": result["first_step_t"],
             "peaks": peaks_mod.peaks_for(devices[0].device_kind),
-            "note": run.note,
+            "note": run.note, "root": run.root, "paths": manifest["paths"],
         }
         for m in metrics_of(manifest, "per_layer", run.workload, reported):
-            value = load_reader(run.root, manifest["paths"], m["name"]).read(ctx)
+            # the reader of a per-layer metric: `<path>/layer_metrics/<name>.py`
+            value = load_module(run.root, manifest["paths"], "layer_metrics",
+                                m["name"]).read(ctx)
             if value is not None:
                 out_metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         shutil.rmtree(run.trace_dir, ignore_errors=True)

@@ -19,8 +19,7 @@ import os
 
 import numpy as np
 
-from benchmark import find_data, weights
-
+from benchmark import find_data, load_module, weights
 
 
 def load_limits(workload: str, root: str, paths: list) -> dict:
@@ -43,19 +42,24 @@ def pick_sample(finished: list, n: int, seed: int) -> list:
     return [finished[order[0]]] + [finished[rest[i]] for i in sorted(extra)]
 
 
-def reference_logits(config: dict, shapes: dict, seed: int, sample: list,
+def load_reference(config: dict, root: str, paths: list):
+    """The configuration's plain reference: `reference/<name>.py` under the
+    manifest's `paths`, by the name in the configuration's file."""
+    return load_module(root, paths, "reference", config["reference"])
+
+
+def reference_logits(ref, config: dict, shapes: dict, seed: int, sample: list,
                      pad_to: int, control: str | None = None):
     """Float32 logits (N, V) at every position that produced a served token
-    of the sample, in order, from the plain reference alone (or from the
-    control: the reference in the lower precision `control`)."""
-    import importlib
-
+    of the sample, in order, from the plain reference `ref` alone (or from
+    the control: the reference in the lower precision `control`). The
+    reference asks for each leaf by its path in the program's tree; what
+    it gets is made here from the seed, never taken from the program."""
     import jax
     import jax.numpy as jnp
 
-    ref = importlib.import_module(f"benchmark.reference.{config['reference']}")
     dtype = jnp.dtype(config["serve_dtype"])
-    depth = config["published"]["num_hidden_layers"]
+    draw = weights.draw_for(ref, config)
     flat = weights.tree_paths(shapes)
     key = weights.root_key(seed)
     longest = max(len(r.prompt) + len(r.tokens) for r in sample)
@@ -73,18 +77,16 @@ def reference_logits(config: dict, shapes: dict, seed: int, sample: list,
     # program, and no run would find the reference's programs in the cache
     def leaf(path):
         return jax.jit(lambda k: weights.make_leaf(
-            k, path, tuple(flat[path].shape), dtype, depth))(key)
+            k, path, tuple(flat[path].shape), dtype, draw))(key)
 
     make = jax.jit(
-        lambda k, stack, l: weights.make_layer(k, flat, stack, l, depth, dtype),
+        lambda k, stack, l: weights.make_layer(k, flat, stack, l, draw, dtype),
         static_argnums=1)
-    h = ref.hidden_states(
-        config, jnp.asarray(ids), leaf("embed/embedding"),
-        lambda stack, l: make(key, stack, l), control)
+    h = ref.hidden_states(config, jnp.asarray(ids), leaf,
+                          lambda stack, l: make(key, stack, l), control)
     h_rows = h[np.asarray(rows), np.asarray(cols)]
     del h
-    return ref.logits_at(config, h_rows, leaf("final_norm/scale"),
-                         leaf("lm_head/kernel"), control)
+    return ref.logits_at(config, h_rows, leaf, control)
 
 
 def logsumexp(logits: np.ndarray) -> np.ndarray:
@@ -92,7 +94,7 @@ def logsumexp(logits: np.ndarray) -> np.ndarray:
     return best + np.log(np.exp(logits - best[:, None]).sum(-1))
 
 
-def check(config: dict, mix: dict, seed: int, recs: list, logprobs: dict,
+def check(ref, config: dict, mix: dict, seed: int, recs: list, logprobs: dict,
           window: dict, shapes: dict, limits: dict,
           control: str | None = None) -> dict:
     """{"correct": bool, "numbers": {name: {"value", "limit"}}, "observed":
@@ -109,14 +111,15 @@ def check(config: dict, mix: dict, seed: int, recs: list, logprobs: dict,
     observed: dict = {"finished_in_window": len(finished),
                       "requests_followed": len(sample)}
     if sample:
-        logits = reference_logits(config, shapes, seed, sample,
+        logits = reference_logits(ref, config, shapes, seed, sample,
                                   mix["check"]["pad_to"])
         served = np.concatenate([np.asarray(r.tokens, np.int64) for r in sample])
         logits = jax.device_get(logits).astype(np.float64)
         stepped = [p for r in sample for p in logprobs.get(r.rid, [])]
         if control:
             low = jax.device_get(reference_logits(
-                config, shapes, seed, sample, mix["check"]["pad_to"], control)
+                ref, config, shapes, seed, sample, mix["check"]["pad_to"],
+                control)
             ).astype(np.float64)
             served = low.argmax(-1)
             low_lp = low[np.arange(len(served)), served] - logsumexp(low)

@@ -10,19 +10,36 @@ Distribution (the benchmark's choice, stated in PERF.md): kernels are normal
 with std fan_in**-0.5; the projections that write into the residual stream
 (`o_proj`, `down_proj`) are scaled down by (2 * published depth)**-0.5, the
 GPT-2 / Megatron "scaled init", so that the stream does not grow with depth;
-the embedding has std 1; norm scales are 1 + 0.1 N; the router's selection
-bias is 0.02 N. Every value is rounded to the dtype it is served in.
+the embedding has std 1; norm scales are 1 + 0.1 N; a `bias` and the router's
+selection bias are 0.02 N. A leaf that only one architecture has is drawn by
+that architecture's reference module: its optional `LEAF_RULES`, {last path
+component: (mean, std)}. Which subtrees carry a leading layer axis is the
+reference's to say too (`stacks(cfg)`). Every value is rounded to the dtype
+it is served in.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import zlib
 
 import jax
 import jax.numpy as jnp
 
-#: leaves whose first axis is the layer axis of a scanned stack
-STACKS = ("dense_layers", "moe_layers", "layers")
+
+@dataclasses.dataclass(frozen=True)
+class Draw:
+    """How one configuration's tree is drawn."""
+    depth: int      # the PUBLISHED depth: the scaled init does not follow a cut
+    stacks: tuple   # subtrees whose leaves have a leading layer axis
+    rules: dict     # the architecture's own leaves: {last component: (mean, std)}
+
+
+def draw_for(ref, config: dict) -> Draw:
+    """`ref`: the configuration's reference module."""
+    return Draw(int(config["published"]["num_hidden_layers"]),
+                tuple(name for name, *_ in ref.stacks(config)),
+                dict(getattr(ref, "LEAF_RULES", {})))
 
 
 def root_key(seed: int) -> jax.Array:
@@ -35,30 +52,34 @@ def _path_key(key: jax.Array, path: str) -> jax.Array:
     return jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
 
 
-def leaf_rule(path: str, shape: tuple, depth: int) -> tuple[float, float]:
+def leaf_rule(path: str, shape: tuple, draw: Draw) -> tuple[float, float]:
     """(mean, std) of the leaf at `path`; `shape` is one layer's shape."""
     name = path.split("/")
     if name[-1] == "scale":
         return 1.0, 0.1
-    if name[-1] == "e_score_bias":
+    if name[-1] in ("e_score_bias", "bias"):
         return 0.0, 0.02
     if name[-1] == "embedding":
         return 0.0, 1.0
     if name[-1] in ("kernel", "weight"):
         std = shape[-2] ** -0.5
         if name[-2] in ("o_proj", "down_proj"):
-            std *= (2.0 * depth) ** -0.5
+            std *= (2.0 * draw.depth) ** -0.5
         return 0.0, std
-    raise KeyError(f"no rule for the weight leaf {path!r}")
+    if name[-1] in draw.rules:
+        mean, std = draw.rules[name[-1]]
+        return float(mean), float(std)
+    raise KeyError(f"no rule for the weight leaf {path!r}: give its "
+                   "reference module a LEAF_RULES entry")
 
 
-def make_leaf(key: jax.Array, path: str, shape: tuple, dtype, depth: int,
+def make_leaf(key: jax.Array, path: str, shape: tuple, dtype, draw: Draw,
               layer: int | jax.Array | None = None) -> jax.Array:
     """One leaf (one layer of it where `layer` is given), traceable."""
     k = _path_key(key, path)
     if layer is not None:
         k = jax.random.fold_in(k, layer)
-    mean, std = leaf_rule(path, shape, depth)
+    mean, std = leaf_rule(path, shape, draw)
     x = mean + std * jax.random.normal(k, shape, jnp.float32)
     return x.astype(dtype)
 
@@ -75,7 +96,7 @@ def tree_paths(shapes: dict, prefix: str = "") -> dict:
     return out
 
 
-def make_params(seed: int, shapes: dict, dtype, depth: int) -> dict:
+def make_params(seed: int, shapes: dict, dtype, draw: Draw) -> dict:
     """The whole tree the program asks for (`shapes`: its own
     `jax.eval_shape(init)`), in `dtype`, in ONE jitted call. A stacked leaf
     is filled layer by layer (`lax.map`), so layer l of it is what
@@ -86,15 +107,15 @@ def make_params(seed: int, shapes: dict, dtype, depth: int) -> dict:
     def build(key):
         out = {}
         for path, s in flat.items():
-            if path.split("/")[0] in STACKS:
+            if path.split("/")[0] in draw.stacks:
                 one = tuple(s.shape[1:])
                 out[path] = jax.lax.map(
                     lambda l, path=path, one=one: make_leaf(
-                        key, path, one, dtype, depth, l),
+                        key, path, one, dtype, draw, l),
                     jnp.arange(s.shape[0]),
                 )
             else:
-                out[path] = make_leaf(key, path, tuple(s.shape), dtype, depth)
+                out[path] = make_leaf(key, path, tuple(s.shape), dtype, draw)
         return out
 
     made = jax.jit(build)(root_key(seed))
@@ -109,13 +130,13 @@ def make_params(seed: int, shapes: dict, dtype, depth: int) -> dict:
 
 
 def make_layer(seed_key: jax.Array, shapes_flat: dict, stack: str, layer: int,
-               depth: int, dtype) -> dict:
+               draw: Draw, dtype) -> dict:
     """The leaves of one layer of `stack`, rounded to `dtype` as served and
     widened to float32 for the reference: {"moe/gate/weight": array}."""
     out = {}
     for path, s in shapes_flat.items():
         if path.split("/")[0] != stack:
             continue
-        leaf = make_leaf(seed_key, path, tuple(s.shape[1:]), dtype, depth, layer)
+        leaf = make_leaf(seed_key, path, tuple(s.shape[1:]), dtype, draw, layer)
         out[path[len(stack) + 1:]] = leaf.astype(jnp.float32)
     return out

@@ -1,7 +1,9 @@
-"""The command driven end to end on the CPU through a tiny configuration:
-everything of a run except the look for a chip. It prints counts only and no
-number under a device metric's name; `correct` is true for the program as it
-is, and false for each control (the reference in int8 or fp8 put in the
+"""The command driven end to end on the CPU through two tiny configurations
+(a DeepseekV3 with MLA and experts; `tiny_dense`, a Mistral-shaped decoder
+with qkv biases that entered the tests' manifest as new files and entries
+alone): everything of a run except the look for a chip. It prints counts only
+and no number under a device metric's name; `correct` is true for the program
+as it is, and false for each control (the reference in int8 or fp8 put in the
 program's place; the program's own int8 path) and for a token altered where
 it is produced."""
 
@@ -19,20 +21,26 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 TINY = os.path.join(HERE, "tiny", "BENCHMARK.json")
 
 
-def drive(*extra, seed=3_000_000_019, trace=0):
+#: each cell of the tests' manifest, and the kernel its configuration's
+#: `step_kernels` names (looked for on the chip only: 0 in a rehearsal)
+CELLS = {"tiny.tiny_c4": "paged_attention_mla",
+         "tiny_dense.tiny_c4": "paged_attention_gqa"}
+
+
+def drive(*extra, seed=3_000_000_019, trace=0, cell="tiny.tiny_c4"):
     out, err = io.StringIO(), io.StringIO()
     with contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
         rc = bench_run.main(
-            ["--workload", "tiny.tiny_c4", "--seed", str(seed), "--seconds", "1",
+            ["--workload", cell, "--seed", str(seed), "--seconds", "1",
              "--trace", str(trace), *extra],
             manifest_path=TINY, on_chip=False)
     lines = out.getvalue().splitlines()
     return rc, lines, err.getvalue().splitlines()
 
 
-@pytest.fixture(scope="module")
-def rehearsal():
-    return drive()
+@pytest.fixture(scope="module", params=list(CELLS))
+def rehearsal(request):
+    return drive(cell=request.param)
 
 
 def test_last_line_is_the_contracts_object(rehearsal):
@@ -55,6 +63,9 @@ def test_a_run_off_the_chip_prints_counts_and_no_device_metric(rehearsal):
     assert counts["first_tokens_in_window"] > 0 and counts["gaps_in_window"] > 0
     before = next(n for n in notes if "step_cache_size" in n)
     assert before["step_cache_size"] == 1 and before["attention_fallbacks"] == {}
+    cell = next(n for n in notes if "workload" in n)["workload"]
+    kernels = next(n for n in notes if "step_kernels" in n)["step_kernels"]
+    assert kernels == {CELLS[cell]: 0}
     last = next(n for n in notes if "compilations_inside_window" in n)
     assert last["compilations_inside_window"] == 0
 
@@ -77,9 +88,10 @@ def test_the_check_follows_the_longest_finished_request(rehearsal):
     assert seen["finished_in_window"] > seen["requests_followed"]
 
 
+@pytest.mark.parametrize("cell", list(CELLS))
 @pytest.mark.parametrize("control", ["fp8", "program_int8"])
-def test_the_control_comes_out_not_correct(control):
-    rc, lines, err = drive("--control", control, seed=5)
+def test_the_control_comes_out_not_correct(control, cell):
+    rc, lines, err = drive("--control", control, seed=5, cell=cell)
     assert rc == 0
     line = json.loads(lines[-1])
     assert line["correct"] is False

@@ -133,8 +133,12 @@ def test_config_file_against_its_source(config):
         held = json.load(f)
     assert held["source"] == config["source"]
     assert held["reduced"] == config["reduced"]
-    assert os.path.exists(os.path.join(
-        ROOT, "benchmark", "reference", f"{held['reference']}.py"))
+    # a new architecture brings its reference and its counts, found by name;
+    # its file says which kernels the lowered step has to contain
+    assert find("reference", f"{held['reference']}.py"), "no reference"
+    assert find("counts", f"{held['reference']}.py"), "no operation counts"
+    assert held["step_kernels"] and all(
+        isinstance(k, str) and k for k in held["step_kernels"])
     if not os.path.exists(CATALOG):
         pytest.skip("no catalog here")
     with open(CATALOG) as f:

@@ -210,7 +210,7 @@ def test_gap_readers_report_the_medians_and_note_the_parts():
     assert notes[0]["longest_gap_ms"]["device_gap"] == pytest.approx(10.1)
 
 
-def test_an_op_under_the_moe_scope_counts_for_moe_and_not_for_scan_copy():
+def test_an_op_under_the_moe_scope_counts_for_moe_and_an_attention_op_for_attn():
     t = trace()
     moe, notes = read("serve_moe_device_ms", t)
     # route 1.5 + experts 46..86 = 40 (two ragged dots, 2 ms shared) + shared
@@ -225,18 +225,14 @@ def test_an_op_under_the_moe_scope_counts_for_moe_and_not_for_scan_copy():
     assert attn == pytest.approx(9.5)
     assert notes[0]["serve_attn_device_ms"] == pytest.approx(
         {"kernel": 8.0, "pool_write": 0.5, "rest": 1.0})
-    scan, notes = read("serve_scan_copy_device_ms", t)
-    # under serve.layers and no sublayer: the 30 ms slice alone; by name the
-    # write-back counts too, which the scopes gave to the MoE block
-    assert scan == pytest.approx(30.0)
-    assert notes[0]["serve_scan_copy_device_ms"] == pytest.approx({"by_name": 30.25})
-    assert moe + attn + scan <= 90.0
+    # what stands under `serve.layers` and no sublayer (the synthetic 30 ms
+    # slice) is neither's: no column reads it since PR 28
+    assert moe + attn <= 90.0 - 30.0
 
 
 def test_no_scope_at_all_gives_none_and_the_note_never_zero():
     t = trace(scoped=False)
-    for name in ("serve_moe_device_ms", "serve_attn_device_ms",
-                 "serve_scan_copy_device_ms"):
+    for name in ("serve_moe_device_ms", "serve_attn_device_ms"):
         value, notes = read(name, t)
         assert value is None
         assert notes == [{name: None, "why": "scopes_missing"}]
@@ -392,7 +388,7 @@ def test_op_names_reads_the_event_metadata_that_profiledata_hides(tmp_path):
     moe, notes = read("serve_moe_device_ms", t)
     assert moe == pytest.approx(0.040)
     assert notes[0]["unscoped_ms"] == {"copy.5": pytest.approx(0.010)}
-    assert read("serve_scan_copy_device_ms", t)[0] == pytest.approx(0.030)
+    assert read("serve_attn_device_ms", t)[0] == pytest.approx(0.020)
 
 
 def test_a_step_from_a_cache_without_scopes_reads_none_not_the_named_ops(tmp_path):
@@ -402,7 +398,6 @@ def test_a_step_from_a_cache_without_scopes_reads_none_not_the_named_ops(tmp_pat
     path.write_bytes(device_xspace(scoped=False))
     t = pt.load(str(path))
     assert [o.by_name for o in t.ops] == [False, True, False, False]
-    for name in ("serve_moe_device_ms", "serve_attn_device_ms",
-                 "serve_scan_copy_device_ms"):
+    for name in ("serve_moe_device_ms", "serve_attn_device_ms"):
         value, notes = read(name, t)
         assert value is None and notes == [{name: None, "why": "scopes_missing"}]

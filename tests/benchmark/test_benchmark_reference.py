@@ -18,7 +18,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 with open(os.path.join(HERE, "tiny", "tiny_deepseek_v3.json")) as _f:
     CFG = json.load(_f)
 SEED = 2**31 + 5
-DEPTH = CFG["published"]["num_hidden_layers"]
+DRAW = weights.draw_for(ref, CFG)
+DEPTH = DRAW.depth
 
 
 @pytest.fixture(scope="module")
@@ -30,7 +31,7 @@ def program():
     model_cfg = spec.config_from_hf(
         hf, dtype=jnp.float32, remat_policy="none", attn_impl="xla")
     shapes = jax.eval_shape(lambda: spec.module.init(model_cfg, jax.random.key(0)))
-    params = weights.make_params(SEED, shapes, jnp.float32, DEPTH)
+    params = weights.make_params(SEED, shapes, jnp.float32, DRAW)
     return spec, model_cfg, shapes, params
 
 
@@ -52,7 +53,7 @@ def test_a_layer_made_alone_is_the_layer_of_the_whole(program, stack, layer):
     _, _, shapes, params = program
     flat = weights.tree_paths(shapes)
     alone = weights.make_layer(weights.root_key(SEED), flat, stack, layer,
-                               DEPTH, jnp.float32)
+                               DRAW, jnp.float32)
     whole = weights.tree_paths(params)
     # to one unit in the last place of float32: inside the whole's loop the
     # compiler may contract a multiply-add that it leaves apart outside it.
@@ -69,7 +70,7 @@ def test_weights_differ_by_seed_layer_and_leaf(program):
     a = flat["moe_layers/moe/experts/up_proj/kernel"]
     assert not np.allclose(a[0], a[1])
     assert not np.allclose(a[0], flat["moe_layers/moe/experts/gate_proj/kernel"][0])
-    other = weights.make_params(SEED + 1, shapes, jnp.float32, DEPTH)
+    other = weights.make_params(SEED + 1, shapes, jnp.float32, DRAW)
     assert not np.allclose(a, other["moe_layers"]["moe"]["experts"]["up_proj"]["kernel"])
     # the rules: residual writers are scaled down, norm scales sit near 1
     assert float(jnp.std(flat["moe_layers/o_proj/kernel"])) == pytest.approx(
@@ -88,11 +89,11 @@ def test_training_forward_gives_the_reference_logits(program):
 
     flat = weights.tree_paths(shapes)
     key = weights.root_key(SEED)
+    leaf = weights.tree_paths(params).__getitem__
     h = ref.hidden_states(
-        CFG, jnp.asarray(ids), weights.tree_paths(params)["embed/embedding"],
-        lambda stack, l: weights.make_layer(key, flat, stack, l, DEPTH, jnp.float32))
-    want = ref.logits_at(CFG, h.reshape(-1, h.shape[-1]),
-                         params["final_norm"]["scale"], params["lm_head"]["kernel"])
+        CFG, jnp.asarray(ids), leaf,
+        lambda stack, l: weights.make_layer(key, flat, stack, l, DRAW, jnp.float32))
+    want = ref.logits_at(CFG, h.reshape(-1, h.shape[-1]), leaf)
     np.testing.assert_allclose(
         np.asarray(got).reshape(want.shape), want, atol=2e-4, rtol=0)
     assert float(jnp.std(want)) > 0.5   # logits are not degenerate
