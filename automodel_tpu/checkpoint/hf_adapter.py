@@ -126,6 +126,9 @@ class DenseDecoderAdapter:
     `style="glm4"` switches to GLM-4 naming: the fused `mlp.gate_up_proj`
     (first half gate, second half up — transformers modeling_glm4 Glm4MLP)
     and the `post_self_attn/post_mlp_layernorm` sandwich-norm names.
+    `style="ouro"` names the sandwich norms `input_layernorm_2` /
+    `post_attention_layernorm_2`; a model with an exit gate carries
+    `model.early_exit_gate.{weight,bias}`.
     """
 
     cfg: TransformerConfig
@@ -164,6 +167,12 @@ class DenseDecoderAdapter:
                     ("post_self_attn_layernorm.weight", ("post_attn_out_norm", "scale"), False),
                     ("post_attention_layernorm.weight", ("post_attn_norm", "scale"), False),
                     ("post_mlp_layernorm.weight", ("post_mlp_norm", "scale"), False),
+                ]
+            elif self.style == "ouro":
+                e += [
+                    ("input_layernorm_2.weight", ("post_attn_out_norm", "scale"), False),
+                    ("post_attention_layernorm.weight", ("post_attn_norm", "scale"), False),
+                    ("post_attention_layernorm_2.weight", ("post_mlp_norm", "scale"), False),
                 ]
             else:
                 # gemma2 4-norm naming
@@ -291,6 +300,12 @@ class DenseDecoderAdapter:
         ]
         if not self.cfg.tie_word_embeddings:
             e.append(("lm_head.weight", ("lm_head", "kernel"), True))
+        if getattr(self.cfg, "exit_gate", False):
+            # nn.Linear(hidden, 1): weight (1, H), bias (1,)
+            e += [
+                ("model.early_exit_gate.weight", ("exit_gate", "kernel"), True),
+                ("model.early_exit_gate.bias", ("exit_gate", "bias"), False),
+            ]
         return [(*entry, None) for entry in e]
 
     def _indexer_absent(self, layer_idx: int) -> bool:

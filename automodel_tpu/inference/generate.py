@@ -27,6 +27,10 @@ the MoE stack, routing each decoded token through the gate; dispatch is
 forced dropless at decode time (exact for any token population — the
 capacity bound would depend on B·S vs B and silently drop differently).
 
+Looped decoders (`cfg.num_passes` > 1: the layer stack walked that many
+times over every token, the final norm after each walk) keep one such cache
+per pass: passes x L entries in all.
+
 Greedy or temperature sampling. Batched beam search is later-round work.
 """
 
@@ -325,16 +329,37 @@ def generate(
         stacks = [(params["layers"], _dense_mlp, L)]
 
     all_windows = [w or 0 for w in layer_windows(cfg, sum(s[2] for s in stacks))]
-    caches = []
+    # a looped decoder (cfg.num_passes > 1) walks its stacks that many times
+    # with the same weights and keeps a cache per (pass, layer): `caches`
+    # holds one list of per-stack caches for each pass
+    caches = [
+        [_cache_shapes(cfg, L, B, T) for _, _, L in stacks]
+        for _ in range(cfg.num_passes)
+    ]
     stack_windows = []
     off = 0
     for _, _, L in stacks:
-        caches.append(_cache_shapes(cfg, L, B, T))
         stack_windows.append(jnp.asarray(all_windows[off : off + L], jnp.int32))
         off += L
 
-    def run_stacks(h, positions, caches, write_at, attend_len,
-                   freq_override=None, deepstack=None):
+    def final_norm(h):
+        return rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps,
+                        cfg.zero_centered_norm)
+
+    def run_stacks(h, positions, caches, write_at, attend_len, **kw):
+        """Every pass over the stacks; the final norm stands between two
+        passes (the last pass's is the caller's, on the rows it reads)."""
+        new_caches = []
+        for t, pass_caches in enumerate(caches):
+            if t:
+                h = final_norm(h)
+            h, pass_caches = run_pass(
+                h, positions, pass_caches, write_at, attend_len, **kw)
+            new_caches.append(pass_caches)
+        return h, new_caches
+
+    def run_pass(h, positions, caches, write_at, attend_len,
+                 freq_override=None, deepstack=None):
         """`freq_override` (per-token angles) replaces the layer-window freq
         table (MRoPE); `deepstack` (K,B,S,H) is injected after global layer
         gidx<K (prefill only)."""
@@ -376,7 +401,7 @@ def generate(
         h, positions, caches, 0, S,
         freq_override=rope_angles, deepstack=deepstack_embeds,
     )
-    h_last = rms_norm(h[:, -1:], params["final_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+    h_last = final_norm(h[:, -1:])
     logits = unembed(params, cfg, h_last)[:, 0]
 
     def sample(logits, key):
@@ -405,7 +430,7 @@ def generate(
         h = _embed(params, cfg, token[:, None])
         h, caches = run_stacks(h, positions, caches, pos, pos + 1,
                                freq_override=freq)
-        h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+        h = final_norm(h)
         logits = unembed(params, cfg, h)[:, 0]
         key, sub = jax.random.split(key)
         next_token = sample(logits, sub)

@@ -75,6 +75,14 @@ class TransformerConfig:
     # per-layer "sliding"/"global" types; None → sliding_window on all layers
     layer_types: Optional[tuple] = None
     use_post_norms: bool = False  # gemma2-style norms on the attn/mlp branches
+    # looped decoders (Ouro's `total_ut_steps`): the whole layer stack runs
+    # `num_passes` times over every token with the SAME weights, the final
+    # norm after each walk; pass t, layer l keeps a cache entry of its own
+    # (index t * num_layers + l). 1 = an ordinary decoder.
+    num_passes: int = 1
+    # a Linear hidden -> 1 with bias on each pass's normed state (shared by
+    # the passes): the per-pass exit probability of a looped decoder
+    exit_gate: bool = False
     logits_soft_cap: Optional[float] = None
     attn_soft_cap: Optional[float] = None
     embed_scale: float = 1.0  # gemma multiplies embeddings by sqrt(hidden)
@@ -182,16 +190,21 @@ class TransformerConfig:
     def flops_per_token(self, seq_len: int) -> float:
         """Training FLOPs/token (fwd+bwd ≈ 6*N + attention term) for MFU."""
         D = self.resolved_head_dim
+        layer_params = (
+            self.attn_params_per_layer()
+            + 3 * self.hidden_size * self.intermediate_size
+        )
         n_params = (
             self.vocab_size * self.hidden_size * (1 if self.tie_word_embeddings else 2)
-            + self.num_layers
-            * (
-                self.attn_params_per_layer()
-                + 3 * self.hidden_size * self.intermediate_size
-            )
+            + self.num_layers * layer_params
         )
         attn_flops = 6 * self.num_layers * self.num_heads * D * seq_len  # 2*2*1.5 causal
-        return 6.0 * n_params + attn_flops
+        # a looped decoder runs its layers (not the embedding or the head)
+        # num_passes times over every token
+        layers_again = (self.num_passes - 1) * (
+            6.0 * self.num_layers * layer_params + attn_flops
+        )
+        return 6.0 * n_params + attn_flops + layers_again
 
 
 def layer_windows(cfg: "TransformerConfig", num_layers: int | None = None) -> tuple:
@@ -335,6 +348,11 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
     }
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"kernel": dense_init(jax.random.fold_in(rng, 99), (H, cfg.vocab_size))}
+    if cfg.exit_gate:
+        params["exit_gate"] = {
+            "kernel": dense_init(jax.random.fold_in(rng, 98), (H, 1)),
+            "bias": jnp.zeros((1,)),
+        }
     return params
 
 
@@ -355,6 +373,8 @@ def param_specs(cfg: TransformerConfig) -> dict:
     }
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = {"kernel": ("embed", "vocab")}
+    if cfg.exit_gate:
+        specs["exit_gate"] = {"kernel": ("embed", None), "bias": (None,)}
     return specs
 
 
@@ -614,10 +634,19 @@ def forward(
     return_hidden: bool = False,
     inputs_embeds: jnp.ndarray | None = None,  # (B,S,H) — VLM merged embeds
     return_aux_hidden: tuple | None = None,    # layer indices → EAGLE-3 aux
+    return_gate_logits: bool = False,          # looped decoders: (P,B,S) too
 ) -> jnp.ndarray:
     """Run the decoder. Returns logits (B,S,V) fp32, or hidden (B,S,H) when
     `return_hidden` (pair with loss/linear_ce.py to avoid materializing
     logits — the FusedLinearCrossEntropy analog).
+
+    A looped decoder (`cfg.num_passes` > 1) walks the same layer stack that
+    many times, the final norm after each walk; the normed state is the next
+    pass's input and the last pass's is what the head reads. With
+    `return_gate_logits` (needs `cfg.exit_gate`) the result becomes
+    (out, gate_logits): the exit gate's logit on each pass's normed state,
+    (num_passes, B, S) fp32. Which pass a token leaves at is not decided
+    here (adaptive exit is not built: every token runs every pass).
 
     `return_aux_hidden=(lo, mid, hi)` additionally returns the outputs of
     those layers (pre-final-norm) stacked (k, B, S, H) — the target-side
@@ -649,6 +678,32 @@ def forward(
 
     inv_freq = rope_frequencies(cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling)
     freq_for = make_freq_for(cfg, inv_freq)
+
+    if return_gate_logits and not cfg.exit_gate:
+        raise ValueError("return_gate_logits needs a model with an exit gate")
+    gate_logits = []
+
+    def end_pass(h):
+        """A pass's final norm (and, when asked, the exit gate's logit on
+        the normed state, (B, S) fp32)."""
+        h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+        if return_gate_logits:
+            g = params["exit_gate"]
+            gate_logits.append(
+                jnp.einsum("bsh,ho->bso", h, g["kernel"].astype(h.dtype),
+                           preferred_element_type=jnp.float32)[..., 0]
+                + g["bias"].astype(jnp.float32)
+            )
+        return h
+
+    if cfg.num_passes > 1 and (
+        return_aux_hidden is not None
+        or (mesh_ctx is not None and mesh_ctx.sizes["pp"] > 1)
+    ):
+        raise NotImplementedError(
+            "a looped decoder (num_passes > 1) under the pp pipeline or with "
+            "aux-hidden capture"
+        )
 
     if mesh_ctx is not None and mesh_ctx.sizes["pp"] > 1:
         from automodel_tpu.parallel.pp import pipeline_layers
@@ -719,15 +774,20 @@ def forward(
                 unroll=cfg.scan_unroll,
             )
         else:
-            h = scan_layers_windowed(
-                layer, h, params["layers"], layer_windows(cfg),
-                remat_policy=cfg.remat_policy, unroll=cfg.scan_unroll,
-            )
+            for t in range(cfg.num_passes):
+                if t:  # the pass before leaves its normed state as the input
+                    h = end_pass(h)
+                h = scan_layers_windowed(
+                    layer, h, params["layers"], layer_windows(cfg),
+                    remat_policy=cfg.remat_policy, unroll=cfg.scan_unroll,
+                )
 
-    h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+    h = end_pass(h)
     out = h if return_hidden else unembed(params, cfg, h)
     if return_aux_hidden is not None:
         return out, aux
+    if return_gate_logits:
+        return out, jnp.stack(gate_logits)
     return out
 
 

@@ -196,6 +196,39 @@ def gemma2_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def ouro_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
+    """OuroForCausalLM (ByteDance Ouro 1.4B / 2.6B, "Scaling Latent Reasoning
+    via Looped Language Models"): a llama-shaped MHA decoder with sandwich
+    norms on both branches whose whole layer stack runs `total_ut_steps`
+    times over every token, the final norm after each walk, a cache entry
+    per (pass, layer), and a Linear hidden -> 1 exit gate on each pass's
+    normed state. With the published `early_exit_threshold` of 1 the exit
+    distribution's cumulated mass reaches the threshold only at the last
+    pass, so every token's logits come from the last pass; a lower threshold
+    lets tokens leave at different passes, which is not built."""
+    threshold = float(hf.get("early_exit_threshold", 1.0))
+    if threshold < 1.0:
+        raise NotImplementedError(
+            f"early_exit_threshold={threshold}: adaptive exit (rows that leave "
+            "the layer stack at different passes) is not built; every token "
+            "runs all total_ut_steps passes, which is what a threshold of 1 "
+            "means"
+        )
+    types = hf.get("layer_types") or []
+    if any(t != "full_attention" for t in types) or hf.get("use_sliding_window"):
+        raise NotImplementedError(
+            "OuroForCausalLM with sliding-window layers (the published models "
+            "are full attention throughout)"
+        )
+    kw = _base_kwargs(hf)
+    kw["rms_norm_eps"] = float(hf.get("rms_norm_eps", 1e-6))
+    kw["use_post_norms"] = True
+    kw["num_passes"] = int(hf.get("total_ut_steps", 4))
+    kw["exit_gate"] = True
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
 def baichuan_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
     """BaichuanForCausalLM — Baichuan2 7B shape (reference: models/baichuan/
     model.py): llama-like MHA with a fused W_pack qkv projection (handled by

@@ -334,6 +334,11 @@ def forward(
     """
     from automodel_tpu.models.common.layers import cast_params
 
+    if cfg.num_passes != 1:
+        raise NotImplementedError(
+            "a looped MoE decoder (num_passes > 1): only the dense decoder's "
+            "forward walks its stack more than once"
+        )
     params = cast_params(params, cfg.dtype)  # fp32 master → compute dtype
     B, S = input_ids.shape
     if positions is None:

@@ -164,6 +164,12 @@ MODEL_ARCH_MAPPING: dict[str, ModelSpec] = {
         "deepseek_v32", moe_families.deepseek_v4_config, moe_decoder,
         adapter_name="moe_decoder", adapter_kwargs={"style": "deepseek"},
     ),
+    # Ouro LoopLM: the dense decoder walked total_ut_steps times, with an
+    # exit gate (HF names its four norms input_layernorm{,_2} and
+    # post_attention_layernorm{,_2})
+    "OuroForCausalLM": ModelSpec(
+        "ouro", families.ouro_config, decoder, adapter_kwargs={"style": "ouro"},
+    ),
     "BaichuanForCausalLM": ModelSpec(
         "baichuan", families.baichuan_config, decoder,
         adapter_kwargs={"style": "baichuan"},

@@ -260,6 +260,8 @@ METRIC_CATALOG = (
     ("serve_cancelled_total", "counter", "requests cancelled mid-flight"),
     ("serve_free_pages", "gauge", "KV pages currently free"),
     ("serve_compiled_signatures", "gauge", "jit cache entries for the serve step"),
+    ("serve_passes", "gauge", "times the serve step walks the layer stack over a token (looped decoders: > 1)"),
+    ("serve_kv_bytes_per_token", "gauge", "KV cache bytes one token holds, every pass and layer counted"),
     # prefix cache
     ("serve_prefix_hits_total", "counter", "admissions that matched a cached prefix"),
     ("serve_prefill_skipped_tokens_total", "counter", "prompt tokens skipped via prefix reuse"),

@@ -79,17 +79,21 @@ def _gqa_kernel(
         ) * scale
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
+        # the causal mask is made on the (Hq, ps) scores: on (Hkv, groups,
+        # ps) a model without grouping (groups == 1: one query head per
+        # key/value head) hands Mosaic a compare with a one-wide sublane
+        # axis, which the TPU compiler refuses (LLO_CHECK ProducesVreg)
+        s = s.reshape(Hq, ps)
         kv_idx = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (Hkv, groups, ps), 2
+            jnp.int32, (Hq, ps), 1
         )
         mask = kv_idx <= pos
         s = jnp.where(mask, s, NEG_INF)
-        s = s.reshape(Hq, ps)
 
         m_prev = m_scr[:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(mask.reshape(Hq, ps), jnp.exp(s - m_new), 0.0)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = jnp.broadcast_to(
             l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
@@ -208,17 +212,18 @@ def _gqa_quant_kernel(
         ) * ks * scale
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
+        # the mask on the 2-D scores, as in `_gqa_kernel` (and why)
+        s = s.reshape(Hq, ps)
         kv_idx = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (Hkv, groups, ps), 2
+            jnp.int32, (Hq, ps), 1
         )
         mask = kv_idx <= pos
         s = jnp.where(mask, s, NEG_INF)
-        s = s.reshape(Hq, ps)
 
         m_prev = m_scr[:, :1]
         m_cur = jnp.max(s, axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(mask.reshape(Hq, ps), jnp.exp(s - m_new), 0.0)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = jnp.broadcast_to(
             l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),

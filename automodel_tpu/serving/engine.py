@@ -97,6 +97,7 @@ from automodel_tpu.serving.kv_pages import (
     PageAllocator,
     apply_defrag,
     init_pool,
+    pool_bytes,
     pool_shardings,
 )
 from automodel_tpu.serving.prefix_cache import PrefixCache, PrefixCacheConfig
@@ -272,8 +273,10 @@ def split_layer_stacks(params: dict, dtype) -> dict:
 
 class ServingEngine:
     """Paged-cache continuous-batching engine for the generic decoder
-    families (TransformerConfig / MoETransformerConfig, GQA or MLA). The
-    heterogeneous python-loop engine (HetMoEConfig) is not servable here."""
+    families (TransformerConfig / MoETransformerConfig, GQA or MLA; a looped
+    decoder's passes are walked over a pool of passes x layers entries). The
+    heterogeneous engine (HetMoEConfig: layers of unlike geometry, sparse
+    index caches) is not servable here."""
 
     def __init__(
         self,
@@ -301,8 +304,10 @@ class ServingEngine:
 
         if isinstance(cfg, HetMoEConfig):
             raise NotImplementedError(
-                "ServingEngine drives the layer-scan decoders; the het "
-                "engine's per-layer python loop needs its own step function"
+                "ServingEngine serves the decoders whose layers share one "
+                "geometry (TransformerConfig / MoETransformerConfig); the het "
+                "engine's unlike layers and index caches need a step function "
+                "and a pool of their own"
             )
         # serve-step linear precision: all decoder/generate linears already
         # route through ops/quant.matmul(x, kernel, cfg.linear_precision),
@@ -436,6 +441,14 @@ class ServingEngine:
                 max_context=serve_cfg.pages_per_slot * serve_cfg.page_size,
             )
         self._needs_hidden = getattr(self._draft_source, "needs_hidden", "none")
+        # what a cached token costs here, every pass and layer (and scale
+        # plane) counted: with the passes, what makes a page dear
+        reg = self.obs.registry
+        reg.gauge("serve_passes").set(cfg.num_passes)
+        reg.gauge("serve_kv_bytes_per_token").set(
+            pool_bytes(self.pool)
+            // ((serve_cfg.num_pages + 1) * serve_cfg.page_size)
+        )
         if self._mesh is None:
             self._step = jax.jit(self._step_impl, donate_argnums=(1,))
         else:
@@ -736,22 +749,35 @@ class ServingEngine:
             h = _embed(params, cfg, b["tok"][None])  # (1, T, H)
         h = self._constrain_rep(h)
 
-        new_pool = []
-        for (pkey, mlp_fn, _), stack, wins in zip(
-            self._stacks, pool, self._stack_windows
-        ):
-            mlp_scope = "serve.mlp" if mlp_fn is _dense_mlp else "serve.moe"
-            new_stack = []
-            with jax.named_scope("serve.layers"):
-                for lp, cache, win in zip(params[pkey], stack, wins):
-                    with jax.named_scope("serve.attn"):
-                        h, cache = self._attn(h, lp, win, cache, b)
-                    with jax.named_scope(mlp_scope):
-                        h = mlp_fn(h, lp, cfg)
-                    h = self._constrain_rep(h)
-                    new_stack.append(cache)
-            new_pool.append(tuple(new_stack))
-        new_pool = self._constrain_pool(new_pool)
+        # A looped decoder (cfg.num_passes = P > 1) walks the same per-layer
+        # weight buffers P times, pass t with the cache entries t * L + l of
+        # each stack (kv_pages.init_pool) and the final norm between two
+        # passes; the last pass's norm is the head's. The exit gate is not
+        # computed here: while every token runs every pass (the only case
+        # built) it cannot change a logit.
+        new_pool = [[] for _ in self._stacks]
+        for t in range(cfg.num_passes):
+            with jax.named_scope("serve.layers"), \
+                    jax.named_scope(f"serve.pass{t}"):
+                for (pkey, mlp_fn, L), stack, wins, new_stack in zip(
+                    self._stacks, pool, self._stack_windows, new_pool
+                ):
+                    mlp_scope = (
+                        "serve.mlp" if mlp_fn is _dense_mlp else "serve.moe"
+                    )
+                    for lp, cache, win in zip(
+                        params[pkey], stack[t * L:(t + 1) * L], wins
+                    ):
+                        with jax.named_scope("serve.attn"):
+                            h, cache = self._attn(h, lp, win, cache, b)
+                        with jax.named_scope(mlp_scope):
+                            h = mlp_fn(h, lp, cfg)
+                        h = self._constrain_rep(h)
+                        new_stack.append(cache)
+                if t + 1 < cfg.num_passes:
+                    h = rms_norm(h, params["final_norm"]["scale"],
+                                 cfg.rms_norm_eps, cfg.zero_centered_norm)
+        new_pool = self._constrain_pool([tuple(st) for st in new_pool])
 
         with jax.named_scope("serve.head"):
             return self._head(params, new_pool, h, b)
@@ -1037,7 +1063,9 @@ class ServingEngine:
         with self.obs.tracer.span(
             "step.plan", track=self.track, step=self.steps_run
         ) as span:
+            preempted = sched.n_preemptions
             plan = sched.schedule(step_idx)
+            span.set_metadata(**sched.turn_stats(preempted))
             if plan is not None:
                 span.set_metadata(rows=plan.n_tokens, samples=plan.n_samples)
         if plan is None:

@@ -396,7 +396,9 @@ class OnlineFrontend:
                         break
                 self._apply_backpressure()
             with span("step.plan") as plan_span:
+                preempted = self.sched.n_preemptions
                 plan = self.sched.schedule(self.step_idx)
+                plan_span.set_metadata(**self.sched.turn_stats(preempted))
                 if plan is not None:
                     plan_span.set_metadata(
                         rows=plan.n_tokens, samples=plan.n_samples

@@ -26,6 +26,17 @@ without branching:
 - MLA:  (c, kr)  (N+1, ps, r) and (N+1, ps, dr)   (absorbed decode —
   r+dr cached floats per token instead of n*(dn+dr+dv))
 
+Looped decoders (`cfg.num_passes` = P > 1: the layer stack runs P times over
+every token): a stack of L layers holds P x L entries, entry `t * L + l` the
+page arrays of pass t, layer l — a token of pass t attends to the earlier
+tokens' keys of the same pass and layer. Page ids stay global: a page means
+"these ps tokens in every pass and layer", so the allocator, the scheduler,
+the prefix tree, copy-on-write, defrag, transfer and the int8 scale planes
+below never see the passes (they tree-map over the leaves). What changes is
+what a page costs: P times the bytes, so P times fewer pages in the same
+memory, and the pool runs out before the slots do: requests wait for pages,
+not for a slot, and growth preempts the youngest.
+
 Quantized pools (kv_cache_dtype="int8"): the same layouts hold int8 and
 each layer gains PARALLEL per-page scale arrays (N+1, ps) — one f32
 scale per cache row, stored page-major so scales travel with their pages
@@ -319,7 +330,7 @@ def pool_shardings(
     layer = tuple(
         mesh_ctx.sharding(*a) for a in pool_axes(cfg, kv_cache_dtype)
     )
-    return [(layer,) * L for L in stack_layers]
+    return [(layer,) * (cfg.num_passes * L) for L in stack_layers]
 
 
 def init_pool(
@@ -328,14 +339,15 @@ def init_pool(
 ):
     """The pool of a decoder: per stack (dense decoders have one; MoE
     decoders a dense prefix + MoE stack — mirrors generate.py) a tuple with
-    one tuple of page arrays per layer. With a `mesh_ctx` the arrays are
+    one tuple of page arrays per (pass, layer): `cfg.num_passes * L`
+    entries, entry `t * L + l`. With a `mesh_ctx` the arrays are
     placed mesh-sharded (`pool_axes`). With kv_cache_dtype="int8" each
     layer carries int8 payloads plus per-page scale arrays — same page
     axis, so COW/defrag/transfer move scales with their pages and the
     host-side allocator never knows."""
     init = init_mla_pool if cfg.attention_type == "mla" else init_gqa_pool
     pool = [
-        init(cfg, L, num_pages, page_size, kv_cache_dtype)
+        init(cfg, cfg.num_passes * L, num_pages, page_size, kv_cache_dtype)
         for L in stack_layers
     ]
     if mesh_ctx is not None:

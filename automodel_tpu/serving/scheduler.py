@@ -291,6 +291,19 @@ class Scheduler:
     def has_work(self) -> bool:
         return bool(self.waiting or self.running)
 
+    def turn_stats(self, preemptions_before: int) -> dict:
+        """What `step.plan`'s span says of the pool after a `schedule()`:
+        pages free, requests resident, and the requests this turn preempted
+        (`preemptions_before`: `n_preemptions` as the turn began). Where a
+        page is dear (a looped decoder's holds every pass) these say what
+        the slots do not: whether requests wait for pages, and what growth
+        costs in preempted work."""
+        return {
+            "free_pages": self.alloc.num_free,
+            "resident": len(self.running),
+            "preempted": self.n_preemptions - preemptions_before,
+        }
+
     def prefix_hit_tokens(self, tokens: list) -> int:
         """Radix-affinity probe: how many of `tokens` this scheduler's
         prefix cache could serve from shared pages (0 without a cache).

@@ -115,6 +115,39 @@ def test_disagg_parity_ragged_stream(params):
     assert res["stats"]["transfer_chunks"] >= 1
 
 
+def test_disagg_looped_decoder_hands_over_every_pass():
+    """A looped decoder through 1 prefill + 1 decode replica: a page's copy
+    in every pass and layer crosses in the one transfer (the pool composes
+    by structure), so greedy tokens equal the monolithic engine's and the
+    wire bytes count the passes."""
+    from tests import ouro_case
+
+    cfg = ouro_case.config()
+    lp = ouro_case.init_params(cfg)
+    sc = ServingConfig(
+        page_size=4, num_pages=32, max_slots=3, pages_per_slot=6,
+        token_budget=8, prefill_chunk=4,
+        prefix_cache=PrefixCacheConfig(enabled=True),
+    )
+    reqs = lambda: _reqs(_prompts([5, 11, 3, 7], 30), [0, 0, 2, 4])  # noqa: E731
+    base = ServingEngine(own(lp), cfg, sc).serve_batch(reqs())
+    router = DisaggRouter(
+        own(lp), cfg, sc,
+        DisaggConfig(enabled=True, transfer_pages=4, prefill_token_budget=16))
+    res = router.serve_batch(reqs())
+    assert res["outputs"] == base["outputs"]
+    assert res["stats"]["handoffs"] == 4
+    moved = res["stats"]["handoff_pages_moved"]
+    assert moved >= 4
+    # a page: 4 tokens x (4 passes x 3 layers) x (k and v) x 4 heads x 8 x 4 B
+    page_bytes = 4 * 12 * 2 * 4 * 8 * 4
+    snap = router.obs.registry.snapshot()
+    assert snap["serve_kv_transfer_bytes_total"] >= moved * page_bytes
+    assert snap["serve_kv_transfer_bytes_total"] % page_bytes == 0
+    assert snap["serve_passes"] == 4
+    assert snap["serve_kv_bytes_per_token"] == page_bytes // 4
+
+
 def test_disagg_parity_decode_side_speculation(params):
     """Decode-class speculation (ngram draft-then-verify) composes with
     the handoff: drafts fire only after migration, acceptance is lossless,

@@ -250,6 +250,37 @@ def test_quant_preemption_parity(params):
     assert eng.alloc.num_free + eng.prefix.cached_pages == 8
 
 
+def test_quant_looped_decoder_scale_planes_per_pass():
+    """A looped decoder under int8 pages: every (pass, layer) entry carries
+    its own two scale planes, copy-on-write and preemption move them with
+    their pages, and greedy tokens through a tight pool equal the roomy
+    quantized engine's."""
+    from tests import ouro_case
+
+    cfg = ouro_case.config()
+    lp = ouro_case.init_params(cfg)
+    prompts = _prompts([4, 4, 4], seed0=20)
+    roomy = dict(page_size=2, num_pages=32, max_slots=3, pages_per_slot=6,
+                 token_budget=6, prefill_chunk=3)
+    base_eng, base = _serve(lp, cfg, ServingConfig(**roomy, **QUANT),
+                            _reqs(prompts, (0, 0, 0), 5))
+    assert len(base_eng.pool[0]) == 12
+    assert all(len(entry) == 4 and entry[0].dtype == jnp.int8
+               and entry[2].shape == (33, 2) for entry in base_eng.pool[0])
+    # written rows left scales other than 1 in every pass's planes
+    assert all(bool((np.asarray(entry[2]) != 1.0).any())
+               for entry in base_eng.pool[0])
+    eng, res = _serve(
+        lp, cfg,
+        ServingConfig(**dict(roomy, num_pages=8), **QUANT,
+                      prefix_cache=PrefixCacheConfig(enabled=True)),
+        _reqs(prompts, (0, 0, 0), 5),
+    )
+    assert res["outputs"] == base["outputs"]
+    assert res["stats"]["preemptions"] >= 1
+    assert eng.alloc.num_free + eng.prefix.cached_pages == 8
+
+
 def test_quant_disagg_handoff_parity(params):
     """Prefill→decode handoff ships quantized pages natively: router
     tokens equal the monolithic quant engine's, and the wire-bytes

@@ -9,6 +9,7 @@ stacked buffers up, and that a split tree passes through and is shared.
 """
 
 import dataclasses
+import hashlib
 import re
 
 import jax
@@ -188,3 +189,73 @@ def test_router_splits_once_for_both_classes():
     for x, y in zip(jax.tree.leaves(p.params["layers"]),
                     jax.tree.leaves(d.params["layers"])):
         assert x is y
+
+
+# -- (c) the looped step -------------------------------------------------------
+#: sha256 of the lowered (StableHLO) serve step of CFG at GEO, as the commit
+#: before looped decoders (a49ab3f) lowered it with jax 0.9.0 on the CPU. The
+#: walk over passes must leave a one-pass model's program as it was: a PR
+#: that changes this step on purpose updates the digest and says why.
+ONE_PASS_STEP_SHA256 = (
+    "1e3546975fa1431d0ac1541b0af9d90f5e0e154454af4b5cfe15bca09662d43c")
+
+
+def test_one_pass_step_text_is_unchanged():
+    eng = ServingEngine(decoder.init(CFG, jax.random.key(0)), CFG,
+                        ServingConfig(**GEO))
+    text = eng.lower_step().as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == ONE_PASS_STEP_SHA256
+
+
+def _ops(text: str, name: str) -> int:
+    return len(re.findall(rf"= \"?stablehlo\.{name}\"?[ (]", text))
+
+
+def test_looped_step_walks_the_same_weights_over_its_own_cache_entries():
+    from automodel_tpu.analysis.hlo import analyze_compiled
+    from tests import ouro_case
+
+    looped = ouro_case.config()
+    single = dataclasses.replace(looped, num_passes=1)
+    P, L = looped.num_passes, looped.num_layers
+    params = ouro_case.init_params(looped)
+    engines = {
+        cfg.num_passes: ServingEngine(own(params), cfg, ServingConfig(**GEO))
+        for cfg in (single, looped)
+    }
+    text = {p: e.lower_step().as_text() for p, e in engines.items()}
+    eng = engines[P]
+    # passes x layers cache entries, each its own pair of arguments; the
+    # weights are arguments ONCE however often they are read
+    assert len(eng.pool[0]) == P * L and len(engines[1].pool[0]) == L
+    n_weights = len(jax.tree.leaves(eng.params))
+    assert n_weights == len(jax.tree.leaves(engines[1].params))
+
+    def n_args(t):
+        return re.search(r"func\.func public @main\((.*?)\) ->", t,
+                         re.S).group(1).count("%arg")
+
+    assert n_args(text[P]) - n_args(text[1]) == 2 * L * (P - 1)
+    # every pass does a pass's work: the pool writes and copy-on-write
+    # scatters, the page gathers and (the head's one aside) the matrix
+    # products of the one-pass step, P times over
+    assert _ops(text[1], "scatter") > 0
+    assert _ops(text[P], "scatter") == P * _ops(text[1], "scatter")
+    assert _ops(text[P], "dot_general") - 1 == P * (_ops(text[1], "dot_general") - 1)
+
+    compiled = eng.lower_step().compile()
+    hlo = compiled.as_text()
+    # no loop around layers or passes, no copy of a pool array, and every
+    # page array donated and aliased to an output
+    assert not [ln for ln in hlo.splitlines()
+                if " while(" in ln and "serve.layers" in ln]
+    pool_shapes = {
+        "f32[" + ",".join(map(str, a.shape)) + "]" for a in jax.tree.leaves(eng.pool)}
+    assert not [ln for ln in hlo.splitlines() if " copy(" in ln
+                and any(sh in ln.split(" copy(")[0] for sh in pool_shapes)]
+    report = analyze_compiled(compiled, entry="looped_serve_step", mesh_axes=None)
+    assert len(report.donation) == len(jax.tree.leaves(eng.pool)) == 2 * P * L
+    # each pass's scope names its ops (what the per-pass reader looks for)
+    for t in range(P):
+        assert f"serve.layers/serve.pass{t}/serve.attn" in hlo
+    assert f"serve.pass{P}" not in hlo

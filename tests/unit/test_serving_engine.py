@@ -333,6 +333,110 @@ def test_deadline_expires_waiting_request_without_pages():
     assert sched.alloc.num_free < free0  # only the running request holds pages
 
 
+# -- a looped decoder: logits against the plain reference --------------------
+#: |the step's log-probability of its token - the float32 reference's|. Both
+#: are float32 here, in another order of arithmetic (chunked prefill and a
+#: paged cache against one full forward): 1.3e-6 at most in these runs, so
+#: 1e-4 leaves seventy times of room; the same engine in bfloat16 reads a
+#: median of 0.01 (0.04 at most) and is refused (below).
+LOGPROB_TOL = 1e-4
+
+
+def _serve_with_logprobs(engine, requests):
+    """`serve_batch`, keeping what every step reported for each request:
+    {rid: [(token, log-probability), ...]} in the order it was sampled."""
+    seen, scheds = {}, []
+    make, inner = engine.make_scheduler, engine.run_step
+
+    def make_scheduler(**kw):
+        scheds.append(make(**kw))
+        return scheds[-1]
+
+    def run_step(plan):
+        out = inner(plan)
+        for slot, _c, samples in plan.scheduled:
+            if samples:
+                rid = scheds[-1].running[slot].rid
+                seen.setdefault(rid, []).append(
+                    (int(out[0][slot]), float(out[1][slot])))
+        return out
+
+    engine.make_scheduler, engine.run_step = make_scheduler, run_step
+    return engine.serve_batch(requests), seen
+
+
+def _logprob_errors(params, prompts, res, seen):
+    """Per request, |reported - reference| at every served token, after
+    checking that each served token is the reference's best."""
+    from tests import ouro_case
+
+    errs = []
+    for rid, (prompt, out) in enumerate(zip(prompts, res["outputs"])):
+        assert [t for t, _ in seen[rid]] == out
+        logits, _ = ouro_case.reference(params, [prompt + out])
+        rows = ouro_case.log_softmax(logits[0, len(prompt) - 1:-1])
+        assert rows.argmax(-1).tolist() == out, rid
+        errs += [abs(lp - rows[i, t]) for i, (t, lp) in enumerate(seen[rid])]
+    return np.asarray(errs)
+
+
+def test_looped_decoder_logits_match_reference_through_preemption():
+    """An Ouro-shaped decoder (3 layers walked 4 times, a cache entry per
+    pass and layer under one page table): chunked prefill, then decode
+    through the paged cache, in a pool so small that requests are preempted
+    and requeued, against the plain reference's full forward; `generate`'s
+    dense cache of passes x layers entries against the same."""
+    from tests import ouro_case
+
+    cfg = ouro_case.config()
+    params = ouro_case.init_params(cfg)
+    geo = dict(page_size=4, num_pages=9, max_slots=3, pages_per_slot=6,
+               token_budget=8, prefill_chunk=5)
+    engine = ServingEngine(own(params), cfg, ServingConfig(**geo))
+    assert len(engine.pool[0]) == cfg.num_passes * cfg.num_layers == 12
+    prompts = _ragged_prompts([9, 14, 6, 11], vocab=ouro_case.VOCAB, seed0=40)
+    reqs = lambda: [Request(prompt=list(p), max_new_tokens=6) for p in prompts]  # noqa: E731
+    res, seen = _serve_with_logprobs(engine, reqs())
+    assert res["stats"]["preemptions"] >= 1
+    assert res["stats"]["compiled_signatures"] == 1
+    errs = _logprob_errors(params, prompts, res, seen)
+    assert len(errs) == 4 * 6 and errs.max() < LOGPROB_TOL, errs.max()
+
+    for p, out in zip(prompts, res["outputs"]):
+        dense = generate(params, cfg, jnp.asarray([p], jnp.int32),
+                         jax.random.key(0), GenerateConfig(max_new_tokens=6))
+        assert np.asarray(dense)[0, len(p):].tolist() == out
+
+    # the same engine in bfloat16 is outside the tolerance
+    low = dataclasses.replace(cfg, dtype=jnp.bfloat16)
+    res_low, seen_low = _serve_with_logprobs(
+        ServingEngine(own(params), low, ServingConfig(**geo)), reqs())
+    low_errs = []
+    for rid, (p, out) in enumerate(zip(prompts, res_low["outputs"])):
+        logits, _ = ouro_case.reference(params, [p + out])
+        rows = ouro_case.log_softmax(logits[0, len(p) - 1:-1])
+        low_errs += [abs(lp - rows[i, t]) for i, (t, lp) in enumerate(seen_low[rid])]
+    assert np.median(low_errs) > 10 * LOGPROB_TOL
+
+
+def test_request_larger_than_the_whole_pool_is_refused_by_name():
+    """The looped cell's geometry: 10 pages of 64 tokens a slot. In a pool of
+    84 pages a 640-token request is admissible; a pool of 8 could never hold
+    it, and `submit` says so instead of stalling the loop."""
+    from automodel_tpu.serving.scheduler import Scheduler
+
+    geo = dict(page_size=64, max_slots=24, pages_per_slot=10, token_budget=48,
+               prefill_chunk=32)
+    long = lambda: Request(prompt=[1] * 384, max_new_tokens=256)  # noqa: E731
+    sched = Scheduler(num_pages=84, **geo)
+    sched.submit(long())
+    assert len(sched.waiting) == 1
+    with pytest.raises(ValueError, match="needs 10 pages but the whole pool holds 8"):
+        Scheduler(num_pages=8, **geo).submit(long())
+    with pytest.raises(ValueError, match="641 positions"):
+        sched.submit(Request(prompt=[1] * 385, max_new_tokens=256))
+
+
 def test_het_engine_rejected():
     from automodel_tpu.serving.engine import ServingEngine as SE
 

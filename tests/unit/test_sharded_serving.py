@@ -95,6 +95,30 @@ def test_tp2_parity_ragged_stream_with_preemption():
     assert tp2["stats"]["preemptions"] >= 1  # the churn actually happened
 
 
+def test_tp2_looped_decoder_parity_with_preemption():
+    """A looped decoder's pool (passes x layers entries, KV heads cut over
+    tp) composes with sharding by structure: tp2 tokens equal the
+    single-chip engine's through a preemption, one compiled step."""
+    from tests import ouro_case
+
+    cfg = ouro_case.config()
+    params = ouro_case.init_params(cfg)
+    sc = ServingConfig(
+        page_size=2, num_pages=8, max_slots=3, pages_per_slot=6,
+        token_budget=6, prefill_chunk=3,
+    )
+    requests = lambda: _reqs(_prompts([4, 4, 4], 20), [0, 0, 0], 5)  # noqa: E731
+    base = _serve(params, cfg, None, sc, requests())
+    eng = ServingEngine(own(params), cfg, sc, mesh_ctx=_tp_ctx(2))
+    assert len(eng.pool[0]) == len(eng._pool_shardings[0]) == 12
+    k, _v = eng.pool[0][7]   # pass 2, layer 1: heads cut over tp
+    assert {s.data.shape for s in k.addressable_shards} == {(9, 2, 2, 8)}
+    tp2 = eng.serve_batch(requests())
+    assert tp2["outputs"] == base["outputs"]
+    assert tp2["stats"]["preemptions"] >= 1
+    assert tp2["stats"]["compiled_signatures"] == 1
+
+
 def test_tp2_parity_prefix_cache_and_speculation():
     """Prefix sharing (radix hits + COW) and draft-then-verify compose
     with the sharded step: tokens equal the plain single-chip engine's,

@@ -1,0 +1,106 @@
+"""Compiles for a DESCRIBED v5e chip (none attached): what interpret mode on
+the CPU cannot show. The TPU's compiler is installed here and compiles for a
+topology that is described, not attached; nothing runs, so these prove that
+the chip's compiler takes a kernel at its real widths, never a result or a
+time. Kept in ONE file, the topology described inside a fixture: only the
+worker that is given this file loads the TPU's library.
+
+Why it is here: `paged_attention_gqa` passed every interpret-mode test and
+the TPU compiler refused it (LLO_CHECK `ProducesVreg`) for a model WITHOUT
+grouping (16 heads over 16 key/value heads, Ouro-2.6B's), where the causal
+mask's compare had a one-wide sublane axis.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to prove
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_branch(monkeypatch):
+    """Point the code that asks for the backend at its TPU branch: the
+    process runs on the CPU, the program is compiled for the chip."""
+    import automodel_tpu.ops.attention as attention
+    import automodel_tpu.ops.pallas.ragged_paged_attention as rpa
+
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(rpa, "_interpret", lambda: False)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield rpa
+    jax.config.update("jax_enable_compilation_cache", cache)
+
+
+#: (rows, heads, key/value heads, head width, pages, page size, pages a slot)
+WIDTHS = {
+    "ouro_2_6b: no grouping": (48, 16, 16, 128, 84, 64, 10),
+    "grouped 4:1": (48, 32, 8, 128, 84, 64, 10),
+    "one key/value head": (48, 8, 1, 128, 84, 64, 10),
+}
+
+
+@pytest.mark.parametrize("widths", WIDTHS.values(), ids=WIDTHS.keys())
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths, quant):
+    T, Hq, Hkv, D, N, ps, P = widths
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = s((T, Hq, D), jnp.bfloat16)
+    pages = s((N + 1, ps, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
+    tables, pos = s((T, P), jnp.int32), s((T,), jnp.int32)
+    if quant:
+        scales = s((N + 1, ps), jnp.float32)
+        fn = lambda q, k, v, ks, vs, pt, pos: tpu_branch.paged_attention_quant_kernel(  # noqa: E731
+            q, k, v, ks, vs, pt, pos, scale=D ** -0.5)
+        args = (q, pages, pages, scales, scales, tables, pos)
+    else:
+        fn = lambda q, k, v, pt, pos: tpu_branch.paged_attention_kernel(  # noqa: E731
+            q, k, v, pt, pos, scale=D ** -0.5)
+        args = (q, pages, pages, tables, pos)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "paged_attention_gqa" in compiled.as_text()
+
+
+def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
+        one_chip, tpu_branch):
+    """The toy looped decoder's serve step, lowered for the chip: passes x
+    layers `paged_attention_gqa` calls in ONE program (what the benchmark's
+    `step_kernels` check counts at the real size: 192)."""
+    from automodel_tpu.serving import ServingConfig, ServingEngine
+    from tests import ouro_case
+    from tests.serving_params import own
+
+    cfg = ouro_case.config(attn_impl="auto")
+    eng = ServingEngine(own(ouro_case.init_params(cfg)), cfg, ServingConfig(
+        page_size=8, num_pages=16, max_slots=2, pages_per_slot=4,
+        token_budget=8))
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            tree)
+
+    batch = eng._plan_batch(eng.empty_plan())
+    lowered = jax.jit(eng._step_impl, donate_argnums=(1,)).lower(
+        shapes(eng.params), shapes(eng.pool), shapes(batch))
+    assert lowered.as_text().count("paged_attention_gqa") == (
+        cfg.num_passes * cfg.num_layers) == 12
+    lowered.compile()
