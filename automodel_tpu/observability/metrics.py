@@ -262,6 +262,8 @@ METRIC_CATALOG = (
     ("serve_compiled_signatures", "gauge", "jit cache entries for the serve step"),
     ("serve_passes", "gauge", "times the serve step walks the layer stack over a token (looped decoders: > 1)"),
     ("serve_kv_bytes_per_token", "gauge", "KV cache bytes one token holds, every pass and layer counted"),
+    ("serve_attn_segments", "gauge", "runs of one slot's rows in the last planned step: the paged-attention grid's segments"),
+    ("serve_attn_live_blocks", "gauge", "(segment, page) blocks of the last planned step that hold a key to attend to"),
     # prefix cache
     ("serve_prefix_hits_total", "counter", "admissions that matched a cached prefix"),
     ("serve_prefill_skipped_tokens_total", "counter", "prompt tokens skipped via prefix reuse"),

@@ -17,8 +17,10 @@ and counts every call it hands to the reference):
   under `JAX_PLATFORMS=cpu`, and is the correctness oracle for the kernel.
 - Pallas TPU kernel (`ops/pallas/ragged_paged_attention.py`): streams pages
   through VMEM with the page table as a scalar-prefetch BlockSpec index map
-  (no gathered (T, P, page, ...) intermediate in HBM). It covers neither
-  sliding windows nor sinks (`_gqa_unsupported_reason` /
+  (no gathered (T, P, page, ...) intermediate in HBM), one (segment, page)
+  block at a time: `row_segments` below groups a step's rows into runs of
+  one sequence, so a prefill chunk's rows fetch their pages once. It covers
+  neither sliding windows nor sinks (`_gqa_unsupported_reason` /
   `_mla_unsupported_reason` below state its rules).
 
 Layouts (see serving/kv_pages.py for the pool):
@@ -57,6 +59,8 @@ verify block is just more ragged rows.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -173,6 +177,119 @@ def ragged_paged_mla_attention_xla(
     return jnp.einsum("tnc,tcr->tnr", p.astype(c.dtype), c)
 
 
+# -- row segments: the Pallas kernels' unit of work ---------------------------
+#: rows of `RowSegments.blocks`
+(BLOCK_TILE, BLOCK_PAGE, BLOCK_OFFSET, BLOCK_LENGTH, BLOCK_POSITION,
+ BLOCK_COLUMN) = range(6)
+
+#: VMEM a q tile's blocks and scratch may take beside two pages of keys and
+#: values (the chip's compiler gives a kernel 16 MiB unasked)
+_TILE_VMEM_BYTES = 6 * 2**20
+#: rows of a q tile at most: a segment longer than one row computes the
+#: whole tile whatever its length, so a larger tile costs every chunk's
+#: tail and every speculative block more than it saves on page fetches
+_TILE_ROWS = 32
+
+
+class RowSegments(NamedTuple):
+    """A step's rows grouped into SEGMENTS, runs of one sequence's rows,
+    and laid out as the list of (segment, page) blocks the Pallas kernels
+    walk: one for every page a segment attends to, a segment's pages in
+    order, nothing for a page past its last position or for a pad row.
+    `tile` (static) is the rows of a q tile. `blocks` is (6, W) int32,
+    column w one block: the tile its segment lies in, the POOL page it
+    reads, then its segment's first row as an offset into the tile, its
+    length and its first row's position, and which page of the sequence
+    this is. `count` () int32 says how many of the W are the step's; the
+    kernels' grid stops there."""
+
+    tile: int
+    blocks: jnp.ndarray
+    count: jnp.ndarray
+
+
+def row_tile(rows: int, row_width: int) -> int:
+    """Rows of a q tile for a step of `rows` rows whose queries and
+    outputs are `row_width` elements a row: a power of two of at least 16
+    (a bf16 tile's sublanes) and at most `_TILE_ROWS`, no more than the
+    step's rows rounded up, and small enough that its blocks (bf16 in and
+    out, double-buffered) and float32 accumulator fit `_TILE_VMEM_BYTES`."""
+    fit = _TILE_VMEM_BYTES // (8 * max(row_width, 1))
+    tile = 16
+    while tile * 2 <= min(_TILE_ROWS, fit) and tile < rows:
+        tile *= 2
+    return tile
+
+
+def max_row_segments(rows: int, max_slots: int, tile: int) -> int:
+    """Static bound on a step's segments when every slot's rows are ONE
+    run, as the scheduler lays them out: a run starts one segment and
+    every tile boundary inside a run one more."""
+    return min(rows, max_slots + rows // tile)
+
+
+def segment_bounds(xp, slot, pos, tile: int):
+    """(is_start, is_last): which rows open and which close a segment.
+    `xp` is numpy (the scheduler's counters) or jax.numpy (the step). A
+    row continues its predecessor's segment when both are real (pos >= 0),
+    of one SLOT (two decode rows of different slots may well hold
+    consecutive positions), at consecutive positions, and in one aligned
+    tile of `tile` rows; pad rows belong to no segment."""
+    rows = xp.arange(slot.shape[0])
+    real = pos >= 0
+    joins = (
+        real[1:] & real[:-1]
+        & (slot[1:] == slot[:-1]) & (pos[1:] == pos[:-1] + 1)
+        & (rows[1:] % tile != 0)
+    )
+    no = xp.zeros((1,), bool)
+    is_start = real & ~xp.concatenate([no, joins])
+    is_last = real & ~xp.concatenate([joins, no])
+    return is_start, is_last
+
+
+def row_segments(slot, pos, page_tables, *, page_size: int, tile: int,
+                 max_segments: int) -> RowSegments:
+    """The step's `RowSegments`, in-jit, from each row's slot, position
+    and page table (T, P). More than `max_segments` runs cannot be told
+    here: the caller's bound must hold (`max_row_segments`)."""
+    G, W = max_segments, max_segments * page_tables.shape[1]
+    is_start, is_last = segment_bounds(jnp, slot, pos, tile)
+    (start,) = jnp.nonzero(is_start, size=G, fill_value=0)
+    (end,) = jnp.nonzero(is_last, size=G, fill_value=0)
+    real = jnp.arange(G) < jnp.sum(is_start)
+    length = jnp.where(real, end - start + 1, 0)
+    pages = jnp.where(real, pos[end] // page_size + 1, 0)  # a segment's blocks
+    ends = jnp.cumsum(pages)
+    count = ends[-1]
+    # block w's segment and which of its pages; past the count, the last's
+    w = jnp.minimum(jnp.arange(W), jnp.maximum(count - 1, 0))
+    seg = jnp.minimum(jnp.searchsorted(ends, w, side="right"), G - 1)
+    column = w - (ends[seg] - pages[seg])
+    row = start[seg]
+    blocks = jnp.stack([
+        row // tile, page_tables[row, column], row % tile, length[seg],
+        pos[row], column,
+    ]).astype(jnp.int32)
+    return RowSegments(tile=tile, blocks=blocks, count=count.astype(jnp.int32))
+
+
+def step_row_segments(slot, pos, page_tables, *, page_size: int, tile: int,
+                      max_slots: int, impl: str = "auto"):
+    """A serve step's `RowSegments`, once for all its attention calls, or
+    None where the dispatch rule hands them to the reference (off the TPU:
+    the step that CPU tests lower holds nothing of this). `page_tables`
+    is per ROW, (T, P)."""
+    from automodel_tpu.ops.attention import resolve_kernel_impl
+
+    if resolve_kernel_impl(impl, "pallas", None, "paged_attention") != "pallas":
+        return None
+    return row_segments(
+        slot, pos, page_tables, page_size=page_size, tile=tile,
+        max_segments=max_row_segments(slot.shape[0], max_slots, tile),
+    )
+
+
 def _tp_size(mesh_ctx) -> int:
     return 1 if mesh_ctx is None else mesh_ctx.sizes["tp"]
 
@@ -192,12 +309,13 @@ def _annotate_tp(x, mesh_ctx, dim: int):
 
 
 def _pallas_gqa_tp(mesh_ctx, q, k_pages, v_pages, page_tables, positions, *,
-                   scale, soft_cap):
+                   scale, soft_cap, segments):
     """The Pallas GQA kernel under tp>1, inside a shard_map: each rank
     runs the SAME kernel on its local head slice — q/k/v/out shard the
-    head dim, page tables and positions replicate, and the grid/BlockSpec
-    machinery (scalar-prefetch page indexing, online softmax) is untouched
-    because GQA groups never cross a KV-head boundary."""
+    head dim, page tables, positions and row segments replicate, and the
+    grid/BlockSpec machinery (scalar-prefetch page indexing, online
+    softmax) is untouched because GQA groups never cross a KV-head
+    boundary."""
     from jax.sharding import PartitionSpec as P
 
     from automodel_tpu.ops.pallas.ragged_paged_attention import (
@@ -207,16 +325,19 @@ def _pallas_gqa_tp(mesh_ctx, q, k_pages, v_pages, page_tables, positions, *,
     heads = P(None, "tp", None)
     pages = P(None, None, "tp", None)
 
-    def body(q, k, v, pt, pos):
+    def body(q, k, v, pt, pos, *seg):
+        seg = RowSegments(segments.tile, *seg) if seg else None
         return paged_attention_kernel(
-            q, k, v, pt, pos, scale=scale, soft_cap=soft_cap,
+            q, k, v, pt, pos, scale=scale, soft_cap=soft_cap, segments=seg,
         )
 
+    seg = () if segments is None else (segments.blocks, segments.count)
     return jax.shard_map(
         body, mesh=mesh_ctx.mesh,
-        in_specs=(heads, pages, pages, P(None, None), P(None)),
+        in_specs=(heads, pages, pages, P(None, None), P(None),
+                  *(P(*(None,) * a.ndim) for a in seg)),
         out_specs=heads, check_vma=False,
-    )(q, k_pages, v_pages, page_tables, positions)
+    )(q, k_pages, v_pages, page_tables, positions, *seg)
 
 
 def _gqa_unsupported_reason(q, k_pages, window, sinks, quant, tp):
@@ -248,13 +369,16 @@ def ragged_paged_attention(
     mesh_ctx=None,
     k_scales=None,
     v_scales=None,
+    segments: RowSegments | None = None,
 ):
     """GQA entry. impl: "xla" | "pallas" | "auto" (pallas on TPU where the
     kernel covers the call). With a `mesh_ctx` (tp>1) the reference path
     carries head-sharding annotations and the Pallas kernel runs inside a
     shard_map over the tp axis (rank-local head slices). With
     `k_scales`/`v_scales` ((N, ps) per-row scales) the pages are int8 and
-    the quantized kernel/reference dequantizes per page."""
+    the quantized kernel/reference dequantizes per page. `segments`
+    (`step_row_segments`) is the kernel's alone: the reference reads each
+    row's own table and never sees it."""
     from automodel_tpu.ops.attention import resolve_kernel_impl
 
     scale = scale if scale is not None else float(q.shape[-1]) ** -0.5
@@ -269,7 +393,7 @@ def ragged_paged_attention(
         if tp > 1:
             return _pallas_gqa_tp(
                 mesh_ctx, q, k_pages, v_pages, page_tables, positions,
-                scale=scale, soft_cap=soft_cap,
+                scale=scale, soft_cap=soft_cap, segments=segments,
             )
         if quant:
             from automodel_tpu.ops.pallas.ragged_paged_attention import (
@@ -279,6 +403,7 @@ def ragged_paged_attention(
             return paged_attention_quant_kernel(
                 q, k_pages, v_pages, k_scales, v_scales,
                 page_tables, positions, scale=scale, soft_cap=soft_cap,
+                segments=segments,
             )
         from automodel_tpu.ops.pallas.ragged_paged_attention import (
             paged_attention_kernel,
@@ -286,7 +411,7 @@ def ragged_paged_attention(
 
         return paged_attention_kernel(
             q, k_pages, v_pages, page_tables, positions,
-            scale=scale, soft_cap=soft_cap,
+            scale=scale, soft_cap=soft_cap, segments=segments,
         )
     q = _annotate_tp(q, mesh_ctx, 1)              # head axis
     k_pages = _annotate_tp(k_pages, mesh_ctx, 2)
@@ -320,6 +445,7 @@ def ragged_paged_mla_attention(
     mesh_ctx=None,
     c_scales=None,
     kr_scales=None,
+    segments: RowSegments | None = None,
 ):
     """MLA (absorbed latent-cache) entry; same dispatch contract as the GQA
     one. Returns latent-space outputs (T, n, r). Under tp>1 the latent rank
@@ -338,7 +464,7 @@ def ragged_paged_mla_attention(
 
             return paged_mla_attention_quant_kernel(
                 q_abs, q_rope, c_pages, kr_pages, c_scales, kr_scales,
-                page_tables, positions, scale=scale,
+                page_tables, positions, scale=scale, segments=segments,
             )
         from automodel_tpu.ops.pallas.ragged_paged_attention import (
             paged_mla_attention_kernel,
@@ -346,7 +472,7 @@ def ragged_paged_mla_attention(
 
         return paged_mla_attention_kernel(
             q_abs, q_rope, c_pages, kr_pages, page_tables, positions,
-            scale=scale,
+            scale=scale, segments=segments,
         )
     q_abs = _annotate_tp(q_abs, mesh_ctx, 2)      # latent-rank axis
     c_pages = _annotate_tp(c_pages, mesh_ctx, 2)

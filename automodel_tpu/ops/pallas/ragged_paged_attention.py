@@ -1,21 +1,37 @@
 """Pallas TPU kernel for ragged paged attention (serving decode/prefill).
 
-The TPU backend of `ops/paged_attention.py` (arXiv:2604.15464 style): the
-grid is (token, page) and the PAGE TABLE drives the kv BlockSpec index map
-through scalar prefetch — page j of token t's sequence is DMA'd from
-`k_pages[page_tables[t, j]]` directly, so the kernel never materializes the
-gathered (T, P, page_size, ...) intermediate the XLA reference builds in
-HBM. Pages are streamed innermost with the usual online-softmax (m, l, acc)
-VMEM scratch carried across pages (the flash_attention.py recipe), and
-pages past a token's position are predicated off with `pl.when` (they still
-prefetch — the table's padded entries must point at a valid page index, the
-pool's trash page).
+The TPU backend of `ops/paged_attention.py` (arXiv:2604.15464 style). The
+unit of work is a (segment, page) BLOCK: a segment is a run of consecutive
+rows of ONE sequence inside one aligned tile of `tile` rows, and the grid
+is the flat list of the blocks that hold a key some row attends to
+(`RowSegments`, derived once a step by `ops/paged_attention.row_segments`
+from each row's slot, position and page table; its length is a run-time
+grid bound, so a step walks its live blocks and no others). The list
+drives the BlockSpec index maps through scalar prefetch: block w DMAs
+`pages[blocks[PAGE, w]]`, so
 
-Covers the serving engine's hot path: GQA (kv-head sharing via reshape, no
-KV repeat) and absorbed-MLA (scores latent + rope parts summed in one
-accumulator, output in latent space). Sliding windows and attention sinks
-are not covered: the dispatcher (ops/paged_attention.py) states the rules
-and never calls in here with them.
+- a sequence's pages are fetched once per segment, not once per row (a
+  prefill chunk's rows share them), and never gathered into a
+  (T, P, page_size, ...) intermediate in HBM as the XLA reference does;
+- a page past a segment's last position, a table's padding and a pad row
+  are no block at all: nothing is fetched for them and no grid step spent.
+
+A segment's pages stream in order with the usual online-softmax (m, l,
+acc) VMEM scratch carried across them (the flash_attention.py recipe),
+reset on its first page and stored on its last. The q and output blocks
+are the segment's aligned tile: consecutive segments of one tile revisit
+the same output block and each stores its own rows alone. A segment of ONE
+row (a decode row) runs a one-row body; a longer one computes the tile's
+rows at once, each row masked at its own position, the rows outside the
+segment wholly. Rows of no segment come out zero (the wrapper zeroes them
+behind the call).
+
+Without descriptors (`segments=None`: tests, chip_smoke.py) every row is
+its own segment. Covers GQA (kv-head sharing, no KV repeat) and
+absorbed-MLA (scores latent + rope parts summed in one accumulator, output
+in latent space), over bf16 and int8 pages. Sliding windows and attention
+sinks are not covered: the dispatcher (ops/paged_attention.py) states the
+rules and never calls in here with them.
 
 GQA head dims that are not lane (128) multiples are zero-padded host-side
 (pad lanes add zero logits / zero value columns — exact), which copies the
@@ -32,6 +48,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from automodel_tpu.ops.paged_attention import (
+    BLOCK_COLUMN,
+    BLOCK_LENGTH,
+    BLOCK_OFFSET,
+    BLOCK_PAGE,
+    BLOCK_POSITION,
+    BLOCK_TILE,
+    RowSegments,
+    row_segments,
+    row_tile,
+)
 from automodel_tpu.ops.pallas.flash_attention import LANE, NEG_INF, _pad_last
 
 
@@ -39,81 +66,294 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+# -- what the four kernels share ---------------------------------------------
+def _block(blocks_ref, page_size):
+    """This grid step's block: its segment's (row offset in the tile,
+    length, first position), the index of its page's first key, and
+    whether the page is its segment's first and its last."""
+    w = pl.program_id(0)
+    off, length, pos0, column = (
+        blocks_ref[row, w]
+        for row in (BLOCK_OFFSET, BLOCK_LENGTH, BLOCK_POSITION, BLOCK_COLUMN)
+    )
+    last = column == (pos0 + length - 1) // page_size
+    return off, length, pos0, column * page_size, column == 0, last
+
+
+def _reset(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _softmax_page(s, mask, m_ref, l_ref, acc_ref, weighted_values):
+    """One page of online softmax: scores `s` (..., R, ps) against the
+    (..., R, LANE) / (..., R, Dv) running state; `weighted_values(p)` is
+    p @ V."""
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[..., :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = jnp.broadcast_to(
+        l_ref[..., :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
+        l_ref.shape,
+    )
+    acc_ref[...] = acc_ref[...] * alpha + weighted_values(p)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+
+
+def _normalized(l_ref, acc_ref):
+    l = l_ref[..., :1]
+    return jnp.where(l == 0.0, 0.0, acc_ref[...] / jnp.where(l == 0.0, 1.0, l))
+
+
+def _tile_row_positions(tile_row, off, length, pos0):
+    """Position of each score row's token, `tile_row` (R, 1) its row of
+    the tile: row i holds position pos0 + i - off inside the segment, -1
+    (it attends to nothing) outside."""
+    inside = jnp.logical_and(tile_row >= off, tile_row < off + length)
+    return jnp.where(inside, pos0 + tile_row - off, -1)
+
+
+def _store_segment_rows(out_ref, val, off, length):
+    """Store tile rows [off, off + length) of `val` (tile, H, w) into the
+    revisited output block, the other rows as they stand."""
+    i = jax.lax.broadcasted_iota(jnp.int32, val.shape, 0)
+    inside = jnp.logical_and(i >= off, i < off + length)
+    out_ref[...] = jnp.where(inside, val.astype(out_ref.dtype), out_ref[...])
+
+
+def _rows_as_segments(page_tables, positions, page_size, row_width):
+    """Every row a sequence of its own: the descriptors of a call that
+    brings none."""
+    T = positions.shape[0]
+    return row_segments(
+        jnp.arange(T, dtype=jnp.int32), positions, page_tables,
+        page_size=page_size, tile=row_tile(T, row_width), max_segments=T,
+    )
+
+
+def _segment_call(kernel, name, interpret, segments, positions, queries,
+                  pages, scales, out_width, scratch):
+    """`pallas_call` over the step's blocks. `queries` are (T, H, w) row
+    arrays blocked by the block's tile, `pages` (N, ps, ...) pools and
+    `scales` their (N, ps) per-row scales blocked by the block's page, the
+    output (T, H, out_width) blocked like the queries."""
+    tile, blocks, count = segments
+    T, H, _ = queries[0].shape
+    ps = pages[0].shape[1]
+
+    def row_map(w, blocks):
+        return (blocks[BLOCK_TILE, w], 0, 0)
+
+    def page_map(ndim):
+        return lambda w, blocks: (blocks[BLOCK_PAGE, w],) + (0,) * (ndim - 1)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        # a step with no real row still walks one block, of length 0
+        grid=(jnp.maximum(count, 1),),
+        in_specs=[
+            *(pl.BlockSpec((tile, *q.shape[1:]), row_map) for q in queries),
+            *(pl.BlockSpec((1, *p.shape[1:]), page_map(p.ndim)) for p in pages),
+            # (N, 1, ps): a (1, ps) block of an (N, ps) array breaks Mosaic's
+            # rule that a block's second-to-last dim is a multiple of 8 or
+            # the whole axis
+            *(pl.BlockSpec((1, 1, ps), page_map(3)) for _ in scales),
+        ],
+        out_specs=pl.BlockSpec((tile, H, out_width), row_map),
+        scratch_shapes=scratch,
+    )
+    out = pl.pallas_call(
+        functools.partial(kernel, tile=tile, page_size=ps),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((T, H, out_width), queries[0].dtype),
+        interpret=interpret,
+        name=name,
+    )(
+        blocks, *queries, *pages,
+        *(s.astype(jnp.float32)[:, None, :] for s in scales),
+    )
+    # rows of no segment were never stored
+    return jnp.where((positions >= 0)[:, None, None], out, 0)
+
+
+# -- GQA ----------------------------------------------------------------------
 def _gqa_kernel(
-    pt_ref,    # (T, P) scalar-prefetch page table
-    pos_ref,   # (T,)   scalar-prefetch positions (-1 = pad row)
-    q_ref,     # (1, Hq, D)
-    k_ref,     # (1, ps, Hkv, D)
+    blocks_ref,  # (6, W) scalar-prefetch blocks (RowSegments.blocks)
+    q_ref,     # (tile, Hq, D)
+    k_ref,     # (1, ps, Hkv, D)   bf16, or int8 with scales
     v_ref,     # (1, ps, Hkv, Dv)
-    out_ref,   # (1, Hq, Dv)
-    m_scr, l_scr, acc_scr,
-    *,
-    scale, soft_cap, page_size, groups,
+    *rest,     # [ks_ref, vs_ref (1, 1, ps) f32 per-row scales of THIS page,]
+               # out_ref (tile, Hq, Dv), one row's m/l/acc, the tile's
+               # head-major q and m/l/acc
+    scale, soft_cap, page_size, groups, tile, quant,
 ):
-    t, j = pl.program_id(0), pl.program_id(1)
-    np_ = pl.num_programs(1)
-    pos = pos_ref[t]
+    """bf16 and int8 pages alike. The tile body scores (Hkv, groups x
+    tile, D) queries, the tile's rows turned head-major once a segment,
+    against the page's (ps, Hkv, D) keys on the MXU, batched over the
+    key/value heads; the one-row body scores on the VPU
+    (`one_row_scores`). int8: the per-page scale rows ride the SAME
+    page-table entry as the payload, and dequantization is algebraic per
+    page: the K scale multiplies each kv slot's score column, the V scale
+    folds into the softmax weights before the value product — the int8
+    blocks are cast for the products, never materialized dequantized in
+    HBM."""
+    if quant:
+        ks_ref, vs_ref, *rest = rest
+    out_ref, m1, l1, acc1, qt, mt, lt, acct = rest
+    off, length, pos0, key0, first_page, last_page = _block(
+        blocks_ref, page_size)
+    one_row = length == 1
+    many_rows = length > 1
+    Hq = q_ref.shape[1]
+    ps, Hkv, Dv = v_ref.shape[1:]
+    kv_idx = key0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # pages whose first slot is past the token's position hold nothing it
-    # may attend to (tables are dense prefixes); pad rows (pos < 0) skip all
-    run = jnp.logical_and(pos >= 0, j * page_size <= pos)
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]                     # (Hq, D)
-        k = k_ref[0]                     # (ps, Hkv, D)
-        v = v_ref[0]                     # (ps, Hkv, Dv)
-        Hq, D = q.shape
-        ps, Hkv, Dv = v.shape
-        qg = q.reshape(Hkv, groups, D)
-        # (Hkv, G, ps): contract D, batch over kv heads
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * scale
+    def finished(s):
+        """Raw scores (..., ps) scaled and capped; int8: the K scale of
+        each kv slot first, before any soft-cap nonlinearity."""
+        if quant:
+            s = s * ks_ref[0]
+        s = s * scale
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
-        # the causal mask is made on the (Hq, ps) scores: on (Hkv, groups,
-        # ps) a model without grouping (groups == 1: one query head per
-        # key/value head) hands Mosaic a compare with a one-wide sublane
-        # axis, which the TPU compiler refuses (LLO_CHECK ProducesVreg)
-        s = s.reshape(Hq, ps)
-        kv_idx = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (Hq, ps), 1
-        )
-        mask = kv_idx <= pos
-        s = jnp.where(mask, s, NEG_INF)
+        return s
 
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
-            l_scr.shape,
-        )
-        # (Hq, Dv) += (Hkv, G, ps) @ (ps, Hkv, Dv) batched over kv heads
-        pv = jax.lax.dot_general(
-            p.reshape(Hkv, groups, ps).astype(v.dtype), v,
-            (((2,), (0,)), ((0,), (1,))),
+    def tile_scores(q):
+        """q (Hkv, R, D) → (Hkv, R, ps) f32 on the MXU."""
+        k = k_ref[0]                     # (ps, Hkv, D)
+        if quant:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        return finished(jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (1,))),
+            preferred_element_type=jnp.float32,
+        ))
+
+    def one_row_scores():
+        """(Hq, ps) f32 scores of the block's one row, on the VPU: a
+        product with the keys and a sum over D for each of the `groups`
+        query heads a key/value head serves. The MXU wants every head's
+        (ps, D) keys turned to (D, ps) first, and for ONE query row that
+        turning is most of the block: 2.9 us a block where this takes
+        1.25 and the page's fetch alone 0.97 (measured on a TPU v5e, 16
+        heads over 16 of 128, 64-token pages: PERF.md, PR 30)."""
+        k = k_ref[0].astype(jnp.float32)  # (ps, Hkv, D)
+        q = q_ref[off].astype(jnp.float32).reshape(Hkv, groups, -1)
+        per_group = []
+        for g in range(groups):
+            # query head g of every key/value head
+            s = jnp.sum(k * q[:, g][None], axis=-1)  # (ps, Hkv)
+            per_group.append(jnp.swapaxes(s, 0, 1))
+        if groups == 1:
+            return finished(per_group[0])
+        return finished(jnp.stack(per_group, axis=1).reshape(Hq, ps))
+
+    def weighted_values(p):
+        """p (Hkv, R, ps) → (Hkv, R, Dv); the V dequant folds into the
+        weights: (p * vs) @ v_int8 == p @ v_fp."""
+        v = v_ref[0]                     # (ps, Hkv, Dv)
+        if quant:
+            p, v = p * vs_ref[...], v.astype(jnp.float32)
+        else:
+            p = p.astype(v.dtype)
+        return jax.lax.dot_general(
+            p, v, (((2,), (0,)), ((0,), (1,))),
             preferred_element_type=jnp.float32,
         )
-        acc_scr[:] = acc_scr[:] * alpha + pv.reshape(Hq, Dv)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(j == np_ - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = jnp.where(l == 0.0, 0.0, acc_scr[:] / l_safe)
-        out_ref[0] = out.astype(out_ref.dtype)
+    # -- a decode row ---------------------------------------------------------
+    @pl.when(jnp.logical_and(one_row, first_page))
+    def _reset_one():
+        _reset(m1, l1, acc1)
+
+    @pl.when(one_row)
+    def _one_row():
+        # the softmax runs on (Hq, ps) scores: on (Hkv, groups, ps) a model
+        # without grouping (groups == 1: one query head per key/value
+        # head) hands Mosaic a compare with a one-wide sublane axis, which
+        # the TPU compiler refuses (LLO_CHECK ProducesVreg)
+        _softmax_page(
+            one_row_scores(), kv_idx <= pos0, m1, l1, acc1,
+            lambda p: weighted_values(
+                p.reshape(Hkv, groups, ps)).reshape(Hq, Dv),
+        )
+
+    @pl.when(jnp.logical_and(one_row, last_page))
+    def _store_one():
+        out_ref[off] = _normalized(l1, acc1).astype(out_ref.dtype)
+
+    # -- a chunk's rows: the whole tile, head-major ---------------------------
+    @pl.when(jnp.logical_and(many_rows, first_page))
+    def _reset_tile():
+        _reset(mt, lt, acct)
+        # (tile, Hq, D) → (Hkv, groups x tile, D): score row g * tile + i
+        # of a key/value head is its query head g of tile row i
+        qt[...] = jnp.swapaxes(q_ref[...], 0, 1).reshape(qt.shape)
+
+    @pl.when(many_rows)
+    def _tile_rows():
+        tile_row = jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (groups * tile, 1), 0), tile)
+        mask = kv_idx <= _tile_row_positions(tile_row, off, length, pos0)
+        _softmax_page(tile_scores(qt[...]), mask[None], mt, lt, acct,
+                      weighted_values)
+
+    @pl.when(jnp.logical_and(many_rows, last_page))
+    def _store_tile():
+        rows = _normalized(lt, acct).reshape(Hq, tile, Dv)
+        _store_segment_rows(out_ref, jnp.swapaxes(rows, 0, 1), off, length)
+
+
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("tile", "scale", "soft_cap", "name", "interpret"))
+def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
+              tile, scale, soft_cap, name, interpret):
+    """Jitted (inlined into its caller) for its cache alone: a step traces
+    one call per layer and pass, and every one after the first is the
+    first's jaxpr."""
+    Hq, Hkv = q.shape[1], k_pages.shape[2]
+    groups = Hq // Hkv
+    Dv = v_pages.shape[-1]
+    qp = _pad_last(q, LANE)
+    kp = _pad_last(k_pages, LANE)
+    vp = _pad_last(v_pages, LANE)
+    Dp, Dvp = qp.shape[-1], vp.shape[-1]
+    f32 = jnp.float32
+    scratch = [
+        pltpu.VMEM((Hq, LANE), f32),
+        pltpu.VMEM((Hq, LANE), f32),
+        pltpu.VMEM((Hq, Dvp), f32),
+        pltpu.VMEM((Hkv, groups * tile, Dp), qp.dtype),
+        pltpu.VMEM((Hkv, groups * tile, LANE), f32),
+        pltpu.VMEM((Hkv, groups * tile, LANE), f32),
+        pltpu.VMEM((Hkv, groups * tile, Dvp), f32),
+    ]
+    kernel = functools.partial(
+        _gqa_kernel, scale=scale, soft_cap=soft_cap, groups=groups,
+        quant=len(scales) > 0,
+    )
+    out = _segment_call(
+        kernel, name, interpret, RowSegments(tile, blocks, count), positions,
+        [qp], [kp, vp], scales, Dvp, scratch,
+    )
+    return out[..., :Dv]
+
+
+def _gqa(q, k_pages, v_pages, scales, page_tables, positions, *, scale,
+         soft_cap, segments, name):
+    if segments is None:
+        segments = _rows_as_segments(
+            page_tables, positions, k_pages.shape[1],
+            q.shape[1] * (q.shape[2] + v_pages.shape[-1]))
+    return _gqa_call(
+        q, k_pages, v_pages, tuple(scales), positions,
+        segments.blocks, segments.count, tile=segments.tile,
+        scale=float(scale), soft_cap=soft_cap, name=name,
+        interpret=_interpret(),
+    )
 
 
 def paged_attention_kernel(
@@ -121,129 +361,16 @@ def paged_attention_kernel(
     *,
     scale: float,
     soft_cap: float | None = None,
+    segments: RowSegments | None = None,
 ):
-    """GQA ragged paged attention; q (T, Hq, D), pages (N, ps, Hkv, D[v])."""
-    T, Hq, D = q.shape
-    N, ps, Hkv, Dv = v_pages.shape
-    P = page_tables.shape[1]
-    G = Hq // Hkv
-
-    qp = _pad_last(q, LANE)
-    kp = _pad_last(k_pages, LANE)
-    vp = _pad_last(v_pages, LANE)
-    Dp, Dvp = qp.shape[-1], vp.shape[-1]
-
-    kernel = functools.partial(
-        _gqa_kernel, scale=scale, soft_cap=soft_cap, page_size=ps, groups=G,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, P),
-        in_specs=[
-            pl.BlockSpec((1, Hq, Dp), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, Dp), lambda t, j, pt, pos: (pt[t, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, Dvp), lambda t, j, pt, pos: (pt[t, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, Dvp), lambda t, j, pt, pos: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, LANE), jnp.float32),
-            pltpu.VMEM((Hq, LANE), jnp.float32),
-            pltpu.VMEM((Hq, Dvp), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, Hq, Dvp), q.dtype),
-        interpret=_interpret(),
+    """GQA ragged paged attention; q (T, Hq, D), pages (N, ps, Hkv, D[v]),
+    `page_tables` (T, P) per ROW. `segments` groups the rows into runs of
+    one sequence (`row_segments`); None: every row its own."""
+    return _gqa(
+        q, k_pages, v_pages, (), page_tables, positions,
+        scale=scale, soft_cap=soft_cap, segments=segments,
         name="paged_attention_gqa",
-    )(page_tables.astype(jnp.int32), positions.astype(jnp.int32), qp, kp, vp)
-    return out[..., :Dv]
-
-
-def _gqa_quant_kernel(
-    pt_ref,    # (T, P) scalar-prefetch page table
-    pos_ref,   # (T,)   scalar-prefetch positions (-1 = pad row)
-    q_ref,     # (1, Hq, D)
-    k_ref,     # (1, ps, Hkv, D)  int8
-    v_ref,     # (1, ps, Hkv, Dv) int8
-    ks_ref,    # (1, 1, ps) f32 per-row K scales of THIS page
-    vs_ref,    # (1, 1, ps) f32 per-row V scales
-    out_ref,   # (1, Hq, Dv)
-    m_scr, l_scr, acc_scr,
-    *,
-    scale, soft_cap, page_size, groups,
-):
-    """The int8 variant of `_gqa_kernel`: identical grid/online-softmax
-    machinery, but pages arrive quantized and the per-page scale rows ride
-    the SAME scalar-prefetch page table (`pt[t, j]` indexes payload and
-    scale blocks alike). Dequantization is algebraic per page: the K scale
-    multiplies each kv slot's score column, the V scale folds into the
-    softmax weights before the value product — the big int8 blocks are
-    cast once for the MXU dots, never materialized dequantized in HBM."""
-    t, j = pl.program_id(0), pl.program_id(1)
-    np_ = pl.num_programs(1)
-    pos = pos_ref[t]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    run = jnp.logical_and(pos >= 0, j * page_size <= pos)
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0]                              # (Hq, D)
-        k = k_ref[0].astype(jnp.float32)          # (ps, Hkv, D)
-        v = v_ref[0].astype(jnp.float32)          # (ps, Hkv, Dv)
-        ks = ks_ref[...]                          # (1, 1, ps) per-slot K scales
-        vs = vs_ref[...]
-        Hq, D = q.shape
-        ps, Hkv, Dv = v.shape
-        qg = q.reshape(Hkv, groups, D).astype(jnp.float32)
-        # (Hkv, G, ps): contract D, batch over kv heads; the per-row K
-        # scale lands on the score column of its kv slot (before any
-        # soft-cap nonlinearity)
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * ks * scale
-        if soft_cap is not None:
-            s = soft_cap * jnp.tanh(s / soft_cap)
-        # the mask on the 2-D scores, as in `_gqa_kernel` (and why)
-        s = s.reshape(Hq, ps)
-        kv_idx = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (Hq, ps), 1
-        )
-        mask = kv_idx <= pos
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
-            l_scr.shape,
-        )
-        # V dequant folds into the weights: (p * vs) @ v_int8 == p @ v_fp
-        pv = jax.lax.dot_general(
-            (p.reshape(Hkv, groups, ps) * vs), v,
-            (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + pv.reshape(Hq, Dv)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    @pl.when(j == np_ - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = jnp.where(l == 0.0, 0.0, acc_scr[:] / l_safe)
-        out_ref[0] = out.astype(out_ref.dtype)
+    )
 
 
 def paged_attention_quant_kernel(
@@ -251,233 +378,153 @@ def paged_attention_quant_kernel(
     *,
     scale: float,
     soft_cap: float | None = None,
+    segments: RowSegments | None = None,
 ):
     """GQA ragged paged attention over int8 pages with (N, ps) per-row
     scales; same contract as `paged_attention_kernel`."""
-    T, Hq, D = q.shape
-    N, ps, Hkv, Dv = v_pages.shape
-    P = page_tables.shape[1]
-    G = Hq // Hkv
-
-    qp = _pad_last(q, LANE)
-    kp = _pad_last(k_pages, LANE)
-    vp = _pad_last(v_pages, LANE)
-    Dp, Dvp = qp.shape[-1], vp.shape[-1]
-
-    kernel = functools.partial(
-        _gqa_quant_kernel,
-        scale=scale, soft_cap=soft_cap, page_size=ps, groups=G,
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, P),
-        in_specs=[
-            pl.BlockSpec((1, Hq, Dp), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, Dp), lambda t, j, pt, pos: (pt[t, j], 0, 0, 0)),
-            pl.BlockSpec((1, ps, Hkv, Dvp), lambda t, j, pt, pos: (pt[t, j], 0, 0, 0)),
-            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, Dvp), lambda t, j, pt, pos: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((Hq, LANE), jnp.float32),
-            pltpu.VMEM((Hq, LANE), jnp.float32),
-            pltpu.VMEM((Hq, Dvp), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, Hq, Dvp), q.dtype),
-        interpret=_interpret(),
+    return _gqa(
+        q, k_pages, v_pages, (k_scales, v_scales), page_tables, positions,
+        scale=scale, soft_cap=soft_cap, segments=segments,
         name="paged_attention_gqa_int8",
-    )(
-        page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-        qp, kp, vp,
-        # (N, 1, ps): a (1, ps) block of an (N, ps) array breaks Mosaic's
-        # rule that a block's second-to-last dim is a multiple of 8 or the
-        # whole axis
-        k_scales.astype(jnp.float32)[:, None, :],
-        v_scales.astype(jnp.float32)[:, None, :],
     )
-    return out[..., :Dv]
 
 
+# -- absorbed MLA -------------------------------------------------------------
 def _mla_kernel(
-    pt_ref, pos_ref,
-    qa_ref,    # (1, n, r)
-    qr_ref,    # (1, n, dr)
-    c_ref,     # (1, ps, r)
+    blocks_ref,  # (6, W) scalar-prefetch blocks (RowSegments.blocks)
+    qa_ref,    # (tile, n, r)
+    qr_ref,    # (tile, n, dr)
+    c_ref,     # (1, ps, r)    bf16, or int8 with scales
     kr_ref,    # (1, ps, dr)
-    out_ref,   # (1, n, r)
-    m_scr, l_scr, acc_scr,
-    *,
-    scale, page_size,
+    *rest,     # [cs_ref, krs_ref (1, 1, ps) f32 per-row scales of THIS page,]
+               # out_ref (tile, n, r), one row's m/l/acc, the tile's m/l/acc
+    scale, page_size, tile, quant,
 ):
-    t, j = pl.program_id(0), pl.program_id(1)
-    np_ = pl.num_programs(1)
-    pos = pos_ref[t]
+    """One shared latent: every head of every row scores against the same
+    (ps, r + dr) page, so a tile's rows are one (tile x n, r) product.
+    int8: the latent and rope score parts carry DIFFERENT per-row scales
+    (two cached quantities, two scale arrays), so each is applied to its
+    dot before the parts sum into the shared accumulator; the latent scale
+    folds into the softmax weights for the value product (values ARE the
+    latent pages)."""
+    if quant:
+        cs_ref, krs_ref, *rest = rest
+    out_ref, m1, l1, acc1, mt, lt, acct = rest
+    off, length, pos0, key0, first_page, last_page = _block(
+        blocks_ref, page_size)
+    one_row = length == 1
+    many_rows = length > 1
+    n, r = qa_ref.shape[1:]
+    ps = c_ref.shape[1]
+    kv_idx = key0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def page(qa, qr, mask, m_ref, l_ref, acc_ref):
+        """qa (R, r), qr (R, dr): the absorbed scores' latent and rope
+        parts share one accumulator."""
+        c, kr = c_ref[0], kr_ref[0]      # (ps, r), (ps, dr)
+        if quant:
+            qa, qr, c, kr = (x.astype(jnp.float32) for x in (qa, qr, c, kr))
+        dims = (((1,), (1,)), ((), ()))
+        s_lat = jax.lax.dot_general(
+            qa, c, dims, preferred_element_type=jnp.float32)
+        s_rope = jax.lax.dot_general(
+            qr, kr, dims, preferred_element_type=jnp.float32)
+        if quant:
+            s_lat, s_rope = s_lat * cs_ref[0], s_rope * krs_ref[0]
 
-    run = jnp.logical_and(pos >= 0, j * page_size <= pos)
+        def weighted_values(p):
+            p = p * cs_ref[0] if quant else p.astype(c.dtype)
+            return jnp.dot(p, c, preferred_element_type=jnp.float32)
 
-    @pl.when(run)
-    def _body():
-        qa = qa_ref[0]   # (n, r)
-        qr = qr_ref[0]   # (n, dr)
-        c = c_ref[0]     # (ps, r)
-        kr = kr_ref[0]   # (ps, dr)
-        n = qa.shape[0]
-        ps = c.shape[0]
-        # absorbed scores: latent part + rope part share one accumulator
-        s = jax.lax.dot_general(
-            qa, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        _softmax_page(
+            (s_lat + s_rope) * scale, mask, m_ref, l_ref, acc_ref,
+            weighted_values,
         )
-        s = s + jax.lax.dot_general(
-            qr, kr, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        s = s * scale
-        kv_idx = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (n, ps), 1)
-        mask = kv_idx <= pos
-        s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
-            l_scr.shape,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+    @pl.when(jnp.logical_and(one_row, first_page))
+    def _reset_one():
+        _reset(m1, l1, acc1)
 
-    @pl.when(j == np_ - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = jnp.where(l == 0.0, 0.0, acc_scr[:] / l_safe)
-        out_ref[0] = out.astype(out_ref.dtype)
+    @pl.when(one_row)
+    def _one_row():
+        page(qa_ref[off], qr_ref[off], kv_idx <= pos0, m1, l1, acc1)
+
+    @pl.when(jnp.logical_and(one_row, last_page))
+    def _store_one():
+        out_ref[off] = _normalized(l1, acc1).astype(out_ref.dtype)
+
+    @pl.when(jnp.logical_and(many_rows, first_page))
+    def _reset_tile():
+        _reset(mt, lt, acct)
+
+    @pl.when(many_rows)
+    def _tile_rows():
+        # score row i * n + h is head h of tile row i
+        tile_row = jax.lax.broadcasted_iota(
+            jnp.int32, (tile * n, 1), 0) // n
+        mask = kv_idx <= _tile_row_positions(tile_row, off, length, pos0)
+        page(
+            qa_ref[...].reshape(tile * n, r),
+            qr_ref[...].reshape(tile * n, -1),
+            mask, mt, lt, acct,
+        )
+
+    @pl.when(jnp.logical_and(many_rows, last_page))
+    def _store_tile():
+        _store_segment_rows(
+            out_ref, _normalized(lt, acct).reshape(tile, n, r), off, length)
+
+
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("tile", "scale", "name", "interpret"))
+def _mla_call(q_abs, q_rope, c_pages, kr_pages, scales, positions, blocks,
+              count, *, tile, scale, name, interpret):
+    """Jitted for its cache alone, as `_gqa_call`."""
+    n, r = q_abs.shape[1:]
+    f32 = jnp.float32
+    scratch = [
+        pltpu.VMEM((n, LANE), f32),
+        pltpu.VMEM((n, LANE), f32),
+        pltpu.VMEM((n, r), f32),
+        pltpu.VMEM((tile * n, LANE), f32),
+        pltpu.VMEM((tile * n, LANE), f32),
+        pltpu.VMEM((tile * n, r), f32),
+    ]
+    kernel = functools.partial(
+        _mla_kernel, scale=scale, quant=len(scales) > 0)
+    return _segment_call(
+        kernel, name, interpret, RowSegments(tile, blocks, count), positions,
+        [q_abs, q_rope], [c_pages, kr_pages], scales, r, scratch,
+    )
+
+
+def _mla(q_abs, q_rope, c_pages, kr_pages, scales, page_tables, positions, *,
+         scale, segments, name):
+    if segments is None:
+        n, r = q_abs.shape[1:]
+        segments = _rows_as_segments(
+            page_tables, positions, c_pages.shape[1],
+            n * (2 * r + q_rope.shape[-1]))
+    return _mla_call(
+        q_abs, q_rope, c_pages, kr_pages, tuple(scales), positions,
+        segments.blocks, segments.count, tile=segments.tile,
+        scale=float(scale), name=name, interpret=_interpret(),
+    )
 
 
 def paged_mla_attention_kernel(
     q_abs, q_rope, c_pages, kr_pages, page_tables, positions,
     *,
     scale: float,
+    segments: RowSegments | None = None,
 ):
-    """Absorbed-MLA ragged paged attention; returns latent outputs (T, n, r)."""
-    T, n, r = q_abs.shape
-    N, ps, _ = c_pages.shape
-    P, dr = page_tables.shape[1], q_rope.shape[-1]
-
-    kernel = functools.partial(_mla_kernel, scale=scale, page_size=ps)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, P),
-        in_specs=[
-            pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, n, dr), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, ps, r), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, ps, dr), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n, LANE), jnp.float32),
-            pltpu.VMEM((n, LANE), jnp.float32),
-            pltpu.VMEM((n, r), jnp.float32),
-        ],
+    """Absorbed-MLA ragged paged attention; returns latent outputs
+    (T, n, r). `segments` as in `paged_attention_kernel`."""
+    return _mla(
+        q_abs, q_rope, c_pages, kr_pages, (), page_tables, positions,
+        scale=scale, segments=segments, name="paged_attention_mla",
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, n, r), q_abs.dtype),
-        interpret=_interpret(),
-        name="paged_attention_mla",
-    )(
-        page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-        q_abs, q_rope, c_pages, kr_pages,
-    )
-
-
-def _mla_quant_kernel(
-    pt_ref, pos_ref,
-    qa_ref,    # (1, n, r)
-    qr_ref,    # (1, n, dr)
-    c_ref,     # (1, ps, r)  int8
-    kr_ref,    # (1, ps, dr) int8
-    cs_ref,    # (1, 1, ps) f32 per-row latent scales of THIS page
-    krs_ref,   # (1, 1, ps) f32 per-row rope scales
-    out_ref,   # (1, n, r)
-    m_scr, l_scr, acc_scr,
-    *,
-    scale, page_size,
-):
-    """int8 variant of `_mla_kernel`: the latent and rope score parts
-    carry DIFFERENT per-row scales (two cached quantities, two scale
-    arrays), so each is applied to its dot before the parts sum into the
-    shared accumulator; the latent scale folds into the softmax weights
-    for the value product (values ARE the latent pages)."""
-    t, j = pl.program_id(0), pl.program_id(1)
-    np_ = pl.num_programs(1)
-    pos = pos_ref[t]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    run = jnp.logical_and(pos >= 0, j * page_size <= pos)
-
-    @pl.when(run)
-    def _body():
-        qa = qa_ref[0].astype(jnp.float32)    # (n, r)
-        qr = qr_ref[0].astype(jnp.float32)    # (n, dr)
-        c = c_ref[0].astype(jnp.float32)      # (ps, r)
-        kr = kr_ref[0].astype(jnp.float32)    # (ps, dr)
-        cs = cs_ref[0]                        # (1, ps)
-        krs = krs_ref[0]
-        n = qa.shape[0]
-        ps = c.shape[0]
-        s = jax.lax.dot_general(
-            qa, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * cs
-        s = s + jax.lax.dot_general(
-            qr, kr, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * krs
-        s = s * scale
-        kv_idx = j * page_size + jax.lax.broadcasted_iota(jnp.int32, (n, ps), 1)
-        mask = kv_idx <= pos
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True),
-            l_scr.shape,
-        )
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p * cs, c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    @pl.when(j == np_ - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = jnp.where(l == 0.0, 0.0, acc_scr[:] / l_safe)
-        out_ref[0] = out.astype(out_ref.dtype)
 
 
 def paged_mla_attention_quant_kernel(
@@ -485,43 +532,13 @@ def paged_mla_attention_quant_kernel(
     page_tables, positions,
     *,
     scale: float,
+    segments: RowSegments | None = None,
 ):
     """Absorbed-MLA ragged paged attention over int8 latent/rope pages
     with (N, ps) per-row scales; same contract as
     `paged_mla_attention_kernel`."""
-    T, n, r = q_abs.shape
-    N, ps, _ = c_pages.shape
-    P, dr = page_tables.shape[1], q_rope.shape[-1]
-
-    kernel = functools.partial(_mla_quant_kernel, scale=scale, page_size=ps)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(T, P),
-        in_specs=[
-            pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, n, dr), lambda t, j, pt, pos: (t, 0, 0)),
-            pl.BlockSpec((1, ps, r), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, ps, dr), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-            pl.BlockSpec((1, 1, ps), lambda t, j, pt, pos: (pt[t, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, n, r), lambda t, j, pt, pos: (t, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((n, LANE), jnp.float32),
-            pltpu.VMEM((n, LANE), jnp.float32),
-            pltpu.VMEM((n, r), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, n, r), q_abs.dtype),
-        interpret=_interpret(),
-        name="paged_attention_mla_int8",
-    )(
-        page_tables.astype(jnp.int32), positions.astype(jnp.int32),
-        q_abs, q_rope, c_pages, kr_pages,
-        # (N, 1, ps) for the same block-shape rule as the GQA int8 kernel
-        c_scales.astype(jnp.float32)[:, None, :],
-        kr_scales.astype(jnp.float32)[:, None, :],
+    return _mla(
+        q_abs, q_rope, c_pages, kr_pages, (c_scales, kr_scales),
+        page_tables, positions,
+        scale=scale, segments=segments, name="paged_attention_mla_int8",
     )

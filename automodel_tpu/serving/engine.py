@@ -87,6 +87,8 @@ from automodel_tpu.models.llm.decoder import (
 from automodel_tpu.ops.paged_attention import (
     ragged_paged_attention,
     ragged_paged_mla_attention,
+    row_tile,
+    step_row_segments,
 )
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.quant import matmul as _mm, quantize_kv_rows
@@ -333,6 +335,12 @@ class ServingEngine:
         self.track = track
         self.is_moe = getattr(cfg, "moe", None) is not None
         self.is_mla = cfg.attention_type == "mla"
+        # rows of the paged kernels' q tile, from the step's rows and the
+        # elements of one row's attention queries and outputs
+        self._attn_row_tile = row_tile(serve_cfg.token_budget, cfg.num_heads * (
+            2 * cfg.mla_kv_lora_rank + cfg.mla_qk_rope_head_dim
+            if self.is_mla else 2 * cfg.resolved_head_dim
+        ))
         # tp/ep-sharded step (mesh_ctx set): the paged pool becomes a
         # mesh-sharded array (kv_pages.pool_axes) and GSPMD partitions the
         # ONE jitted step over the mesh — page IDs stay global, so the host
@@ -663,7 +671,8 @@ class ServingEngine:
             out_lat = ragged_paged_mla_attention(
                 q_abs[0], q_rope[0], pool_k, pool_v,
                 b["pt_tok"], b["pos"],
-                scale=scale, window=window, mesh_ctx=self._mesh, **scales_kw,
+                scale=scale, window=window, mesh_ctx=self._mesh,
+                segments=b["segments"], **scales_kw,
             )
             attn = jnp.einsum("tnr,rnd->tnd", out_lat, w_uv)
             attn = attn.reshape(1, -1, n * dv)
@@ -701,7 +710,7 @@ class ServingEngine:
             q[0], pool_k, pool_v, b["pt_tok"], b["pos"],
             scale=scale, window=window,
             soft_cap=cfg.attn_soft_cap, sinks=lp.get("sinks"),
-            mesh_ctx=self._mesh, **scales_kw,
+            mesh_ctx=self._mesh, segments=b["segments"], **scales_kw,
         )
         T = attn.shape[0]
         attn = attn.reshape(1, T, cfg.num_heads * attn.shape[-1])
@@ -734,6 +743,14 @@ class ServingEngine:
         # position is -1, so they attend to nothing
         b = dict(b)
         b["pt_tok"] = b["page_tables"][jnp.maximum(b["slot"], 0)]
+        # the rows grouped into runs of one slot, once for every attention
+        # call of the step: the Pallas kernels' unit of work (None, and
+        # nothing traced, where the calls go to the XLA reference)
+        b["segments"] = step_row_segments(
+            b["slot"], b["pos"], b["pt_tok"],
+            page_size=self.serve_cfg.page_size, tile=self._attn_row_tile,
+            max_slots=self.serve_cfg.max_slots,
+        )
         # copy-on-write splits first (≤ 1 per slot; idle entries copy the
         # trash page onto itself): a slot about to append into a page some
         # other table or the radix tree still reads gets a private copy
@@ -1065,13 +1082,21 @@ class ServingEngine:
         ) as span:
             preempted = sched.n_preemptions
             plan = sched.schedule(step_idx)
-            span.set_metadata(**sched.turn_stats(preempted))
+            self.note_turn(span, sched.turn_stats(preempted, plan))
             if plan is not None:
                 span.set_metadata(rows=plan.n_tokens, samples=plan.n_samples)
         if plan is None:
             return None, 0, 0.0
         n_new, dt = self.run_and_absorb(sched, plan, step_idx)
         return plan, n_new, dt
+
+    def note_turn(self, span, stats: dict) -> None:
+        """A turn's `Scheduler.turn_stats` onto its `step.plan` span, and
+        the attention grid's two onto /metrics."""
+        span.set_metadata(**stats)
+        reg = self.obs.registry
+        reg.gauge("serve_attn_segments").set(stats["attn_segments"])
+        reg.gauge("serve_attn_live_blocks").set(stats["attn_live_blocks"])
 
     def _mirror_stats(self, stats: dict, sched: Scheduler) -> None:
         """Land one serve_batch call's outcome counters on the central
@@ -1115,6 +1140,7 @@ class ServingEngine:
             alloc=self.alloc, prefix=self.prefix,
             arrival_gating=arrival_gating,
             tracer=self.obs.tracer, track=self.track,
+            attn_row_tile=self._attn_row_tile,
         )
 
     def reset_prefix_cache(self) -> int:

@@ -398,7 +398,9 @@ class OnlineFrontend:
             with span("step.plan") as plan_span:
                 preempted = self.sched.n_preemptions
                 plan = self.sched.schedule(self.step_idx)
-                plan_span.set_metadata(**self.sched.turn_stats(preempted))
+                self.engine.note_turn(
+                    plan_span, self.sched.turn_stats(preempted, plan)
+                )
                 if plan is not None:
                     plan_span.set_metadata(
                         rows=plan.n_tokens, samples=plan.n_samples

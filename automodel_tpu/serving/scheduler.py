@@ -72,6 +72,7 @@ from collections import deque
 import numpy as np
 
 from automodel_tpu.observability.trace import NULL_TRACER
+from automodel_tpu.ops.paged_attention import segment_bounds
 from automodel_tpu.resilience.faults import fault_hit
 from automodel_tpu.serving.kv_pages import PageAllocator, pages_for
 from automodel_tpu.serving.prefix_cache import (
@@ -189,6 +190,7 @@ class Scheduler:
         arrival_gating: bool = True,
         tracer=None,             # observability.trace.Tracer (None → no-op)
         track: str = "engine",
+        attn_row_tile: int | None = None,  # the paged kernels' q tile, rows
     ):
         # lifecycle tracing (observability/trace.py): the null tracer makes
         # every emit a constant-time no-op, so the untraced hot path is
@@ -210,6 +212,9 @@ class Scheduler:
         self.token_budget = token_budget
         self.prefill_chunk = prefill_chunk or token_budget
         self.trash_page = num_pages  # pool arrays carry num_pages + 1 pages
+        # where the engine's attention kernels cut a slot's run of rows
+        # (ops/paged_attention.row_tile); alone, a run is never cut
+        self.attn_row_tile = attn_row_tile or token_budget
         if admission_policy not in ("fifo", "prefix-hit"):
             raise ValueError(f"unknown admission_policy {admission_policy!r}")
         if admission_policy == "prefix-hit" and not (
@@ -291,17 +296,32 @@ class Scheduler:
     def has_work(self) -> bool:
         return bool(self.waiting or self.running)
 
-    def turn_stats(self, preemptions_before: int) -> dict:
-        """What `step.plan`'s span says of the pool after a `schedule()`:
+    def turn_stats(self, preemptions_before: int, plan=None) -> dict:
+        """What `step.plan`'s span says after a `schedule()`. Of the pool:
         pages free, requests resident, and the requests this turn preempted
         (`preemptions_before`: `n_preemptions` as the turn began). Where a
         page is dear (a looped decoder's holds every pass) these say what
         the slots do not: whether requests wait for pages, and what growth
-        costs in preempted work."""
+        costs in preempted work. Of the turn's `plan` (None: no step): the
+        (segment, page) blocks each paged-attention call of its step
+        walks, `attn_segments` runs of one slot's rows and
+        `attn_live_blocks` pages they attend to in all; against rows x
+        pages_per_slot, the share of a (row, page) grid that is left."""
+        segments = live_blocks = 0
+        if plan is not None:
+            is_start, is_last = segment_bounds(
+                np, plan.slot, plan.pos, self.attn_row_tile
+            )
+            segments = int(is_start.sum())
+            live_blocks = int(
+                (plan.pos[is_last] // self.page_size + 1).sum()
+            )
         return {
             "free_pages": self.alloc.num_free,
             "resident": len(self.running),
             "preempted": self.n_preemptions - preemptions_before,
+            "attn_segments": segments,
+            "attn_live_blocks": live_blocks,
         }
 
     def prefix_hit_tokens(self, tokens: list) -> int:

@@ -26,6 +26,7 @@ then `{"ok": true, "device": {...}}`.
 from __future__ import annotations
 
 import argparse
+import functools
 import gc
 import json
 import math
@@ -127,24 +128,26 @@ def _paged_batch(rng, *, T, P, ps, num_pages):
     """A mixed step's rows: decode rows of long contexts, two prefill
     chunks, pad rows; per-row page tables are dense prefixes of distinct
     pages, padded with the trash page (the last one), as the scheduler
-    emits them."""
+    emits them. Returns (tables, positions, each row's slot)."""
     import numpy as np
 
     trash = num_pages - 1
     perm = rng.permutation(trash)
     tables = np.full((T, P), trash, np.int32)
     pos = np.full((T,), -1, np.int32)
-    row, used = 0, 0
+    slot = np.full((T,), -1, np.int32)
+    row, used, slots = 0, 0, 0
 
     def context(length, rows_pos):
-        nonlocal row, used
+        nonlocal row, used, slots
         n = -(-length // ps)
         pages = perm[used : used + n]
         used += n
         for p in rows_pos:
             tables[row, :n] = pages
-            pos[row] = p
+            pos[row], slot[row] = p, slots
             row += 1
+        slots += 1
 
     for _ in range(12):  # decode rows, one per running request
         length = int(rng.integers(ps, P * ps))
@@ -153,7 +156,7 @@ def _paged_batch(rng, *, T, P, ps, num_pages):
         length = int(rng.integers(chunk, P * ps))
         context(length, range(length - chunk, length))
     assert row <= T - 8 and used <= trash  # the rest stay pad rows
-    return tables, pos
+    return tables, pos, slot
 
 
 def phase_kernels() -> None:
@@ -162,8 +165,12 @@ def phase_kernels() -> None:
 
     from automodel_tpu.ops.attention import make_attention_mask, xla_attention
     from automodel_tpu.ops.paged_attention import (
+        RowSegments,
+        max_row_segments,
         ragged_paged_attention_xla,
         ragged_paged_mla_attention_xla,
+        row_segments,
+        row_tile,
     )
     from automodel_tpu.ops.pallas import flash_attention as fa
     from automodel_tpu.ops.pallas import ragged_paged_attention as rpa
@@ -212,7 +219,18 @@ def phase_kernels() -> None:
 
     def paged_case(name, kernel, oracle, q_shapes, page_shapes, num_pages,
                    quant):
-        tables, pos = _paged_batch(rng, T=T, P=P, ps=ps, num_pages=num_pages)
+        """`kernel` over the step's rows grouped as the engine groups
+        them (a decode row a segment of its own, a chunk cut at the
+        tile), against `oracle` reading every row's own table."""
+        tables, pos, slot = _paged_batch(
+            rng, T=T, P=P, ps=ps, num_pages=num_pages)
+        # a row's queries and its output (as wide as the first of them)
+        tile = row_tile(T, sum(h * w for h, w in q_shapes + q_shapes[:1]))
+        _tile, *segments = row_segments(
+            jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(tables),
+            page_size=ps, tile=tile,
+            max_segments=max_row_segments(T, 14, tile),
+        )
         kk = jax.random.split(jax.random.key(len(name)), 4)
         qs = [jax.random.normal(a, (T, *s), jnp.bfloat16)
               for a, s in zip(kk[:2], q_shapes)]
@@ -227,9 +245,11 @@ def phase_kernels() -> None:
             pages = [q8.reshape(p.shape) for (q8, _), p in zip(pairs, pages)]
             scales = [sc.reshape(num_pages, ps) for _, sc in pairs]
         tables, pos = jnp.asarray(tables), jnp.asarray(pos)
-        fn = jax.jit(kernel)
-        got = fn(*qs, *pages, *scales, tables, pos)
-        txt = fn.lower(*qs, *pages, *scales, tables, pos).compile().as_text()
+        fn = jax.jit(lambda blocks, count, *a: kernel(
+            *a, segments=RowSegments(tile, blocks, count)))
+        got = fn(*segments, *qs, *pages, *scales, tables, pos)
+        txt = fn.lower(
+            *segments, *qs, *pages, *scales, tables, pos).compile().as_text()
         check(len(mosaic_calls(txt, name)) == 1, f"{name} is a Mosaic call")
         with jax.default_matmul_precision("highest"):
             want = jax.jit(oracle)(*f32(qs), *pages, *scales, tables, pos)
@@ -245,17 +265,15 @@ def phase_kernels() -> None:
     mla_pages = [(m["latent"],), (m["rope"],)]
     paged_case(
         "paged_attention_mla",
-        lambda qa, qr, c, kr, pt, pos: rpa.paged_mla_attention_kernel(
-            qa, qr, c, kr, pt, pos, scale=mla_scale),
+        functools.partial(rpa.paged_mla_attention_kernel, scale=mla_scale),
         lambda qa, qr, c, kr, pt, pos: ragged_paged_mla_attention_xla(
             qa, qr, f32(c), f32(kr), pt, pos, scale=mla_scale),
         mla_q, mla_pages, m["num_pages"], quant=False,
     )
     paged_case(
         "paged_attention_mla_int8",
-        lambda qa, qr, c, kr, cs, krs, pt, pos:
-            rpa.paged_mla_attention_quant_kernel(
-                qa, qr, c, kr, cs, krs, pt, pos, scale=mla_scale),
+        functools.partial(
+            rpa.paged_mla_attention_quant_kernel, scale=mla_scale),
         lambda qa, qr, c, kr, cs, krs, pt, pos: ragged_paged_mla_attention_xla(
             qa, qr, c, kr, pt, pos, scale=mla_scale,
             c_scales=cs, kr_scales=krs),
@@ -267,16 +285,14 @@ def phase_kernels() -> None:
     gqa_pages = [(g["kv_heads"], g["head_dim"])] * 2
     paged_case(
         "paged_attention_gqa",
-        lambda q, k, v, pt, pos: rpa.paged_attention_kernel(
-            q, k, v, pt, pos, scale=gqa_scale),
+        functools.partial(rpa.paged_attention_kernel, scale=gqa_scale),
         lambda q, k, v, pt, pos: ragged_paged_attention_xla(
             q, f32(k), f32(v), pt, pos, scale=gqa_scale),
         gqa_q, gqa_pages, g["num_pages"], quant=False,
     )
     paged_case(
         "paged_attention_gqa_int8",
-        lambda q, k, v, ks_, vs_, pt, pos: rpa.paged_attention_quant_kernel(
-            q, k, v, ks_, vs_, pt, pos, scale=gqa_scale),
+        functools.partial(rpa.paged_attention_quant_kernel, scale=gqa_scale),
         lambda q, k, v, ks_, vs_, pt, pos: ragged_paged_attention_xla(
             q, k, v, pt, pos, scale=gqa_scale, k_scales=ks_, v_scales=vs_),
         gqa_q, gqa_pages, g["num_pages"], quant=True,

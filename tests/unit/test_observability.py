@@ -263,9 +263,16 @@ def test_tracing_on_off_parity_and_compile_once(params):
             run = runs[e.step]
             assert run.ts <= e.ts and e.ts + e.dur <= run.ts + run.dur
     assert all(set(r.args) == {"rows", "samples"} for r in runs.values())
+    # a planned turn says what grid its step's paged-attention calls walk:
+    # at most a segment a real row, at least a live page a segment
+    plans = [e.args for e in spans if e.name == "step.plan" and "rows" in e.args]
+    assert all(1 <= p["attn_segments"] <= p["rows"] for p in plans)
+    assert all(p["attn_live_blocks"] >= p["attn_segments"] for p in plans)
     # with neither sink nothing is recorded
     assert base_eng.obs.tracer is NULL_TRACER and NULL_TRACER.events == ()
     reg = eng.obs.registry.snapshot()
+    assert reg["serve_attn_segments"] == plans[-1]["attn_segments"]
+    assert reg["serve_attn_live_blocks"] == plans[-1]["attn_live_blocks"]
     assert reg["serve_steps_total"] > 0
     assert reg["serve_new_tokens_total"] == sum(
         len(o) for o in res["outputs"]

@@ -47,18 +47,34 @@ def tpu_branch(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", cache)
 
 
-#: (rows, heads, key/value heads, head width, pages, page size, pages a slot)
+#: (rows, heads, key/value heads, head width, pages, page size, pages a
+#: slot, slots)
 WIDTHS = {
-    "ouro_2_6b: no grouping": (48, 16, 16, 128, 84, 64, 10),
-    "grouped 4:1": (48, 32, 8, 128, 84, 64, 10),
-    "one key/value head": (48, 8, 1, 128, 84, 64, 10),
+    "ouro_2_6b: no grouping": (48, 16, 16, 128, 84, 64, 10, 24),
+    "grouped 4:1": (48, 32, 8, 128, 84, 64, 10, 24),
+    "one key/value head": (48, 8, 1, 128, 84, 64, 10, 24),
 }
+
+
+def _segments(rows, slots, pages_a_slot, row_width, shape):
+    """The step's row segments as the engine derives them, their arrays
+    shapes: the kernels compile with both bodies, a decode row's and a
+    chunk's, and a run-time grid bound, whatever a step holds. Returns
+    (tile, segments at most, the two shapes, shapes → RowSegments)."""
+    from automodel_tpu.ops.paged_attention import (
+        RowSegments, max_row_segments, row_tile,
+    )
+
+    tile = row_tile(rows, row_width)
+    most = max_row_segments(rows, slots, tile)
+    shapes = (shape((6, most * pages_a_slot), jnp.int32), shape((), jnp.int32))
+    return tile, most, shapes, lambda b, c: RowSegments(tile, b, c)
 
 
 @pytest.mark.parametrize("widths", WIDTHS.values(), ids=WIDTHS.keys())
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
 def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths, quant):
-    T, Hq, Hkv, D, N, ps, P = widths
+    T, Hq, Hkv, D, N, ps, P, S = widths
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -66,17 +82,51 @@ def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths,
     q = s((T, Hq, D), jnp.bfloat16)
     pages = s((N + 1, ps, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
     tables, pos = s((T, P), jnp.int32), s((T,), jnp.int32)
+    tile, most, seg, segments = _segments(T, S, P, 2 * Hq * D, s)
+    assert (tile, most) == (32, 25)
     if quant:
         scales = s((N + 1, ps), jnp.float32)
-        fn = lambda q, k, v, ks, vs, pt, pos: tpu_branch.paged_attention_quant_kernel(  # noqa: E731
-            q, k, v, ks, vs, pt, pos, scale=D ** -0.5)
-        args = (q, pages, pages, scales, scales, tables, pos)
+        fn = lambda q, k, v, ks, vs, pt, pos, *seg: tpu_branch.paged_attention_quant_kernel(  # noqa: E731
+            q, k, v, ks, vs, pt, pos, scale=D ** -0.5, segments=segments(*seg))
+        args = (q, pages, pages, scales, scales, tables, pos, *seg)
     else:
-        fn = lambda q, k, v, pt, pos: tpu_branch.paged_attention_kernel(  # noqa: E731
-            q, k, v, pt, pos, scale=D ** -0.5)
-        args = (q, pages, pages, tables, pos)
+        fn = lambda q, k, v, pt, pos, *seg: tpu_branch.paged_attention_kernel(  # noqa: E731
+            q, k, v, pt, pos, scale=D ** -0.5, segments=segments(*seg))
+        args = (q, pages, pages, tables, pos, *seg)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "paged_attention_gqa" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_mla_compiles_for_the_chip(one_chip, tpu_branch, quant):
+    """Moonlight's cell: 256 rows, 16 heads over one shared latent of 512
+    + 64 rope, 2,049 pages of 64, 20 pages and 64 slots."""
+    T, n, r, dr, N, ps, P, S = 256, 16, 512, 64, 2049, 64, 20, 64
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    dtype = jnp.int8 if quant else jnp.bfloat16
+    qa, qr = s((T, n, r), jnp.bfloat16), s((T, n, dr), jnp.bfloat16)
+    c, kr = s((N, ps, r), dtype), s((N, ps, dr), dtype)
+    tables, pos = s((T, P), jnp.int32), s((T,), jnp.int32)
+    tile, most, seg, segments = _segments(T, S, P, n * (2 * r + dr), s)
+    assert (tile, most) == (32, 72)
+    if quant:
+        scales = s((N, ps), jnp.float32)
+        fn = lambda qa, qr, c, kr, cs, krs, pt, pos, *seg: (  # noqa: E731
+            tpu_branch.paged_mla_attention_quant_kernel(
+                qa, qr, c, kr, cs, krs, pt, pos, scale=192 ** -0.5,
+                segments=segments(*seg)))
+        args = (qa, qr, c, kr, scales, scales, tables, pos, *seg)
+    else:
+        fn = lambda qa, qr, c, kr, pt, pos, *seg: (  # noqa: E731
+            tpu_branch.paged_mla_attention_kernel(
+                qa, qr, c, kr, pt, pos, scale=192 ** -0.5,
+                segments=segments(*seg)))
+        args = (qa, qr, c, kr, tables, pos, *seg)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "paged_attention_mla" in compiled.as_text()
 
 
 def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
