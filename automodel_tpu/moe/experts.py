@@ -146,8 +146,8 @@ def experts_forward_dropless(
     shapes throughout (TK rows total), so jit-compatible.
 
     Scope: replicated or dp-sharded experts (ep=1) — ragged group sizes
-    don't currently split across an `ep` axis under GSPMD; EP meshes use the
-    capacity dispatcher.
+    don't split across an `ep` axis under GSPMD; on an ep>1 mesh
+    `moe/layer.py` calls `experts_forward_dropless_ep` below instead.
     """
     T, H = x.shape
     K = cfg.experts_per_token

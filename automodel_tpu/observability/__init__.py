@@ -98,7 +98,7 @@ class ObservabilityConfig:
     #: alternatively: capture when a step exceeds this many ms
     itl_spike_ms: Optional[float] = None
     profile_dir: Optional[str] = None
-    #: serve a tiny HTTP /metrics + /healthz endpoint from OnlineFrontend
+    #: serve a tiny HTTP /metrics + /healthz endpoint from the online frontend
     #: (0 picks an ephemeral port; None disables)
     http_port: Optional[int] = None
 

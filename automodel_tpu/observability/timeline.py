@@ -19,7 +19,7 @@ construction:
 ITL attribution splits each inter-commit gap into step time (overlap with
 `step.run` spans), backpressure (stream-pause overlap), and scheduling
 remainder. `attribution_summary` picks the median-TTFT request so the
-reported components sum to the p50 the bench headline already prints.
+reported components sum to the run's p50.
 """
 
 from __future__ import annotations

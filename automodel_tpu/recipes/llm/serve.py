@@ -55,7 +55,6 @@ YAML:
         stream_buffer: 32             #   admission; null → never shed)
         max_waiting: null
         shed_deadlines: true
-        shed_safety: 1.0
       resilience:                     # typed: ServeResilienceConfig
         enabled: true                 # replica failure recovery (health
         degrade: true                 #   board + evacuate-and-requeue);

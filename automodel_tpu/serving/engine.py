@@ -1066,37 +1066,41 @@ class ServingEngine:
         tokens, _lps = out
         return sched.update(plan, tokens, step_idx)
 
-    def run_one_step(
-        self, sched: Scheduler, step_idx: int,
-    ) -> tuple[StepPlan | None, int, float]:
-        """ONE reentrant serve step: schedule → run → absorb. Returns
-        (plan, tokens committed, device-step seconds) with plan=None when
-        nothing could be packed this step (empty queue, future arrivals,
-        every slot paused, or pool-blocked — the CALLER decides whether to
-        fast-forward, sleep, or shed; this layer never blocks). The shared
-        inner loop of the offline `serve_batch` below and the async online
-        frontend (serving/frontend.py), which drives it from an event loop
-        with live admission between calls."""
+    def plan_turn(self, sched: Scheduler, step_idx: int) -> StepPlan | None:
+        """The first half of a serve turn, the one place any loop plans:
+        `sched.schedule(step_idx)` under the `step.plan` span, on this
+        engine's track and under the number `run_step` will stamp on the
+        turn's `step.run`. The span says what the turn did to the pool and
+        what grid the step's attention walks (`Scheduler.turn_stats`; the
+        grid's two also on /metrics) and, where a step was planned, its
+        `rows` / `samples`. None when nothing could be packed: the CALLER
+        decides whether to fast-forward, sleep or shed. Not held across
+        an `await`."""
         with self.obs.tracer.span(
             "step.plan", track=self.track, step=self.steps_run
         ) as span:
             preempted = sched.n_preemptions
             plan = sched.schedule(step_idx)
-            self.note_turn(span, sched.turn_stats(preempted, plan))
+            stats = sched.turn_stats(preempted, plan)
             if plan is not None:
-                span.set_metadata(rows=plan.n_tokens, samples=plan.n_samples)
+                stats.update(rows=plan.n_tokens, samples=plan.n_samples)
+            span.set_metadata(**stats)
+            reg = self.obs.registry
+            reg.gauge("serve_attn_segments").set(stats["attn_segments"])
+            reg.gauge("serve_attn_live_blocks").set(stats["attn_live_blocks"])
+        return plan
+
+    def run_one_step(
+        self, sched: Scheduler, step_idx: int,
+    ) -> tuple[StepPlan | None, int, float]:
+        """ONE reentrant serve step: plan → run → absorb. Returns (plan,
+        tokens committed, device-step seconds), plan=None when `plan_turn`
+        packed nothing. The inner loop of the offline `serve_batch` below."""
+        plan = self.plan_turn(sched, step_idx)
         if plan is None:
             return None, 0, 0.0
         n_new, dt = self.run_and_absorb(sched, plan, step_idx)
         return plan, n_new, dt
-
-    def note_turn(self, span, stats: dict) -> None:
-        """A turn's `Scheduler.turn_stats` onto its `step.plan` span, and
-        the attention grid's two onto /metrics."""
-        span.set_metadata(**stats)
-        reg = self.obs.registry
-        reg.gauge("serve_attn_segments").set(stats["attn_segments"])
-        reg.gauge("serve_attn_live_blocks").set(stats["attn_live_blocks"])
 
     def _mirror_stats(self, stats: dict, sched: Scheduler) -> None:
         """Land one serve_batch call's outcome counters on the central

@@ -62,11 +62,17 @@ The jitted step is the only blocking call and runs in a worker thread
 (`run_in_executor`); every scheduler mutation happens on the event-loop
 thread between steps, so the scheduler needs no locks.
 
-`DisaggOnlineFrontend` is the same loop over a `DisaggRouter`'s replica
-classes: arrivals route to prefill replicas, finished prefills migrate as
-page-granular KV handoffs, decode replicas stream — with cancellation
-releasing in-flight handoff pins and shedding fed by the prefill-class
-backlog.
+Two frontends, one client side. `FrontendBase` holds what a client and a
+stream see — the stream table, arrivals and cancels, the shedding rule,
+back-pressure, token delivery, close, rolling restart and the /metrics
+endpoint — over whatever schedulers its subclass names. `OnlineFrontend`
+drives ONE engine (with `plan_broadcast` and `on_failure`);
+`DisaggOnlineFrontend` drives a `DisaggRouter`'s replica classes: arrivals
+route to prefill replicas, finished prefills migrate as page-granular KV
+handoffs, decode replicas stream. Each says only what differs: where an
+arrival lands, how a rid is cancelled, what counts as work left, how
+recovered work comes back, and its `_drive`. Every loop plans through
+`ServingEngine.plan_turn`, which owns the `step.plan` span.
 """
 
 from __future__ import annotations
@@ -89,6 +95,13 @@ from automodel_tpu.serving.resilience import (
 from automodel_tpu.serving.scheduler import Request, Scheduler
 
 
+#: headroom factor on the steps-to-first-token estimate (shed when
+#: step + safety * est_steps >= deadline); >1 would shed earlier
+SHED_SAFETY = 1.0
+#: wall-clock inter-token-latency EWMA decay (reporting only)
+ITL_DECAY = 0.9
+
+
 @dataclasses.dataclass(frozen=True)
 class FrontendConfig:
     """Typed `serving.online` section."""
@@ -100,11 +113,6 @@ class FrontendConfig:
     max_waiting: int | None = None
     #: deadline-aware admission control + waiting-queue early expiry
     shed_deadlines: bool = True
-    #: headroom factor on the steps-to-first-token estimate (shed when
-    #: step + safety * est_steps >= deadline); >1 sheds earlier
-    shed_safety: float = 1.0
-    #: wall-clock inter-token-latency EWMA decay (reporting only)
-    itl_decay: float = 0.9
     #: event-loop sleep while nothing is runnable
     idle_sleep_s: float = 0.001
     #: close(): finish resident work (True) or cancel it (False)
@@ -115,10 +123,6 @@ class FrontendConfig:
             raise ValueError("stream_buffer must be >= 1")
         if self.max_waiting is not None and self.max_waiting < 1:
             raise ValueError("max_waiting must be >= 1 (or None)")
-        if self.shed_safety <= 0:
-            raise ValueError("shed_safety must be > 0")
-        if not (0.0 <= self.itl_decay < 1.0):
-            raise ValueError("itl_decay must be in [0, 1)")
 
 
 class TokenStream:
@@ -226,40 +230,45 @@ async def _handle_metrics_http(frontend, reader, writer) -> None:
         writer.close()
 
 
-class OnlineFrontend:
-    """Async streaming serve loop over ONE engine (single-chip or a
-    tp/ep-sharded mesh slice). `start()` launches the drive task;
-    `submit()` returns a live TokenStream; `close()` drains and stops.
+class FrontendBase:
+    """The client side of an online serve loop, the same over one engine
+    or many: the stream table, arrivals and cancels, the shedding rule,
+    back-pressure, token delivery, close, rolling restart and the /metrics
+    endpoint. It walks whatever schedulers its subclass names and never an
+    engine.
 
-    `plan_broadcast` (serving/plan_wire.py transport, lead side) turns
-    this into the lead process of a multi-host replica: every plan is
-    broadcast before it runs, and the stop frame is sent on close."""
+    A subclass names its schedulers to the constructor:
+
+    - `scheds`: every scheduler a turn walks;
+    - `arrival_scheds`: those whose waiting queues hold fresh arrivals
+      (early expiry walks them);
+    - `sink`: the scheduler whose `finished` list files a request that no
+      scheduler holds (shed at the door, cancelled or expired in between);
+
+    and defines `_place` (where an arrival lands), `_cancel_now`,
+    `_has_work`, `_drain_recovered` / `_recovery_backlog` (how work
+    evacuated off a dead replica comes back), `_drive`, and what it adds
+    to `_pending_deadlines`, `_after_close` and `stats`."""
 
     #: idle close-drain turns tolerated before stalled work is cancelled
     CLOSE_STALL_TURNS = 200
 
-    def __init__(
-        self,
-        engine,
-        cfg: FrontendConfig = FrontendConfig(),
-        *,
-        plan_broadcast=None,
-        name: str = "frontend",
-    ):
-        self.engine = engine
+    def __init__(self, cfg: FrontendConfig, *, scheds: list,
+                 arrival_scheds: list, sink: Scheduler, obs,
+                 draft_len: int, name: str):
         self.cfg = cfg
         self.name = name
-        self.sched: Scheduler = engine.make_scheduler(arrival_gating=False)
-        self.plan_broadcast = plan_broadcast
+        self.obs = obs
+        self._scheds = scheds
+        self._arrival_scheds = arrival_scheds
+        self._sink = sink
         self.step_idx = 0
         self.steps_run = 0
-        self._draft_len = (
-            engine._spec.draft_len if engine._spec is not None else 0
-        )
-        if cfg.stream_buffer <= self._draft_len:
+        self._draft_len = draft_len
+        if cfg.stream_buffer <= draft_len:
             raise ValueError(
                 f"stream_buffer={cfg.stream_buffer} must exceed the "
-                f"speculative draft_len={self._draft_len} — a verify block "
+                f"speculative draft_len={draft_len} — a verify block "
                 "commits up to draft_len+1 tokens at once"
             )
         #: rid → (Request, TokenStream) for every live (unfinished) request
@@ -267,33 +276,22 @@ class OnlineFrontend:
         self._emitted: dict[int, int] = {}       # rid → tokens pushed
         self._arrivals: asyncio.Queue = asyncio.Queue()
         self._cancels: list[int] = []
-        #: (req, stream, emitted) evacuated off a DEAD replica, buffered by
-        #: `adopt()` until the top of the next turn (drained before fresh
-        #: arrivals, in adoption order — deterministic requeue)
-        self._adopted: list = []
-        #: router-installed replica-death handler (serving/resilience.py):
-        #: called with (self, exc) when the jitted step raises; None →
-        #: the error propagates out of the drive task unchanged
-        self.on_failure = None
         self._next_rid = 0
         self._closed = False
         self._draining = False                   # rolling-restart admission stop
         self._task: asyncio.Task | None = None
         self._step_waiter: asyncio.Event = asyncio.Event()
         self._idle_close = 0
-        # counters / reporting
-        self.n_submitted = 0
-        self.n_shed = 0
-        self.n_rejected = 0
-        self.n_recovered = 0                     # adopted-and-requeued here
-        self.itl_ewma_s: float | None = None   # wall ITL (reporting only)
-        self._sha = hashlib.sha1()             # lockstep digest (broadcast)
-        # observability: share the engine's bundle (same registry/tracer)
-        self.obs = getattr(engine, "obs", None) or NULL_OBSERVABILITY
         self._paused_rids: set = set()         # pause/resume edge detection
         self._http_server = None
         self._http_task: asyncio.Task | None = None
         self.http_port: int | None = None      # bound /metrics port, once up
+        # counters / reporting
+        self.n_submitted = 0
+        self.n_shed = 0
+        self.n_rejected = 0
+        self.n_recovered = 0                     # requeued here after a death
+        self.itl_ewma_s: float | None = None   # wall ITL (reporting only)
 
     # -- client API ---------------------------------------------------------
     def submit(self, req: Request, *, deadline_in: int | None = None
@@ -326,7 +324,7 @@ class OnlineFrontend:
         references its pages), freeing its slot pages the same turn."""
         self._cancels.append(rid)
 
-    def start(self) -> "OnlineFrontend":
+    def start(self):
         if self._task is None:
             self._task = asyncio.ensure_future(self._drive())
             if self.obs.cfg.http_port is not None:
@@ -347,15 +345,13 @@ class OnlineFrontend:
             self._http_server.close()
             await self._http_server.wait_closed()
             self._http_server = None
-        if self.plan_broadcast is not None:
-            sc = self.engine.serve_cfg
-            self.plan_broadcast.send(pack_stop(
-                sc.token_budget, sc.max_slots, sc.pages_per_slot,
-                self._draft_len or None,
-            ))
+        self._after_close()
         return self.stats()
 
-    async def __aenter__(self) -> "OnlineFrontend":
+    def _after_close(self) -> None:
+        """What a subclass sends once its drive task has ended."""
+
+    async def __aenter__(self):
         return self.start()
 
     async def __aexit__(self, *exc) -> None:
@@ -367,11 +363,411 @@ class OnlineFrontend:
         while self.step_idx < n:
             await self._step_waiter.wait()
 
+    # -- rolling restart -----------------------------------------------------
+    def drain(self) -> None:
+        """Stop ADMITTING (new arrivals shed as "draining") while the
+        loop keeps running and resident requests (and in-flight handoffs)
+        finish and flush their streams — the first half of a rolling
+        restart. Unlike `close()`, the frontend stays alive;
+        `resume_admission()` reopens it."""
+        self._draining = True
+
+    def resume_admission(self) -> None:
+        self._draining = False
+
+    async def quiesce(self) -> None:
+        """`drain()` and block until nothing is resident (requests
+        finished, handoffs landed, streams flushed, queues empty): the
+        point where the process behind a replica can restart without
+        dropping work."""
+        self.drain()
+        while self._has_work or not self._arrivals.empty():
+            await self.wait_step(self.step_idx + 1)
+
+    # -- the turn's shared halves -------------------------------------------
+    def _intake(self) -> bool:
+        """Top of a turn, once cancels are applied: recovered work, then
+        fresh arrivals, then early expiry of what queued too long. False
+        when the frontend is closed and nothing is left to serve."""
+        self._drain_arrivals()
+        self._shed_waiting()
+        if self._closed:
+            if not self.cfg.drain:
+                self._abort_resident()
+            if not self._has_work:
+                return False
+        return True
+
+    async def _idle_turn(self) -> None:
+        """A turn that planned nothing. Deadline expiry inside schedule()
+        may still have finished work, so streams are served first."""
+        self._emit()
+        self._advance()
+        if self._closed and self._has_work:
+            # close-drain with nothing runnable: consumers that stopped
+            # reading (paused slots) or a pool-blocked queue would hang
+            # the drain forever — give them a grace window of idle turns,
+            # then cancel stragglers (unless a pending deadline will
+            # resolve it first)
+            self._idle_close += 1
+            if (
+                self._idle_close > self.CLOSE_STALL_TURNS
+                and not any(d is not None for d in self._pending_deadlines())
+            ):
+                self._abort_resident()
+        await asyncio.sleep(self.cfg.idle_sleep_s)
+
+    def _pending_deadlines(self) -> list:
+        return [s.next_deadline for s in self._scheds]
+
+    def _note_itl(self, dt: float, n_new: int) -> None:
+        """One step's wall seconds over the tokens it committed: the ITL
+        histogram and the EWMA beside it (reporting only)."""
+        if not n_new:
+            return
+        itl = dt / n_new
+        self.obs.registry.histogram(
+            "request_itl_ms", "inter-token latency (ms)"
+        ).observe(itl * 1e3)
+        self.itl_ewma_s = (
+            itl if self.itl_ewma_s is None
+            else ITL_DECAY * self.itl_ewma_s + (1 - ITL_DECAY) * itl
+        )
+
+    def _advance(self) -> None:
+        self.step_idx += 1
+        waiter, self._step_waiter = self._step_waiter, asyncio.Event()
+        waiter.set()
+
+    # -- cancellation --------------------------------------------------------
+    def _apply_cancels(self) -> None:
+        cancels, self._cancels = self._cancels, []
+        for rid in cancels:
+            self._cancel_now(rid)
+
+    def _abort_resident(self) -> None:
+        for rid in list(self._active):
+            self._cancel_now(rid)
+
+    def _count_cancel(self) -> None:
+        self.obs.registry.counter(
+            "frontend_cancelled_total", "streams cancelled by the caller"
+        ).inc()
+
+    def _retire(self, req: Request, reason: str) -> None:
+        """File a request that no scheduler holds under the sink's
+        `finished` (with its counter, for the two reasons a scheduler
+        counts) and end its stream."""
+        req.finish_reason = reason
+        req.finished_at = self.step_idx
+        self._sink.finished.append(req)
+        if reason == "cancelled":
+            self._sink.n_cancelled += 1
+            self._count_cancel()
+        elif reason == "timed_out":
+            self._sink.n_timed_out += 1
+        self._finish_stream(req.rid)
+
+    # -- admission / shedding ------------------------------------------------
+    def _drain_arrivals(self) -> None:
+        self._drain_recovered()
+        while not self._arrivals.empty():
+            req, stream, deadline_in = self._arrivals.get_nowait()
+            self._active[req.rid] = (req, stream)
+            self._emitted[req.rid] = 0
+            req.arrived_t = time.perf_counter()
+            if deadline_in is not None:
+                req.deadline = self.step_idx + deadline_in
+            if self._closed or self._draining:
+                self._shed_one(
+                    req, "shed",
+                    why="closed" if self._closed else "draining",
+                )
+                continue
+            self._place(req, fresh=True)
+
+    def _admit(self, req: Request, sched: Scheduler, *, fresh: bool) -> bool:
+        """Judge `req` against the scheduler it would land in and submit
+        it there, or shed it at the door. A recovered request (`fresh`
+        false) skips the queue cap — it was admitted once already — and
+        is held to its deadline against the survivor's queues PLUS the
+        recovery backlog still buffered behind it: it re-prefills its
+        whole `known`."""
+        if (
+            fresh and self.cfg.max_waiting is not None
+            and len(sched.waiting) >= self.cfg.max_waiting
+        ):
+            self._shed_one(req, "shed", why="queue_full")
+            return False
+        if self.cfg.shed_deadlines and not self._reachable(
+            req,
+            self._backlog(sched) + self._waiting_backlog(sched)
+            + self._recovery_backlog(),
+            sched,
+        ):
+            self._shed_one(req, "shed", why="deadline")
+            return False
+        try:
+            sched.submit(req)
+        except ValueError:
+            # oversized/invalid request: surface as a rejected stream
+            # instead of crashing the loop every other client shares
+            self._shed_one(req, "rejected")
+            return False
+        return True
+
+    def _note_recovered(self, req: Request, **args) -> None:
+        self.n_recovered += 1
+        self.obs.registry.counter(
+            "serve_requests_recovered_total",
+            "requests requeued onto survivors after a replica death",
+        ).inc()
+        self.obs.registry.counter(
+            "serve_recovery_reprefill_tokens_total",
+            "known tokens requeued for re-prefill by failure recovery",
+        ).inc(len(req.known))
+        self.obs.tracer.instant(
+            "request.adopt", track=self.name, step=self.step_idx,
+            rid=req.rid, known=len(req.known), **args,
+        )
+
+    def _shed_one(self, req: Request, reason: str,
+                  why: str | None = None) -> None:
+        if reason == "rejected":
+            self.n_rejected += 1
+            self.obs.registry.counter(
+                "frontend_rejected_total", "submissions rejected at admission"
+            ).inc()
+        else:
+            self.n_shed += 1
+            self.obs.registry.counter(
+                "frontend_shed_total", "requests shed (labeled by reason)",
+                reason=why or reason,
+            ).inc()
+        self.obs.tracer.instant(
+            "request.shed", track=self.name, step=self.step_idx,
+            rid=req.rid, reason=why or reason,
+        )
+        self._retire(req, reason)
+
+    @staticmethod
+    def _backlog(sched: Scheduler) -> int:
+        """Unfed tokens resident on device (running prefill remainder)."""
+        return sum(
+            max(len(r.known) - r.fed, 0) for r in sched.running.values()
+        )
+
+    @staticmethod
+    def _waiting_backlog(sched: Scheduler) -> int:
+        return sum(len(r.known) - r.fed for r in sched.waiting)
+
+    def _reachable(self, req: Request, backlog: int,
+                   sched: Scheduler) -> bool:
+        """Can `req` plausibly commit even ONE token before its deadline?
+        The queued prefill backlog plus its own prompt must flow through
+        `sched`'s token budget first; a request that cannot clear that
+        by its deadline would only occupy pool pages and die, so it sheds
+        at the door. Pure step arithmetic — identical traces shed
+        identical sets (the wall-clock ITL EWMA is reported next to it
+        but never consulted)."""
+        if req.deadline is None:
+            return True
+        pending = len(req.known) - req.fed
+        est = -(-(SHED_SAFETY * (backlog + pending)) // sched.token_budget)
+        return self.step_idx + int(est) < req.deadline
+
+    def _shed_waiting(self) -> None:
+        """Early-expire waiting requests whose deadline became unreachable
+        while they queued (load grew ahead of them) — the 'early-expire'
+        half of shedding: they exit NOW as shed instead of burning pool
+        time later as timed_out."""
+        if not self.cfg.shed_deadlines:
+            return
+        for sched in self._arrival_scheds:
+            backlog = self._backlog(sched) + self._recovery_backlog()
+            for req in list(sched.waiting):
+                if not self._reachable(req, backlog, sched):
+                    sched.waiting.remove(req)
+                    self._shed_one(req, "shed", why="deadline")
+                else:
+                    backlog += len(req.known) - req.fed
+
+    # -- streaming ----------------------------------------------------------
+    def _apply_backpressure(self) -> None:
+        """Withhold any slot whose consumer lacks room for this step's
+        worst-case commit (1 token, +draft_len speculative): its stream
+        queue never exceeds stream_buffer + one verify block, and the
+        step loop never blocks on a slow reader."""
+        room_needed = 1 + self._draft_len
+        now_paused = set()
+        for sched in self._scheds:
+            sched.paused.clear()
+            for slot, req in sched.running.items():
+                entry = self._active.get(req.rid)
+                if entry is None:
+                    continue
+                if entry[1]._lag() + room_needed > self.cfg.stream_buffer:
+                    sched.paused.add(slot)
+                    now_paused.add(req.rid)
+        _trace_pause_edges(
+            self.obs.tracer, self.name, self.step_idx,
+            self._paused_rids, now_paused,
+        )
+        self._paused_rids = now_paused
+
+    def _emit(self) -> None:
+        """Push newly committed tokens to their streams, in commit order;
+        end the stream of everything that finished this turn (a request
+        mid-migration is neither running nor done: its stream ends only
+        once a terminal finish_reason lands)."""
+        for rid, (req, stream) in list(self._active.items()):
+            sent = self._emitted[rid]
+            new = req.generated[sent:]
+            if new:
+                if req.ttft_s < 0 and req.arrived_t >= 0:
+                    req.ttft_s = time.perf_counter() - req.arrived_t
+                    self.obs.registry.histogram(
+                        "request_ttft_ms", "time to first token (ms)"
+                    ).observe(req.ttft_s * 1e3)
+                for tok in new:
+                    stream._push(tok)
+                self._emitted[rid] = sent + len(new)
+            if req.done:
+                self._finish_stream(rid)
+
+    def _finish_stream(self, rid: int) -> None:
+        entry = self._active.pop(rid, None)
+        self._emitted.pop(rid, None)
+        if entry is not None:
+            entry[1]._end()
+            self.obs.registry.counter(
+                "frontend_finished_total", "streams finished (any reason)"
+            ).inc()
+            if rid in self._paused_rids:
+                # close the open pause so the timeline's pause intervals pair
+                self._paused_rids.discard(rid)
+                self.obs.tracer.instant(
+                    "stream.resume", track=self.name,
+                    step=self.step_idx, rid=rid,
+                )
+
+    # -- metrics endpoint ----------------------------------------------------
+    async def _serve_http(self) -> None:
+        self._http_server = await asyncio.start_server(
+            functools.partial(_handle_metrics_http, self), "127.0.0.1",
+            self.obs.cfg.http_port,
+        )
+        self.http_port = self._http_server.sockets[0].getsockname()[1]
+
+    async def http_address(self) -> tuple:
+        """(host, port) of the /metrics endpoint, once it is listening."""
+        if self._http_task is not None:
+            await self._http_task
+        if self.http_port is None:
+            raise RuntimeError("observability.http_port is not configured")
+        return ("127.0.0.1", self.http_port)
+
+    # -- reporting ----------------------------------------------------------
+    def stats(self) -> dict:
+        """The keys both frontends report, summed over `_scheds`; also
+        refreshes the frontend's gauges on /metrics."""
+        scheds = self._scheds
+        running = sum(len(s.running) for s in scheds)
+        waiting = sum(len(s.waiting) for s in scheds)
+        reg = self.obs.registry
+        reg.gauge("frontend_running", "requests resident in slots"
+                  ).set(running)
+        reg.gauge("frontend_waiting", "requests queued for admission"
+                  ).set(waiting)
+        reg.gauge("frontend_paused", "slots paused for stream backpressure"
+                  ).set(sum(len(s.paused) for s in scheds))
+        if self.itl_ewma_s is not None:
+            reg.gauge(
+                "frontend_itl_ewma_ms",
+                "decayed inter-token latency estimate (ms)",
+            ).set(self.itl_ewma_s * 1e3)
+        reasons: dict = {}
+        for s in scheds:
+            for r in s.finished:
+                reasons[r.finish_reason] = reasons.get(r.finish_reason, 0) + 1
+        return {
+            "steps": self.steps_run,
+            "submitted": self.n_submitted,
+            "finished": sum(len(s.finished) for s in scheds),
+            "finish_reasons": reasons,
+            "shed": self.n_shed,
+            "rejected": self.n_rejected,
+            "recovered": self.n_recovered,
+            "draining": self._draining,
+            "cancelled": sum(s.n_cancelled for s in scheds),
+            "timed_out": sum(s.n_timed_out for s in scheds),
+            "running": running,
+            "waiting": waiting,
+            "itl_ewma_ms": (
+                round(self.itl_ewma_s * 1e3, 4)
+                if self.itl_ewma_s is not None else None
+            ),
+        }
+
+
+class OnlineFrontend(FrontendBase):
+    """Async streaming serve loop over ONE engine (single-chip or a
+    tp/ep-sharded mesh slice). `start()` launches the drive task;
+    `submit()` returns a live TokenStream; `close()` drains and stops.
+
+    `plan_broadcast` (serving/plan_wire.py transport, lead side) turns
+    this into the lead process of a multi-host replica: every plan is
+    broadcast before it runs, and the stop frame is sent on close."""
+
+    def __init__(
+        self,
+        engine,
+        cfg: FrontendConfig = FrontendConfig(),
+        *,
+        plan_broadcast=None,
+        name: str = "frontend",
+    ):
+        self.engine = engine
+        self.sched: Scheduler = engine.make_scheduler(arrival_gating=False)
+        super().__init__(
+            cfg,
+            scheds=[self.sched], arrival_scheds=[self.sched],
+            sink=self.sched,
+            # share the engine's bundle (same registry/tracer)
+            obs=getattr(engine, "obs", None) or NULL_OBSERVABILITY,
+            draft_len=(
+                engine._spec.draft_len if engine._spec is not None else 0
+            ),
+            name=name,
+        )
+        self.plan_broadcast = plan_broadcast
+        #: (req, stream, emitted) evacuated off a DEAD replica, buffered by
+        #: `adopt()` until the top of the next turn (drained before fresh
+        #: arrivals, in adoption order — deterministic requeue)
+        self._adopted: list = []
+        #: router-installed replica-death handler (serving/resilience.py):
+        #: called with (self, exc) when the jitted step raises; None →
+        #: the error propagates out of the drive task unchanged
+        self.on_failure = None
+        self._sha = hashlib.sha1()             # lockstep digest (broadcast)
+
+    def _after_close(self) -> None:
+        if self.plan_broadcast is not None:
+            sc = self.engine.serve_cfg
+            self.plan_broadcast.send(pack_stop(
+                sc.token_budget, sc.max_slots, sc.pages_per_slot,
+                self._draft_len or None,
+            ))
+
     @property
     def digest(self) -> str:
         """sha1 over every step's sampled-token output — matches the
         followers' PlanFollower digest when the broadcast is lockstep."""
         return self._sha.hexdigest()
+
+    @property
+    def _has_work(self) -> bool:
+        return self.sched.has_work or bool(self._adopted)
 
     # -- drive loop ---------------------------------------------------------
     async def _drive(self) -> None:
@@ -387,41 +783,12 @@ class OnlineFrontend:
             )
             with span("frontend.intake"):
                 self._apply_cancels()
-                self._drain_arrivals()
-                self._shed_waiting()
-                if self._closed:
-                    if not self.cfg.drain:
-                        self._abort_resident()
-                    if not self.sched.has_work:
-                        break
+                if not self._intake():
+                    break
                 self._apply_backpressure()
-            with span("step.plan") as plan_span:
-                preempted = self.sched.n_preemptions
-                plan = self.sched.schedule(self.step_idx)
-                self.engine.note_turn(
-                    plan_span, self.sched.turn_stats(preempted, plan)
-                )
-                if plan is not None:
-                    plan_span.set_metadata(
-                        rows=plan.n_tokens, samples=plan.n_samples
-                    )
+            plan = self.engine.plan_turn(self.sched, self.step_idx)
             if plan is None:
-                # deadline expiry inside schedule() may have evicted work
-                self._emit()
-                self._advance()
-                if self._closed and self.sched.has_work:
-                    # close-drain with nothing runnable: consumers that
-                    # stopped reading (paused slots) or a pool-blocked
-                    # queue would hang the drain forever — give them a
-                    # grace window of idle turns, then cancel stragglers
-                    # (unless a pending deadline will resolve it first)
-                    self._idle_close += 1
-                    if (
-                        self._idle_close > self.CLOSE_STALL_TURNS
-                        and self.sched.next_deadline is None
-                    ):
-                        self._abort_resident()
-                await asyncio.sleep(self.cfg.idle_sleep_s)
+                await self._idle_turn()
                 continue
             self._idle_close = 0
             if self.plan_broadcast is not None:
@@ -460,87 +827,26 @@ class OnlineFrontend:
                     self.sched, plan, out, self.step_idx
                 )
             self.steps_run += 1
-            if n_new:
-                itl = dt / n_new
-                self.obs.registry.histogram(
-                    "request_itl_ms", "inter-token latency (ms)"
-                ).observe(itl * 1e3)
-                d = self.cfg.itl_decay
-                self.itl_ewma_s = (
-                    itl if self.itl_ewma_s is None
-                    else d * self.itl_ewma_s + (1 - d) * itl
-                )
+            self._note_itl(dt, n_new)
             with span("frontend.emit"):
                 self._emit()
                 self._advance()
 
-    def _advance(self) -> None:
-        self.step_idx += 1
-        waiter, self._step_waiter = self._step_waiter, asyncio.Event()
-        waiter.set()
-
-    def _apply_cancels(self) -> None:
-        cancels, self._cancels = self._cancels, []
-        for rid in cancels:
-            self._cancel_now(rid)
+    def _place(self, req: Request, *, fresh: bool) -> bool:
+        return self._admit(req, self.sched, fresh=fresh)
 
     def _cancel_now(self, rid: int) -> None:
         # adopted-but-not-yet-requeued (mid-recovery) cancels land here
         for entry in list(self._adopted):
             if entry[0].rid == rid:
                 self._adopted.remove(entry)
-                req = entry[0]
-                req.finish_reason = "cancelled"
-                req.finished_at = self.step_idx
-                self.sched.finished.append(req)
-                self.sched.n_cancelled += 1
-                self.obs.registry.counter(
-                    "frontend_cancelled_total",
-                    "streams cancelled by the caller",
-                ).inc()
-                self._active.setdefault(rid, (req, entry[1]))
+                self._active.setdefault(rid, (entry[0], entry[1]))
                 self._emitted.setdefault(rid, entry[2])
-                self._finish_stream(rid)
+                self._retire(entry[0], "cancelled")
                 return
         if self.sched.cancel(rid, self.step_idx):
-            self.obs.registry.counter(
-                "frontend_cancelled_total", "streams cancelled by the caller"
-            ).inc()
+            self._count_cancel()
             self._finish_stream(rid)
-
-    def _drain_arrivals(self) -> None:
-        self._drain_adopted()
-        while not self._arrivals.empty():
-            req, stream, deadline_in = self._arrivals.get_nowait()
-            self._active[req.rid] = (req, stream)
-            self._emitted[req.rid] = 0
-            req.arrived_t = time.perf_counter()
-            if deadline_in is not None:
-                req.deadline = self.step_idx + deadline_in
-            if self._closed or self._draining:
-                self._shed_one(
-                    req, "shed",
-                    why="closed" if self._closed else "draining",
-                )
-                continue
-            if (
-                self.cfg.max_waiting is not None
-                and len(self.sched.waiting) >= self.cfg.max_waiting
-            ):
-                self._shed_one(req, "shed", why="queue_full")
-                continue
-            if self.cfg.shed_deadlines and not self._reachable(
-                req, self._backlog() + self._waiting_backlog()
-                + self._recovery_backlog()
-            ):
-                self._shed_one(req, "shed", why="deadline")
-                continue
-            try:
-                self.sched.submit(req)
-            except ValueError:
-                # oversized/invalid request: surface as a rejected stream
-                # instead of crashing the loop every other client shares
-                self._shed_one(req, "rejected")
 
     # -- failure recovery ----------------------------------------------------
     def adopt(self, req: Request, stream: TokenStream, emitted: int) -> None:
@@ -553,389 +859,105 @@ class OnlineFrontend:
         sees the continuation."""
         self._adopted.append((req, stream, emitted))
 
-    def _drain_adopted(self) -> None:
+    def _drain_recovered(self) -> None:
         while self._adopted:
             req, stream, emitted = self._adopted.pop(0)
             self._active[req.rid] = (req, stream)
             self._emitted[req.rid] = emitted
             self._next_rid = max(self._next_rid, req.rid + 1)
-            # deadline re-check against the SURVIVOR's queues PLUS the
-            # adopted-but-not-yet-queued recovery backlog: a recovered
-            # request re-prefills its whole `known`, and the old formula
-            # (device + waiting backlog only) under-counted exactly that,
-            # admitting mid-recovery work that could no longer make its
-            # deadline. Shed stays a pure function of queue state, so the
-            # shed set is pinned across identical chaos traces.
-            if self.cfg.shed_deadlines and not self._reachable(
-                req, self._backlog() + self._waiting_backlog()
-                + self._recovery_backlog()
-            ):
-                self._shed_one(req, "shed", why="deadline")
-                continue
-            try:
-                self.sched.submit(req)
-            except ValueError:
-                self._shed_one(req, "rejected")
-                continue
-            self.n_recovered += 1
-            self.obs.registry.counter(
-                "serve_requests_recovered_total",
-                "requests requeued onto survivors after a replica death",
-            ).inc()
-            self.obs.registry.counter(
-                "serve_recovery_reprefill_tokens_total",
-                "known tokens requeued for re-prefill by failure recovery",
-            ).inc(len(req.known))
-            self.obs.tracer.instant(
-                "request.adopt", track=self.name, step=self.step_idx,
-                rid=req.rid, known=len(req.known), emitted=emitted,
-            )
+            if self._place(req, fresh=False):
+                self._note_recovered(req, emitted=emitted)
 
     def _recovery_backlog(self) -> int:
         """Re-prefill tokens adopted but not yet queued anywhere — the
         term mid-recovery shed arithmetic must price in."""
         return sum(len(r.known) - r.fed for r, _s, _e in self._adopted)
 
-    # -- rolling restart -----------------------------------------------------
-    def drain(self) -> None:
-        """Stop ADMITTING (new arrivals shed as "draining") while the
-        loop keeps running and resident requests finish and flush their
-        streams — the first half of a rolling restart. Unlike `close()`,
-        the frontend stays alive; `resume_admission()` reopens it."""
-        self._draining = True
-
-    def resume_admission(self) -> None:
-        self._draining = False
-
-    async def quiesce(self) -> None:
-        """`drain()` and block until nothing is resident (requests
-        finished, streams flushed, queues empty): the point where the
-        process behind this replica can restart without dropping work."""
-        self.drain()
-        while (
-            self.sched.has_work or not self._arrivals.empty()
-            or self._adopted
-        ):
-            await self.wait_step(self.step_idx + 1)
-
-    def _shed_one(self, req: Request, reason: str,
-                  why: str | None = None) -> None:
-        req.finish_reason = reason
-        req.finished_at = self.step_idx
-        self.sched.finished.append(req)
-        if reason == "rejected":
-            self.n_rejected += 1
-            self.obs.registry.counter(
-                "frontend_rejected_total", "submissions rejected at admission"
-            ).inc()
-        else:
-            self.n_shed += 1
-            self.obs.registry.counter(
-                "frontend_shed_total", "requests shed (labeled by reason)",
-                reason=why or reason,
-            ).inc()
-        self.obs.tracer.instant(
-            "request.shed", track=self.name, step=self.step_idx,
-            rid=req.rid, reason=why or reason,
-        )
-        self._finish_stream(req.rid)
-
-    # -- load shedding -------------------------------------------------------
-    def _backlog(self) -> int:
-        """Unfed tokens resident on device (running prefill remainder)."""
-        return sum(
-            max(len(r.known) - r.fed, 0)
-            for r in self.sched.running.values()
-        )
-
-    def _waiting_backlog(self) -> int:
-        return sum(len(r.known) - r.fed for r in self.sched.waiting)
-
-    def _reachable(self, req: Request, backlog: int) -> bool:
-        """Can `req` plausibly commit even ONE token before its deadline?
-        The queued prefill backlog plus its own prompt must flow through
-        the step's token budget first; a request that cannot clear that
-        by its deadline would only occupy pool pages and die, so it sheds
-        at the door. Pure step arithmetic — identical traces shed
-        identical sets (the wall-clock ITL EWMA is reported next to it
-        but never consulted)."""
-        if req.deadline is None:
-            return True
-        pending = len(req.known) - req.fed
-        budget = self.sched.token_budget
-        est = -(-(self.cfg.shed_safety * (backlog + pending)) // budget)
-        return self.step_idx + int(est) < req.deadline
-
-    def _shed_waiting(self) -> None:
-        """Early-expire waiting requests whose deadline became unreachable
-        while they queued (load grew ahead of them) — the 'early-expire'
-        half of shedding: they exit NOW as shed instead of burning pool
-        time later as timed_out."""
-        if not self.cfg.shed_deadlines:
-            return
-        backlog = self._backlog() + self._recovery_backlog()
-        for req in list(self.sched.waiting):
-            if not self._reachable(req, backlog):
-                self.sched.waiting.remove(req)
-                self._shed_one(req, "shed", why="deadline")
-            else:
-                backlog += len(req.known) - req.fed
-
-    # -- streaming ----------------------------------------------------------
-    def _apply_backpressure(self) -> None:
-        """Withhold any slot whose consumer lacks room for this step's
-        worst-case commit (1 token, +draft_len speculative): its stream
-        queue never exceeds stream_buffer + one verify block, and the
-        step loop never blocks on a slow reader."""
-        self.sched.paused.clear()
-        room_needed = 1 + self._draft_len
-        now_paused = set()
-        for slot, req in self.sched.running.items():
-            entry = self._active.get(req.rid)
-            if entry is None:
-                continue
-            if entry[1]._lag() + room_needed > self.cfg.stream_buffer:
-                self.sched.paused.add(slot)
-                now_paused.add(req.rid)
-        _trace_pause_edges(
-            self.obs.tracer, self.name, self.step_idx,
-            self._paused_rids, now_paused,
-        )
-        self._paused_rids = now_paused
-
-    def _emit(self) -> None:
-        """Push newly committed tokens to their streams, in commit order;
-        end the stream of everything that finished this turn."""
-        for rid, (req, stream) in list(self._active.items()):
-            sent = self._emitted[rid]
-            new = req.generated[sent:]
-            if new:
-                if req.ttft_s < 0 and req.arrived_t >= 0:
-                    req.ttft_s = time.perf_counter() - req.arrived_t
-                    self.obs.registry.histogram(
-                        "request_ttft_ms", "time to first token (ms)"
-                    ).observe(req.ttft_s * 1e3)
-                for tok in new:
-                    stream._push(tok)
-                self._emitted[rid] = sent + len(new)
-            if req.done:
-                self._finish_stream(rid)
-
-    def _finish_stream(self, rid: int) -> None:
-        entry = self._active.pop(rid, None)
-        self._emitted.pop(rid, None)
-        if entry is not None:
-            entry[1]._end()
-            self.obs.registry.counter(
-                "frontend_finished_total", "streams finished (any reason)"
-            ).inc()
-            if rid in self._paused_rids:
-                # close the open pause so the timeline's pause intervals pair
-                self._paused_rids.discard(rid)
-                self.obs.tracer.instant(
-                    "stream.resume", track=self.name,
-                    step=self.step_idx, rid=rid,
-                )
-
-    def _abort_resident(self) -> None:
-        for rid in list(self._active):
-            self._cancel_now(rid)
-
-    # -- metrics endpoint ----------------------------------------------------
-    async def _serve_http(self) -> None:
-        self._http_server = await asyncio.start_server(
-            self._handle_http, "127.0.0.1", self.obs.cfg.http_port
-        )
-        self.http_port = self._http_server.sockets[0].getsockname()[1]
-
-    async def http_address(self) -> tuple:
-        """(host, port) of the /metrics endpoint, once it is listening."""
-        if self._http_task is not None:
-            await self._http_task
-        if self.http_port is None:
-            raise RuntimeError("observability.http_port is not configured")
-        return ("127.0.0.1", self.http_port)
-
-    async def _handle_http(self, reader, writer) -> None:
-        await _handle_metrics_http(self, reader, writer)
-
     # -- reporting ----------------------------------------------------------
     def stats(self) -> dict:
         s = self.sched
-        reg = self.obs.registry
-        reg.gauge("frontend_running", "requests resident in slots"
-                  ).set(len(s.running))
-        reg.gauge("frontend_waiting", "requests queued for admission"
-                  ).set(len(s.waiting))
-        reg.gauge("frontend_paused", "slots paused for stream backpressure"
-                  ).set(len(s.paused))
-        if self.itl_ewma_s is not None:
-            reg.gauge(
-                "frontend_itl_ewma_ms",
-                "decayed inter-token latency estimate (ms)",
-            ).set(self.itl_ewma_s * 1e3)
-        reasons: dict = {}
-        for r in s.finished:
-            reasons[r.finish_reason] = reasons.get(r.finish_reason, 0) + 1
         return {
-            "steps": self.steps_run,
-            "submitted": self.n_submitted,
-            "finished": len(s.finished),
-            "finish_reasons": reasons,
-            "shed": self.n_shed,
-            "rejected": self.n_rejected,
-            "recovered": self.n_recovered,
-            "draining": self._draining,
-            "cancelled": s.n_cancelled,
-            "timed_out": s.n_timed_out,
+            **super().stats(),
             "preemptions": s.n_preemptions,
-            "running": len(s.running),
-            "waiting": len(s.waiting),
             "paused": len(s.paused),
             "free_pages": s.alloc.num_free,
-            "itl_ewma_ms": (
-                round(self.itl_ewma_s * 1e3, 4)
-                if self.itl_ewma_s is not None else None
-            ),
             "compiled_signatures": self.engine.step_cache_size(),
         }
 
 
-class DisaggOnlineFrontend:
-    """The same live loop over a `DisaggRouter`'s replica classes:
-    arrivals route to a prefill replica, finished prefills migrate to a
-    decode replica as page-granular KV handoffs, decode replicas stream.
+class DisaggOnlineFrontend(FrontendBase):
+    """The live loop over a `DisaggRouter`'s replica classes: arrivals
+    route to a prefill replica, finished prefills migrate to a decode
+    replica as page-granular KV handoffs, decode replicas stream.
 
     One drive task owns every scheduler (the handoff dance needs a
     consistent view of both classes each turn); engine steps for all
-    replicas of a turn run back-to-back in the worker thread. Shedding
-    uses the LEAST-LOADED prefill replica's backlog (that is where the
-    request would land); cancellation additionally releases in-flight
-    handoff pins — the one eviction path the offline loop only had for
-    deadline expiry."""
+    replicas of a turn run back-to-back in the worker thread. An arrival
+    is judged against the LEAST-LOADED prefill replica's backlog (that is
+    where it would land); cancellation and deadline expiry also release
+    the page pins of a handoff in flight between the classes."""
 
     def __init__(self, router, cfg: FrontendConfig = FrontendConfig()):
         self.router = router
-        self.cfg = cfg
         self.p_scheds = [
             eng.make_scheduler(arrival_gating=False) for eng in router.prefill
         ]
         self.d_scheds = [
             eng.make_scheduler(arrival_gating=False) for eng in router.decode
         ]
+        super().__init__(
+            cfg,
+            scheds=self.p_scheds + self.d_scheds,
+            arrival_scheds=self.p_scheds, sink=self.d_scheds[0],
+            # router-shared bundle when the router built one; else borrow
+            # the first prefill engine's (every engine owns a null one)
+            obs=(
+                getattr(router, "obs", None)
+                or getattr(router.prefill[0], "obs", None)
+                or NULL_OBSERVABILITY
+            ),
+            draft_len=max(
+                (e._spec.draft_len for e in router.decode
+                 if e._spec is not None),
+                default=0,
+            ),
+            name="frontend",
+        )
         #: rids prefill-ROUTED to each borrowed decode replica (autoscale):
         #: the extract_handoffs(rids=...) guard — only these migrate out,
         #: the replica's resident decode work is never evacuated
         self._borrow_rids: dict[int, set] = {}
         self.inflight: list = []
-        self.step_idx = 0
-        self.steps_run = 0
-        self._draft_len = max(
-            (e._spec.draft_len for e in router.decode if e._spec is not None),
-            default=0,
-        )
-        if cfg.stream_buffer <= self._draft_len:
-            raise ValueError("stream_buffer must exceed draft_len")
-        self._active: dict[int, tuple[Request, TokenStream]] = {}
-        self._emitted: dict[int, int] = {}
-        self._arrivals: asyncio.Queue = asyncio.Queue()
-        self._cancels: list[int] = []
         #: requests evacuated off a dead replica (or rolled back from an
         #: exhausted transfer), requeued at the top of the next turn —
         #: before fresh arrivals, in evacuation order (deterministic)
         self._requeued: list = []
-        self._next_rid = 0
-        self._closed = False
-        self._draining = False
-        self._task: asyncio.Task | None = None
-        self._step_waiter: asyncio.Event = asyncio.Event()
-        self._idle_close = 0
-        self.n_submitted = 0
-        self.n_shed = 0
-        self.n_rejected = 0
-        self.n_recovered = 0
         self.n_cancelled_inflight = 0
-        self.itl_ewma_s: float | None = None
-        self.name = "frontend"
-        # router-shared bundle when the router built one; else borrow the
-        # first prefill engine's (every engine owns at least a null bundle)
-        self.obs = (
-            getattr(router, "obs", None)
-            or getattr(router.prefill[0], "obs", None)
-            or NULL_OBSERVABILITY
-        )
-        self._paused_rids: set = set()
-
-    # -- client API ---------------------------------------------------------
-    def submit(self, req: Request, *, deadline_in: int | None = None
-               ) -> TokenStream:
-        if self._closed:
-            raise RuntimeError("frontend is closed")
-        if req.rid < 0:
-            req.rid = self._next_rid
-        self._next_rid = max(self._next_rid, req.rid + 1)
-        stream = TokenStream(req)
-        self.n_submitted += 1
-        self.obs.registry.counter(
-            "frontend_submitted_total", "requests submitted to the frontend"
-        ).inc()
-        self.obs.tracer.instant(
-            "frontend.submit", track=self.name, step=self.step_idx,
-            rid=req.rid, prompt_len=len(req.prompt),
-            max_new=req.max_new_tokens,
-        )
-        self._arrivals.put_nowait((req, stream, deadline_in))
-        return stream
-
-    def cancel(self, rid: int) -> None:
-        self._cancels.append(rid)
-
-    def start(self) -> "DisaggOnlineFrontend":
-        if self._task is None:
-            self._task = asyncio.ensure_future(self._drive())
-        return self
-
-    async def close(self) -> dict:
-        self._closed = True
-        if self._task is not None:
-            await self._task
-            self._task = None
-        return self.stats()
-
-    async def __aenter__(self) -> "DisaggOnlineFrontend":
-        return self.start()
-
-    async def __aexit__(self, *exc) -> None:
-        await self.close()
-
-    async def wait_step(self, n: int) -> None:
-        while self.step_idx < n:
-            await self._step_waiter.wait()
-
-    # -- drive --------------------------------------------------------------
-    def _all_scheds(self):
-        return self.p_scheds + self.d_scheds
 
     @property
     def _has_work(self) -> bool:
         return bool(self.inflight) or bool(self._requeued) or any(
-            s.has_work for s in self._all_scheds()
+            s.has_work for s in self._scheds
         )
 
+    def _pending_deadlines(self) -> list:
+        return super()._pending_deadlines() + [
+            h.req.deadline for h in self.inflight
+        ]
+
+    # -- drive --------------------------------------------------------------
     async def _drive(self) -> None:
+        # runtime import: router imports this module at its top level
+        from automodel_tpu.serving.router import _Handoff
+
         loop = asyncio.get_running_loop()
         while True:
             self._apply_cancels()
             self.router.autoscale_tick(
                 self.p_scheds, self.d_scheds, self.step_idx
             )
-            self._drain_arrivals()
-            self._shed_waiting()
-            if self._closed:
-                if not self.cfg.drain:
-                    self._abort_resident()
-                if not self._has_work:
-                    break
+            if not self._intake():
+                break
             self._expire_inflight()
             self._admit_inflight()
             self._apply_backpressure()
@@ -948,24 +970,11 @@ class DisaggOnlineFrontend:
                     continue
                 if not sched.has_work:
                     continue
-                plan = sched.schedule(self.step_idx)
+                plan = eng.plan_turn(sched, self.step_idx)
                 if plan is not None:
                     plans.append((eng, sched, plan))
             if not plans:
-                self._emit()
-                self._advance()
-                if self._closed and self._has_work:
-                    # same stalled-drain escape hatch as OnlineFrontend
-                    self._idle_close += 1
-                    deadlines = [
-                        s.next_deadline for s in self._all_scheds()
-                    ] + [h.req.deadline for h in self.inflight]
-                    if (
-                        self._idle_close > OnlineFrontend.CLOSE_STALL_TURNS
-                        and not any(d is not None for d in deadlines)
-                    ):
-                        self._abort_resident()
-                await asyncio.sleep(self.cfg.idle_sleep_s)
+                await self._idle_turn()
                 continue
             self._idle_close = 0
             t0 = time.perf_counter()
@@ -991,9 +1000,6 @@ class DisaggOnlineFrontend:
                     self._recover_replica("p", self.p_scheds.index(sched), exc)
                 else:
                     self._recover_replica("d", self.d_scheds.index(sched), exc)
-            # runtime import: router imports this module at its top level
-            from automodel_tpu.serving.router import _Handoff
-
             for r, sched in enumerate(self.p_scheds):
                 for req, n_tok, src in sched.extract_handoffs():
                     self.inflight.append(_Handoff(req, n_tok, src, r))
@@ -1008,23 +1014,9 @@ class DisaggOnlineFrontend:
                     rids.discard(req.rid)
                     self.inflight.append(_Handoff(req, n_tok, src, ("d", j)))
             self.steps_run += 1
-            if n_new:
-                itl = dt / n_new
-                self.obs.registry.histogram(
-                    "request_itl_ms", "inter-token latency (ms)"
-                ).observe(itl * 1e3)
-                d = self.cfg.itl_decay
-                self.itl_ewma_s = (
-                    itl if self.itl_ewma_s is None
-                    else d * self.itl_ewma_s + (1 - d) * itl
-                )
+            self._note_itl(dt, n_new)
             self._emit()
             self._advance()
-
-    def _advance(self) -> None:
-        self.step_idx += 1
-        waiter, self._step_waiter = self._step_waiter, asyncio.Event()
-        waiter.set()
 
     @staticmethod
     def _run_plans(plans):
@@ -1041,7 +1033,7 @@ class DisaggOnlineFrontend:
                 outs.append((eng, sched, plan, None, e))
         return outs
 
-    # -- admission / shedding ------------------------------------------------
+    # -- admission ------------------------------------------------------------
     def _route_scheds(self):
         """The prefill ROUTING SET, health-aware: admittable prefill
         replicas plus any autoscaler-borrowed decode replicas — or, when
@@ -1073,111 +1065,29 @@ class DisaggOnlineFrontend:
                 tags.append("mono")
         return (scheds, tags) if scheds else None
 
-    def _drain_arrivals(self) -> None:
-        self._drain_requeued()
-        while not self._arrivals.empty():
-            req, stream, deadline_in = self._arrivals.get_nowait()
-            self._active[req.rid] = (req, stream)
-            self._emitted[req.rid] = 0
-            req.arrived_t = time.perf_counter()
-            if deadline_in is not None:
-                req.deadline = self.step_idx + deadline_in
-            if self._closed or self._draining:
-                self._shed_one(
-                    req, "shed",
-                    why="closed" if self._closed else "draining",
-                )
-                continue
-            route = self._route_scheds()
-            if route is None:
-                # nothing can admit and degradation is off/exhausted —
-                # shed loudly-labeled rather than queueing into a wedge
-                self._shed_one(req, "shed", why="no_replica")
-                continue
-            route_scheds, tags = route
-            r = self.router.route_prefill(req, route_scheds)
-            sched = route_scheds[r]
-            if (
-                self.cfg.max_waiting is not None
-                and len(sched.waiting) >= self.cfg.max_waiting
-            ):
-                self._shed_one(req, "shed", why="queue_full")
-                continue
-            if self.cfg.shed_deadlines and not self._reachable(
-                req, sched,
-                self._sched_backlog(sched, waiting=True)
-                + self._recovery_backlog(),
-            ):
-                self._shed_one(req, "shed", why="deadline")
-                continue
-            try:
-                sched.submit(req)
-            except ValueError:
-                self._shed_one(req, "rejected")
-                continue
-            if isinstance(tags[r], int):
-                self._borrow_rids.setdefault(tags[r], set()).add(req.rid)
+    def _place(self, req: Request, *, fresh: bool) -> bool:
+        route = self._route_scheds()
+        if route is None:
+            # nothing can admit and degradation is off/exhausted —
+            # shed loudly-labeled rather than queueing into a wedge
+            self._shed_one(req, "shed", why="no_replica")
+            return False
+        route_scheds, tags = route
+        r = self.router.route_prefill(req, route_scheds)
+        if not self._admit(req, route_scheds[r], fresh=fresh):
+            return False
+        if isinstance(tags[r], int):
+            self._borrow_rids.setdefault(tags[r], set()).add(req.rid)
+        return True
 
-    def _drain_requeued(self) -> None:
-        """Requeue evacuated requests BEFORE fresh arrivals, re-running
-        the deadline check against the survivor's backlog plus the
-        still-buffered recovery backlog (`_recovery_backlog`) — the
-        re-prefill cost the pre-resilience shed formula missed."""
+    def _drain_recovered(self) -> None:
         while self._requeued:
             req = self._requeued.pop(0)
-            route = self._route_scheds()
-            if route is None:
-                self._shed_one(req, "shed", why="no_replica")
-                continue
-            route_scheds, tags = route
-            r = self.router.route_prefill(req, route_scheds)
-            sched = route_scheds[r]
-            if self.cfg.shed_deadlines and not self._reachable(
-                req, sched,
-                self._sched_backlog(sched, waiting=True)
-                + self._recovery_backlog(),
-            ):
-                self._shed_one(req, "shed", why="deadline")
-                continue
-            try:
-                sched.submit(req)
-            except ValueError:
-                self._shed_one(req, "rejected")
-                continue
-            if isinstance(tags[r], int):
-                self._borrow_rids.setdefault(tags[r], set()).add(req.rid)
-            self.n_recovered += 1
-            self.obs.registry.counter(
-                "serve_requests_recovered_total",
-                "requests requeued onto survivors after a replica death",
-            ).inc()
-            self.obs.registry.counter(
-                "serve_recovery_reprefill_tokens_total",
-                "known tokens requeued for re-prefill by failure recovery",
-            ).inc(len(req.known))
-            self.obs.tracer.instant(
-                "request.adopt", track=self.name, step=self.step_idx,
-                rid=req.rid, known=len(req.known),
-            )
+            if self._place(req, fresh=False):
+                self._note_recovered(req)
 
     def _recovery_backlog(self) -> int:
         return sum(len(r.known) - r.fed for r in self._requeued)
-
-    # -- rolling restart -----------------------------------------------------
-    def drain(self) -> None:
-        """Stop ADMITTING (arrivals shed as "draining"); resident work,
-        in-flight handoffs, and streams keep flowing to completion."""
-        self._draining = True
-
-    def resume_admission(self) -> None:
-        self._draining = False
-
-    async def quiesce(self) -> None:
-        """`drain()` and block until nothing is resident across either
-        replica class (handoffs landed, streams flushed)."""
-        self.drain()
-        while self._has_work or not self._arrivals.empty():
-            await self.wait_step(self.step_idx + 1)
 
     # -- failure recovery ----------------------------------------------------
     def _recover_replica(self, klass: str, r: int, exc) -> None:
@@ -1201,8 +1111,7 @@ class DisaggOnlineFrontend:
         src = r if klass == "p" else ("d", r)
         for h in list(self.inflight):
             if h.src == src:
-                self.inflight.remove(h)
-                scheds[r].release_handoff(h.src_pages)
+                self._drop_inflight(h)
                 h.req.fed = 0
                 h.req.donated_pages = 0
                 evac.append(h.req)
@@ -1233,8 +1142,7 @@ class DisaggOnlineFrontend:
             name, self.step_idx, str(exc)
         )
         self.d_scheds[r].evict_for_recovery(h.req.rid)
-        self._src_sched(h).release_handoff(h.src_pages)
-        self.inflight.remove(h)
+        self._drop_inflight(h)
         h.req.recovered += 1
         self._requeued.append(h.req)
         self.obs.tracer.instant(
@@ -1244,109 +1152,30 @@ class DisaggOnlineFrontend:
         if state == "dead":
             self._recover_replica("d", r, exc)
 
-    def _sched_backlog(self, sched, *, waiting: bool) -> int:
-        b = sum(
-            max(len(r.known) - r.fed, 0) for r in sched.running.values()
-        )
-        if waiting:
-            b += sum(len(r.known) - r.fed for r in sched.waiting)
-        return b
-
-    def _reachable(self, req: Request, sched, backlog: int) -> bool:
-        if req.deadline is None:
-            return True
-        pending = len(req.known) - req.fed
-        est = -(-(self.cfg.shed_safety * (backlog + pending))
-                // sched.token_budget)
-        return self.step_idx + int(est) < req.deadline
-
-    def _shed_waiting(self) -> None:
-        if not self.cfg.shed_deadlines:
-            return
-        for sched in self.p_scheds:
-            backlog = (
-                self._sched_backlog(sched, waiting=False)
-                + self._recovery_backlog()
-            )
-            for req in list(sched.waiting):
-                if not self._reachable(req, sched, backlog):
-                    sched.waiting.remove(req)
-                    self._shed_one(req, "shed", why="deadline")
-                else:
-                    backlog += len(req.known) - req.fed
-
-    def _shed_one(self, req: Request, reason: str,
-                  why: str | None = None) -> None:
-        req.finish_reason = reason
-        req.finished_at = self.step_idx
-        self.d_scheds[0].finished.append(req)
-        if reason == "rejected":
-            self.n_rejected += 1
-            self.obs.registry.counter(
-                "frontend_rejected_total", "submissions rejected at admission"
-            ).inc()
-        else:
-            self.n_shed += 1
-            self.obs.registry.counter(
-                "frontend_shed_total", "requests shed (labeled by reason)",
-                reason=why or reason,
-            ).inc()
-        self.obs.tracer.instant(
-            "request.shed", track=self.name, step=self.step_idx,
-            rid=req.rid, reason=why or reason,
-        )
-        self._finish_stream(req.rid)
-
     # -- cancellation --------------------------------------------------------
-    def _apply_cancels(self) -> None:
-        cancels, self._cancels = self._cancels, []
-        for rid in cancels:
-            self._cancel_now(rid)
-
     def _cancel_now(self, rid: int) -> None:
         # evacuated-but-not-yet-requeued (mid-recovery) cancels land here
         for q in list(self._requeued):
             if q.rid == rid:
                 self._requeued.remove(q)
-                q.finish_reason = "cancelled"
-                q.finished_at = self.step_idx
-                self.d_scheds[0].finished.append(q)
-                self.d_scheds[0].n_cancelled += 1
-                self.obs.registry.counter(
-                    "frontend_cancelled_total",
-                    "streams cancelled by the caller",
-                ).inc()
-                self._finish_stream(rid)
+                self._retire(q, "cancelled")
                 return
-        # in-flight handoff: drop the prefill-side page pins THIS turn —
-        # the bugfix half the offline loop only had for deadline expiry
+        # a handoff in flight: its prefill-side page pins drop THIS turn
         for h in list(self.inflight):
             if h.req.rid == rid:
-                self.inflight.remove(h)
-                self._src_sched(h).release_handoff(h.src_pages)
-                h.req.finish_reason = "cancelled"
-                h.req.finished_at = self.step_idx
-                self.d_scheds[0].finished.append(h.req)
-                self.d_scheds[0].n_cancelled += 1
+                self._drop_inflight(h)
                 self.n_cancelled_inflight += 1
-                self.obs.registry.counter(
-                    "frontend_cancelled_total",
-                    "streams cancelled by the caller",
-                ).inc()
                 self.obs.tracer.instant(
                     "request.cancel", track=self.name, step=self.step_idx,
                     rid=rid, inflight=1,
                 )
-                self._finish_stream(rid)
+                self._retire(h.req, "cancelled")
                 return
         for rids in self._borrow_rids.values():
             rids.discard(rid)
-        for sched in self._all_scheds():
+        for sched in self._scheds:
             if sched.cancel(rid, self.step_idx):
-                self.obs.registry.counter(
-                    "frontend_cancelled_total",
-                    "streams cancelled by the caller",
-                ).inc()
+                self._count_cancel()
                 self._finish_stream(rid)
                 return
 
@@ -1357,6 +1186,12 @@ class DisaggOnlineFrontend:
         if isinstance(h.src, tuple):
             return self.d_scheds[h.src[1]]
         return self.p_scheds[h.src]
+
+    def _drop_inflight(self, h) -> None:
+        """A handoff leaves flight (admitted, cancelled, expired, rolled
+        back): its source-side page pins are released."""
+        self.inflight.remove(h)
+        self._src_sched(h).release_handoff(h.src_pages)
 
     def _transfer(self, h, r):
         if isinstance(h.src, tuple):
@@ -1369,12 +1204,7 @@ class DisaggOnlineFrontend:
                 h.req.deadline is not None
                 and self.step_idx >= h.req.deadline
             ):
-                self.inflight.remove(h)
-                self._src_sched(h).release_handoff(h.src_pages)
-                h.req.finish_reason = "timed_out"
-                h.req.finished_at = self.step_idx
-                self.d_scheds[0].finished.append(h.req)
-                self.d_scheds[0].n_timed_out += 1
+                self._drop_inflight(h)
                 self.obs.registry.counter(
                     "serve_handoff_expired_total",
                     "handoffs expired before decode admission",
@@ -1383,7 +1213,7 @@ class DisaggOnlineFrontend:
                     "request.expire", track=self.name, step=self.step_idx,
                     rid=h.req.rid, inflight=1,
                 )
-                self._finish_stream(h.req.rid)
+                self._retire(h.req, "timed_out")
 
     def _admit_inflight(self) -> None:
         for h in list(self.inflight):
@@ -1411,112 +1241,22 @@ class DisaggOnlineFrontend:
                 except RetryBudgetExhausted as e:
                     self._transfer_exhausted(h, r, e)
                     break
-                self._src_sched(h).release_handoff(h.src_pages)
-                self.inflight.remove(h)
+                self._drop_inflight(h)
                 break
-
-    # -- streaming ----------------------------------------------------------
-    def _apply_backpressure(self) -> None:
-        now_paused = set()
-        for sched in self._all_scheds():
-            sched.paused.clear()
-            room_needed = 1 + self._draft_len
-            for slot, req in sched.running.items():
-                entry = self._active.get(req.rid)
-                if entry is None:
-                    continue
-                if entry[1]._lag() + room_needed > self.cfg.stream_buffer:
-                    sched.paused.add(slot)
-                    now_paused.add(req.rid)
-        _trace_pause_edges(
-            self.obs.tracer, self.name, self.step_idx,
-            self._paused_rids, now_paused,
-        )
-        self._paused_rids = now_paused
-
-    def _emit(self) -> None:
-        for rid, (req, stream) in list(self._active.items()):
-            sent = self._emitted[rid]
-            new = req.generated[sent:]
-            if new:
-                if req.ttft_s < 0 and req.arrived_t >= 0:
-                    req.ttft_s = time.perf_counter() - req.arrived_t
-                    self.obs.registry.histogram(
-                        "request_ttft_ms", "time to first token (ms)"
-                    ).observe(req.ttft_s * 1e3)
-                for tok in new:
-                    stream._push(tok)
-                self._emitted[rid] = sent + len(new)
-            # a request mid-migration is neither running nor done — only
-            # end the stream once a terminal finish_reason lands
-            if req.done:
-                self._finish_stream(rid)
-
-    def _finish_stream(self, rid: int) -> None:
-        entry = self._active.pop(rid, None)
-        self._emitted.pop(rid, None)
-        if entry is not None:
-            entry[1]._end()
-            self.obs.registry.counter(
-                "frontend_finished_total", "streams finished (any reason)"
-            ).inc()
-            if rid in self._paused_rids:
-                self._paused_rids.discard(rid)
-                self.obs.tracer.instant(
-                    "stream.resume", track=self.name,
-                    step=self.step_idx, rid=rid,
-                )
-
-    def _abort_resident(self) -> None:
-        for rid in list(self._active):
-            self._cancel_now(rid)
 
     # -- reporting ----------------------------------------------------------
     def stats(self) -> dict:
-        scheds = self._all_scheds()
-        if hasattr(self.router, "_mirror_transfers"):
-            self.router._mirror_transfers()
-        reg = self.obs.registry
-        reg.gauge("frontend_running", "requests resident in slots"
-                  ).set(sum(len(s.running) for s in scheds))
-        reg.gauge("frontend_waiting", "requests queued for admission"
-                  ).set(sum(len(s.waiting) for s in scheds))
-        reg.gauge("frontend_paused", "slots paused for stream backpressure"
-                  ).set(sum(len(s.paused) for s in scheds))
-        if self.itl_ewma_s is not None:
-            reg.gauge(
-                "frontend_itl_ewma_ms",
-                "decayed inter-token latency estimate (ms)",
-            ).set(self.itl_ewma_s * 1e3)
-        reasons: dict = {}
-        for s in scheds:
-            for r in s.finished:
-                reasons[r.finish_reason] = reasons.get(r.finish_reason, 0) + 1
+        self.router._mirror_transfers()
         return {
-            "steps": self.steps_run,
-            "submitted": self.n_submitted,
-            "finished": sum(len(s.finished) for s in scheds),
-            "finish_reasons": reasons,
-            "shed": self.n_shed,
-            "rejected": self.n_rejected,
-            "recovered": self.n_recovered,
-            "draining": self._draining,
+            **super().stats(),
             "replica_health": self.router.health.snapshot(),
             "degraded": self.router.degraded,
-            "cancelled": sum(s.n_cancelled for s in scheds),
             "cancelled_inflight": self.n_cancelled_inflight,
-            "timed_out": sum(s.n_timed_out for s in scheds),
             "inflight_handoffs": len(self.inflight),
             "handoffs": sum(s.n_handoffs_in for s in self.d_scheds),
             "borrowed": sorted(self.router.borrowed),
             "autoscale_borrows": self.router.n_borrows,
             "autoscale_returns": self.router.n_returns,
-            "waiting": sum(len(s.waiting) for s in scheds),
-            "running": sum(len(s.running) for s in scheds),
-            "itl_ewma_ms": (
-                round(self.itl_ewma_s * 1e3, 4)
-                if self.itl_ewma_s is not None else None
-            ),
             "compiled_signatures_prefill": max(
                 e.step_cache_size() for e in self.router.prefill
             ),

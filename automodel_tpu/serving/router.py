@@ -312,7 +312,7 @@ class ReplicaRouter:
             for r, (eng, sched) in enumerate(zip(self.engines, scheds)):
                 if not self.health.alive(eng.track) or not sched.has_work:
                     continue
-                plan = sched.schedule(step_idx)
+                plan = eng.plan_turn(sched, step_idx)
                 if plan is None:
                     continue
                 try:
@@ -1090,7 +1090,7 @@ class DisaggRouter:
             for r, (eng, sched) in enumerate(zip(self.decode, d_scheds)):
                 if not self.health.alive(eng.track) or not sched.has_work:
                     continue
-                plan = sched.schedule(step_idx)
+                plan = eng.plan_turn(sched, step_idx)
                 if plan is None:
                     continue
                 try:
@@ -1115,7 +1115,7 @@ class DisaggRouter:
             for r, (eng, sched) in enumerate(zip(self.prefill, p_scheds)):
                 if not self.health.alive(eng.track) or not sched.has_work:
                     continue
-                plan = sched.schedule(step_idx)
+                plan = eng.plan_turn(sched, step_idx)
                 if plan is None:
                     continue
                 try:

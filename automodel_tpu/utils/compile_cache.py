@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, at one place for every entry point.
 
 A chip machine starts cold and compiling is a large part of a cold run, so
-`cli/app.py:main`, `chip_smoke.py` and `bench.py` each call
+`cli/app.py:main`, `chip_smoke.py` and `benchmark/run.py` each call
 `enable_compile_cache()` once, before anything compiles. The cache's path
 is part of its key, so it never moves: where `JAX_COMPILATION_CACHE_DIR`
 is set JAX reads it itself and this module sets no other; otherwise the

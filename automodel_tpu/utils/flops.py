@@ -4,7 +4,7 @@ The analog of the reference `AutoMFU` + flops_utils (reference:
 nemo_automodel/_transformers/mfu.py:110, components/utils/flops_utils.py):
 per-architecture FLOPs formulas live on the model configs
 (`flops_per_token`); this module adds the device peak-FLOPs table and the
-MFU/TPS computation used by recipes and bench.py.
+MFU/TPS computation used by recipes.
 """
 
 from __future__ import annotations

@@ -1,6 +1,6 @@
 """Force the host (CPU) JAX platform with a virtual device count.
 
-Single home for the recipe used by tests/conftest.py, bench.py and
+Single home for the recipe used by tests/conftest.py and
 __graft_entry__.py. `JAX_PLATFORMS=cpu` alone selects the CPU; this adds
 the virtual device count a multi-device mesh needs. Must be called BEFORE
 the first JAX backend initialization (importing jax is fine — backends are

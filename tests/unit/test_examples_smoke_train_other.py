@@ -1,0 +1,13 @@
+"""Hermetic example smokes, group `train_other`: the pre-training, KD, sequence-classification, long-context,
+multimodal, VLM, diffusion and dLLM examples
+(tests/examples_smoke.py; the parse tier is test_examples.py)."""
+
+import pytest
+
+from tests.examples_smoke import example_id, run_smoke, smokes
+
+
+@pytest.mark.recipe
+@pytest.mark.parametrize("path", smokes("train_other"), ids=example_id)
+def test_example_smoke_trains(path, tmp_path):
+    run_smoke(path, tmp_path)

@@ -28,6 +28,7 @@ import pytest
 from automodel_tpu.inference.generate import GenerateConfig, generate
 from automodel_tpu.models.llm import decoder
 from automodel_tpu.models.llm.decoder import TransformerConfig
+from automodel_tpu.observability import ObservabilityConfig
 from automodel_tpu.serving import (
     AutoscaleConfig,
     DisaggConfig,
@@ -58,11 +59,44 @@ def _params():
     return decoder.init(CFG, jax.random.key(0))
 
 
-def _engine(params, **geo):
+def _serve_cfg(**geo):
     base = dict(page_size=4, num_pages=24, max_slots=3, pages_per_slot=6,
                 token_budget=8, prefill_chunk=4)
     base.update(geo)
-    return ServingEngine(own(params), CFG, ServingConfig(**base))
+    return ServingConfig(**base)
+
+
+def _engine(params, **geo):
+    return ServingEngine(own(params), CFG, _serve_cfg(**geo))
+
+
+def _frontend(kind, params, cfg=FAST, **geo):
+    """The client side is one class under both frontends: "online" is an
+    `OnlineFrontend` over one engine, "disagg" a `DisaggOnlineFrontend`
+    over a prefill and a decode replica of the same geometry."""
+    if kind == "online":
+        return OnlineFrontend(_engine(params, **geo), cfg)
+    return DisaggOnlineFrontend(
+        DisaggRouter(
+            own(params), CFG, _serve_cfg(**geo),
+            DisaggConfig(enabled=True, prefill_replicas=1, decode_replicas=1),
+        ),
+        cfg,
+    )
+
+
+def _scheds(fe):
+    if isinstance(fe, OnlineFrontend):
+        return [fe.sched]
+    return fe.p_scheds + fe.d_scheds
+
+
+def _shed_count(fe, why):
+    return fe.obs.registry.snapshot().get(
+        f'frontend_shed_total{{reason="{why}"}}', 0)
+
+
+BOTH_FRONTENDS = pytest.mark.parametrize("kind", ["online", "disagg"])
 
 
 def _prompts(lens, vocab=64, seed0=0):
@@ -136,18 +170,19 @@ def test_load_test_harness_parity_under_sustained_load():
 # backpressure
 # ---------------------------------------------------------------------------
 
-def test_slow_consumer_pauses_only_its_own_stream():
+@BOTH_FRONTENDS
+def test_slow_consumer_pauses_only_its_own_stream(kind):
     """One consumer stops reading: its stream queue stays bounded by
     stream_buffer (the slot is withheld from plans), the OTHER requests
     run to completion meanwhile, and once the stalled consumer resumes
     it still receives its full, correct continuation."""
     params = _params()
-    engine = _engine(params, num_pages=48, max_slots=3, pages_per_slot=12)
     cfg = dataclasses.replace(FAST, stream_buffer=4)
     prompts = _prompts([4, 6, 5])
 
     async def run():
-        fe = OnlineFrontend(engine, cfg).start()
+        fe = _frontend(kind, params, cfg, num_pages=48, max_slots=3,
+                       pages_per_slot=12).start()
         slow = fe.submit(Request(prompt=list(prompts[0]),
                                  max_new_tokens=24))
         fast = [
@@ -157,7 +192,7 @@ def test_slow_consumer_pauses_only_its_own_stream():
         # consume only the fast streams; the slow one is never read
         fast_outs = await asyncio.gather(*(s.collect() for s in fast))
         lag_while_stalled = slow._lag()
-        paused = fe.sched.paused.copy()
+        paused = set().union(*(sched.paused for sched in _scheds(fe)))
         # resume the stalled consumer: it must still get everything
         slow_out = await slow.collect()
         await fe.close()
@@ -176,15 +211,15 @@ def test_slow_consumer_pauses_only_its_own_stream():
 # load shedding
 # ---------------------------------------------------------------------------
 
-def _shed_trace(params):
+def _shed_trace(kind, params):
     """Overload a tiny engine with tight-deadline arrivals; return the
     per-rid finish reasons."""
-    engine = _engine(params, num_pages=16, max_slots=2, pages_per_slot=8,
-                     token_budget=4, prefill_chunk=4)
     prompts = _prompts([8, 8, 8, 8, 8, 8], seed0=11)
 
     async def run():
-        fe = OnlineFrontend(engine, FAST).start()
+        fe = _frontend(kind, params, num_pages=16, max_slots=2,
+                       pages_per_slot=8, token_budget=4,
+                       prefill_chunk=4).start()
         streams = [
             fe.submit(Request(prompt=list(p), max_new_tokens=4),
                       deadline_in=9)
@@ -192,26 +227,74 @@ def _shed_trace(params):
         ]
         await asyncio.gather(*(s.collect() for s in streams))
         stats = await fe.close()
-        return {s.rid: s.finish_reason for s in streams}, stats
+        return {s.rid: s.finish_reason for s in streams}, stats, fe
 
     return asyncio.run(run())
 
 
-def test_deadline_shedding_is_deterministic():
+@BOTH_FRONTENDS
+def test_deadline_shedding_is_deterministic(kind):
     """Six 8-token prompts with a 9-step deadline through a 4-token/step
     engine: the backlog makes the tail provably unreachable, so it sheds
     AT ADMISSION — and because the decision is pure step arithmetic, an
     identical trace sheds the identical rid set."""
     params = _params()
-    reasons_a, stats_a = _shed_trace(params)
-    reasons_b, stats_b = _shed_trace(params)
+    reasons_a, stats_a, fe = _shed_trace(kind, params)
+    reasons_b, stats_b, _fe = _shed_trace(kind, params)
     assert reasons_a == reasons_b  # deterministic across runs
     shed = {r for r, why in reasons_a.items() if why == "shed"}
     done = {r for r, why in reasons_a.items() if why in ("eos", "length")}
     assert shed and done, f"want a mix under overload, got {reasons_a}"
     assert stats_a["shed"] == len(shed) == stats_b["shed"]
+    assert _shed_count(fe, "deadline") == len(shed)
     # shed requests never occupied pool pages
-    assert stats_a["free_pages"] == 16
+    assert all(sched.alloc.num_free == 16 for sched in _scheds(fe))
+
+
+@BOTH_FRONTENDS
+def test_full_queue_sheds_at_the_door(kind):
+    """`max_waiting` caps the queue an arrival would join: five arrivals
+    in one turn against a cap of two admit two and shed three as
+    "queue_full", whatever their deadlines; the admitted keep parity."""
+    params = _params()
+    cfg = dataclasses.replace(FAST, max_waiting=2)
+    prompts = _prompts([5, 6, 4, 7, 5], seed0=17)
+
+    async def run():
+        fe = _frontend(kind, params, cfg).start()
+        streams = [fe.submit(Request(prompt=list(p), max_new_tokens=3))
+                   for p in prompts]
+        outs = await asyncio.gather(*(s.collect() for s in streams))
+        return fe, streams, outs, await fe.close()
+
+    fe, streams, outs, stats = asyncio.run(run())
+    assert [s.finish_reason for s in streams] == ["length"] * 2 + ["shed"] * 3
+    assert outs[2:] == [[], [], []]
+    for p, out in zip(prompts[:2], outs):
+        assert out == _ref(params, p, 3)
+    assert stats["shed"] == 3 == _shed_count(fe, "queue_full")
+    assert stats["finished"] == 5 and stats["finish_reasons"]["shed"] == 3
+
+
+@BOTH_FRONTENDS
+def test_closed_frontend_sheds_what_it_had_not_admitted(kind):
+    """An arrival still in the queue when `close()` is called was never
+    admitted: it sheds as "closed" on the loop's last turn (its stream
+    ends, nothing hangs), and a later `submit` raises."""
+    params = _params()
+
+    async def run():
+        fe = _frontend(kind, params).start()
+        stream = fe.submit(Request(prompt=[1, 2, 3], max_new_tokens=2))
+        stats = await fe.close()  # before the loop's first turn
+        with pytest.raises(RuntimeError, match="closed"):
+            fe.submit(Request(prompt=[4, 5], max_new_tokens=1))
+        return fe, stream, await stream.collect(), stats
+
+    fe, stream, out, stats = asyncio.run(run())
+    assert stream.finish_reason == "shed" and out == []
+    assert stats["shed"] == 1 == _shed_count(fe, "closed")
+    assert stats["steps"] == 0 and stats["running"] == stats["waiting"] == 0
 
 
 def test_no_deadline_means_no_shedding():
@@ -235,21 +318,63 @@ def test_no_deadline_means_no_shedding():
 
 
 # ---------------------------------------------------------------------------
+# /metrics endpoint
+# ---------------------------------------------------------------------------
+
+@BOTH_FRONTENDS
+def test_metrics_endpoint_serves_the_registry(kind):
+    """`observability.http_port` (0: any free port) puts /metrics and
+    /healthz on the serve loop of either frontend: the registry's
+    Prometheus text with the frontend's gauges refreshed, and liveness."""
+    params = _params()
+
+    async def get(addr, path):
+        reader, writer = await asyncio.open_connection(*addr)
+        writer.write(f"GET {path} HTTP/1.1\r\nHost: x\r\n\r\n".encode())
+        await writer.drain()
+        reply = await reader.read()
+        writer.close()
+        head, _, body = reply.partition(b"\r\n\r\n")
+        return head.split(b"\r\n")[0], body.decode()
+
+    async def run():
+        fe = _frontend(
+            kind, params,
+            observability=ObservabilityConfig(http_port=0)).start()
+        addr = await fe.http_address()
+        out = await fe.submit(
+            Request(prompt=[1, 2, 3], max_new_tokens=3)).collect()
+        replies = [await get(addr, p)
+                   for p in ("/metrics", "/healthz", "/nowhere")]
+        await fe.close()
+        return out, replies
+
+    out, (metrics, health, nowhere) = asyncio.run(run())
+    assert len(out) == 3
+    assert metrics[0] == b"HTTP/1.1 200 OK"
+    assert "frontend_submitted_total 1" in metrics[1]
+    assert "frontend_running 0" in metrics[1]
+    assert health == (b"HTTP/1.1 200 OK", "ok\n")
+    assert nowhere[0] == b"HTTP/1.1 404 Not Found"
+
+
+# ---------------------------------------------------------------------------
 # cancellation
 # ---------------------------------------------------------------------------
 
-def test_cancel_storm_leaks_no_pages():
+@BOTH_FRONTENDS
+def test_cancel_storm_leaks_no_pages(kind):
     """Cancel most of a live wave mid-generation (running AND queued):
     every cancelled stream terminates with reason "cancelled", survivors
     finish with parity, and afterwards every page is either free or held
     by the prefix cache: free + cached == total."""
     params = _params()
-    engine = _engine(params, num_pages=40, max_slots=3, pages_per_slot=8,
-                     prefix_cache=PrefixCacheConfig(enabled=True))
     prompts = _prompts([6, 7, 5, 9, 4, 8, 6, 7], seed0=23)
 
     async def run():
-        fe = OnlineFrontend(engine, FAST).start()
+        fe = _frontend(
+            kind, params, num_pages=40, max_slots=3, pages_per_slot=8,
+            prefix_cache=PrefixCacheConfig(enabled=True)).start()
         streams = [
             fe.submit(Request(prompt=list(p), max_new_tokens=20))
             for p in prompts
@@ -260,23 +385,25 @@ def test_cancel_storm_leaks_no_pages():
         keep = await asyncio.gather(*(s.collect() for s in streams[:2]))
         rest = await asyncio.gather(*(s.collect() for s in streams[2:]))
         stats = await fe.close()
-        return keep, rest, stats, streams
+        return fe, keep, rest, stats, streams
 
-    keep, rest, stats, streams = asyncio.run(run())
+    fe, keep, rest, stats, streams = asyncio.run(run())
     for p, out in zip(prompts[:2], keep):
         assert out == _ref(params, p, 20)
     assert all(s.finish_reason == "cancelled" for s in streams[2:])
     assert stats["cancelled"] == 6
-    assert engine.alloc.num_free + engine.prefix.cached_pages == 40
-    assert engine.step_cache_size() == 1
+    for sched in _scheds(fe):
+        assert sched.alloc.num_free + sched.prefix.cached_pages == 40
+    assert {v for k, v in stats.items()
+            if k.startswith("compiled_signatures")} == {1}
 
 
-def test_cancel_unknown_rid_is_noop():
+@BOTH_FRONTENDS
+def test_cancel_unknown_rid_is_noop(kind):
     params = _params()
-    engine = _engine(params)
 
     async def run():
-        async with OnlineFrontend(engine, FAST) as fe:
+        async with _frontend(kind, params) as fe:
             s = fe.submit(Request(prompt=[1, 2, 3], max_new_tokens=2))
             fe.cancel(999)  # never submitted: must not disturb anything
             return await s.collect()

@@ -44,8 +44,11 @@ from automodel_tpu.observability.metrics import Counter, Gauge, Histogram
 from automodel_tpu.resilience.faults import FaultCrash, injected
 from automodel_tpu.serving import (
     DisaggConfig,
+    DisaggOnlineFrontend,
     DisaggRouter,
+    ReplicaRouter,
     Request,
+    ServeMeshConfig,
     ServingConfig,
     ServingEngine,
 )
@@ -278,6 +281,86 @@ def test_tracing_on_off_parity_and_compile_once(params):
         len(o) for o in res["outputs"]
     )
     assert reg["serve_step_ms"]["count"] == reg["serve_steps_total"]
+
+
+PLAN_ARGS = {"free_pages", "resident", "preempted", "attn_segments",
+             "attn_live_blocks", "rows", "samples"}
+
+
+def _stream_all(fe, reqs):
+    """Submit `reqs` to a started frontend, read every stream, close."""
+
+    async def main():
+        fe.start()
+        outs = await asyncio.gather(*[fe.submit(r).collect() for r in reqs])
+        await fe.close()
+        return outs
+
+    return asyncio.run(main())
+
+
+def _serve_loop(loop, params, sc, reqs):
+    """Drive `reqs` through one of the five serve loops, tracer on;
+    returns (the tracer, the engines whose steps it drove)."""
+    dc = DisaggConfig(enabled=True, prefill_replicas=1, decode_replicas=1)
+    if loop == "engine":
+        eng = ServingEngine(own(params), CFG, sc)
+        eng.serve_batch(reqs)
+        return eng.obs.tracer, [eng]
+    if loop == "replica_router":
+        router = ReplicaRouter(own(params), CFG, sc, ServeMeshConfig(replicas=2))
+        router.serve_batch(reqs)
+        return router.obs.tracer, router.engines
+    if loop == "disagg_router":
+        router = DisaggRouter(own(params), CFG, sc, dc)
+        router.serve_batch(reqs)
+        return router.obs.tracer, router.prefill + router.decode
+    if loop == "online":
+        eng = ServingEngine(own(params), CFG, sc)
+        _stream_all(OnlineFrontend(eng, FrontendConfig(idle_sleep_s=0.0002)),
+                    reqs)
+        return eng.obs.tracer, [eng]
+    assert loop == "disagg_online"
+    router = DisaggRouter(own(params), CFG, sc, dc)
+    _stream_all(
+        DisaggOnlineFrontend(router, FrontendConfig(idle_sleep_s=0.0002)),
+        reqs)
+    return router.obs.tracer, router.prefill + router.decode
+
+
+@pytest.mark.parametrize("loop", ["engine", "replica_router", "disagg_router",
+                                  "online", "disagg_online"])
+def test_every_serve_loop_plans_under_the_step_plan_span(params, loop):
+    """All five loops plan through `ServingEngine.plan_turn`: every step an
+    engine ran has, under that engine's number for the step, a `step.plan`
+    span saying what the turn did to the pool, what grid the
+    step's attention walks and the rows it planned, closed before the
+    step's `step.run` began."""
+    sc = _sc(observability=ObservabilityConfig(enabled=True))
+    tracer, engines = _serve_loop(loop, params, sc,
+                                  _reqs([5, 9, 3, 7], seed0=40))
+    spans = [e for e in tracer.events if e.ph == "X"]
+    # several engines count their steps apart: the track tells them apart
+    key = (lambda e: (e.track, e.step)) if len(engines) > 1 else (
+        lambda e: (engines[0].track, e.step))
+    runs = {key(e): e for e in spans if e.name == "step.run"}
+    assert sorted(runs) == sorted(
+        (eng.track, n) for eng in engines for n in range(eng.steps_run))
+    assert all(eng.steps_run > 2 for eng in engines)
+    plans = {key(e): e for e in spans
+             if e.name == "step.plan" and "rows" in e.args}
+    assert sorted(plans) == sorted(runs)
+    for key, plan in plans.items():
+        run = runs[key]
+        assert set(plan.args) == PLAN_ARGS
+        assert all(isinstance(v, int) for v in plan.args.values())
+        assert (plan.args["rows"], plan.args["samples"]) == (
+            run.args["rows"], run.args["samples"])
+        assert 1 <= plan.args["attn_segments"] <= plan.args["rows"]
+        assert plan.ts + plan.dur <= run.ts, "planned before it ran"
+    # a turn that planned nothing says so too (the pool's args, no rows)
+    assert all(set(e.args) >= PLAN_ARGS - {"rows", "samples"}
+               for e in spans if e.name == "step.plan")
 
 
 def test_digest_stable_across_identical_load_tests(params):

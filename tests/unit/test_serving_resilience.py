@@ -462,12 +462,13 @@ def test_recovery_backlog_prices_reprefill_into_shedding(params):
 
     probe = Request(prompt=list(range(1, 9)), max_new_tokens=4)  # 8 pending
     probe.deadline = fe.step_idx + 4
-    base = fe._backlog() + fe._waiting_backlog()
+    base = fe._backlog(fe.sched) + fe._waiting_backlog(fe.sched)
     # without the recovery term the request looks easily reachable...
-    assert fe._reachable(probe, base) is True
+    assert fe._reachable(probe, base, fe.sched) is True
     # ...but the 40-token re-prefill ahead of it makes the deadline
     # unreachable — the fixed formula sheds it at the door
-    assert fe._reachable(probe, base + fe._recovery_backlog()) is False
+    assert fe._reachable(
+        probe, base + fe._recovery_backlog(), fe.sched) is False
 
 
 # ---------------------------------------------------------------------------
