@@ -279,7 +279,7 @@ def forward(
             )
         else:
             routed = experts_forward_dropless(
-                lp["experts"], cfg.moe, flat, weights, indices
+                lp["experts"], cfg.moe, flat, weights, indices, mesh_ctx
             )
         m = routed.reshape(B, S, cfg.hidden_size)
         m = rms_norm(m, lp["post_ffn_norm_2"]["scale"], eps, zero_centered=True)

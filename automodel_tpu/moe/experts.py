@@ -14,9 +14,15 @@ shapes (capacity padding) keep everything jit-compatible; overflow tokens
 are dropped (capacity_factor controls headroom), matching Megatron-style
 capacity dispatch semantics.
 
-A sort-based dropless path (ragged grouped GEMM ≙ megablox gmm) is the
-planned second dispatcher; this module keeps the dispatcher abstraction so
-both share the gate and expert weights.
+The second dispatcher is the sort-based dropless path
+(`experts_forward_dropless`, and `_dropless_ep_local` across an `ep` axis):
+rows sorted by expert, the three expert products as grouped matmuls
+(`ops/grouped_matmul.py`: a Pallas kernel that streams every expert's
+weights once on a TPU where the call qualifies, `lax.ragged_dot` everywhere
+else). Its contract on the rows past `sum(group_sizes)`, where the sort puts
+the masked tokens' sentinel rows, is that they come out ZERO: their combine
+weight is 0, and 0 x NaN is NaN. Both dispatchers share the gate and the
+expert weights.
 """
 
 from __future__ import annotations
@@ -27,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from automodel_tpu.moe.config import MoEConfig
+from automodel_tpu.ops.grouped_matmul import grouped_matmul
 
 _EXPERT_ACT = {
     "silu": jax.nn.silu,
@@ -136,18 +143,24 @@ def experts_forward_dropless(
     x: jnp.ndarray,        # (T, H)
     weights: jnp.ndarray,  # (T, K)
     indices: jnp.ndarray,  # (T, K)
+    mesh_ctx=None,
 ) -> jnp.ndarray:
     """Dropless sort-based dispatch + ragged grouped GEMM.
 
     The megablox/`GroupedExpertsDeepEP` analog (reference: experts.py:651):
     (token, slot) pairs are sorted by expert id, the three expert matmuls run
-    as `lax.ragged_dot` over the per-expert group sizes (no capacity padding,
-    no dropped tokens), and outputs scatter-add back into token order. Static
-    shapes throughout (TK rows total), so jit-compatible.
+    as `ops/grouped_matmul.grouped_matmul` over the per-expert group sizes
+    (no capacity padding, no dropped tokens), and outputs scatter-add back
+    into token order. Static shapes throughout (TK rows total), so
+    jit-compatible. Masked tokens sort last (sentinel expert E) and belong
+    to no group: the grouped matmul returns their rows as zeros.
 
     Scope: replicated or dp-sharded experts (ep=1) — ragged group sizes
     don't split across an `ep` axis under GSPMD; on an ep>1 mesh
-    `moe/layer.py` calls `experts_forward_dropless_ep` below instead.
+    `moe/layer.py` calls `experts_forward_dropless_ep` below instead. A
+    GSPMD caller on more than one device passes its `mesh_ctx` (the Pallas
+    kernel has no partitioning rule: the reference serves such a call); a
+    caller inside a `shard_map` passes none.
     """
     T, H = x.shape
     K = cfg.experts_per_token
@@ -165,17 +178,18 @@ def experts_forward_dropless(
     # masked tokens carry the sentinel index E (see gate_forward) — clip once
     # for the bias gathers; their rows are zero-weighted in the combine anyway
     safe_expert = jnp.clip(expert_of, 0, E - 1)
-    u = jax.lax.ragged_dot(xs, params["up_proj"]["kernel"].astype(dtype), group_sizes)
+    gmm = functools.partial(grouped_matmul, mesh_ctx=mesh_ctx)
+    u = gmm(xs, params["up_proj"]["kernel"].astype(dtype), group_sizes)
     if "bias" in params["up_proj"]:
         u = u + jnp.take(params["up_proj"]["bias"].astype(dtype), safe_expert, axis=0)
     if cfg.gated_experts:
-        g = jax.lax.ragged_dot(xs, params["gate_proj"]["kernel"].astype(dtype), group_sizes)
+        g = gmm(xs, params["gate_proj"]["kernel"].astype(dtype), group_sizes)
         if "bias" in params["gate_proj"]:
             g = g + jnp.take(params["gate_proj"]["bias"].astype(dtype), safe_expert, axis=0)
         h_in = gated_combine(g, u, cfg.expert_activation, cfg.swiglu_limit)
     else:
         h_in = _EXPERT_ACT[cfg.expert_activation](u)
-    y = jax.lax.ragged_dot(h_in, params["down_proj"]["kernel"].astype(dtype), group_sizes)
+    y = gmm(h_in, params["down_proj"]["kernel"].astype(dtype), group_sizes)
     if "bias" in params["down_proj"]:
         y = y + jnp.take(params["down_proj"]["bias"].astype(dtype), safe_expert, axis=0)
 
@@ -248,7 +262,7 @@ def _dropless_ep_local(params, cfg, x, weights, indices, *, axis_name, bucket,
 
     Layout invariant: rows sorted by global expert id are grouped by owner
     rank (experts are contiguous per rank), so one stable sort serves both
-    the send bucketing and, on the receiver, the ragged_dot grouping.
+    the send bucketing and, on the receiver, the grouped matmuls' grouping.
     """
     from jax import lax
 
@@ -326,17 +340,17 @@ def _dropless_ep_local(params, cfg, x, weights, indices, *, axis_name, bucket,
     group_sizes = jnp.bincount(key, length=E_loc + 1)[:E_loc].astype(jnp.int32)
     safe_le = jnp.clip(jnp.take(key, sort2), 0, E_loc - 1)
 
-    u = lax.ragged_dot(xs2, params["up_proj"]["kernel"].astype(dtype), group_sizes)
+    u = grouped_matmul(xs2, params["up_proj"]["kernel"].astype(dtype), group_sizes)
     if "bias" in params["up_proj"]:
         u = u + jnp.take(params["up_proj"]["bias"].astype(dtype), safe_le, axis=0)
     if cfg.gated_experts:
-        g = lax.ragged_dot(xs2, params["gate_proj"]["kernel"].astype(dtype), group_sizes)
+        g = grouped_matmul(xs2, params["gate_proj"]["kernel"].astype(dtype), group_sizes)
         if "bias" in params["gate_proj"]:
             g = g + jnp.take(params["gate_proj"]["bias"].astype(dtype), safe_le, axis=0)
         h_in = gated_combine(g, u, cfg.expert_activation, cfg.swiglu_limit)
     else:
         h_in = _EXPERT_ACT[cfg.expert_activation](u)
-    y2 = lax.ragged_dot(h_in, params["down_proj"]["kernel"].astype(dtype), group_sizes)
+    y2 = grouped_matmul(h_in, params["down_proj"]["kernel"].astype(dtype), group_sizes)
     if "bias" in params["down_proj"]:
         y2 = y2 + jnp.take(params["down_proj"]["bias"].astype(dtype), safe_le, axis=0)
     y2 = jnp.where(jnp.take(recv_valid, sort2)[:, None], y2, 0.0)

@@ -103,7 +103,9 @@ def moe_forward(
                     params["experts"], cfg, flat, weights, indices, mesh_ctx
                 )
             else:
-                routed = experts_forward_dropless(params["experts"], cfg, flat, weights, indices)
+                routed = experts_forward_dropless(
+                    params["experts"], cfg, flat, weights, indices, mesh_ctx
+                )
         else:
             capacity = compute_capacity(cfg, B * S)
             dispatch, combine = dispatch_tensors(cfg, indices, weights, capacity)

@@ -313,6 +313,9 @@ METRIC_CATALOG = (
     # attention dispatch (ops/attention.py resolve_kernel_impl; trace time,
     # process-global registry)
     ("attention_reference_fallbacks_total", "counter", "impl='auto' call sites compiled to the XLA reference on a TPU (labeled by op and reason)"),
+    # the routed experts' grouped matmul (ops/grouped_matmul.py; trace time,
+    # process-global registry)
+    ("grouped_matmul_calls_total", "counter", "traced grouped-matmul call sites (labeled by impl=pallas|xla and reason)"),
 )
 
 #: Process-global registry for components without an engine in hand

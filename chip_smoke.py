@@ -54,6 +54,10 @@ FLASH_SHAPE = dict(B=1, S=4096, H=16, D_qk=192, D_v=128)
 PAGED_STEP = dict(T=256, P=26, ps=64)
 MLA_POOL = dict(heads=16, latent=512, rope=64, nope=128, num_pages=2049)
 GQA_POOL = dict(q_heads=32, kv_heads=8, head_dim=128, num_pages=513)
+# Grouped matmul: the serve step's gate / up product of one expert layer
+# (256 rows x top-6 sorted over Moonlight's 64 experts of 2048 -> 1408), the
+# last 36 rows past the last group as a masked token's are.
+EXPERTS_CALL = dict(m=1536, k=2048, n=1408, E=64, masked_rows=36)
 
 # bf16's unit roundoff is 2^-9. A kernel rounds its softmax weights and its
 # output to bf16 and accumulates in float32, so against a float32 oracle on
@@ -298,6 +302,33 @@ def phase_kernels() -> None:
         gqa_q, gqa_pages, g["num_pages"], quant=True,
     )
 
+    # -- the routed experts' grouped matmul
+    from automodel_tpu.ops import grouped_matmul as gmm
+    from automodel_tpu.ops.pallas import grouped_matmul as gmm_kernel
+
+    e = EXPERTS_CALL
+    check(not gmm_kernel._interpret(), "grouped_matmul compiles for the chip")
+    kk = jax.random.split(jax.random.key(32), 2)
+    lhs = jax.random.normal(kk[0], (e["m"], e["k"]), jnp.bfloat16)
+    rhs = jax.random.normal(
+        kk[1], (e["E"], e["k"], e["n"]), jnp.bfloat16) * e["k"] ** -0.5
+    routed = e["m"] - e["masked_rows"]
+    sizes = jnp.asarray(
+        rng.multinomial(routed, np.ones(e["E"] - 4) / (e["E"] - 4)).tolist()
+        + [0] * 4, jnp.int32)  # four experts idle
+    fn = jax.jit(functools.partial(gmm.grouped_matmul, impl="pallas"))
+    got = fn(lhs, rhs, sizes)
+    txt = fn.lower(lhs, rhs, sizes).compile().as_text()
+    check(len(mosaic_calls(txt, "grouped_matmul")) == 1,
+          "grouped_matmul is a Mosaic call")
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.lax.ragged_dot)(*f32((lhs, rhs)), sizes)
+    err = rel_err(got[:routed], want[:routed])
+    check(err < KERNEL_TOL, f"grouped_matmul vs float32 lax.ragged_dot: "
+          f"max|err|/max|ref| = {err:.2e} < {KERNEL_TOL:.2e}")
+    check(float(jnp.max(jnp.abs(got[routed:].astype(jnp.float32)))) == 0.0,
+          "grouped_matmul: rows past the last group come out zero")
+
 
 # ---------------------------------------------------------------------------
 # phase: serve
@@ -381,6 +412,10 @@ def phase_serve() -> None:
     check(len(calls) >= 1,
           f"the compiled serve step calls the Mosaic MLA paged kernel "
           f"({len(calls)} call sites, q_abs {calls and calls[0][2]})")
+    calls = mosaic_calls(txt, "grouped_matmul")
+    check(len(calls) >= 3 and "ragged-dot" not in txt,
+          f"the experts' products are the Mosaic grouped matmul "
+          f"({len(calls)} call sites, no ragged-dot left)")
     mixed = _mixed_steps(os.path.join(run_dir, "serve.trace.jsonl"))
     check(mixed >= 1, f"{mixed} steps mixed prefill and decode rows")
 

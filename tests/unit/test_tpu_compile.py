@@ -20,15 +20,19 @@ from jax.sharding import SingleDeviceSharding
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler here: nothing to prove
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -39,12 +43,24 @@ def tpu_branch(monkeypatch):
     import automodel_tpu.ops.attention as attention
     import automodel_tpu.ops.pallas.ragged_paged_attention as rpa
 
+    import automodel_tpu.ops.grouped_matmul as gmm
+    import automodel_tpu.ops.pallas.grouped_matmul as gmm_kernel
+
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(rpa, "_interpret", lambda: False)
+    monkeypatch.setattr(gmm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(gmm_kernel, "_interpret", lambda: False)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     yield rpa
     jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def _shapes(sharding, *trees):
+    """The trees' arrays as shapes on the described chip."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        trees)
 
 
 #: (rows, heads, key/value heads, head width, pages, page size, pages a
@@ -129,6 +145,35 @@ def test_paged_attention_mla_compiles_for_the_chip(one_chip, tpu_branch, quant):
     assert "paged_attention_mla" in compiled.as_text()
 
 
+#: (rows, k, n, experts): the sorted rows of one expert layer's product
+EXPERT_CALLS = {
+    "moonlight: gate / up": (1536, 2048, 1408, 64),
+    "moonlight: down": (1536, 1408, 2048, 64),
+    "moonlight: 64 decode rows": (384, 2048, 1408, 64),
+    "qwen3-30b-a3b: gate / up": (2048, 2048, 768, 128),
+}
+
+
+@pytest.mark.parametrize("call", EXPERT_CALLS.values(), ids=EXPERT_CALLS.keys())
+def test_grouped_matmul_compiles_for_the_chip(one_chip, tpu_branch, call):
+    """The dispatcher's own choice for a serve step's call: the kernel, at
+    the tiles its rule gives (whole (k, n) slabs: 5.8 MB each, two in
+    flight, past the compiler's default 16 MiB of VMEM)."""
+    from automodel_tpu.ops.grouped_matmul import grouped_matmul, tiles
+
+    m, k, n, E = call
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert tiles(m, k, n, jnp.bfloat16) == (128, n)
+    compiled = jax.jit(grouped_matmul).lower(
+        s((m, k), jnp.bfloat16), s((E, k, n), jnp.bfloat16),
+        s((E,), jnp.int32)).compile()
+    assert "grouped_matmul" in compiled.as_text()
+    assert "ragged-dot" not in compiled.as_text()
+
+
 def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
         one_chip, tpu_branch):
     """The toy looped decoder's serve step, lowered for the chip: passes x
@@ -143,14 +188,76 @@ def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
         page_size=8, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=8))
 
-    def shapes(tree):
-        return jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            tree)
-
     batch = eng._plan_batch(eng.empty_plan())
     lowered = jax.jit(eng._step_impl, donate_argnums=(1,)).lower(
-        shapes(eng.params), shapes(eng.pool), shapes(batch))
+        *_shapes(one_chip, eng.params, eng.pool, batch))
     assert lowered.as_text().count("paged_attention_gqa") == (
         cfg.num_passes * cfg.num_layers) == 12
     lowered.compile()
+
+
+def test_grouped_matmul_compiles_inside_a_shard_map_over_four_chips(
+        topo, tpu_branch):
+    """The EP path's use: each chip's local experts over the rows it
+    received, inside a `shard_map` (a training batch's: 1,536 a group)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from automodel_tpu.ops.grouped_matmul import grouped_matmul
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("ep",))
+
+    def s(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    fn = jax.shard_map(
+        grouped_matmul, mesh=mesh,
+        in_specs=(P("ep", None), P("ep", None, None), P("ep")),
+        out_specs=P("ep", None), check_vma=False)
+    compiled = jax.jit(fn).lower(
+        s((4 * 24576, 2048), jnp.bfloat16, P("ep", None)),
+        s((64, 2048, 1408), jnp.bfloat16, P("ep", None, None)),
+        s((64,), jnp.int32, P("ep"))).compile()
+    assert "grouped_matmul" in compiled.as_text()
+
+
+def test_expert_step_lowered_for_the_chip_holds_three_grouped_matmuls_a_layer(
+        one_chip, tpu_branch):
+    """A toy DeepSeek-shaped decoder's serve step (one dense layer, two
+    expert layers), lowered for the chip: gate, up and down of every expert
+    layer are `grouped_matmul` calls under `serve.moe.experts` and no
+    `ragged_dot` is left (at Moonlight's size the benchmark counts 24)."""
+    from automodel_tpu.models.moe_lm import decoder as moe_decoder
+    from automodel_tpu.models.moe_lm.decoder import MoETransformerConfig
+    from automodel_tpu.moe.config import MoEConfig
+    from automodel_tpu.serving import ServingConfig, ServingEngine
+
+    cfg = MoETransformerConfig(
+        vocab_size=64, hidden_size=128, intermediate_size=128, num_layers=3,
+        num_heads=4, num_kv_heads=4, first_k_dense=1, dtype=jnp.bfloat16,
+        remat_policy="none",
+        attention_type="mla", mla_kv_lora_rank=128, mla_q_lora_rank=64,
+        mla_qk_nope_head_dim=64, mla_qk_rope_head_dim=64, mla_v_head_dim=64,
+        moe=MoEConfig(
+            n_routed_experts=8, n_shared_experts=1, experts_per_token=2,
+            moe_intermediate_size=128, shared_expert_intermediate_size=128,
+            aux_loss_coeff=0.0, dispatcher="dropless",
+        ),
+    )
+    eng = ServingEngine(moe_decoder.init(cfg, jax.random.key(0)), cfg, ServingConfig(
+        page_size=16, num_pages=8, max_slots=2, pages_per_slot=2,
+        token_budget=16))
+
+    batch = eng._plan_batch(eng.empty_plan())
+    lowered = jax.jit(eng._step_impl, donate_argnums=(1,)).lower(
+        *_shapes(one_chip, eng.params, eng.pool, batch))
+    text = lowered.as_text()
+    assert text.count("ragged_dot") == 0
+    assert text.count("grouped_matmul") == 6
+    assert text.count("paged_attention_mla") == 3
+    # the compiled calls keep their scope: `serve_moe_device_ms` reads them
+    calls = [ln for ln in lowered.compile().as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert sum("serve.moe.experts/grouped_matmul/pallas_call" in ln
+               for ln in calls) == 6
