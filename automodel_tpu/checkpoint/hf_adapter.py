@@ -711,9 +711,102 @@ class MoEDecoderAdapter:
         return out
 
 
+@dataclasses.dataclass
+class JambaAdapter:
+    """JambaForCausalLM (dense sizes) <-> a decoder whose layers name their
+    mixer (`TransformerConfig.layer_ops`): `layers` holds every layer's two
+    norms and MLP, `attn_layers` / `mamba_layers` the operators by kind.
+    Layouts: conv1d.weight (C, 1, K) <-> conv/kernel (K, C); A_log (C, N)
+    <-> (N, C); dt_proj.bias <-> the leaf `dt_bias`; Linears transposed."""
+
+    cfg: TransformerConfig
+
+    #: (hf suffix, path under the stack, transpose)
+    LAYER = [
+        ("input_layernorm.weight", ("input_norm", "scale"), False),
+        ("pre_ff_layernorm.weight", ("post_attn_norm", "scale"), False),
+        ("feed_forward.gate_proj.weight", ("gate_proj", "kernel"), True),
+        ("feed_forward.up_proj.weight", ("up_proj", "kernel"), True),
+        ("feed_forward.down_proj.weight", ("down_proj", "kernel"), True),
+    ]
+    OPERATORS = {
+        "attention": [
+            ("self_attn.q_proj.weight", ("q_proj", "kernel"), True),
+            ("self_attn.k_proj.weight", ("k_proj", "kernel"), True),
+            ("self_attn.v_proj.weight", ("v_proj", "kernel"), True),
+            ("self_attn.o_proj.weight", ("o_proj", "kernel"), True),
+        ],
+        "mamba": [
+            ("mamba.in_proj.weight", ("in_proj", "kernel"), True),
+            ("mamba.conv1d.weight", ("conv", "kernel"), "conv"),
+            ("mamba.conv1d.bias", ("conv", "bias"), False),
+            ("mamba.x_proj.weight", ("x_proj", "kernel"), True),
+            ("mamba.dt_layernorm.weight", ("dt_norm", "scale"), False),
+            ("mamba.b_layernorm.weight", ("b_norm", "scale"), False),
+            ("mamba.c_layernorm.weight", ("c_norm", "scale"), False),
+            ("mamba.dt_proj.weight", ("dt_proj", "kernel"), True),
+            ("mamba.dt_proj.bias", ("dt_bias",), False),
+            ("mamba.A_log", ("A_log",), True),
+            ("mamba.D", ("D",), False),
+            ("mamba.out_proj.weight", ("o_proj", "kernel"), True),
+        ],
+    }
+    TOP = [
+        ("model.embed_tokens.weight", ("embed", "embedding"), False),
+        ("model.final_layernorm.weight", ("final_norm", "scale"), False),
+    ]
+
+    @staticmethod
+    def _lay(x, transpose, inverse: bool):
+        x = np.asarray(x)
+        if transpose == "conv":  # (C, 1, K) <-> (K, C)
+            return _t(x)[:, None, :] if inverse else _t(x[:, 0, :])
+        return _t(x) if transpose else x
+
+    def _top(self):
+        head = [] if self.cfg.tie_word_embeddings else [
+            ("lm_head.weight", ("lm_head", "kernel"), True)]
+        return self.TOP + head
+
+    def _by_layer(self):
+        """(layer i, stack key, index in the stack, entries)."""
+        from automodel_tpu.models.llm.decoder import OPERATOR_STACKS, layer_operators
+
+        for i, (kind, j) in enumerate(layer_operators(self.cfg)):
+            yield i, "layers", i, self.LAYER
+            yield i, OPERATOR_STACKS[kind], j, self.OPERATORS[kind]
+
+    def to_hf(self, params: Mapping) -> Iterator[tuple[str, np.ndarray]]:
+        for name, path, transpose in self._top():
+            yield name, self._lay(_get(params, path), transpose, True)
+        for i, stack, j, entries in self._by_layer():
+            for suffix, path, transpose in entries:
+                x = np.asarray(_get(params[stack], path)[j])
+                yield f"model.layers.{i}.{suffix}", self._lay(x, transpose, True)
+
+    def from_hf(self, read: Reader, shardings: Any = None) -> dict:
+        out: dict = {}
+        rows: dict = {}  # (stack,) + path -> {index in the stack: array}
+
+        def put(path, value):
+            sh = _get(shardings, path) if shardings is not None else None
+            _set(out, path, jax.device_put(value, sh) if sh is not None else value)
+
+        for name, path, transpose in self._top():
+            put(path, self._lay(read(name), transpose, False))
+        for i, stack, j, entries in self._by_layer():
+            for suffix, path, transpose in entries:
+                rows.setdefault((stack,) + path, {})[j] = self._lay(
+                    read(f"model.layers.{i}.{suffix}"), transpose, False)
+        for path, by_index in rows.items():
+            put(path, np.stack([by_index[j] for j in range(len(by_index))]))
+        return out
+
+
 ADAPTERS = {
     "dense_decoder": DenseDecoderAdapter,
     "moe_decoder": MoEDecoderAdapter,
+    "jamba": JambaAdapter,
 }
 
 

@@ -304,6 +304,13 @@ def generate(
         return het_generate(
             params, cfg, input_ids, rng, gen, prompt_embeds=prompt_embeds
         )
+    if getattr(cfg, "layer_ops", None) is not None:
+        raise NotImplementedError(
+            "generate() over layers that name their mixer (layer_ops: a "
+            "state-space layer's convolution and recurrent state are not in "
+            "this dense per-request cache); serve such a model through "
+            "serving.ServingEngine, which carries the state per slot"
+        )
     params = cast_params(params, cfg.dtype)
     B, S = input_ids.shape
     T = S + gen.max_new_tokens

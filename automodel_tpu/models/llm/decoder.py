@@ -83,6 +83,22 @@ class TransformerConfig:
     # a Linear hidden -> 1 with bias on each pass's normed state (shared by
     # the passes): the per-pass exit probability of a looped decoder
     exit_gate: bool = False
+    # layers that name their mixer (Jamba): per layer "attention" | "mamba".
+    # None = attention in every layer, the operator's weights inside the
+    # `layers` stack. With it, `layers` holds what every layer has (the two
+    # norms and the MLP) and each kind's operators are a stack of their own
+    # (OPERATOR_STACKS), entry j the j-th layer of that kind.
+    layer_ops: Optional[tuple] = None
+    # the Mamba-1 mixer's widths (models/llm/mamba.py): states per channel,
+    # taps of the causal convolution, inner channels = expand * hidden, rank
+    # of the low-rank dt projection (None -> ceil(hidden / 16))
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: Optional[int] = None
+    # False: no rotary embedding on q and k (Jamba has no positional
+    # encoding at all: its state-space layers carry the order)
+    use_rope: bool = True
     logits_soft_cap: Optional[float] = None
     attn_soft_cap: Optional[float] = None
     embed_scale: float = 1.0  # gemma multiplies embeddings by sqrt(hidden)
@@ -167,6 +183,20 @@ class TransformerConfig:
         d = round(self.resolved_head_dim * self.partial_rotary_factor)
         return d - (d % 2)
 
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def resolved_dt_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.hidden_size // 16)
+
+    @property
+    def holds_state(self) -> bool:
+        """Whether some layer carries a recurrent state from token to token
+        (what a serving engine must keep per slot, beside the pages)."""
+        return any(op != "attention" for op in self.layer_ops or ())
+
     def attn_params_per_layer(self) -> int:
         """Projection parameter count of one attention block."""
         H = self.hidden_size
@@ -194,11 +224,20 @@ class TransformerConfig:
             self.attn_params_per_layer()
             + 3 * self.hidden_size * self.intermediate_size
         )
+        n_attn = self.num_layers
+        mixers_instead = 0  # layers whose mixer is not attention
+        if self.layer_ops is not None:
+            from automodel_tpu.models.llm.mamba import mamba_params_per_layer
+
+            n_attn = sum(op == "attention" for op in self.layer_ops)
+            mixers_instead = (self.num_layers - n_attn) * (
+                mamba_params_per_layer(self) - self.attn_params_per_layer()
+            )
         n_params = (
             self.vocab_size * self.hidden_size * (1 if self.tie_word_embeddings else 2)
-            + self.num_layers * layer_params
+            + self.num_layers * layer_params + mixers_instead
         )
-        attn_flops = 6 * self.num_layers * self.num_heads * D * seq_len  # 2*2*1.5 causal
+        attn_flops = 6 * n_attn * self.num_heads * D * seq_len  # 2*2*1.5 causal
         # a looped decoder runs its layers (not the embedding or the head)
         # num_passes times over every token
         layers_again = (self.num_passes - 1) * (
@@ -218,6 +257,27 @@ def layer_windows(cfg: "TransformerConfig", num_layers: int | None = None) -> tu
     return tuple(
         cfg.sliding_window if t == "sliding" else None for t in cfg.layer_types
     )
+
+
+#: the parameter tree's key for each kind of operator's stack (a model with
+#: `layer_ops`); "attention" in a model without them lives in the layers
+OPERATOR_STACKS = {"attention": "attn_layers", "mamba": "mamba_layers"}
+
+
+def layer_operators(cfg: "TransformerConfig") -> tuple:
+    """Per layer (kind, index into that kind's operator stack), or None for
+    a decoder of one kind (attention's weights inside the layer stack)."""
+    if cfg.layer_ops is None:
+        return None
+    assert len(cfg.layer_ops) == cfg.num_layers, (len(cfg.layer_ops), cfg.num_layers)
+    seen: dict = {}
+    out = []
+    for op in cfg.layer_ops:
+        if op not in OPERATOR_STACKS:
+            raise NotImplementedError(f"no layer operator {op!r}")
+        out.append((op, seen.get(op, 0)))
+        seen[op] = seen.get(op, 0) + 1
+    return tuple(out)
 
 
 def mixed_window_xs(windows: tuple, freq_for) -> tuple:
@@ -328,12 +388,44 @@ def attention_layer_specs(cfg: TransformerConfig) -> dict:
     return layers
 
 
+#: the norms every layer has whatever its operator: before the operator and
+#: before the MLP
+_LAYER_NORMS = ("input_norm", "post_attn_norm")
+
+
+def _check_layer_ops(cfg: TransformerConfig) -> None:
+    if cfg.layer_ops is None:
+        return
+    if cfg.attention_type != "gqa" or cfg.num_passes != 1 or cfg.sliding_window:
+        raise NotImplementedError(
+            "layers that name their mixer (layer_ops) with MLA, more than "
+            "one pass or sliding windows"
+        )
+
+
 def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
     """Build fp32 master params with per-layer weights stacked on dim 0."""
+    _check_layer_ops(cfg)
     H, I, L = cfg.hidden_size, cfg.intermediate_size, cfg.num_layers
     ks = jax.random.split(rng, 8)
 
-    layers = init_attention_layers(cfg, ks[0], L)
+    ops = layer_operators(cfg)
+    operators = {}
+    if ops is None:
+        layers = init_attention_layers(cfg, ks[0], L)
+    else:
+        # every layer's two norms here; each kind's operators a stack apart
+        from automodel_tpu.models.llm.mamba import init_mamba_layers
+
+        layers = {name: {"scale": jnp.ones((L, H))} for name in _LAYER_NORMS}
+        n_attn = sum(op == "attention" for op, _ in ops)
+        if n_attn:
+            attn = init_attention_layers(cfg, ks[0], n_attn)
+            operators["attn_layers"] = {
+                k: v for k, v in attn.items() if k not in _LAYER_NORMS
+            }
+        if L - n_attn:
+            operators["mamba_layers"] = init_mamba_layers(cfg, ks[1], L - n_attn)
     layers.update(
         {
             "gate_proj": {"kernel": _stack(dense_init, ks[4], (H, I), L)},
@@ -344,6 +436,7 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
     params = {
         "embed": {"embedding": embed_init(ks[7], (cfg.vocab_size, H))},
         "layers": layers,
+        **operators,
         "final_norm": {"scale": jnp.ones((H,))},
     }
     if not cfg.tie_word_embeddings:
@@ -358,7 +451,20 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
 
 def param_specs(cfg: TransformerConfig) -> dict:
     """Logical axis names per param (consumed by parallel/sharding.py)."""
+    ops = layer_operators(cfg)
     layers = attention_layer_specs(cfg)
+    operators = {}
+    if ops is not None:
+        from automodel_tpu.models.llm.mamba import mamba_layer_specs
+
+        kinds = {op for op, _ in ops}
+        if "attention" in kinds:
+            operators["attn_layers"] = {
+                k: v for k, v in layers.items() if k not in _LAYER_NORMS
+            }
+        if "mamba" in kinds:
+            operators["mamba_layers"] = mamba_layer_specs(cfg)
+        layers = {k: layers[k] for k in _LAYER_NORMS}
     layers.update(
         {
             "gate_proj": {"kernel": ("layers", "embed", "mlp")},
@@ -369,6 +475,7 @@ def param_specs(cfg: TransformerConfig) -> dict:
     specs = {
         "embed": {"embedding": ("vocab", "embed")},
         "layers": layers,
+        **operators,
         "final_norm": {"scale": ("norm",)},
     }
     if not cfg.tie_word_embeddings:
@@ -705,7 +812,12 @@ def forward(
             "aux-hidden capture"
         )
 
-    if mesh_ctx is not None and mesh_ctx.sizes["pp"] > 1:
+    if cfg.layer_ops is not None:
+        h = _walk_named_layers(
+            params, cfg, h, positions, segment_ids, inv_freq, constrain,
+            mesh_ctx, return_aux_hidden,
+        )
+    elif mesh_ctx is not None and mesh_ctx.sizes["pp"] > 1:
         from automodel_tpu.parallel.pp import pipeline_layers
 
         if return_aux_hidden is not None:
@@ -791,6 +903,41 @@ def forward(
     return out
 
 
+def _walk_named_layers(params, cfg, h, positions, segment_ids, inv_freq,
+                       constrain, mesh_ctx, return_aux_hidden):
+    """The layers of a decoder whose layers name their mixer, in a Python
+    loop: layer i's norms and MLP from `layers`, its operator from its
+    kind's stack. Forward-correct; nothing here is tuned or measured."""
+    from automodel_tpu.models.common.layers import maybe_remat
+    from automodel_tpu.models.llm.mamba import mamba_block
+
+    _check_layer_ops(cfg)
+    if return_aux_hidden is not None or (mesh_ctx is not None and (
+        mesh_ctx.sizes["pp"] > 1 or mesh_ctx.sizes["cp"] > 1
+    )):
+        raise NotImplementedError(
+            "layers that name their mixer (layer_ops) under the pp pipeline, "
+            "under context parallelism (a scan over positions is order-"
+            "sensitive: no ring, no permuted layout) or with aux-hidden capture"
+        )
+
+    def layer(h, lp, kind):
+        if kind == "attention":
+            h = attention_block(h, lp, cfg, positions, segment_ids, inv_freq,
+                                constrain, None, mesh_ctx)
+        else:
+            h = mamba_block(h, lp, cfg, positions, constrain)
+        return mlp_block(h, lp, cfg, constrain, mesh_ctx)
+
+    for i, (kind, j) in enumerate(layer_operators(cfg)):
+        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        lp.update(jax.tree.map(lambda a: a[j], params[OPERATOR_STACKS[kind]]))
+        h = maybe_remat(
+            lambda h, lp, kind=kind: layer(h, lp, kind), cfg.remat_policy
+        )(h, lp)
+    return h
+
+
 def head_kernel(params: dict, cfg: TransformerConfig) -> jnp.ndarray:
     """(H, V) output-projection kernel: tied/untied, with baichuan NormHead
     L2-normalization per vocab row when cfg.normalized_lm_head."""
@@ -832,8 +979,9 @@ def project_qkv(x, lp, cfg: TransformerConfig, positions, inv_freq):
     if cfg.qk_norm and not cfg.qk_norm_after_rope:
         q = rms_norm(q, lp["q_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
         k = rms_norm(k, lp["k_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
-    q = apply_rope(q, positions, inv_freq, cfg.rope_interleaved)
-    k = apply_rope(k, positions, inv_freq, cfg.rope_interleaved)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, inv_freq, cfg.rope_interleaved)
+        k = apply_rope(k, positions, inv_freq, cfg.rope_interleaved)
     if cfg.qk_norm and cfg.qk_norm_after_rope:
         q = rms_norm(q, lp["q_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
         k = rms_norm(k, lp["k_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)

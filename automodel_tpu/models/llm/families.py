@@ -229,6 +229,46 @@ def ouro_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def jamba_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
+    """JambaForCausalLM (AI21 Jamba / Jamba2; the family's modeling_jamba.py):
+    layer i is attention where `i % attn_layer_period == attn_layer_offset`
+    and a Mamba-1 mixer everywhere else (models/llm/mamba.py); every layer
+    `x += mixer(rms(x))`, `x += mlp(rms(x))`, a silu-gated MLP. No positional
+    encoding of any kind: attention runs WITHOUT rotary embedding. The sizes
+    with experts (`num_experts` > 1 on every `expert_layer_period`-th layer)
+    are refused: the dense sizes (Jamba2-3B, Jamba Reasoning 3B) are what is
+    built."""
+    if int(hf.get("num_experts", 1)) != 1:
+        raise NotImplementedError(
+            f"JambaForCausalLM with num_experts={hf['num_experts']}: the "
+            "expert layers of the larger Jamba sizes are not built (every "
+            "MLP here is dense)"
+        )
+    if hf.get("mamba_proj_bias", False) or not hf.get("mamba_conv_bias", True):
+        raise NotImplementedError(
+            "JambaForCausalLM with mamba_proj_bias or without mamba_conv_bias "
+            "(no published size has either)"
+        )
+    if hf.get("sliding_window"):
+        raise NotImplementedError("JambaForCausalLM with a sliding window")
+    kw = _base_kwargs(hf)
+    kw["rms_norm_eps"] = float(hf.get("rms_norm_eps", 1e-6))
+    period = int(hf.get("attn_layer_period", 8))
+    offset = int(hf.get("attn_layer_offset", 4))
+    kw["layer_ops"] = tuple(
+        "attention" if i % period == offset else "mamba"
+        for i in range(kw["num_layers"])
+    )
+    kw["use_rope"] = False
+    kw["mamba_d_state"] = int(hf.get("mamba_d_state", 16))
+    kw["mamba_d_conv"] = int(hf.get("mamba_d_conv", 4))
+    kw["mamba_expand"] = int(hf.get("mamba_expand", 2))
+    rank = hf.get("mamba_dt_rank", "auto")
+    kw["mamba_dt_rank"] = None if rank in (None, "auto") else int(rank)
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
 def baichuan_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
     """BaichuanForCausalLM — Baichuan2 7B shape (reference: models/baichuan/
     model.py): llama-like MHA with a fused W_pack qkv projection (handled by

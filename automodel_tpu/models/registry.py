@@ -170,6 +170,12 @@ MODEL_ARCH_MAPPING: dict[str, ModelSpec] = {
     "OuroForCausalLM": ModelSpec(
         "ouro", families.ouro_config, decoder, adapter_kwargs={"style": "ouro"},
     ),
+    # Jamba (dense sizes): Mamba-1 mixers with attention every
+    # attn_layer_period-th layer, no positional encoding; the layers name
+    # their mixer (decoder.TransformerConfig.layer_ops)
+    "JambaForCausalLM": ModelSpec(
+        "jamba", families.jamba_config, decoder, adapter_name="jamba",
+    ),
     "BaichuanForCausalLM": ModelSpec(
         "baichuan", families.baichuan_config, decoder,
         adapter_kwargs={"style": "baichuan"},

@@ -261,11 +261,15 @@ METRIC_CATALOG = (
     ("serve_free_pages", "gauge", "KV pages currently free"),
     ("serve_compiled_signatures", "gauge", "jit cache entries for the serve step"),
     ("serve_passes", "gauge", "times the serve step walks the layer stack over a token (looped decoders: > 1)"),
-    ("serve_kv_bytes_per_token", "gauge", "KV cache bytes one token holds, every pass and layer counted"),
+    ("serve_kv_bytes_per_token", "gauge", "KV cache bytes one token holds, every pass and attention layer counted"),
+    ("serve_attn_layers", "gauge", "layers whose operator is attention over pages (pool entries a pass)"),
+    ("serve_ssm_layers", "gauge", "layers whose operator is a state-space mixer with a per-slot state (0: attention alone)"),
+    ("serve_state_bytes_per_slot", "gauge", "bytes of convolution and recurrent state one slot holds whatever its length"),
     ("serve_attn_segments", "gauge", "runs of one slot's rows in the last planned step: the paged-attention grid's segments"),
     ("serve_attn_live_blocks", "gauge", "(segment, page) blocks of the last planned step that hold a key to attend to"),
     # prefix cache
     ("serve_prefix_hits_total", "counter", "admissions that matched a cached prefix"),
+    ("serve_prefix_hits_cut_total", "counter", "admissions whose cached-prefix match was cut to none (the model holds a recurrent state)"),
     ("serve_prefill_skipped_tokens_total", "counter", "prompt tokens skipped via prefix reuse"),
     ("serve_cow_copies_total", "counter", "copy-on-write page copies"),
     # speculative decoding

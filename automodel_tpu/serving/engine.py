@@ -20,6 +20,11 @@ Layer math is shared with generate.py (project_qkv / mlp_inner / the MoE
 stack split); only attention differs — the ragged paged op from
 ops/paged_attention.py (XLA gather reference on CPU, Pallas kernel on TPU),
 with the MLA absorbed-decode algebra reproduced over the latent page pool.
+A layer that names another mixer than attention (a Mamba-1 state-space layer,
+models/llm/mamba.py) runs the ragged selective scan (ops/selective_scan.py)
+over a per-SLOT state that lives in a tree of its own beside the pool
+(`ServingEngine.state`, kv_pages.init_state; donated and aliased like the
+pool): `step(params, pool, batch, state) -> (pool, tokens, logprobs, state)`.
 The layers are walked differently too: generate.py scans stacked arrays, the
 step loops in Python over PER-LAYER buffers (split_layer_stacks below splits
 the weights once, at construction; kv_pages.init_pool makes the pool per
@@ -65,6 +70,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 import time
 
 import jax
@@ -79,10 +85,17 @@ from automodel_tpu.inference.generate import (
 from automodel_tpu.inference.sampling import filter_logits
 from automodel_tpu.models.common.layers import cast_params
 from automodel_tpu.models.llm.decoder import (
+    OPERATOR_STACKS,
     _dense,
+    layer_operators,
     layer_windows,
     project_qkv,
     unembed,
+)
+from automodel_tpu.models.llm.mamba import (
+    mamba_inputs,
+    mamba_output,
+    mamba_selection,
 )
 from automodel_tpu.ops.paged_attention import (
     ragged_paged_attention,
@@ -93,14 +106,21 @@ from automodel_tpu.ops.paged_attention import (
 from automodel_tpu.ops.norms import rms_norm
 from automodel_tpu.ops.quant import matmul as _mm, quantize_kv_rows
 from automodel_tpu.ops.rope import rope_frequencies
+from automodel_tpu.ops.selective_scan import (
+    ragged_conv,
+    ragged_selective_scan,
+    step_runs,
+)
 from automodel_tpu.observability import Observability, ObservabilityConfig
 from automodel_tpu.resilience.faults import fault_hit
 from automodel_tpu.serving.kv_pages import (
     PageAllocator,
     apply_defrag,
     init_pool,
+    init_state,
     pool_bytes,
     pool_shardings,
+    state_shardings,
 )
 from automodel_tpu.serving.prefix_cache import PrefixCache, PrefixCacheConfig
 from automodel_tpu.serving.scheduler import Request, Scheduler, StepPlan
@@ -207,8 +227,12 @@ def _resolve_ttft(watch: list) -> list:
     return still
 
 
-#: the keys of a decoder's parameter tree that hold its layers
-LAYER_STACKS = ("layers", "dense_layers", "moe_layers")
+logger = logging.getLogger(__name__)
+
+#: the keys of a decoder's parameter tree that hold its layers, and (a model
+#: whose layers name their mixer) each kind of operator's stack
+LAYER_STACKS = ("layers", "dense_layers", "moe_layers",
+                *OPERATOR_STACKS.values())
 
 
 @functools.partial(jax.jit, static_argnames=("dtype",))
@@ -275,10 +299,16 @@ def split_layer_stacks(params: dict, dtype) -> dict:
 
 class ServingEngine:
     """Paged-cache continuous-batching engine for the generic decoder
-    families (TransformerConfig / MoETransformerConfig, GQA or MLA; a looped
-    decoder's passes are walked over a pool of passes x layers entries). The
-    heterogeneous engine (HetMoEConfig: layers of unlike geometry, sparse
-    index caches) is not servable here."""
+    families (TransformerConfig / MoETransformerConfig): GQA or MLA attention
+    over pages; a looped decoder's passes walked over a pool of passes x
+    layers entries; layers that name a state-space mixer instead of attention
+    (`cfg.layer_ops`), whose convolution and recurrent state live per SLOT in
+    `self.state` beside the pool. What begins a request at a position other
+    than 0 without having run the rows before it has no state to begin from:
+    for a model that holds state prefix hits are cut to none (counted),
+    speculation and a hand-off between pools are refused by name. The
+    heterogeneous engine (HetMoEConfig: attention layers of unlike geometry,
+    sparse index caches) is not servable here."""
 
     def __init__(
         self,
@@ -306,10 +336,11 @@ class ServingEngine:
 
         if isinstance(cfg, HetMoEConfig):
             raise NotImplementedError(
-                "ServingEngine serves the decoders whose layers share one "
-                "geometry (TransformerConfig / MoETransformerConfig); the het "
-                "engine's unlike layers and index caches need a step function "
-                "and a pool of their own"
+                "ServingEngine serves TransformerConfig / MoETransformerConfig "
+                "decoders: attention layers of ONE geometry over one page "
+                "pool, beside them layers whose state lives per slot; the het "
+                "engine's attention layers of unlike geometry and its index "
+                "caches need a step function and a pool of their own"
             )
         # serve-step linear precision: all decoder/generate linears already
         # route through ops/quant.matmul(x, kernel, cfg.linear_precision),
@@ -335,6 +366,15 @@ class ServingEngine:
         self.track = track
         self.is_moe = getattr(cfg, "moe", None) is not None
         self.is_mla = cfg.attention_type == "mla"
+        # some layer carries a state from token to token (kv_pages.init_state)
+        self.holds_state = cfg.holds_state
+        spec = serve_cfg.speculative
+        if self.holds_state and spec is not None and spec.enabled:
+            raise NotImplementedError(
+                "speculative decoding over a model that holds a recurrent "
+                "state: a rejected draft would have to roll the state back, "
+                "and no snapshot of it is kept"
+            )
         # rows of the paged kernels' q tile, from the step's rows and the
         # elements of one row's attention queries and outputs
         self._attn_row_tile = row_tile(serve_cfg.token_budget, cfg.num_heads * (
@@ -386,6 +426,19 @@ class ServingEngine:
                 ("layers", _dense_mlp, len(self.params["layers"]))
             ]
 
+        # per stack and layer (kind, index into that kind's operator stack),
+        # the index None where attention's weights are the layer's own
+        ops = layer_operators(cfg)
+        self._stack_ops = [
+            ops if ops is not None else (("attention", None),) * L
+            for *_, L in self._stacks
+        ]
+        # pool entries a pass: one per attention layer of each stack
+        self._stack_attn = [
+            sum(kind == "attention" for kind, _ in ops)
+            for ops in self._stack_ops
+        ]
+
         n_layers = sum(L for *_, L in self._stacks)
         windows = [w or 0 for w in layer_windows(cfg, n_layers)]
         self._stack_windows = []
@@ -400,9 +453,10 @@ class ServingEngine:
         # the op's dispatch rule (ops/paged_attention.py) then runs the XLA
         # reference for the whole model, as it does for sinks
         self._any_window = any(windows)
+        # a model without rotary embedding (cfg.use_rope False) has no table
         self._inv_freq = rope_frequencies(
             cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
-        )
+        ) if cfg.use_rope else None
         if cfg.rope_local_theta is not None:
             inv_local = rope_frequencies(cfg.rope_dim, cfg.rope_local_theta, None)
             self._freq_for_win = lambda win: jnp.where(
@@ -412,15 +466,20 @@ class ServingEngine:
             self._freq_for_win = lambda win: self._inv_freq
 
         self.pool = init_pool(
-            cfg, [L for *_, L in self._stacks],
+            cfg, self._stack_attn,
             serve_cfg.num_pages, serve_cfg.page_size,
             mesh_ctx=self._mesh, kv_cache_dtype=serve_cfg.kv_cache_dtype,
         )
         # the pool's shardings, in the pool's own structure (mesh only)
         self._pool_shardings = None if self._mesh is None else pool_shardings(
-            cfg, [L for *_, L in self._stacks], self._mesh,
+            cfg, self._stack_attn, self._mesh,
             serve_cfg.kv_cache_dtype,
         )
+        # what the layers that are not attention carry per SLOT: a tree of
+        # its own, so that nothing which maps over the pool's page axis
+        # (copy-on-write, defrag, transfer) ever sees a slot-axis array;
+        # `()` for a decoder of attention alone
+        self.state = init_state(cfg, serve_cfg.max_slots, self._mesh)
         # ENGINE-LIFETIME prefix cache (SGLang-RadixAttention-style): with
         # the cache enabled, the refcounted allocator and the radix tree
         # are created ONCE here and threaded through every scheduler this
@@ -430,6 +489,12 @@ class ServingEngine:
         # each scheduler keeps its private throwaway allocator (per-call
         # semantics exactly as before).
         pc = serve_cfg.prefix_cache
+        if self.holds_state and pc is not None and pc.enabled:
+            logger.warning(
+                "prefix cache over a model that holds a recurrent state: every "
+                "hit is CUT (serve_prefix_hits_cut_total counts them): adopted "
+                "pages would skip rows whose state nobody kept"
+            )
         if pc is not None and pc.enabled:
             self.alloc = PageAllocator(serve_cfg.num_pages, serve_cfg.page_size)
             self.prefix = PrefixCache(self.alloc, serve_cfg.page_size, pc)
@@ -440,7 +505,6 @@ class ServingEngine:
         # plain engines each compile exactly one step program (the plain
         # program is byte-identical to the non-speculative engine's, so
         # the paged_serve_step HLO baseline is untouched)
-        spec = serve_cfg.speculative
         self._spec = spec if (spec is not None and spec.enabled) else None
         self._draft_source = None
         if self._spec is not None:
@@ -457,8 +521,15 @@ class ServingEngine:
             pool_bytes(self.pool)
             // ((serve_cfg.num_pages + 1) * serve_cfg.page_size)
         )
+        # and what a slot costs whatever its length (0: attention alone)
+        reg.gauge("serve_attn_layers").set(sum(self._stack_attn))
+        reg.gauge("serve_ssm_layers").set(len(self.state))
+        reg.gauge("serve_state_bytes_per_slot").set(
+            pool_bytes(self.state) // (serve_cfg.max_slots + 1)
+        )
+        # the pool and the state are donated: both are written in place
         if self._mesh is None:
-            self._step = jax.jit(self._step_impl, donate_argnums=(1,))
+            self._step = jax.jit(self._step_impl, donate_argnums=(1, 3))
         else:
             # explicit in/out shardings: jit normalizes sharding specs on
             # its outputs (trailing/size-1 axes dropped), so without a
@@ -478,14 +549,19 @@ class ServingEngine:
                 out_sh.append(rep)
                 if self._needs_hidden in ("frontier", "rows"):
                     out_sh.append(rep)
+            in_sh: list = [
+                jax.tree.map(lambda p: p.sharding, self.params),
+                psh,
+                {k: rep for k in batch_keys},
+            ]
+            if self.state:
+                ssh = state_shardings(cfg, self._mesh)
+                in_sh.append(ssh)
+                out_sh.append(ssh)
             self._step = jax.jit(
                 self._step_impl,
-                donate_argnums=(1,),
-                in_shardings=(
-                    jax.tree.map(lambda p: p.sharding, self.params),
-                    psh,
-                    {k: rep for k in batch_keys},
-                ),
+                donate_argnums=(1, 3),
+                in_shardings=tuple(in_sh),
                 out_shardings=tuple(out_sh),
             )
         self.steps_run = 0
@@ -505,6 +581,12 @@ class ServingEngine:
                     "ReplicaRouter for data parallelism"
                 )
         tp, ep = sizes["tp"], sizes["ep"]
+        if tp > 1 and cfg.holds_state:
+            raise ValueError(
+                f"tp={tp} over a model that holds a recurrent state: the "
+                "per-slot state and the scan over it are not partitioned; "
+                "serve it on tp=1 and replicate engines behind a ReplicaRouter"
+            )
         if tp > 1:
             if cfg.attention_type == "mla":
                 if cfg.mla_kv_lora_rank % tp:
@@ -724,7 +806,35 @@ class ServingEngine:
             return h + attn_out, (pool_k, pool_v, s_k, s_v)
         return h + attn_out, (pool_k, pool_v)
 
-    def _step_impl(self, params, pool, b):
+    def _ssm(self, h, lp, cache, b):
+        """One state-space mixer (models/llm/mamba.py) over the step's ragged
+        rows; `cache` is the layer's `(conv, ssm)` per-slot state. Each run
+        of one slot's rows continues what the slot carried in, or starts
+        from zeros at position 0 (ops/selective_scan.py). Returns
+        (post-residual h, written state). h is (1, T, H)."""
+        cfg = self.cfg
+        conv_state, ssm_state = cache
+        with jax.named_scope("serve.ssm.proj"):
+            x = rms_norm(h[0], lp["input_norm"]["scale"], cfg.rms_norm_eps,
+                         cfg.zero_centered_norm)
+            u, z = mamba_inputs(x, lp, cfg)
+        with jax.named_scope("serve.ssm.conv"):
+            u, conv_state = ragged_conv(
+                u, lp["conv"]["kernel"], lp["conv"]["bias"], conv_state,
+                b["pos"], b["runs"],
+            )
+            u = jax.nn.silu(u).astype(h.dtype)
+        with jax.named_scope("serve.ssm.proj"):
+            delta, sel_b, sel_c, a = mamba_selection(u, lp, cfg)
+        with jax.named_scope("serve.ssm.scan"):
+            y, ssm_state = ragged_selective_scan(
+                u, delta, a, sel_b, sel_c, ssm_state, b["runs"]
+            )
+        with jax.named_scope("serve.ssm.proj"):
+            out = mamba_output(y, u, z, lp, cfg)
+        return h + out[None], (conv_state, ssm_state)
+
+    def _step_impl(self, params, pool, b, state=()):
         cfg = self.cfg
         # The layers are walked in a Python loop at trace time over
         # per-layer buffers: each layer's weights (split_layer_stacks) and
@@ -751,6 +861,12 @@ class ServingEngine:
             page_size=self.serve_cfg.page_size, tile=self._attn_row_tile,
             max_slots=self.serve_cfg.max_slots,
         )
+        if state:
+            # the rows grouped into runs of one slot at consecutive positions,
+            # once for every state-space layer of the step, on every backend
+            b["runs"] = step_runs(
+                b["slot"], b["pos"], trash=self.serve_cfg.max_slots
+            )
         # copy-on-write splits first (≤ 1 per slot; idle entries copy the
         # trash page onto itself): a slot about to append into a page some
         # other table or the radix tree still reads gets a private copy
@@ -772,32 +888,50 @@ class ServingEngine:
         # passes; the last pass's norm is the head's. The exit gate is not
         # computed here: while every token runs every pass (the only case
         # built) it cannot change a logit.
+        #
+        # A layer's operator is attention over its pool entry or (a model
+        # whose layers name their mixer) a state-space mixer over its entry
+        # of `state`; the operator's weights then come from its kind's stack.
         new_pool = [[] for _ in self._stacks]
+        new_state = list(state)
         for t in range(cfg.num_passes):
             with jax.named_scope("serve.layers"), \
                     jax.named_scope(f"serve.pass{t}"):
-                for (pkey, mlp_fn, L), stack, wins, new_stack in zip(
-                    self._stacks, pool, self._stack_windows, new_pool
+                for (pkey, mlp_fn, L), stack, wins, new_stack, ops, A in zip(
+                    self._stacks, pool, self._stack_windows, new_pool,
+                    self._stack_ops, self._stack_attn,
                 ):
                     mlp_scope = (
                         "serve.mlp" if mlp_fn is _dense_mlp else "serve.moe"
                     )
-                    for lp, cache, win in zip(
-                        params[pkey], stack[t * L:(t + 1) * L], wins
-                    ):
-                        with jax.named_scope("serve.attn"):
-                            h, cache = self._attn(h, lp, win, cache, b)
+                    caches = iter(stack[t * A:(t + 1) * A])
+                    for lp, win, (kind, j) in zip(params[pkey], wins, ops):
+                        if j is not None:
+                            lp = {**lp, **params[OPERATOR_STACKS[kind]][j]}
+                        if kind == "attention":
+                            with jax.named_scope("serve.attn"):
+                                h, cache = self._attn(
+                                    h, lp, win, next(caches), b
+                                )
+                            new_stack.append(cache)
+                        else:
+                            with jax.named_scope("serve.ssm"):
+                                h, new_state[j] = self._ssm(
+                                    h, lp, state[j], b
+                                )
                         with jax.named_scope(mlp_scope):
                             h = mlp_fn(h, lp, cfg)
                         h = self._constrain_rep(h)
-                        new_stack.append(cache)
                 if t + 1 < cfg.num_passes:
                     h = rms_norm(h, params["final_norm"]["scale"],
                                  cfg.rms_norm_eps, cfg.zero_centered_norm)
         new_pool = self._constrain_pool([tuple(st) for st in new_pool])
 
         with jax.named_scope("serve.head"):
-            return self._head(params, new_pool, h, b)
+            out = self._head(params, new_pool, h, b)
+        # the state last, where there is one: a decoder of attention alone
+        # lowers to the program it always was
+        return out + (tuple(new_state),) if state else out
 
     def _head(self, params, new_pool, h, b):
         """Final norm, unembed of the sample rows, sampling, log-softmax."""
@@ -985,13 +1119,21 @@ class ServingEngine:
             with span("step.dispatch"):
                 if self.serve_cfg.guard_transfers:
                     with jax.transfer_guard("disallow"):
-                        out = self._step(self.params, self.pool, batch)
+                        out = self._step(*self._step_args(batch))
                 else:
-                    out = self._step(self.params, self.pool, batch)
+                    out = self._step(*self._step_args(batch))
             self.pool = out[0]
+            if self.state:
+                *out, self.state = out
             self.steps_run += 1
             with span("step.readback"):
                 return tuple(np.asarray(x) for x in out[1:])
+
+    def _step_args(self, batch: dict) -> tuple:
+        """The jitted step's arguments: the per-slot state last, where the
+        model holds one."""
+        args = (self.params, self.pool, batch)
+        return args + (self.state,) if self.state else args
 
     def empty_plan(self) -> StepPlan:
         """A zero-work StepPlan with the engine's fixed shapes — shape
@@ -1023,7 +1165,7 @@ class ServingEngine:
         does NOT populate the jit call cache, so `step_cache_size()` —
         the compile-once contract — is unaffected."""
         batch = self._plan_batch(plan if plan is not None else self.empty_plan())
-        return self._step.lower(self.params, self.pool, batch)
+        return self._step.lower(*self._step_args(batch))
 
     def run_and_absorb(
         self, sched: Scheduler, plan: StepPlan, step_idx: int,
@@ -1072,7 +1214,9 @@ class ServingEngine:
         engine's track and under the number `run_step` will stamp on the
         turn's `step.run`. The span says what the turn did to the pool and
         what grid the step's attention walks (`Scheduler.turn_stats`; the
-        grid's two also on /metrics) and, where a step was planned, its
+        grid's two also on /metrics; `state_runs`, the runs of one slot's
+        rows that a state-space layer's scan continues or starts, where the
+        model holds state) and, where a step was planned, its
         `rows` / `samples`. None when nothing could be packed: the CALLER
         decides whether to fast-forward, sleep or shed. Not held across
         an `await`."""
@@ -1112,6 +1256,7 @@ class ServingEngine:
             ("serve_preemptions_total", "preemptions"),
             ("serve_timed_out_total", "timed_out"),
             ("serve_prefix_hits_total", "prefix_hits"),
+            ("serve_prefix_hits_cut_total", "prefix_hits_cut"),
             ("serve_prefill_skipped_tokens_total", "prefill_skipped_tokens"),
             ("serve_cow_copies_total", "cow_copies"),
             ("serve_spec_drafted_total", "drafted_tokens"),
@@ -1145,6 +1290,7 @@ class ServingEngine:
             arrival_gating=arrival_gating,
             tracer=self.obs.tracer, track=self.track,
             attn_row_tile=self._attn_row_tile,
+            carries_state=self.holds_state,
         )
 
     def reset_prefix_cache(self) -> int:
@@ -1317,6 +1463,7 @@ class ServingEngine:
         if sched.prefix is not None:
             stats.update({
                 "prefix_hits": sched.n_prefix_hits,
+                "prefix_hits_cut": sched.n_prefix_hits_cut,
                 "prefill_skipped_tokens": sched.prefill_skipped,
                 "cow_copies": sched.n_cow,
                 "prefix_cached_pages": sched.prefix.cached_pages,

@@ -53,6 +53,21 @@ so every integer in this file — page IDs, tables, refcounts, defrag
 plans — is mesh-oblivious and admission/COW/preemption/prefix-sharing
 compose with sharding unchanged.
 
+Layers that are not attention (`cfg.layer_ops`: a state-space mixer) keep no
+pages. What such a layer carries from token to token does not grow with the
+sequence, so its handle is the SLOT, not the page: `init_state` below makes,
+per such layer, `(conv (K-1, S+1, C), ssm (S+1, N, C) float32)`, S =
+`max_slots`, slot index S the trash slot that pad rows use. The state is a
+TREE OF ITS OWN beside the pool (`ServingEngine.state`), donated to the step
+and aliased to its output like the pool, so no page-axis operation here or
+elsewhere (the step's copy-on-write block, `apply_defrag`, `kv_transfer`,
+the int8 planes, `pool_shardings`) can index a slot-axis array: they
+tree-map over the pool and the state is not in it. The pool of such a model
+holds entries for its attention layers alone. A state per PAGE (a snapshot
+at every page's end, which prefix hits and hand-offs could adopt) would cost
+the whole state per page: for a model whose state is megabytes and whose
+keys and values are a kilobyte a token, nearly all of the pool.
+
 The allocator is deliberately host-side pure-python: page churn is a few
 integer ops per request per step, nothing a device roundtrip could beat.
 `defrag()` exists for pool COMPACTION (paged allocation never fragments in
@@ -355,6 +370,46 @@ def init_pool(
             pool, pool_shardings(cfg, stack_layers, mesh_ctx, kv_cache_dtype)
         )
     return pool
+
+
+def state_shapes(cfg, max_slots: int) -> tuple:
+    """One state layer's (conv, ssm) ShapeDtypeStructs. The convolution's
+    carried inputs in the model's dtype with the slot axis second (K-1 = 3
+    rows would pad to a whole tile of 16 if they were the second-minor
+    axis); the recurrent state in float32 whatever the model's dtype (the
+    family's kernels accumulate it so; rounded to bf16 at every token its
+    error compounds), (N, C) with the channels the vector axis."""
+    C, N, K = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
+    return (
+        jax.ShapeDtypeStruct((K - 1, max_slots + 1, C), cfg.dtype),
+        jax.ShapeDtypeStruct((max_slots + 1, N, C), jnp.float32),
+    )
+
+
+def num_state_layers(cfg) -> int:
+    return sum(op != "attention" for op in cfg.layer_ops or ())
+
+
+def state_shardings(cfg, mesh_ctx):
+    """NamedShardings matching `init_state`: replicated (an engine whose
+    model holds state refuses tp > 1, see ServingEngine._validate_mesh)."""
+    layer = (mesh_ctx.sharding(None, None, None),) * 2
+    return (layer,) * num_state_layers(cfg)
+
+
+def init_state(cfg, max_slots: int, mesh_ctx=None) -> tuple:
+    """The per-slot state of a decoder: one `(conv, ssm)` pair per layer
+    that is not attention, in layer order; `()` for a decoder of attention
+    alone. Zeros, though nothing reads them: a run that starts at position 0
+    starts from zeros by a select (ops/selective_scan.py), so a slot is never
+    reset, whoever held it before."""
+    state = tuple(
+        tuple(jnp.zeros(s.shape, s.dtype) for s in state_shapes(cfg, max_slots))
+        for _ in range(num_state_layers(cfg))
+    )
+    if mesh_ctx is not None and state:
+        state = jax.device_put(state, state_shardings(cfg, mesh_ctx))
+    return state
 
 
 def pool_bytes(pool) -> int:

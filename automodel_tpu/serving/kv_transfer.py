@@ -69,6 +69,20 @@ def _scatter_pages(dst_pool, rows, dst_idx):
     return jax.tree.map(lambda d, r: d.at[dst_idx].set(r), dst_pool, rows)
 
 
+def refuse_state_handoff(cfg) -> None:
+    """A hand-off between pools moves PAGES. A model that also carries a
+    per-slot recurrent state (kv_pages.init_state) would arrive without it
+    and decode from a state of zeros: refused by name until the hand-off
+    carries the state."""
+    if getattr(cfg, "holds_state", False):
+        raise NotImplementedError(
+            "a hand-off between pools (kv_transfer / DisaggRouter) for a "
+            "model that holds a recurrent state: the pages would move and "
+            "the per-slot state would not; serve it from one pool (a "
+            "ServingEngine, or ReplicaRouter over whole engines)"
+        )
+
+
 class KVTransfer:
     """Page mover from one engine's pool to another's.
 
@@ -87,6 +101,7 @@ class KVTransfer:
             )
         if batch_pages < 1:
             raise ValueError(f"batch_pages must be >= 1, got {batch_pages}")
+        refuse_state_handoff(src_engine.cfg)
         self.src = src_engine
         self.dst = dst_engine
         self.batch_pages = int(batch_pages)

@@ -52,7 +52,7 @@ from automodel_tpu.serving.frontend import (
     OnlineFrontend,
     TokenStream,
 )
-from automodel_tpu.serving.kv_transfer import KVTransfer
+from automodel_tpu.serving.kv_transfer import KVTransfer, refuse_state_handoff
 from automodel_tpu.serving.resilience import (
     HealthBoard,
     ReplicaFailure,
@@ -618,6 +618,8 @@ class DisaggRouter:
         draft_source_factory=None,
         resilience: ServeResilienceConfig | None = None,
     ):
+        # before any engine is built: the hand-off moves pages, not state
+        refuse_state_handoff(cfg)
         self.disagg = disagg
         self.resilience = resilience or ServeResilienceConfig()
         n_p, n_d = disagg.prefill_replicas, disagg.decode_replicas

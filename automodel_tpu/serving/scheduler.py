@@ -108,6 +108,7 @@ class Request:
     finish_reason: str | None = None
     prefix_hit_tokens: int = 0  # prefill tokens skipped via the radix cache
     donated_pages: int = 0      # full pages already offered to the tree
+    prefix_cut: bool = False    # a radix hit was cut (the model holds state)
     # wall-clock latency stamps (serve-loop-owned — the loop is the only
     # layer that knows when a step's arrival window actually opened):
     # ttft_s stays -1 for requests that never committed a token
@@ -191,6 +192,7 @@ class Scheduler:
         tracer=None,             # observability.trace.Tracer (None → no-op)
         track: str = "engine",
         attn_row_tile: int | None = None,  # the paged kernels' q tile, rows
+        carries_state: bool = False,  # some layer's state lives per slot
     ):
         # lifecycle tracing (observability/trace.py): the null tracer makes
         # every emit a constant-time no-op, so the untraced hot path is
@@ -253,6 +255,12 @@ class Scheduler:
         self.n_cancelled = 0
         self.n_cow = 0
         self.n_prefix_hits = 0        # admissions that adopted cached pages
+        # The model carries a per-slot recurrent state (serving/kv_pages.py
+        # init_state). A request may then begin only at position 0: adopted
+        # pages would skip rows whose state nobody kept, so every prefix hit
+        # is CUT to none and counted here.
+        self.carries_state = carries_state
+        self.n_prefix_hits_cut = 0    # admissions whose radix hit was cut
         self.prefill_skipped = 0      # prompt tokens never re-prefilled
         # speculative-decoding counters
         self.n_drafted = 0            # provisional tokens fed for scoring
@@ -316,13 +324,20 @@ class Scheduler:
             live_blocks = int(
                 (plan.pos[is_last] // self.page_size + 1).sum()
             )
-        return {
+        stats = {
             "free_pages": self.alloc.num_free,
             "resident": len(self.running),
             "preempted": self.n_preemptions - preemptions_before,
             "attn_segments": segments,
             "attn_live_blocks": live_blocks,
         }
+        if self.carries_state:
+            # runs of one slot's rows at consecutive positions: what each
+            # state-space layer's scan starts or continues this turn
+            stats["state_runs"] = 0 if plan is None else int(
+                segment_bounds(np, plan.slot, plan.pos, len(plan.slot))[0].sum()
+            )
+        return stats
 
     def prefix_hit_tokens(self, tokens: list) -> int:
         """Radix-affinity probe: how many of `tokens` this scheduler's
@@ -332,14 +347,20 @@ class Scheduler:
         never dilutes the cache. Strictly READ-ONLY (no LRU tick): every
         replica is probed per request, and warming the losers' trees
         would let probe-only pages outlive genuinely served ones."""
-        if self.prefix is None:
+        if self.prefix is None or self.carries_state:
             return 0
         return self.prefix.peek_match_tokens(list(tokens))
 
     def _match(self, req: Request) -> PrefixMatch:
+        none = PrefixMatch(pages=[], fed=0, matched_tokens=0, cow_pending=False)
         if self.prefix is None:
-            return PrefixMatch(pages=[], fed=0, matched_tokens=0,
-                               cow_pending=False)
+            return none
+        if self.carries_state:
+            # read-only probe: what a model without state would have adopted
+            if not req.prefix_cut and self.prefix.peek_match_tokens(req.known):
+                req.prefix_cut = True   # once a request, however often probed
+                self.n_prefix_hits_cut += 1
+            return none
         return self.prefix.lookup(req.known)
 
     def _need(self, req: Request, match: PrefixMatch) -> int:

@@ -42,6 +42,10 @@ PARITY_TESTS = {
     # benchmark decides `correct` with (benchmark/reference/ouro.py)
     "ouro": ("test_ouro_model.py",
              "test_forward_matches_the_reference_logits_and_gates"),
+    # against the family's published modelling code as the plain reference
+    # writes it out (benchmark/reference/jamba.py), the head tied
+    "jamba": ("test_jamba.py",
+              "test_forward_matches_reference_with_the_tied_head"),
 }
 
 #: Known gaps — families with functional tests (adapter roundtrips, recipe

@@ -69,6 +69,7 @@ WIDTHS = {
     "ouro_2_6b: no grouping": (48, 16, 16, 128, 84, 64, 10, 24),
     "grouped 4:1": (48, 32, 8, 128, 84, 64, 10, 24),
     "one key/value head": (48, 8, 1, 128, 84, 64, 10, 24),
+    "jamba2_3b: 20 heads over one": (256, 20, 1, 128, 3072, 64, 24, 128),
 }
 
 
@@ -99,7 +100,7 @@ def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths,
     pages = s((N + 1, ps, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
     tables, pos = s((T, P), jnp.int32), s((T,), jnp.int32)
     tile, most, seg, segments = _segments(T, S, P, 2 * Hq * D, s)
-    assert (tile, most) == (32, 25)
+    assert (tile, most) == (32, min(T, S + T // 32))
     if quant:
         scales = s((N + 1, ps), jnp.float32)
         fn = lambda q, k, v, ks, vs, pt, pos, *seg: tpu_branch.paged_attention_quant_kernel(  # noqa: E731
@@ -194,6 +195,35 @@ def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
     assert lowered.as_text().count("paged_attention_gqa") == (
         cfg.num_passes * cfg.num_layers) == 12
     lowered.compile()
+
+
+def test_state_space_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
+    """AI21-Jamba2-3B's serve step at its cell's geometry, from shapes alone
+    (no weight is allocated): all 28 layers, 26 of them a scan over the
+    step's 256 rows beside the two paged calls, compiled for the described
+    chip in ONE program whose arguments are the 6.39 GB of weights, the
+    1.20 GB of per-slot state and the 0.20 GB pool, the last two donated and
+    aliased to the outputs."""
+    import json
+
+    from tests.step_shapes import engine_of_shapes
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "jamba2_3b_serve_v5e1.json")) as f:
+        config = json.load(f)
+    eng, args = engine_of_shapes(config, config["serving"], one_chip)
+    assert eng.cfg.layer_ops.count("mamba") == 26 and len(args[3]) == 26
+    lowered = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args)
+    assert lowered.as_text().count("paged_attention_gqa") == 2
+    mem = lowered.compile().memory_analysis()
+    state = 26 * 129 * (16 * 5120 * 4 + 3 * 5120 * 2)
+    pool = 2 * 2 * 3073 * 64 * 128 * 2
+    assert mem.alias_size_in_bytes >= state + pool
+    # nothing is padded: a (5120, 16) state would take eight times its bytes
+    assert mem.argument_size_in_bytes < 7.9e9
+    assert mem.temp_size_in_bytes < 1.5e9
 
 
 def test_grouped_matmul_compiles_inside_a_shard_map_over_four_chips(
