@@ -320,6 +320,9 @@ METRIC_CATALOG = (
     # the routed experts' grouped matmul (ops/grouped_matmul.py; trace time,
     # process-global registry)
     ("grouped_matmul_calls_total", "counter", "traced grouped-matmul call sites (labeled by impl=pallas|xla and reason)"),
+    # a serve step's ragged selective scan (ops/selective_scan.py; trace
+    # time, process-global registry)
+    ("selective_scan_calls_total", "counter", "traced ragged-selective-scan call sites (labeled by impl=pallas|xla and reason)"),
 )
 
 #: Process-global registry for components without an engine in hand

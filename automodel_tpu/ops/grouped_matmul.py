@@ -85,33 +85,12 @@ def _unsupported_reason(lhs, rhs, sharded: bool) -> str | None:
 
 def _resolve(impl: str, unsupported: str | None) -> str:
     """"pallas" or "xla" for this call site, counted."""
-    if impl == "pallas":
-        if unsupported is not None:
-            raise NotImplementedError(
-                f"grouped_matmul: impl='pallas' cannot be honoured: "
-                f"{unsupported}")
-        choice, reason = "pallas", "requested"
-    elif impl == "xla":
-        choice, reason = "xla", "requested"
-    elif impl != "auto":
-        raise ValueError(f"Unknown grouped_matmul impl '{impl}'")
-    elif not _on_tpu():
-        choice, reason = "xla", "no TPU"
-    elif unsupported is not None:
-        choice, reason = "xla", unsupported
-    else:
-        choice, reason = "pallas", "bf16 call on a TPU"
-    from automodel_tpu.observability.metrics import default_registry
+    from automodel_tpu.ops.dispatch import resolve_counted
 
-    counter = default_registry().counter(
-        "grouped_matmul_calls_total", impl=choice, reason=reason)
-    if impl == "auto" and unsupported is not None and _on_tpu() \
-            and counter.value == 0:
-        logger.warning(
-            "grouped_matmul: impl='auto' runs lax.ragged_dot on this TPU: %s",
-            unsupported)
-    counter.inc()
-    return choice
+    return resolve_counted(
+        "grouped_matmul", impl, unsupported, on_tpu=_on_tpu(),
+        counter="grouped_matmul_calls_total", taken="bf16 call on a TPU",
+        reference="lax.ragged_dot", logger=logger)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

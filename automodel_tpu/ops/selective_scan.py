@@ -30,26 +30,59 @@ Entry points:
   index of the slot axis. The runs are derived inside the step from `slot`
   and `pos` alone, on every backend.
 
-The form the step takes is XLA's: the convolution and everything per row is
-batched over the rows; the recurrence is one `lax.scan` over the rows that
-carries ONE (N, C) state, reads a slot's state where a run starts and writes
-it where a run ends (both dynamic slices of the donated state array, in
-place), `ROW_UNROLL` rows a trip. An associative scan over (rows, N, C)
-operands would move tens of gigabytes a step. Each slot has at most ONE run
-a step (the scheduler packs a slot's rows together; speculation, which
-would add more, is refused for such models): the convolution reads every
-slot's carried inputs once, before any run writes.
+Two backends of the recurrence behind one rule (`ragged_selective_scan`),
+built like `ops/grouped_matmul.py` and `ops/paged_attention.py`; the
+convolution and everything per row is batched over the rows on both:
+
+- the XLA form (the reference): off the TPU, and on it for a call the
+  kernel does not take. One `lax.scan` over the rows that carries ONE
+  (N, C) state, reads a slot's state where a run starts and writes it where
+  a run ends (both dynamic slices of the donated state array, in place),
+  `ROW_UNROLL` rows a trip. Every CPU test lowers through it. (An
+  associative scan over (rows, N, C) operands would move tens of gigabytes
+  a step.)
+- the Pallas TPU kernel (`ops/pallas/selective_scan.py`), whose unit of
+  work is a RUN: a run's state is fetched once from its slot, held in VMEM
+  for the run's rows and written back once, the state array aliased in
+  place. The rule (`_unsupported_reason`) reads the call alone: the state's
+  dtype, whether its widths tile the chip's registers and fit its VMEM, and
+  whether GSPMD may shard the operands; it has no clause on the rows or the
+  runs' lengths (on the chip the kernel won on a decode-only step, a
+  prefill-heavy one and the mix between: PERF.md section 6, PR 35).
+
+Every traced call site ticks `selective_scan_calls_total{impl, reason}` on
+the process registry (trace time: once a compiled call site, not once a
+step), and the first call a TPU hands to the reference is logged.
+
+Each slot has at most ONE run a step (the scheduler packs a slot's rows
+together; speculation, which would add more, is refused for such models):
+the convolution reads every slot's carried inputs once, before any run
+writes, and the kernel fetches a run's state while the run before computes.
 """
 
 from __future__ import annotations
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
+logger = logging.getLogger(__name__)
+
 F32 = jnp.float32
-#: rows a trip of the step's scan: on a v5e one layer's call over 256 rows took
-#: 2.26 ms at 1, 1.34 at 4, 1.44 at 8 (PR 34's chip runs, CHANGES.md)
+#: rows a trip of the XLA form's scan, the reference that serves off the TPU
+#: and what the kernel does not take: on a v5e one layer's call over 256 rows
+#: took 2.26 ms at 1, 1.34 at 4, 1.44 at 8 (PR 34's chip runs, CHANGES.md).
+#: The Pallas kernel has no such knob: it walks a run's rows one by one on
+#: a state that stays in VMEM
 ROW_UNROLL = 4
+#: VMEM the kernel's resident blocks may take (the rows' inputs and outputs
+#: of one channel block, twice buffered): the chip has 128 MiB
+_VMEM_BYTES = 64 * 2**20
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
 
 
 def ssm_update(h, delta_t, u_t, b_t, c_t, a):
@@ -155,11 +188,72 @@ def ragged_conv(x, kernel, bias, conv_state, pos, runs):
     return out, new_state
 
 
-def ragged_selective_scan(u, delta, a, b, c, ssm_state, runs):
-    """The recurrence over a step's rows in plan order. u, delta (T, C)
-    float32; b, c (T, N); a (N, C); ssm_state (S+1, N, C) float32, donated by
-    the step and updated in place. Returns (y (T, C) float32, new state)."""
-    a = a.astype(F32)
+def channel_block(T: int, C: int, N: int) -> int:
+    """Channels of the kernel's block for a call's shapes: all of C where
+    the rows' resident blocks fit `_VMEM_BYTES`, else the widest multiple
+    of 128 lanes dividing C that does; 0 where none does."""
+    from automodel_tpu.ops.pallas.selective_scan import vmem_bytes
+
+    for parts in range(1, C // 128 + 1):
+        cb = C // parts
+        if C % parts == 0 and cb % 128 == 0 \
+                and vmem_bytes(T, N, cb) <= _VMEM_BYTES:
+            return cb
+    return 0
+
+
+def _unsupported_reason(delta, ssm_state, sharded: bool) -> str | None:
+    """Why the Pallas kernel does not take this call, or None."""
+    (T, C), N = delta.shape, ssm_state.shape[1]
+    if sharded:
+        # a Mosaic call has no partitioning rule: GSPMD would gather it
+        return "operands sharded under GSPMD"
+    if ssm_state.dtype != F32:
+        return f"state {ssm_state.dtype}, not float32"
+    if C % 128 or N % 8:
+        return f"C={C}, N={N} not multiples of 128 lanes and 8 sublanes"
+    if not channel_block(T, C, N):
+        return f"T={T}: the rows of one 128-lane block pass {_VMEM_BYTES} bytes"
+    return None
+
+
+def _resolve(impl: str, unsupported: str | None) -> str:
+    """"pallas" or "xla" for this call site, counted."""
+    from automodel_tpu.ops.dispatch import resolve_counted
+
+    return resolve_counted(
+        "ragged_selective_scan", impl, unsupported, on_tpu=_on_tpu(),
+        counter="selective_scan_calls_total", taken="float32 state on a TPU",
+        reference="the lax.scan", logger=logger)
+
+
+def ragged_selective_scan(u, delta, a, b, c, ssm_state, runs, *,
+                          impl: str = "auto", mesh_ctx=None):
+    """The recurrence over a step's rows in plan order. u, delta (T, C);
+    b, c (T, N); a (N, C); ssm_state (S+1, N, C) float32, donated by the
+    step and updated in place. Returns (y (T, C) float32, new state).
+
+    impl: "xla" | "pallas" | "auto" (the kernel on a TPU where the call
+    qualifies). `mesh_ctx` is the caller's when its operands may be sharded
+    by GSPMD (more than one device): the reference then serves it."""
+    a, delta = a.astype(F32), delta.astype(F32)
+    sharded = mesh_ctx is not None and mesh_ctx.num_devices > 1
+    unsupported = _unsupported_reason(delta, ssm_state, sharded)
+    if _resolve(impl, unsupported) == "pallas":
+        from automodel_tpu.ops.pallas.selective_scan import (
+            selective_scan_kernel,
+        )
+
+        return selective_scan_kernel(
+            delta, delta * u.astype(F32), a, b.astype(F32), c.astype(F32),
+            ssm_state, runs,
+            cb=channel_block(*delta.shape, ssm_state.shape[1]))
+    return _ragged_scan_xla(u, delta, a, b, c, ssm_state, runs)
+
+
+def _ragged_scan_xla(u, delta, a, b, c, ssm_state, runs):
+    """The reference: one `lax.scan` over the rows, `ROW_UNROLL` a trip.
+    The recurrence is float32 whatever the state array's dtype."""
     # a run that begins at position 0 (and a pad row) starts from zeros
     fresh = runs["start"] & (runs["first_pos"] <= 0)
     carried = runs["start"] & ~fresh
@@ -169,13 +263,14 @@ def ragged_selective_scan(u, delta, a, b, c, ssm_state, runs):
         delta_t, u_t, b_t, c_t, fresh_t, carried_t, read_t, write_t = xs
         with jax.named_scope("serve.ssm.state"):
             h_in = jax.lax.dynamic_index_in_dim(state, read_t, keepdims=False)
-        h = jnp.where(fresh_t, 0.0, jnp.where(carried_t, h_in, h))
+        h = jnp.where(fresh_t, 0.0, jnp.where(carried_t, h_in.astype(F32), h))
         h, y_t = ssm_update(h, delta_t, u_t, b_t, c_t, a)
         with jax.named_scope("serve.ssm.state"):
-            state = jax.lax.dynamic_update_index_in_dim(state, h, write_t, 0)
+            state = jax.lax.dynamic_update_index_in_dim(
+                state, h.astype(state.dtype), write_t, 0)
         return (h, state), y_t
 
-    xs = (delta.astype(F32), u.astype(F32), b.astype(F32), c.astype(F32),
+    xs = (delta, u.astype(F32), b.astype(F32), c.astype(F32),
           fresh, carried, runs["read"], runs["write"])
     (_, state), y = jax.lax.scan(
         body, (jnp.zeros(ssm_state.shape[1:], F32), ssm_state), xs,

@@ -828,7 +828,8 @@ class ServingEngine:
             delta, sel_b, sel_c, a = mamba_selection(u, lp, cfg)
         with jax.named_scope("serve.ssm.scan"):
             y, ssm_state = ragged_selective_scan(
-                u, delta, a, sel_b, sel_c, ssm_state, b["runs"]
+                u, delta, a, sel_b, sel_c, ssm_state, b["runs"],
+                mesh_ctx=self._mesh,
             )
         with jax.named_scope("serve.ssm.proj"):
             out = mamba_output(y, u, z, lp, cfg)

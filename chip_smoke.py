@@ -58,6 +58,10 @@ GQA_POOL = dict(q_heads=32, kv_heads=8, head_dim=128, num_pages=513)
 # (256 rows x top-6 sorted over Moonlight's 64 experts of 2048 -> 1408), the
 # last 36 rows past the last group as a masked token's are.
 EXPERTS_CALL = dict(m=1536, k=2048, n=1408, E=64, masked_rows=36)
+# Selective scan: one state-space layer of AI21-Jamba2-3B over a 256-row
+# step (5120 channels, 16 states, 128 slots): 100 decode rows, a chunk of 60
+# continuing its slot, a chunk of 40 from position 0, 56 pad rows.
+SCAN_CALL = dict(T=256, C=5120, N=16, slots=128, decode=100, chunks=(60, 40))
 
 # bf16's unit roundoff is 2^-9. A kernel rounds its softmax weights and its
 # output to bf16 and accumulates in float32, so against a float32 oracle on
@@ -328,6 +332,44 @@ def phase_kernels() -> None:
           f"max|err|/max|ref| = {err:.2e} < {KERNEL_TOL:.2e}")
     check(float(jnp.max(jnp.abs(got[routed:].astype(jnp.float32)))) == 0.0,
           "grouped_matmul: rows past the last group come out zero")
+
+    # -- a serve step's ragged selective scan
+    from automodel_tpu.ops import selective_scan as scan
+    from automodel_tpu.ops.pallas import selective_scan as scan_kernel
+
+    c = SCAN_CALL
+    check(not scan_kernel._interpret(), "selective_scan compiles for the chip")
+    T, C, N, S = c["T"], c["C"], c["N"], c["slots"]
+    runs_of = [(s, 200 + s, 1) for s in range(c["decode"])] + [
+        (c["decode"] + i, 64 * (1 - i), n) for i, n in enumerate(c["chunks"])]
+    rows = [(s, p) for s, p0, n in runs_of for p in range(p0, p0 + n)]
+    slot = np.full(T, -1, np.int32)
+    pos = np.full(T, -1, np.int32)
+    slot[:len(rows)], pos[:len(rows)] = np.asarray(rows, np.int32).T
+    runs = scan.step_runs(jnp.asarray(slot), jnp.asarray(pos), trash=S)
+    operands = (
+        jnp.asarray(rng.normal(size=(T, C)), jnp.bfloat16),
+        jnp.asarray(np.exp(rng.normal(-4, 1, (T, C))), jnp.float32),
+        -jnp.exp(jnp.asarray(rng.normal(1.5, 0.7, (N, C)), jnp.float32)),
+        jnp.asarray(rng.normal(size=(T, N)), jnp.float32),
+        jnp.asarray(rng.normal(size=(T, N)), jnp.float32),
+        jnp.asarray(rng.normal(size=(S + 1, N, C)), jnp.float32),
+    )
+    fn = jax.jit(functools.partial(
+        scan.ragged_selective_scan, runs=runs, impl="pallas"))
+    txt = fn.lower(*operands).compile().as_text()
+    check(len(mosaic_calls(txt, "selective_scan")) == 1,
+          "selective_scan is a Mosaic call")
+    y, state = fn(*operands)
+    y_ref, state_ref = jax.jit(functools.partial(
+        scan.ragged_selective_scan, runs=runs, impl="xla"))(*operands)
+    err = max(rel_err(y[:len(rows)], y_ref[:len(rows)]),
+              rel_err(state[:S], state_ref[:S]))
+    check(err < 2.0 ** -20, f"selective_scan vs the float32 lax.scan: "
+          f"max|err|/max|ref| = {err:.2e} < {2.0 ** -20:.2e}")
+    absent = np.arange(c["decode"] + len(c["chunks"]), S)
+    check(bool(jnp.array_equal(state[absent], operands[5][absent])),
+          "selective_scan: a slot that is not in the step is not touched")
 
 
 # ---------------------------------------------------------------------------

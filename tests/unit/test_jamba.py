@@ -133,7 +133,11 @@ def test_control_precision_moves_the_reference(case):
 
 
 # -- the ragged scan against the whole-sequence scan --------------------------
-C, N, K, SLOTS = 16, 4, 4, 3
+# widths the Pallas kernel's rule takes (one 128-lane tile of channels, one
+# 8-sublane tile of states): both implementations run every case, the kernel
+# interpreted on the CPU
+C, N, K, SLOTS = 128, 8, 4, 3
+IMPLS = ["xla", "pallas"]
 
 
 def _inputs(rng, length):
@@ -152,7 +156,7 @@ def _whole(seq, kernel, bias, a):
     return np.asarray(u[0]), np.asarray(y[0])
 
 
-def _step(rows, seqs, conv_state, ssm_state, kernel, bias, a, T):
+def _step(rows, seqs, conv_state, ssm_state, kernel, bias, a, T, impl="xla"):
     """One serve step over `rows` [(slot, sequence index, position)], padded
     to T rows. Returns (conv out, scan out, new states) for the real rows."""
     n = len(rows)
@@ -164,17 +168,19 @@ def _step(rows, seqs, conv_state, ssm_state, kernel, bias, a, T):
         slot[i], pos[i] = s, p
         for k in feed:
             feed[k][i] = seqs[q][k][p]
-    runs = scan_ops.step_runs(jnp.asarray(slot), jnp.asarray(pos), trash=SLOTS)
+    trash = ssm_state.shape[0] - 1
+    runs = scan_ops.step_runs(jnp.asarray(slot), jnp.asarray(pos), trash=trash)
     u, conv_state = scan_ops.ragged_conv(
         jnp.asarray(feed["x"]), kernel, bias, conv_state, jnp.asarray(pos), runs)
     y, ssm_state = scan_ops.ragged_selective_scan(
         u, jnp.asarray(feed["delta"]), a, jnp.asarray(feed["b"]),
-        jnp.asarray(feed["c"]), ssm_state, runs)
+        jnp.asarray(feed["c"]), ssm_state, runs, impl=impl)
     return np.asarray(u)[:n], np.asarray(y)[:n], conv_state, ssm_state, runs
 
 
+@pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("chunk", [1, 2, 3, 5, 8])
-def test_ragged_scan_matches_whole_sequence_at_every_chunk_boundary(chunk):
+def test_ragged_scan_matches_whole_sequence_at_every_chunk_boundary(chunk, impl):
     """Three sequences of unlike length through steps of interleaved runs
     (a chunk of one, then one decode-like row of each other), every chunk
     boundary from inside the convolution's reach to past it; the states
@@ -200,7 +206,7 @@ def test_ragged_scan_matches_whole_sequence_at_every_chunk_boundary(chunk):
             for p in range(fed[q], min(fed[q] + take, len(seqs[q]["x"]))):
                 rows.append((q, q, p))      # slot q holds sequence q
         u, y, conv_state, ssm_state, runs = _step(
-            rows, seqs, conv_state, ssm_state, kernel, bias, a, T)
+            rows, seqs, conv_state, ssm_state, kernel, bias, a, T, impl)
         for (s, q, p), u_t, y_t in zip(rows, u, y):
             assert p == fed[q]
             got[q][0].append(u_t)
@@ -227,7 +233,74 @@ def test_step_runs_from_slot_and_pos():
     assert np.asarray(two["start"]).tolist() == [1, 1]
 
 
-def test_a_reused_slot_needs_no_reset():
+def test_run_list_compacts_the_runs_and_leaves_the_pads_out():
+    """What the kernel walks: a column a run (first row, length, slot, from
+    zeros), the step's runs first; a pad row is no run, and a step of pads
+    alone has one run, of row 0 into the trash slot."""
+    from automodel_tpu.ops.pallas.selective_scan import run_list
+
+    slot = jnp.asarray([2, 2, 2, 0, -1, 1, 1, -1], jnp.int32)
+    pos = jnp.asarray([5, 6, 7, 9, -1, 0, 1, -1], jnp.int32)
+    table, count = run_list(scan_ops.step_runs(slot, pos, trash=3), trash=3)
+    assert int(count) == 3
+    assert np.asarray(table)[:, :3].tolist() == [
+        [0, 3, 5], [3, 1, 2], [2, 0, 1], [0, 0, 1]]
+    pads = jnp.full((4,), -1, jnp.int32)
+    table, count = run_list(scan_ops.step_runs(pads, pads, trash=3), trash=3)
+    assert int(count) == 1
+    assert np.asarray(table)[:, 0].tolist() == [0, 1, 3, 1]
+
+
+#: the steps the kernel must take, as [(slot, first position, rows)] in plan
+#: order over 8 slots and 24 rows: name -> runs
+MIXES = {
+    "decode only": [(s, 3 + s, 1) for s in range(8)],
+    "the cell's mix": [(s, 9 + s, 1) for s in range(5)] + [(5, 4, 7), (7, 0, 6)],
+    "one run of the whole budget": [(2, 6, 24)],
+    "pads only": [],
+    "from position 0 in a slot that carried state": [(4, 0, 3), (1, 0, 1)],
+    "a long run, a decode row, no pad": [(6, 2, 23), (0, 11, 1)],
+}
+
+
+@pytest.mark.parametrize("mix", MIXES.values(), ids=MIXES.keys())
+def test_kernel_matches_the_xla_form_on_the_mixes_a_step_can_hold(mix):
+    """The Pallas kernel (interpreted) against the XLA form on the same
+    step, to float32 rounding: y of the real rows and every slot's state.
+    The states start as junk: a run from position 0 must not read its
+    slot's, a slot absent from the step stays BIT-identical, and pad rows
+    write nothing but the trash slot."""
+    slots, T = 8, 24
+    rng = np.random.default_rng(len(mix))
+    seq = _inputs(rng, 64)
+    a = -jnp.exp(jnp.asarray(rng.normal(size=(N, C)).astype(np.float32)))
+    state = jnp.asarray(rng.normal(size=(slots + 1, N, C)) * 1e3, jnp.float32)
+    rows = [(s, p) for s, p0, n in mix for p in range(p0, p0 + n)]
+    slot = np.full(T, -1, np.int32)
+    pos = np.full(T, -1, np.int32)
+    slot[:len(rows)], pos[:len(rows)] = np.asarray(rows, np.int32).reshape(-1, 2).T
+    runs = scan_ops.step_runs(jnp.asarray(slot), jnp.asarray(pos), trash=slots)
+    feed = {k: jnp.asarray(v[:T]) for k, v in seq.items()}
+    (y_ref, state_ref), (y, new) = (
+        scan_ops.ragged_selective_scan(
+            feed["x"], feed["delta"], a, feed["b"], feed["c"], state, runs,
+            impl=impl)
+        for impl in IMPLS)
+    n = len(rows)
+    scale = max(1.0, float(np.abs(np.asarray(y_ref)[:n]).max(initial=0)))
+    np.testing.assert_allclose(np.asarray(y)[:n], np.asarray(y_ref)[:n],
+                               rtol=1e-5, atol=1e-6 * scale)
+    np.testing.assert_allclose(np.asarray(new)[:slots],
+                               np.asarray(state_ref)[:slots], rtol=1e-5,
+                               atol=1e-3)
+    absent = sorted(set(range(slots)) - {s for s, _, _ in mix})
+    np.testing.assert_array_equal(np.asarray(new)[absent],
+                                  np.asarray(state)[absent])
+    assert np.isfinite(np.asarray(y)).all()
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_a_reused_slot_needs_no_reset(impl):
     """A slot's next holder starts at position 0 and reads zeros whatever
     the last holder left; pad rows write the trash slot alone."""
     rng = np.random.default_rng(9)
@@ -239,12 +312,12 @@ def test_a_reused_slot_needs_no_reset():
     ssm_state = jnp.zeros((SLOTS + 1, N, C), jnp.float32)
     _, _, conv_state, ssm_state, _ = _step(
         [(1, 0, p) for p in range(6)], [first], conv_state, ssm_state,
-        kernel, bias, a, 8)
+        kernel, bias, a, 8, impl)
     assert np.abs(np.asarray(ssm_state[1])).max() > 0
     before = np.asarray(ssm_state)
     u, y, conv_state, ssm_state, _ = _step(
         [(1, 0, p) for p in range(5)], [second], conv_state, ssm_state,
-        kernel, bias, a, 8)
+        kernel, bias, a, 8, impl)
     u_want, y_want = _whole(second, kernel, bias, a)
     np.testing.assert_allclose(u, u_want, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(y, y_want, rtol=1e-4, atol=1e-5)

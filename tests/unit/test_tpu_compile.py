@@ -45,11 +45,15 @@ def tpu_branch(monkeypatch):
 
     import automodel_tpu.ops.grouped_matmul as gmm
     import automodel_tpu.ops.pallas.grouped_matmul as gmm_kernel
+    import automodel_tpu.ops.pallas.selective_scan as scan_kernel
+    import automodel_tpu.ops.selective_scan as scan
 
     monkeypatch.setattr(attention, "_on_tpu", lambda: True)
     monkeypatch.setattr(rpa, "_interpret", lambda: False)
     monkeypatch.setattr(gmm, "_on_tpu", lambda: True)
     monkeypatch.setattr(gmm_kernel, "_interpret", lambda: False)
+    monkeypatch.setattr(scan, "_on_tpu", lambda: True)
+    monkeypatch.setattr(scan_kernel, "_interpret", lambda: False)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     yield rpa
@@ -175,6 +179,46 @@ def test_grouped_matmul_compiles_for_the_chip(one_chip, tpu_branch, call):
     assert "ragged-dot" not in compiled.as_text()
 
 
+#: (rows, channels, states, slots, the rule's channel block) of one
+#: state-space layer's call
+SCAN_CALLS = {
+    "jamba2_3b: a 256-row step": (256, 5120, 16, 128, 5120),
+    "jamba2_3b: a 512-row step, two channel blocks": (512, 5120, 16, 128, 2560),
+    "mamba-130m's widths, 64 rows": (64, 1536, 16, 16, 1536),
+}
+
+
+@pytest.mark.parametrize("call", SCAN_CALLS.values(), ids=SCAN_CALLS.keys())
+def test_selective_scan_compiles_for_the_chip(one_chip, tpu_branch, call):
+    """The dispatcher's own choice for a serve step's call: the kernel, at
+    the channel block its rule gives (Jamba2-3B's: a run's whole 320 KiB
+    state a block, the rows' inputs resident: 46 MB of VMEM, past the
+    compiler's default 16 MiB; twice the rows, half the channels), the
+    state updated in place."""
+    from automodel_tpu.ops.selective_scan import (
+        channel_block, ragged_selective_scan, step_runs,
+    )
+
+    T, C, N, slots, cb = call
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(u, delta, a, b, c, state, slot, pos):
+        return ragged_selective_scan(
+            u, delta, a, b, c, state, step_runs(slot, pos, trash=slots))
+
+    assert channel_block(T, C, N) == cb
+    compiled = jax.jit(layer, donate_argnums=(5,)).lower(
+        s((T, C), jnp.bfloat16), s((T, C)), s((N, C)), s((T, N)), s((T, N)),
+        s((slots + 1, N, C)), s((T,), jnp.int32), s((T,), jnp.int32)).compile()
+    assert "selective_scan" in compiled.as_text()
+    assert "while" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= (slots + 1) * N * C * 4
+    assert mem.temp_size_in_bytes < 4 * T * C * 4
+
+
 def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
         one_chip, tpu_branch):
     """The toy looped decoder's serve step, lowered for the chip: passes x
@@ -199,11 +243,12 @@ def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
 
 def test_state_space_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
     """AI21-Jamba2-3B's serve step at its cell's geometry, from shapes alone
-    (no weight is allocated): all 28 layers, 26 of them a scan over the
-    step's 256 rows beside the two paged calls, compiled for the described
-    chip in ONE program whose arguments are the 6.39 GB of weights, the
-    1.20 GB of per-slot state and the 0.20 GB pool, the last two donated and
-    aliased to the outputs."""
+    (no weight is allocated): all 28 layers, 26 of them a `selective_scan`
+    Mosaic call over the step's runs beside the two paged calls, compiled
+    for the described chip in ONE program whose arguments are the 6.39 GB of
+    weights, the 1.20 GB of per-slot state and the 0.20 GB pool, the last
+    two donated and aliased to the outputs: the kernels update the state in
+    place."""
     import json
 
     from tests.step_shapes import engine_of_shapes
@@ -215,8 +260,16 @@ def test_state_space_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
         config = json.load(f)
     eng, args = engine_of_shapes(config, config["serving"], one_chip)
     assert eng.cfg.layer_ops.count("mamba") == 26 and len(args[3]) == 26
+    from automodel_tpu.observability.metrics import default_registry
+
+    kernels = default_registry().counter(
+        "selective_scan_calls_total", impl="pallas",
+        reason="float32 state on a TPU")
+    before = kernels.value
     lowered = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args)
     assert lowered.as_text().count("paged_attention_gqa") == 2
+    assert lowered.as_text().count("selective_scan") == 26
+    assert kernels.value - before == 26
     mem = lowered.compile().memory_analysis()
     state = 26 * 129 * (16 * 5120 * 4 + 3 * 5120 * 2)
     pool = 2 * 2 * 3073 * 64 * 128 * 2
