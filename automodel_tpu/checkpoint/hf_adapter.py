@@ -557,10 +557,12 @@ class MoEDecoderAdapter:
             if "e_score_bias" in moe["gate"]:
                 b = np.asarray(moe["gate"]["e_score_bias"][li])
                 yield self._escore_name(i), (b[None] if self.style == "ernie" else b)
-            for e in range(cfg.moe.n_routed_experts):
-                names = self._expert_names(i, e)
+            # the tree holds the held experts alone (all, or one chip's
+            # share): entry j is the checkpoint's expert first_held + j
+            for j in range(cfg.moe.num_held):
+                names = self._expert_names(i, cfg.moe.first_held_expert + j)
                 for proj in ("gate_proj", "up_proj", "down_proj"):
-                    yield names[proj], _t(np.asarray(moe["experts"][proj]["kernel"][li, e]))
+                    yield names[proj], _t(np.asarray(moe["experts"][proj]["kernel"][li, j]))
             if cfg.moe.n_shared_experts > 0:
                 base = self._shared_base(i)
                 for proj in ("gate_proj", "up_proj", "down_proj"):
@@ -691,8 +693,9 @@ class MoEDecoderAdapter:
                 [
                     np.stack(
                         [
-                            _t(read(self._expert_names(fk + li, e)[proj]))
-                            for e in range(cfg.moe.n_routed_experts)
+                            _t(read(self._expert_names(
+                                fk + li, cfg.moe.first_held_expert + j)[proj]))
+                            for j in range(cfg.moe.num_held)
                         ]
                     )
                     for li in range(cfg.num_moe_layers)

@@ -321,6 +321,10 @@ def generate(
         # selection is traced per layer off the scanned (L,) window array
         inv_freq_local = rope_frequencies(cfg.rope_dim, cfg.rope_local_theta, None)
         freq_for_win = lambda win: jnp.where(win > 0, inv_freq_local, inv_freq)
+    elif cfg.rope_layers == "sliding":
+        # the layers without a window have no rotary embedding: the zero
+        # table rotates by no angle, exactly the identity
+        freq_for_win = lambda win: jnp.where(win > 0, inv_freq, 0.0)
     else:
         freq_for_win = lambda win: inv_freq
 

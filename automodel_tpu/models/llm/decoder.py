@@ -99,6 +99,10 @@ class TransformerConfig:
     # False: no rotary embedding on q and k (Jamba has no positional
     # encoding at all: its state-space layers carry the order)
     use_rope: bool = True
+    # which layers rotate q and k, where `use_rope`: "all", or "sliding": the
+    # layers with a window alone (EXAONE 4.0 / K-EXAONE: a full-attention
+    # layer has no positional encoding, the window layers carry the order)
+    rope_layers: str = "all"
     logits_soft_cap: Optional[float] = None
     attn_soft_cap: Optional[float] = None
     embed_scale: float = 1.0  # gemma multiplies embeddings by sqrt(hidden)
@@ -287,7 +291,13 @@ def mixed_window_xs(windows: tuple, freq_for) -> tuple:
     win_arr = jnp.asarray(
         [w if w is not None else (1 << 30) for w in windows], jnp.int32
     )
-    freq_arr = jnp.stack([freq_for(w) for w in windows])
+    freqs = [freq_for(w) for w in windows]
+    some = next(f for f in freqs if f is not None)
+    # a layer without rotary embedding rides the scan as the zero table: a
+    # rotation by no angle, exactly the identity
+    freq_arr = jnp.stack(
+        [f if f is not None else jnp.zeros_like(some) for f in freqs]
+    )
     return win_arr, freq_arr
 
 
@@ -298,7 +308,14 @@ def make_freq_for(cfg: "TransformerConfig", inv_freq):
     Gemma3TextConfig): sliding-window layers rotate with a LOCAL unscaled
     theta while global layers use rope_theta + rope_scaling. Window
     grouping is static (scan_layers_windowed groups layers by window), so
-    this is a python-level selection with no traced branching."""
+    this is a python-level selection with no traced branching.
+
+    `cfg.rope_layers == "sliding"`: a layer without a window gets None, and
+    `project_qkv` then leaves its q and k unrotated."""
+    if cfg.rope_layers == "sliding":
+        assert cfg.rope_local_theta is None, "rope_layers with a local theta"
+        return lambda window: inv_freq if window is not None else None
+    assert cfg.rope_layers == "all", cfg.rope_layers
     if cfg.rope_local_theta is None:
         return lambda window: inv_freq
     local = rope_frequencies(cfg.rope_dim, cfg.rope_local_theta, None)
@@ -979,7 +996,8 @@ def project_qkv(x, lp, cfg: TransformerConfig, positions, inv_freq):
     if cfg.qk_norm and not cfg.qk_norm_after_rope:
         q = rms_norm(q, lp["q_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
         k = rms_norm(k, lp["k_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
-    if cfg.use_rope:
+    # `inv_freq` None: this layer's kind has no rotary embedding (make_freq_for)
+    if cfg.use_rope and inv_freq is not None:
         q = apply_rope(q, positions, inv_freq, cfg.rope_interleaved)
         k = apply_rope(k, positions, inv_freq, cfg.rope_interleaved)
     if cfg.qk_norm and cfg.qk_norm_after_rope:

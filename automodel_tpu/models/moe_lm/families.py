@@ -86,6 +86,80 @@ def deepseek_v3_moe_config(hf: Mapping[str, Any], **overrides) -> MoETransformer
     return MoETransformerConfig(moe=moe_overrides or moe, first_k_dense=first_k, **kw)
 
 
+def exaone_moe_config(hf: Mapping[str, Any], **overrides) -> MoETransformerConfig:
+    """ExaoneMoeForCausalLM (K-EXAONE-236B-A23B): GQA with a per-head RMSNorm
+    on q and k, a window in the `sliding_attention` layers of `layer_types`
+    and rotary embedding in those layers ALONE (EXAONE 4.0's attention, which
+    the family keeps: transformers `modeling_exaone4.py` Exaone4Attention), a
+    pre-norm before each sublayer, `first_k_dense_replace` leading dense
+    layers (`mlp_layer_types`), then DeepSeek-style experts: sigmoid scores,
+    a selection bias, top-k renormalised and scaled, shared experts.
+
+    `num_experts` is how many experts the tree HOLDS. A configuration that
+    stands for one chip's share of an expert-parallel deployment gives the
+    router's published width under `router_num_experts` (and the first held
+    expert under `first_held_expert`, default 0): the router then scores all
+    of them and the layer computes its own experts' part. The multi-token
+    prediction module (`num_nextn_predict_layers`) is not built: the config
+    does not say how it joins the previous state with the next embedding."""
+    kw = _base_kwargs(hf)
+    rope = hf.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        raise NotImplementedError(f"exaone_moe rope_type {rope['rope_type']!r}")
+    if "rope_theta" in rope:
+        kw["rope_theta"] = float(rope["rope_theta"])
+    kw["qk_norm"] = True
+    kw["rope_layers"] = "sliding"
+    types = hf.get("layer_types")
+    if hf.get("sliding_window") and types:
+        if len(types) != kw["num_layers"]:
+            raise ValueError(
+                f"layer_types has {len(types)} entries for "
+                f"{kw['num_layers']} layers"
+            )
+        kw["sliding_window"] = int(hf["sliding_window"])
+        kw["layer_types"] = tuple(
+            "sliding" if t == "sliding_attention" else "global" for t in types
+        )
+    else:
+        kw["rope_layers"] = "all"  # no window anywhere: every layer rotates
+    first_k = int(hf.get("first_k_dense_replace", 0))
+    mlp_types = hf.get("mlp_layer_types")
+    if mlp_types is not None:
+        first_k = sum(t == "dense" for t in mlp_types)
+        want = ["dense"] * first_k + ["sparse"] * (len(mlp_types) - first_k)
+        if list(mlp_types) != want or len(mlp_types) != kw["num_layers"]:
+            raise NotImplementedError(
+                "exaone_moe mlp_layer_types other than leading dense layers "
+                f"then sparse ones: {list(mlp_types)}"
+            )
+    if int(hf.get("num_nextn_predict_layers") or 0):
+        raise NotImplementedError(
+            "exaone_moe multi-token prediction (num_nextn_predict_layers): "
+            "the module is not built; set it 0 to run the 48 main layers"
+        )
+    held = int(hf["num_experts"])
+    routed = int(hf.get("router_num_experts", held))
+    moe = MoEConfig(
+        n_routed_experts=routed,
+        n_held_experts=None if held == routed else held,
+        first_held_expert=int(hf.get("first_held_expert", 0)),
+        n_shared_experts=int(hf.get("num_shared_experts", 0)),
+        experts_per_token=int(hf["num_experts_per_tok"]),
+        n_groups=int(hf.get("n_group", 1)),
+        topk_groups=int(hf.get("topk_group", 1)),
+        moe_intermediate_size=int(hf["moe_intermediate_size"]),
+        score_func="sigmoid" if hf.get("scoring_func", "sigmoid") == "sigmoid" else "softmax",
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        route_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        aux_loss_coeff=float(hf.get("aux_loss_alpha", 0.0)),
+        gate_bias_update_speed=float(hf.get("bias_update_speed", 0.001)),
+    )
+    moe_overrides = overrides.pop("moe", None)
+    kw.update(overrides)
+    return MoETransformerConfig(moe=moe_overrides or moe, first_k_dense=first_k, **kw)
+
+
 def deepseek_v4_config(hf: Mapping[str, Any], **overrides) -> MoETransformerConfig:
     """DeepseekV4ForCausalLM: the V3 MoE+MLA body plus DSA — the lightning
     indexer's top-k sparse attention (reference: components/models/

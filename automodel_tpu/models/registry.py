@@ -75,6 +75,12 @@ MODEL_ARCH_MAPPING: dict[str, ModelSpec] = {
         "deepseek_v3", moe_families.deepseek_v3_moe_config, moe_decoder,
         adapter_name="moe_decoder", adapter_kwargs={"style": "deepseek"},
     ),
+    # K-EXAONE: window and full attention mixed (rope in the window layers
+    # alone), DeepSeek-style experts, names as deepseek's
+    "ExaoneMoeForCausalLM": ModelSpec(
+        "exaone_moe", moe_families.exaone_moe_config, moe_decoder,
+        adapter_name="moe_decoder", adapter_kwargs={"style": "deepseek"},
+    ),
     "DeepseekV4ForCausalLM": ModelSpec(
         "deepseek_v4", moe_families.deepseek_v4_config, moe_decoder,
         adapter_name="moe_decoder", adapter_kwargs={"style": "deepseek"},

@@ -18,6 +18,13 @@ from typing import Optional
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
     n_routed_experts: int = 8
+    # one chip's share of an expert-parallel deployment: the router scores
+    # and selects over all `n_routed_experts`, this layer HOLDS the weights
+    # of `n_held_experts` of them, experts `first_held_expert` and on, and
+    # computes their part of the result alone; what the absent experts would
+    # add is left out and no exchange stands in for it. None = all held.
+    n_held_experts: Optional[int] = None
+    first_held_expert: int = 0
     n_shared_experts: int = 0
     experts_per_token: int = 2  # top-k
     n_groups: int = 1           # deepseek group-limited routing
@@ -54,12 +61,36 @@ class MoEConfig:
                 f"Unknown MoE dispatcher '{self.dispatcher}' "
                 "(expected 'capacity' or 'dropless')"
             )
+        if self.n_held_experts is not None:
+            last = self.first_held_expert + self.n_held_experts
+            if not (1 <= self.n_held_experts and 0 <= self.first_held_expert
+                    and last <= self.n_routed_experts):
+                raise ValueError(
+                    f"held experts [{self.first_held_expert}, {last}) are not "
+                    f"among the {self.n_routed_experts} routed ones"
+                )
+            if self.dispatcher != "dropless":
+                raise ValueError(
+                    "a layer that holds a share of its experts needs the "
+                    "dropless dispatcher (the capacity einsum is over all)"
+                )
         known_acts = ("silu", "geglu", "quick_geglu", "relu2", "swigluoai")
         for field in ("expert_activation", "shared_expert_activation"):
             if getattr(self, field) not in known_acts:
                 raise ValueError(
                     f"Unknown {field} '{getattr(self, field)}' (expected one of {known_acts})"
                 )
+
+    @property
+    def num_held(self) -> int:
+        """Experts whose weights this layer holds (all of them, or its share)."""
+        if self.n_held_experts is None:
+            return self.n_routed_experts
+        return self.n_held_experts
+
+    @property
+    def holds_all_experts(self) -> bool:
+        return self.num_held == self.n_routed_experts
 
     @property
     def gated_experts(self) -> bool:

@@ -54,7 +54,9 @@ def gated_combine(g, u, kind: str, limit: float = 7.0):
 
 
 def init_experts(cfg: MoEConfig, hidden_size: int, rng: jax.Array) -> dict:
-    E, H, I = cfg.n_routed_experts, hidden_size, cfg.moe_intermediate_size
+    """The weights of the experts this layer holds (`cfg.num_held`: all the
+    routed ones, or one chip's share of them)."""
+    E, H, I = cfg.num_held, hidden_size, cfg.moe_intermediate_size
     k1, k2, k3 = jax.random.split(rng, 3)
     std_in, std_out = H ** -0.5, I ** -0.5
     params = {
@@ -161,13 +163,23 @@ def experts_forward_dropless(
     GSPMD caller on more than one device passes its `mesh_ctx` (the Pallas
     kernel has no partitioning rule: the reference serves such a call); a
     caller inside a `shard_map` passes none.
+
+    A layer that holds a SHARE of its experts (`cfg.n_held_experts`) sorts
+    the pairs whose expert it holds to the front, by the expert's index among
+    the held, and every other pair behind them under the sentinel: they
+    belong to no group, the grouped matmul gives their rows zeros, and the
+    token's result is the held experts' part alone, each with the weight the
+    router gave it over ALL its chosen experts.
     """
     T, H = x.shape
     K = cfg.experts_per_token
-    E = cfg.n_routed_experts
+    E = cfg.num_held
     dtype = x.dtype
 
     flat_expert = indices.reshape(T * K)
+    if not cfg.holds_all_experts:
+        local = flat_expert - cfg.first_held_expert
+        flat_expert = jnp.where((local >= 0) & (local < E), local, E)
     # stable sort groups rows by expert while keeping token order within
     sort_idx = jnp.argsort(flat_expert, stable=True)
     token_of = sort_idx // K

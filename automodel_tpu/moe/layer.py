@@ -99,6 +99,12 @@ def moe_forward(
     with jax.named_scope(f"{scope}.experts"):
         if cfg.dispatcher == "dropless":
             if mesh_ctx is not None and mesh_ctx.sizes["ep"] > 1:
+                if not cfg.holds_all_experts:
+                    raise NotImplementedError(
+                        "a share of the experts (n_held_experts) under ep > 1: "
+                        "the share IS one rank's part of an expert-parallel "
+                        "layer, held without its exchange"
+                    )
                 routed = experts_forward_dropless_ep(
                     params["experts"], cfg, flat, weights, indices, mesh_ctx
                 )

@@ -261,15 +261,19 @@ METRIC_CATALOG = (
     ("serve_free_pages", "gauge", "KV pages currently free"),
     ("serve_compiled_signatures", "gauge", "jit cache entries for the serve step"),
     ("serve_passes", "gauge", "times the serve step walks the layer stack over a token (looped decoders: > 1)"),
-    ("serve_kv_bytes_per_token", "gauge", "KV cache bytes one token holds, every pass and attention layer counted"),
-    ("serve_attn_layers", "gauge", "layers whose operator is attention over pages (pool entries a pass)"),
+    ("serve_kv_bytes_per_token", "gauge", "KV cache bytes one token holds in the pool, every pass and attention layer that keeps pages counted (a window layer's ring is per slot: serve_window_bytes_per_slot)"),
+    ("serve_attn_layers", "gauge", "layers whose operator is attention, over pages or over a ring per slot"),
+    ("serve_window_layers", "gauge", "attention layers with a sliding window, whose keys and values live in a ring per slot (0: none)"),
+    ("serve_full_layers", "gauge", "attention layers that keep pool pages: pool entries a pass"),
+    ("serve_window_bytes_per_slot", "gauge", "bytes of the window layers' rings one slot holds whatever its context"),
+    ("serve_experts_held", "gauge", "routed experts whose weights an expert layer holds here (all, or one chip's share; 0: no expert layer)"),
     ("serve_ssm_layers", "gauge", "layers whose operator is a state-space mixer with a per-slot state (0: attention alone)"),
     ("serve_state_bytes_per_slot", "gauge", "bytes of convolution and recurrent state one slot holds whatever its length"),
     ("serve_attn_segments", "gauge", "runs of one slot's rows in the last planned step: the paged-attention grid's segments"),
     ("serve_attn_live_blocks", "gauge", "(segment, page) blocks of the last planned step that hold a key to attend to"),
     # prefix cache
     ("serve_prefix_hits_total", "counter", "admissions that matched a cached prefix"),
-    ("serve_prefix_hits_cut_total", "counter", "admissions whose cached-prefix match was cut to none (the model holds a recurrent state)"),
+    ("serve_prefix_hits_cut_total", "counter", "admissions whose cached-prefix match was cut to none (the model holds a recurrent state, or a window layer's ring, per slot)"),
     ("serve_prefill_skipped_tokens_total", "counter", "prompt tokens skipped via prefix reuse"),
     ("serve_cow_copies_total", "counter", "copy-on-write page copies"),
     # speculative decoding

@@ -19,9 +19,12 @@ and counts every call it hands to the reference):
   through VMEM with the page table as a scalar-prefetch BlockSpec index map
   (no gathered (T, P, page, ...) intermediate in HBM), one (segment, page)
   block at a time: `row_segments` below groups a step's rows into runs of
-  one sequence, so a prefill chunk's rows fetch their pages once. It covers
-  neither sliding windows nor sinks (`_gqa_unsupported_reason` /
-  `_mla_unsupported_reason` below state its rules).
+  one sequence, so a prefill chunk's rows fetch their pages once. The GQA
+  kernel takes a sliding window as a static of the call (the block list
+  then starts at the page of the segment's first in-window key and the mask
+  drops what lies further back); the MLA kernels take none, and neither
+  takes sinks (`_gqa_unsupported_reason` / `_mla_unsupported_reason` below
+  state the rules).
 
 Layouts (see serving/kv_pages.py for the pool):
 
@@ -195,7 +198,8 @@ class RowSegments(NamedTuple):
     """A step's rows grouped into SEGMENTS, runs of one sequence's rows,
     and laid out as the list of (segment, page) blocks the Pallas kernels
     walk: one for every page a segment attends to, a segment's pages in
-    order, nothing for a page past its last position or for a pad row.
+    order, nothing for a page past its last position, for a page that lies
+    wholly before its first row's window, or for a pad row.
     `tile` (static) is the rows of a q tile. `blocks` is (6, W) int32,
     column w one block: the tile its segment lies in, the POOL page it
     reads, then its segment's first row as an offset into the tile, its
@@ -248,24 +252,43 @@ def segment_bounds(xp, slot, pos, tile: int):
     return is_start, is_last
 
 
+def window_first_page(pos0, window: int, page_size: int):
+    """Which page of its sequence holds the first key that a row at
+    position `pos0` attends to under `window`."""
+    return jnp.maximum(pos0 - window + 1, 0) // page_size
+
+
 def row_segments(slot, pos, page_tables, *, page_size: int, tile: int,
-                 max_segments: int) -> RowSegments:
+                 max_segments: int, window: int | None = None,
+                 max_pages: int | None = None) -> RowSegments:
     """The step's `RowSegments`, in-jit, from each row's slot, position
     and page table (T, P). More than `max_segments` runs cannot be told
-    here: the caller's bound must hold (`max_row_segments`)."""
-    G, W = max_segments, max_segments * page_tables.shape[1]
+    here: the caller's bound must hold (`max_row_segments`). With a
+    `window` (static) a segment's blocks begin at the page of its FIRST
+    row's first in-window key, the near end of what any of its rows
+    attends to; `max_pages` then bounds the pages one segment can span
+    (default: the table's width)."""
+    P = page_tables.shape[1]
+    G, W = max_segments, max_segments * min(P, max_pages or P)
     is_start, is_last = segment_bounds(jnp, slot, pos, tile)
     (start,) = jnp.nonzero(is_start, size=G, fill_value=0)
     (end,) = jnp.nonzero(is_last, size=G, fill_value=0)
     real = jnp.arange(G) < jnp.sum(is_start)
     length = jnp.where(real, end - start + 1, 0)
-    pages = jnp.where(real, pos[end] // page_size + 1, 0)  # a segment's blocks
+    # a segment's blocks
+    pages = pos[end] // page_size + 1
+    if window:
+        first = window_first_page(pos[start], window, page_size)
+        pages = pages - first
+    pages = jnp.where(real, pages, 0)
     ends = jnp.cumsum(pages)
     count = ends[-1]
     # block w's segment and which of its pages; past the count, the last's
     w = jnp.minimum(jnp.arange(W), jnp.maximum(count - 1, 0))
     seg = jnp.minimum(jnp.searchsorted(ends, w, side="right"), G - 1)
     column = w - (ends[seg] - pages[seg])
+    if window:
+        column = jnp.minimum(first[seg] + column, P - 1)
     row = start[seg]
     blocks = jnp.stack([
         row // tile, page_tables[row, column], row % tile, length[seg],
@@ -275,11 +298,12 @@ def row_segments(slot, pos, page_tables, *, page_size: int, tile: int,
 
 
 def step_row_segments(slot, pos, page_tables, *, page_size: int, tile: int,
-                      max_slots: int, impl: str = "auto"):
-    """A serve step's `RowSegments`, once for all its attention calls, or
-    None where the dispatch rule hands them to the reference (off the TPU:
-    the step that CPU tests lower holds nothing of this). `page_tables`
-    is per ROW, (T, P)."""
+                      max_slots: int, impl: str = "auto",
+                      window: int | None = None, max_pages: int | None = None):
+    """A serve step's `RowSegments`, once for all its attention calls of
+    one kind (full, or with `window`), or None where the dispatch rule
+    hands them to the reference (off the TPU: the step that CPU tests lower
+    holds nothing of this). `page_tables` is per ROW, (T, P)."""
     from automodel_tpu.ops.attention import resolve_kernel_impl
 
     if resolve_kernel_impl(impl, "pallas", None, "paged_attention") != "pallas":
@@ -287,6 +311,7 @@ def step_row_segments(slot, pos, page_tables, *, page_size: int, tile: int,
     return row_segments(
         slot, pos, page_tables, page_size=page_size, tile=tile,
         max_segments=max_row_segments(slot.shape[0], max_slots, tile),
+        window=window, max_pages=max_pages,
     )
 
 
@@ -309,7 +334,7 @@ def _annotate_tp(x, mesh_ctx, dim: int):
 
 
 def _pallas_gqa_tp(mesh_ctx, q, k_pages, v_pages, page_tables, positions, *,
-                   scale, soft_cap, segments):
+                   scale, soft_cap, segments, window):
     """The Pallas GQA kernel under tp>1, inside a shard_map: each rank
     runs the SAME kernel on its local head slice — q/k/v/out shard the
     head dim, page tables, positions and row segments replicate, and the
@@ -329,6 +354,7 @@ def _pallas_gqa_tp(mesh_ctx, q, k_pages, v_pages, page_tables, positions, *,
         seg = RowSegments(segments.tile, *seg) if seg else None
         return paged_attention_kernel(
             q, k, v, pt, pos, scale=scale, soft_cap=soft_cap, segments=seg,
+            window=window,
         )
 
     seg = () if segments is None else (segments.blocks, segments.count)
@@ -343,8 +369,9 @@ def _pallas_gqa_tp(mesh_ctx, q, k_pages, v_pages, page_tables, positions, *,
 def _gqa_unsupported_reason(q, k_pages, window, sinks, quant, tp):
     """Why the Pallas GQA kernels cannot take this call, or None."""
     Hq, Hkv = q.shape[1], k_pages.shape[2]
-    if window is not None:
-        return "sliding windows"
+    if window is not None and not isinstance(window, int):
+        # the kernel's block list and mask take the window as a static
+        return "a traced sliding window"
     if sinks is not None:
         return "attention sinks"
     if Hq % Hkv != 0:
@@ -378,7 +405,9 @@ def ragged_paged_attention(
     `k_scales`/`v_scales` ((N, ps) per-row scales) the pages are int8 and
     the quantized kernel/reference dequantizes per page. `segments`
     (`step_row_segments`) is the kernel's alone: the reference reads each
-    row's own table and never sees it."""
+    row's own table and never sees it. `window`: None or 0 for none; a
+    Python int is a static of the kernel call (`segments` must then have
+    been built with the same window), a traced value goes to the reference."""
     from automodel_tpu.ops.attention import resolve_kernel_impl
 
     scale = scale if scale is not None else float(q.shape[-1]) ** -0.5
@@ -394,6 +423,7 @@ def ragged_paged_attention(
             return _pallas_gqa_tp(
                 mesh_ctx, q, k_pages, v_pages, page_tables, positions,
                 scale=scale, soft_cap=soft_cap, segments=segments,
+                window=window,
             )
         if quant:
             from automodel_tpu.ops.pallas.ragged_paged_attention import (
@@ -403,7 +433,7 @@ def ragged_paged_attention(
             return paged_attention_quant_kernel(
                 q, k_pages, v_pages, k_scales, v_scales,
                 page_tables, positions, scale=scale, soft_cap=soft_cap,
-                segments=segments,
+                segments=segments, window=window,
             )
         from automodel_tpu.ops.pallas.ragged_paged_attention import (
             paged_attention_kernel,
@@ -411,7 +441,7 @@ def ragged_paged_attention(
 
         return paged_attention_kernel(
             q, k_pages, v_pages, page_tables, positions,
-            scale=scale, soft_cap=soft_cap, segments=segments,
+            scale=scale, soft_cap=soft_cap, segments=segments, window=window,
         )
     q = _annotate_tp(q, mesh_ctx, 1)              # head axis
     k_pages = _annotate_tp(k_pages, mesh_ctx, 2)

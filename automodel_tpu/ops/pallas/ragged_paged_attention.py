@@ -29,9 +29,13 @@ behind the call).
 Without descriptors (`segments=None`: tests, chip_smoke.py) every row is
 its own segment. Covers GQA (kv-head sharing, no KV repeat) and
 absorbed-MLA (scores latent + rope parts summed in one accumulator, output
-in latent space), over bf16 and int8 pages. Sliding windows and attention
-sinks are not covered: the dispatcher (ops/paged_attention.py) states the
-rules and never calls in here with them.
+in latent space), over bf16 and int8 pages. The GQA kernels take a sliding
+window as a static of the call: the block list then begins at the page of
+the segment's first in-window key (`row_segments(window=...)`) and the mask
+drops every key `window` or more positions behind its row; such a call is
+named `paged_attention_window_gqa`. The MLA kernels
+take no window and none takes attention sinks: the dispatcher
+(ops/paged_attention.py) states the rules and never calls in here with them.
 
 GQA head dims that are not lane (128) multiples are zero-padded host-side
 (pad lanes add zero logits / zero value columns — exact), which copies the
@@ -58,6 +62,7 @@ from automodel_tpu.ops.paged_attention import (
     RowSegments,
     row_segments,
     row_tile,
+    window_first_page,
 )
 from automodel_tpu.ops.pallas.flash_attention import LANE, NEG_INF, _pad_last
 
@@ -67,17 +72,29 @@ def _interpret() -> bool:
 
 
 # -- what the four kernels share ---------------------------------------------
-def _block(blocks_ref, page_size):
+def _block(blocks_ref, page_size, window=None):
     """This grid step's block: its segment's (row offset in the tile,
     length, first position), the index of its page's first key, and
-    whether the page is its segment's first and its last."""
+    whether the page is its segment's first and its last. With a `window`
+    the first is the page of the first row's first in-window key."""
     w = pl.program_id(0)
     off, length, pos0, column = (
         blocks_ref[row, w]
         for row in (BLOCK_OFFSET, BLOCK_LENGTH, BLOCK_POSITION, BLOCK_COLUMN)
     )
     last = column == (pos0 + length - 1) // page_size
-    return off, length, pos0, column * page_size, column == 0, last
+    first = column == (
+        window_first_page(pos0, window, page_size) if window else 0)
+    return off, length, pos0, column * page_size, first, last
+
+
+def _attends(kv_idx, row_pos, window):
+    """Which keys a row at `row_pos` attends to: none after it and, with a
+    window, none `window` or more positions before it."""
+    mask = kv_idx <= row_pos
+    if window:
+        mask = jnp.logical_and(mask, row_pos - kv_idx < window)
+    return mask
 
 
 def _reset(m_ref, l_ref, acc_ref):
@@ -124,13 +141,15 @@ def _store_segment_rows(out_ref, val, off, length):
     out_ref[...] = jnp.where(inside, val.astype(out_ref.dtype), out_ref[...])
 
 
-def _rows_as_segments(page_tables, positions, page_size, row_width):
+def _rows_as_segments(page_tables, positions, page_size, row_width,
+                      window=None):
     """Every row a sequence of its own: the descriptors of a call that
     brings none."""
     T = positions.shape[0]
     return row_segments(
         jnp.arange(T, dtype=jnp.int32), positions, page_tables,
         page_size=page_size, tile=row_tile(T, row_width), max_segments=T,
+        window=window,
     )
 
 
@@ -188,7 +207,7 @@ def _gqa_kernel(
     *rest,     # [ks_ref, vs_ref (1, 1, ps) f32 per-row scales of THIS page,]
                # out_ref (tile, Hq, Dv), one row's m/l/acc, the tile's
                # head-major q and m/l/acc
-    scale, soft_cap, page_size, groups, tile, quant,
+    scale, soft_cap, page_size, groups, tile, quant, window,
 ):
     """bf16 and int8 pages alike. The tile body scores (Hkv, groups x
     tile, D) queries, the tile's rows turned head-major once a segment,
@@ -204,7 +223,7 @@ def _gqa_kernel(
         ks_ref, vs_ref, *rest = rest
     out_ref, m1, l1, acc1, qt, mt, lt, acct = rest
     off, length, pos0, key0, first_page, last_page = _block(
-        blocks_ref, page_size)
+        blocks_ref, page_size, window)
     one_row = length == 1
     many_rows = length > 1
     Hq = q_ref.shape[1]
@@ -275,7 +294,7 @@ def _gqa_kernel(
         # head) hands Mosaic a compare with a one-wide sublane axis, which
         # the TPU compiler refuses (LLO_CHECK ProducesVreg)
         _softmax_page(
-            one_row_scores(), kv_idx <= pos0, m1, l1, acc1,
+            one_row_scores(), _attends(kv_idx, pos0, window), m1, l1, acc1,
             lambda p: weighted_values(
                 p.reshape(Hkv, groups, ps)).reshape(Hq, Dv),
         )
@@ -296,7 +315,8 @@ def _gqa_kernel(
     def _tile_rows():
         tile_row = jax.lax.rem(jax.lax.broadcasted_iota(
             jnp.int32, (groups * tile, 1), 0), tile)
-        mask = kv_idx <= _tile_row_positions(tile_row, off, length, pos0)
+        mask = _attends(
+            kv_idx, _tile_row_positions(tile_row, off, length, pos0), window)
         _softmax_page(tile_scores(qt[...]), mask[None], mt, lt, acct,
                       weighted_values)
 
@@ -308,9 +328,10 @@ def _gqa_kernel(
 
 @functools.partial(
     jax.jit, inline=True,
-    static_argnames=("tile", "scale", "soft_cap", "name", "interpret"))
+    static_argnames=("tile", "scale", "soft_cap", "name", "interpret",
+                     "window"))
 def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
-              tile, scale, soft_cap, name, interpret):
+              tile, scale, soft_cap, name, interpret, window=None):
     """Jitted (inlined into its caller) for its cache alone: a step traces
     one call per layer and pass, and every one after the first is the
     first's jaxpr."""
@@ -333,7 +354,7 @@ def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
     ]
     kernel = functools.partial(
         _gqa_kernel, scale=scale, soft_cap=soft_cap, groups=groups,
-        quant=len(scales) > 0,
+        quant=len(scales) > 0, window=window,
     )
     out = _segment_call(
         kernel, name, interpret, RowSegments(tile, blocks, count), positions,
@@ -343,16 +364,21 @@ def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
 
 
 def _gqa(q, k_pages, v_pages, scales, page_tables, positions, *, scale,
-         soft_cap, segments, name):
+         soft_cap, segments, name, window=None):
+    window = window or None
+    if window:
+        # the same body under a name of its own: a trace and a lowered step
+        # tell a window layer's calls from a full layer's
+        name = name.replace("paged_attention", "paged_attention_window")
     if segments is None:
         segments = _rows_as_segments(
             page_tables, positions, k_pages.shape[1],
-            q.shape[1] * (q.shape[2] + v_pages.shape[-1]))
+            q.shape[1] * (q.shape[2] + v_pages.shape[-1]), window)
     return _gqa_call(
         q, k_pages, v_pages, tuple(scales), positions,
         segments.blocks, segments.count, tile=segments.tile,
         scale=float(scale), soft_cap=soft_cap, name=name,
-        interpret=_interpret(),
+        interpret=_interpret(), window=window,
     )
 
 
@@ -362,14 +388,17 @@ def paged_attention_kernel(
     scale: float,
     soft_cap: float | None = None,
     segments: RowSegments | None = None,
+    window: int | None = None,
 ):
     """GQA ragged paged attention; q (T, Hq, D), pages (N, ps, Hkv, D[v]),
     `page_tables` (T, P) per ROW. `segments` groups the rows into runs of
-    one sequence (`row_segments`); None: every row its own."""
+    one sequence (`row_segments`, built with the same `window`); None:
+    every row its own. `window` (static): a row attends to the keys fewer
+    than `window` positions behind it."""
     return _gqa(
         q, k_pages, v_pages, (), page_tables, positions,
         scale=scale, soft_cap=soft_cap, segments=segments,
-        name="paged_attention_gqa",
+        name="paged_attention_gqa", window=window,
     )
 
 
@@ -379,13 +408,14 @@ def paged_attention_quant_kernel(
     scale: float,
     soft_cap: float | None = None,
     segments: RowSegments | None = None,
+    window: int | None = None,
 ):
     """GQA ragged paged attention over int8 pages with (N, ps) per-row
     scales; same contract as `paged_attention_kernel`."""
     return _gqa(
         q, k_pages, v_pages, (k_scales, v_scales), page_tables, positions,
         scale=scale, soft_cap=soft_cap, segments=segments,
-        name="paged_attention_gqa_int8",
+        name="paged_attention_gqa_int8", window=window,
     )
 
 

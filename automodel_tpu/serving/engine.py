@@ -25,6 +25,11 @@ models/llm/mamba.py) runs the ragged selective scan (ops/selective_scan.py)
 over a per-SLOT state that lives in a tree of its own beside the pool
 (`ServingEngine.state`, kv_pages.init_state; donated and aliased like the
 pool): `step(params, pool, batch, state) -> (pool, tokens, logprobs, state)`.
+A GQA attention layer with a WINDOW keeps no pool pages either: its keys and
+values live in a ring of a few pages per slot (kv_pages.init_rings), in the
+same state tree behind the state-space entries, and the same paged kernel
+reads it through an arithmetic page table with the window in its block list
+and mask. The pool then holds the full-attention layers alone.
 The layers are walked differently too: generate.py scans stacked arrays, the
 step loops in Python over PER-LAYER buffers (split_layer_stacks below splits
 the weights once, at construction; kv_pages.init_pool makes the pool per
@@ -68,6 +73,7 @@ through loggers/metric_logger.MetricLogger when one is passed).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import logging
@@ -89,6 +95,7 @@ from automodel_tpu.models.llm.decoder import (
     _dense,
     layer_operators,
     layer_windows,
+    make_freq_for,
     project_qkv,
     unembed,
 )
@@ -117,9 +124,13 @@ from automodel_tpu.serving.kv_pages import (
     PageAllocator,
     apply_defrag,
     init_pool,
+    init_rings,
     init_state,
+    keeps_rings,
     pool_bytes,
     pool_shardings,
+    ring_page_tables,
+    ring_pages,
     state_shardings,
 )
 from automodel_tpu.serving.prefix_cache import PrefixCache, PrefixCacheConfig
@@ -303,9 +314,11 @@ class ServingEngine:
     over pages; a looped decoder's passes walked over a pool of passes x
     layers entries; layers that name a state-space mixer instead of attention
     (`cfg.layer_ops`), whose convolution and recurrent state live per SLOT in
-    `self.state` beside the pool. What begins a request at a position other
-    than 0 without having run the rows before it has no state to begin from:
-    for a model that holds state prefix hits are cut to none (counted),
+    `self.state` beside the pool, and GQA layers with a sliding window, whose
+    keys and values live in a ring per slot in that same tree. What begins a
+    request at a position other than 0 without having run the rows before
+    it has no state, and no ring, to begin from: for a model that holds
+    either (`begins_at_zero`) prefix hits are cut to none (counted),
     speculation and a hand-off between pools are refused by name. The
     heterogeneous engine (HetMoEConfig: attention layers of unlike geometry,
     sparse index caches) is not servable here."""
@@ -368,12 +381,18 @@ class ServingEngine:
         self.is_mla = cfg.attention_type == "mla"
         # some layer carries a state from token to token (kv_pages.init_state)
         self.holds_state = cfg.holds_state
+        self._plan_layers()
+        # a request may begin at position 0 alone: what a slot carries
+        # (a recurrent state, a window layer's ring) cannot be adopted
+        self.begins_at_zero = self.holds_state or self._ring_pages > 0
         spec = serve_cfg.speculative
-        if self.holds_state and spec is not None and spec.enabled:
+        if self.begins_at_zero and spec is not None and spec.enabled:
             raise NotImplementedError(
                 "speculative decoding over a model that holds a recurrent "
-                "state: a rejected draft would have to roll the state back, "
-                "and no snapshot of it is kept"
+                "state or keeps a window layer's keys in a ring per slot: a "
+                "rejected draft would have to roll the state back (no "
+                "snapshot of it is kept), and a verify block's rows may "
+                "overwrite ring pages the pending row still reads"
             )
         # rows of the paged kernels' q tile, from the step's rows and the
         # elements of one row's attention queries and outputs
@@ -410,61 +429,6 @@ class ServingEngine:
                 ),
             )
 
-        # stacks mirror generate.py: dense decoder = one; MoE = dense prefix
-        # stack then MoE stack. Under an ep>1 mesh the MoE stack routes
-        # through PR 1's EP shard_map machinery (dropless dispatch + expert
-        # A2A INSIDE the step) instead of the single-shard dropless path.
-        if self.is_moe:
-            self._stacks = []
-            if cfg.first_k_dense > 0:
-                self._stacks.append(("dense_layers", _dense_mlp, cfg.first_k_dense))
-            self._stacks.append(
-                ("moe_layers", self._moe_mlp, cfg.num_moe_layers)
-            )
-        else:
-            self._stacks = [
-                ("layers", _dense_mlp, len(self.params["layers"]))
-            ]
-
-        # per stack and layer (kind, index into that kind's operator stack),
-        # the index None where attention's weights are the layer's own
-        ops = layer_operators(cfg)
-        self._stack_ops = [
-            ops if ops is not None else (("attention", None),) * L
-            for *_, L in self._stacks
-        ]
-        # pool entries a pass: one per attention layer of each stack
-        self._stack_attn = [
-            sum(kind == "attention" for kind, _ in ops)
-            for ops in self._stack_ops
-        ]
-
-        n_layers = sum(L for *_, L in self._stacks)
-        windows = [w or 0 for w in layer_windows(cfg, n_layers)]
-        self._stack_windows = []
-        off = 0
-        for *_, L in self._stacks:
-            self._stack_windows.append(
-                jnp.asarray(windows[off : off + L], jnp.int32)
-            )
-            off += L
-        # windows ride the layer loop as per-layer array values, so one
-        # windowed layer hands every layer's `window` to the paged op — and
-        # the op's dispatch rule (ops/paged_attention.py) then runs the XLA
-        # reference for the whole model, as it does for sinks
-        self._any_window = any(windows)
-        # a model without rotary embedding (cfg.use_rope False) has no table
-        self._inv_freq = rope_frequencies(
-            cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
-        ) if cfg.use_rope else None
-        if cfg.rope_local_theta is not None:
-            inv_local = rope_frequencies(cfg.rope_dim, cfg.rope_local_theta, None)
-            self._freq_for_win = lambda win: jnp.where(
-                win > 0, inv_local, self._inv_freq
-            )
-        else:
-            self._freq_for_win = lambda win: self._inv_freq
-
         self.pool = init_pool(
             cfg, self._stack_attn,
             serve_cfg.num_pages, serve_cfg.page_size,
@@ -478,8 +442,15 @@ class ServingEngine:
         # what the layers that are not attention carry per SLOT: a tree of
         # its own, so that nothing which maps over the pool's page axis
         # (copy-on-write, defrag, transfer) ever sees a slot-axis array;
-        # `()` for a decoder of attention alone
-        self.state = init_state(cfg, serve_cfg.max_slots, self._mesh)
+        # behind them the window layers' rings, one per layer and pass; `()`
+        # for a decoder of full attention alone
+        ssm_state = init_state(cfg, serve_cfg.max_slots, self._mesh)
+        self._num_ssm = len(ssm_state)
+        self.state = ssm_state + init_rings(
+            cfg, cfg.num_passes * sum(self._stack_rings), serve_cfg.max_slots,
+            self._ring_pages, serve_cfg.page_size, self._mesh,
+            serve_cfg.kv_cache_dtype,
+        )
         # ENGINE-LIFETIME prefix cache (SGLang-RadixAttention-style): with
         # the cache enabled, the refcounted allocator and the radix tree
         # are created ONCE here and threaded through every scheduler this
@@ -489,11 +460,13 @@ class ServingEngine:
         # each scheduler keeps its private throwaway allocator (per-call
         # semantics exactly as before).
         pc = serve_cfg.prefix_cache
-        if self.holds_state and pc is not None and pc.enabled:
+        if self.begins_at_zero and pc is not None and pc.enabled:
             logger.warning(
-                "prefix cache over a model that holds a recurrent state: every "
-                "hit is CUT (serve_prefix_hits_cut_total counts them): adopted "
-                "pages would skip rows whose state nobody kept"
+                "prefix cache over a model that holds a recurrent state or a "
+                "window layer's ring per slot: every hit is CUT "
+                "(serve_prefix_hits_cut_total counts them): adopted pages "
+                "would skip rows whose state, or whose window's keys, nobody "
+                "kept"
             )
         if pc is not None and pc.enabled:
             self.alloc = PageAllocator(serve_cfg.num_pages, serve_cfg.page_size)
@@ -522,11 +495,21 @@ class ServingEngine:
             // ((serve_cfg.num_pages + 1) * serve_cfg.page_size)
         )
         # and what a slot costs whatever its length (0: attention alone)
-        reg.gauge("serve_attn_layers").set(sum(self._stack_attn))
-        reg.gauge("serve_ssm_layers").set(len(self.state))
+        reg.gauge("serve_attn_layers").set(
+            sum(self._stack_attn) + sum(self._stack_rings))
+        reg.gauge("serve_ssm_layers").set(self._num_ssm)
         reg.gauge("serve_state_bytes_per_slot").set(
-            pool_bytes(self.state) // (serve_cfg.max_slots + 1)
+            pool_bytes(self.state[:self._num_ssm]) // (serve_cfg.max_slots + 1)
         )
+        # of the attention layers, which keep a ring per slot and which pages
+        # (`serve_kv_bytes_per_token` counts the latter alone)
+        reg.gauge("serve_window_layers").set(sum(self._stack_rings))
+        reg.gauge("serve_full_layers").set(sum(self._stack_attn))
+        reg.gauge("serve_window_bytes_per_slot").set(
+            pool_bytes(self.state[self._num_ssm:]) // (serve_cfg.max_slots + 1)
+        )
+        reg.gauge("serve_experts_held").set(
+            cfg.moe.num_held if self.is_moe else 0)
         # the pool and the state are donated: both are written in place
         if self._mesh is None:
             self._step = jax.jit(self._step_impl, donate_argnums=(1, 3))
@@ -555,7 +538,8 @@ class ServingEngine:
                 {k: rep for k in batch_keys},
             ]
             if self.state:
-                ssh = state_shardings(cfg, self._mesh)
+                ssh = state_shardings(cfg, self._mesh) + jax.tree.map(
+                    lambda a: a.sharding, self.state[self._num_ssm:])
                 in_sh.append(ssh)
                 out_sh.append(ssh)
             self._step = jax.jit(
@@ -565,6 +549,70 @@ class ServingEngine:
                 out_shardings=tuple(out_sh),
             )
         self.steps_run = 0
+
+    def _plan_layers(self) -> None:
+        """What `_step_impl` walks, from the configurations alone (no
+        parameter is read: tests/step_shapes.py builds a step from shapes):
+        per stack its layers' operators, windows, rotary tables, and which
+        of its attention layers read the pool and which a ring."""
+        cfg, sc = self.cfg, self.serve_cfg
+        # stacks mirror generate.py: dense decoder = one; MoE = dense prefix
+        # stack then MoE stack. Under an ep>1 mesh the MoE stack routes
+        # through PR 1's EP shard_map machinery (dropless dispatch + expert
+        # A2A INSIDE the step) instead of the single-shard dropless path.
+        if self.is_moe:
+            self._stacks = []
+            if cfg.first_k_dense > 0:
+                self._stacks.append(("dense_layers", _dense_mlp, cfg.first_k_dense))
+            self._stacks.append(
+                ("moe_layers", self._moe_mlp, cfg.num_moe_layers)
+            )
+        else:
+            self._stacks = [("layers", _dense_mlp, cfg.num_layers)]
+
+        # per stack and layer (kind, index into that kind's operator stack),
+        # the index None where attention's weights are the layer's own
+        ops = layer_operators(cfg)
+        self._stack_ops = [
+            ops if ops is not None else (("attention", None),) * L
+            for *_, L in self._stacks
+        ]
+        # per stack and layer its window, a STATIC of the trace (None: full
+        # attention): the step's loop is Python's, so the paged op takes the
+        # window as a static of each call and a rotary table is chosen, or
+        # left out, per layer
+        windows = layer_windows(cfg, sum(L for *_, L in self._stacks))
+        self._stack_windows, off = [], 0
+        for *_, L in self._stacks:
+            self._stack_windows.append(tuple(windows[off : off + L]))
+            off += L
+        # A GQA layer with a window keeps its keys and values in a ring of
+        # `_ring_pages` pages per slot instead of pool pages (kv_pages.py);
+        # the ring holds the window behind the longest run of rows one slot
+        # can have in a step, the prefill chunk. (MLA layers with a window
+        # stay on the pool and the reference.)
+        rings = keeps_rings(cfg)
+        self._ring_pages = ring_pages(
+            cfg.sliding_window, sc.prefill_chunk or sc.token_budget,
+            sc.page_size,
+        ) if rings else 0
+        # per stack: layers that read a ring, and pool entries a pass (one
+        # per attention layer that does not)
+        self._stack_rings = [
+            sum(kind == "attention" and bool(w) for (kind, _), w in zip(ops, wins))
+            if rings else 0
+            for ops, wins in zip(self._stack_ops, self._stack_windows)
+        ]
+        self._stack_attn = [
+            sum(kind == "attention" for kind, _ in ops) - n_rings
+            for ops, n_rings in zip(self._stack_ops, self._stack_rings)
+        ]
+        # a model without rotary embedding (cfg.use_rope False) has no table;
+        # a layer kind without one (cfg.rope_layers) gets None
+        self._inv_freq = rope_frequencies(
+            cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
+        ) if cfg.use_rope else None
+        self._freq_for = make_freq_for(cfg, self._inv_freq)
 
     # -- mesh plumbing ------------------------------------------------------
     @staticmethod
@@ -586,6 +634,13 @@ class ServingEngine:
                 f"tp={tp} over a model that holds a recurrent state: the "
                 "per-slot state and the scan over it are not partitioned; "
                 "serve it on tp=1 and replicate engines behind a ReplicaRouter"
+            )
+        if tp > 1 and keeps_rings(cfg):
+            raise ValueError(
+                f"tp={tp} over a model with sliding-window layers: their "
+                "keys and values live in a ring per slot, which is not "
+                "partitioned over heads; serve it on tp=1 and replicate "
+                "engines behind a ReplicaRouter"
             )
         if tp > 1:
             if cfg.attention_type == "mla":
@@ -611,6 +666,12 @@ class ServingEngine:
             moe = getattr(cfg, "moe", None)
             if moe is None:
                 raise ValueError("ep>1 needs an MoE decoder")
+            if not moe.holds_all_experts:
+                raise ValueError(
+                    f"ep={ep} over a layer that holds a share of its experts "
+                    f"({moe.num_held} of {moe.n_routed_experts}): the share is "
+                    "one rank's part, held without its exchange"
+                )
             if moe.n_routed_experts % ep:
                 raise ValueError(
                     f"n_routed_experts={moe.n_routed_experts} not "
@@ -703,16 +764,25 @@ class ServingEngine:
         return h + moe_out
 
     # -- device step --------------------------------------------------------
-    def _attn(self, h, lp, win, cache, b):
+    def _attn(self, h, lp, window, cache, b):
         """One attention sub-block over the paged pool; `cache` is one
         layer's page arrays — (k, v) fp, or (k, v, k_scale, v_scale)
         with kv_cache_dtype="int8", where new-token rows quantize IN-JIT at
         scatter time (ops/quant.quantize_kv_rows) and attention dequantizes
-        behind the page gather. Returns (post-residual h, written cache).
-        h is (1, T, H)."""
+        behind the page gather. `window` is the layer's, static (None:
+        full attention). With a window, in a model that keeps rings, `cache`
+        is the layer's ring of pages per slot, not a pool entry, and the
+        rows' write pages, page tables and block list are the ring's.
+        Returns (post-residual h, written cache). h is (1, T, H)."""
         cfg = self.cfg
-        window = win if self._any_window else None
-        freq = self._freq_for_win(win)
+        freq = self._freq_for(window)
+        if window and self._ring_pages:
+            page, tables, segments, write = (
+                b["ring_page"], b["ring_pt_tok"], b["ring_segments"],
+                "serve.ring_write")
+        else:
+            page, tables, segments, write = (
+                b["page"], b["pt_tok"], b["segments"], "serve.pool_write")
         positions = jnp.maximum(b["pos"], 0)[None]  # (1, T); pads clamped
         x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_norm_eps,
                      cfg.zero_centered_norm)
@@ -731,19 +801,19 @@ class ServingEngine:
                 pool_k, pool_v, s_c, s_kr = cache
                 qc, c_rows = quantize_kv_rows(c_kv[0])
                 qkr, kr_rows = quantize_kv_rows(k_rope[0])
-                with jax.named_scope("serve.pool_write"):
-                    pool_k = pool_k.at[b["page"], b["off"]].set(qc)
-                    pool_v = pool_v.at[b["page"], b["off"]].set(qkr)
-                    s_c = s_c.at[b["page"], b["off"]].set(c_rows)
-                    s_kr = s_kr.at[b["page"], b["off"]].set(kr_rows)
+                with jax.named_scope(write):
+                    pool_k = pool_k.at[page, b["off"]].set(qc)
+                    pool_v = pool_v.at[page, b["off"]].set(qkr)
+                    s_c = s_c.at[page, b["off"]].set(c_rows)
+                    s_kr = s_kr.at[page, b["off"]].set(kr_rows)
                 scales_kw = dict(c_scales=s_c, kr_scales=s_kr)
             else:
                 pool_k, pool_v = cache
-                with jax.named_scope("serve.pool_write"):
-                    pool_k = pool_k.at[b["page"], b["off"]].set(
+                with jax.named_scope(write):
+                    pool_k = pool_k.at[page, b["off"]].set(
                         c_kv[0].astype(pool_k.dtype)
                     )
-                    pool_v = pool_v.at[b["page"], b["off"]].set(
+                    pool_v = pool_v.at[page, b["off"]].set(
                         k_rope[0].astype(pool_v.dtype)
                     )
             scale = (
@@ -752,9 +822,9 @@ class ServingEngine:
             )
             out_lat = ragged_paged_mla_attention(
                 q_abs[0], q_rope[0], pool_k, pool_v,
-                b["pt_tok"], b["pos"],
+                tables, b["pos"],
                 scale=scale, window=window, mesh_ctx=self._mesh,
-                segments=b["segments"], **scales_kw,
+                segments=segments, **scales_kw,
             )
             attn = jnp.einsum("tnr,rnd->tnd", out_lat, w_uv)
             attn = attn.reshape(1, -1, n * dv)
@@ -769,19 +839,19 @@ class ServingEngine:
             pool_k, pool_v, s_k, s_v = cache
             qk, k_rows = quantize_kv_rows(k[0])
             qv, v_rows = quantize_kv_rows(v[0])
-            with jax.named_scope("serve.pool_write"):
-                pool_k = pool_k.at[b["page"], b["off"]].set(qk)
-                pool_v = pool_v.at[b["page"], b["off"]].set(qv)
-                s_k = s_k.at[b["page"], b["off"]].set(k_rows)
-                s_v = s_v.at[b["page"], b["off"]].set(v_rows)
+            with jax.named_scope(write):
+                pool_k = pool_k.at[page, b["off"]].set(qk)
+                pool_v = pool_v.at[page, b["off"]].set(qv)
+                s_k = s_k.at[page, b["off"]].set(k_rows)
+                s_v = s_v.at[page, b["off"]].set(v_rows)
             scales_kw = dict(k_scales=s_k, v_scales=s_v)
         else:
             pool_k, pool_v = cache
-            with jax.named_scope("serve.pool_write"):
-                pool_k = pool_k.at[b["page"], b["off"]].set(
+            with jax.named_scope(write):
+                pool_k = pool_k.at[page, b["off"]].set(
                     k[0].astype(pool_k.dtype)
                 )
-                pool_v = pool_v.at[b["page"], b["off"]].set(
+                pool_v = pool_v.at[page, b["off"]].set(
                     v[0].astype(pool_v.dtype)
                 )
         scale = (
@@ -789,10 +859,10 @@ class ServingEngine:
             else cfg.resolved_head_dim ** -0.5
         )
         attn = ragged_paged_attention(
-            q[0], pool_k, pool_v, b["pt_tok"], b["pos"],
+            q[0], pool_k, pool_v, tables, b["pos"],
             scale=scale, window=window,
             soft_cap=cfg.attn_soft_cap, sinks=lp.get("sinks"),
-            mesh_ctx=self._mesh, segments=b["segments"], **scales_kw,
+            mesh_ctx=self._mesh, segments=segments, **scales_kw,
         )
         T = attn.shape[0]
         attn = attn.reshape(1, T, cfg.num_heads * attn.shape[-1])
@@ -862,11 +932,27 @@ class ServingEngine:
             page_size=self.serve_cfg.page_size, tile=self._attn_row_tile,
             max_slots=self.serve_cfg.max_slots,
         )
-        if state:
+        if self._num_ssm:
             # the rows grouped into runs of one slot at consecutive positions,
             # once for every state-space layer of the step, on every backend
             b["runs"] = step_runs(
                 b["slot"], b["pos"], trash=self.serve_cfg.max_slots
+            )
+        if self._ring_pages:
+            # the window layers' side of the same rows, once for all of
+            # them: where each row's key and value land in its slot's ring
+            # (pad rows in the trash slot's), the ring as a page table, and
+            # the block list that starts at the first in-window page
+            sc, R = self.serve_cfg, self._ring_pages
+            ring_slot = jnp.where(b["slot"] >= 0, b["slot"], sc.max_slots)
+            b["ring_page"] = ring_slot * R + (
+                jnp.maximum(b["pos"], 0) // sc.page_size) % R
+            b["ring_pt_tok"] = ring_page_tables(ring_slot, R, sc.pages_per_slot)
+            b["ring_segments"] = step_row_segments(
+                b["slot"], b["pos"], b["ring_pt_tok"],
+                page_size=sc.page_size, tile=self._attn_row_tile,
+                max_slots=sc.max_slots, window=self.cfg.sliding_window,
+                max_pages=R,
             )
         # copy-on-write splits first (≤ 1 per slot; idle entries copy the
         # trash page onto itself): a slot about to append into a page some
@@ -890,11 +976,19 @@ class ServingEngine:
         # computed here: while every token runs every pass (the only case
         # built) it cannot change a logit.
         #
-        # A layer's operator is attention over its pool entry or (a model
-        # whose layers name their mixer) a state-space mixer over its entry
-        # of `state`; the operator's weights then come from its kind's stack.
+        # A layer's operator is attention over its pool entry, attention
+        # with a window over its ring (entry `num_ssm + r` of `state`, r
+        # counting the ring layers of every pass in order), or (a model whose
+        # layers name their mixer) a state-space mixer over its entry of
+        # `state`; the operator's weights then come from its kind's stack.
         new_pool = [[] for _ in self._stacks]
         new_state = list(state)
+        r = self._num_ssm
+        # the full layers' own scope only beside window layers: a model
+        # without them lowers as it always did
+        full_scope = functools.partial(
+            jax.named_scope, "serve.attn.full"
+        ) if self._ring_pages else contextlib.nullcontext
         for t in range(cfg.num_passes):
             with jax.named_scope("serve.layers"), \
                     jax.named_scope(f"serve.pass{t}"):
@@ -909,8 +1003,15 @@ class ServingEngine:
                     for lp, win, (kind, j) in zip(params[pkey], wins, ops):
                         if j is not None:
                             lp = {**lp, **params[OPERATOR_STACKS[kind]][j]}
-                        if kind == "attention":
-                            with jax.named_scope("serve.attn"):
+                        if kind == "attention" and win and self._ring_pages:
+                            with jax.named_scope("serve.attn"), \
+                                    jax.named_scope("serve.attn.window"):
+                                h, new_state[r] = self._attn(
+                                    h, lp, win, state[r], b
+                                )
+                            r += 1
+                        elif kind == "attention":
+                            with jax.named_scope("serve.attn"), full_scope():
                                 h, cache = self._attn(
                                     h, lp, win, next(caches), b
                                 )
@@ -1291,7 +1392,8 @@ class ServingEngine:
             arrival_gating=arrival_gating,
             tracer=self.obs.tracer, track=self.track,
             attn_row_tile=self._attn_row_tile,
-            carries_state=self.holds_state,
+            carries_state=self.begins_at_zero,
+            attn_window=self.cfg.sliding_window if self._ring_pages else None,
         )
 
     def reset_prefix_cache(self) -> int:
@@ -1303,6 +1405,8 @@ class ServingEngine:
     def defrag(self, scheduler: Scheduler) -> bool:
         """Compact live pages to a dense pool prefix (kv_pages.defrag_plan);
         returns whether a compaction ran."""
+        if not jax.tree.leaves(self.pool):
+            return False  # every attention layer keeps a ring: no page array
         plan = scheduler.alloc.defrag_plan()
         if plan is None:
             return False

@@ -525,10 +525,10 @@ class FrontendBase:
         self.obs.registry.counter(
             "serve_recovery_reprefill_tokens_total",
             "known tokens requeued for re-prefill by failure recovery",
-        ).inc(len(req.known))
+        ).inc(req.known_len)
         self.obs.tracer.instant(
             "request.adopt", track=self.name, step=self.step_idx,
-            rid=req.rid, known=len(req.known), **args,
+            rid=req.rid, known=req.known_len, **args,
         )
 
     def _shed_one(self, req: Request, reason: str,
@@ -554,12 +554,12 @@ class FrontendBase:
     def _backlog(sched: Scheduler) -> int:
         """Unfed tokens resident on device (running prefill remainder)."""
         return sum(
-            max(len(r.known) - r.fed, 0) for r in sched.running.values()
+            max(r.known_len - r.fed, 0) for r in sched.running.values()
         )
 
     @staticmethod
     def _waiting_backlog(sched: Scheduler) -> int:
-        return sum(len(r.known) - r.fed for r in sched.waiting)
+        return sum(r.known_len - r.fed for r in sched.waiting)
 
     def _reachable(self, req: Request, backlog: int,
                    sched: Scheduler) -> bool:
@@ -572,7 +572,7 @@ class FrontendBase:
         but never consulted)."""
         if req.deadline is None:
             return True
-        pending = len(req.known) - req.fed
+        pending = req.known_len - req.fed
         est = -(-(SHED_SAFETY * (backlog + pending)) // sched.token_budget)
         return self.step_idx + int(est) < req.deadline
 
@@ -590,7 +590,7 @@ class FrontendBase:
                     sched.waiting.remove(req)
                     self._shed_one(req, "shed", why="deadline")
                 else:
-                    backlog += len(req.known) - req.fed
+                    backlog += req.known_len - req.fed
 
     # -- streaming ----------------------------------------------------------
     def _apply_backpressure(self) -> None:
@@ -871,7 +871,7 @@ class OnlineFrontend(FrontendBase):
     def _recovery_backlog(self) -> int:
         """Re-prefill tokens adopted but not yet queued anywhere — the
         term mid-recovery shed arithmetic must price in."""
-        return sum(len(r.known) - r.fed for r, _s, _e in self._adopted)
+        return sum(r.known_len - r.fed for r, _s, _e in self._adopted)
 
     # -- reporting ----------------------------------------------------------
     def stats(self) -> dict:
@@ -1087,7 +1087,7 @@ class DisaggOnlineFrontend(FrontendBase):
                 self._note_recovered(req)
 
     def _recovery_backlog(self) -> int:
-        return sum(len(r.known) - r.fed for r in self._requeued)
+        return sum(r.known_len - r.fed for r in self._requeued)
 
     # -- failure recovery ----------------------------------------------------
     def _recover_replica(self, klass: str, r: int, exc) -> None:

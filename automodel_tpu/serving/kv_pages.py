@@ -68,6 +68,30 @@ at every page's end, which prefix hits and hand-offs could adopt) would cost
 the whole state per page: for a model whose state is megabytes and whose
 keys and values are a kilobyte a token, nearly all of the pool.
 
+Attention layers with a WINDOW (`cfg.sliding_window` on some or all GQA
+layers) keep no pool pages either. A row at position t of such a layer reads
+the keys of positions t - window + 1 .. t and nothing older, so what a slot
+needs alive is bounded whatever its context: `window - 1` tokens behind the
+first row of a step's chunk and the chunk itself. Each such layer gets a RING
+per slot (`init_rings`): R = `ring_pages(window, prefill_chunk, page_size)`
+pages of the pool's page shape, page j of a slot's sequence at ring index
+j % R, slot s's ring at "pages" s * R .. s * R + R - 1 of one array of
+(S + 1) * R pages, slot S the trash slot that pad rows write into. So the
+array reads as a small pool of its own: the paged kernel and its reference
+take it with a page table that is arithmetic (`ring_page_tables`), the block
+list starts at the page of the first in-window key, and the mask drops what
+lies further back, which is also everything a recycled page still holds of
+the position R pages earlier. The rings live in the per-slot state tree
+(`ServingEngine.state`, behind the state-space layers' entries), donated and
+aliased like it: `num_pages`, the allocator, copy-on-write, defrag, transfer
+and the prefix tree count and move the FULL layers' pages alone and cannot
+see a ring. Nothing resets a ring when its slot changes hands: a new request
+starts at position 0 and every key at or before a row's position has been
+written by its own request by the time the row reads it. What cannot follow:
+a prefix hit or a hand-off begins a request past position 0 with the ring
+empty behind it, so hits are cut (counted) and hand-offs refused, as for a
+model that holds state.
+
 The allocator is deliberately host-side pure-python: page churn is a few
 integer ops per request per step, nothing a device roundtrip could beat.
 `defrag()` exists for pool COMPACTION (paged allocation never fragments in
@@ -410,6 +434,51 @@ def init_state(cfg, max_slots: int, mesh_ctx=None) -> tuple:
     if mesh_ctx is not None and state:
         state = jax.device_put(state, state_shardings(cfg, mesh_ctx))
     return state
+
+
+def keeps_rings(cfg) -> bool:
+    """Whether some attention layer keeps its keys and values in a ring per
+    slot: a GQA layer with a sliding window (MLA layers with one stay on the
+    pool). Such a model's requests begin at position 0 alone."""
+    from automodel_tpu.models.llm.decoder import layer_windows
+
+    return cfg.attention_type != "mla" and any(layer_windows(cfg))
+
+
+def ring_pages(window: int, prefill_chunk: int, page_size: int) -> int:
+    """Pages of one slot's ring: what `window - 1` tokens behind a chunk's
+    first row and the chunk's own `prefill_chunk` rows can span, and one more
+    for where the span starts inside a page."""
+    return pages_for(window - 1 + prefill_chunk, page_size) + 1
+
+
+def init_rings(
+    cfg, num_layers: int, max_slots: int, ring: int, page_size: int,
+    mesh_ctx=None, kv_cache_dtype: str | None = None,
+) -> tuple:
+    """The window layers' cache: per layer (and pass) the page arrays of a
+    pool of (max_slots + 1) * `ring` pages, slot s's ring at pages s * ring
+    and on, the last slot the trash slot. Zeros, and nothing ever clears
+    them (see the module's text). Replicated on a mesh: an engine with
+    window layers refuses tp > 1."""
+    row = (cfg.num_kv_heads, cfg.resolved_head_dim)
+    rings = tuple(
+        _init_layer((row, row), cfg.dtype, (max_slots + 1) * ring - 1,
+                    page_size, kv_cache_dtype)
+        for _ in range(num_layers)
+    )
+    if mesh_ctx is not None and rings:
+        rings = jax.device_put(rings, mesh_ctx.replicated())
+    return rings
+
+
+def ring_page_tables(ring_slot, ring: int, pages_per_slot: int):
+    """(T, P) int32: for each row the ring page that holds page c of its
+    slot's sequence, c < P: the ring as a page table, so that the paged
+    attention op reads a ring as it reads the pool. `ring_slot` (T,) is the
+    row's slot, the trash slot for a pad row."""
+    column = jnp.arange(pages_per_slot, dtype=jnp.int32) % ring
+    return ring_slot[:, None] * ring + column[None, :]
 
 
 def pool_bytes(pool) -> int:

@@ -73,7 +73,19 @@ def refuse_state_handoff(cfg) -> None:
     """A hand-off between pools moves PAGES. A model that also carries a
     per-slot recurrent state (kv_pages.init_state) would arrive without it
     and decode from a state of zeros: refused by name until the hand-off
-    carries the state."""
+    carries the state. So would a model with GQA window layers, whose keys
+    and values live in a ring per slot (kv_pages.init_rings) and not in the
+    pages."""
+    from automodel_tpu.serving.kv_pages import keeps_rings
+
+    if keeps_rings(cfg):
+        raise NotImplementedError(
+            "a hand-off between pools (kv_transfer / DisaggRouter) for a "
+            "model with sliding-window layers: the full layers' pages would "
+            "move and the window layers' ring per slot would not; serve it "
+            "from one pool (a ServingEngine, or ReplicaRouter over whole "
+            "engines)"
+        )
     if getattr(cfg, "holds_state", False):
         raise NotImplementedError(
             "a hand-off between pools (kv_transfer / DisaggRouter) for a "

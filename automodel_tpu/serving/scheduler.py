@@ -128,6 +128,22 @@ class Request:
         return self.prompt + self.generated
 
     @property
+    def known_len(self) -> int:
+        """`len(self.known)` without building the list: the serve loop asks
+        for it several times a slot a turn, and a context is thousands long."""
+        return len(self.prompt) + len(self.generated)
+
+    def known_slice(self, start: int, stop: int) -> list:
+        """`self.known[start:stop]`, 0 <= start <= stop, without building
+        the list."""
+        n = len(self.prompt)
+        if stop <= n:
+            return self.prompt[start:stop]
+        if start >= n:
+            return self.generated[start - n:stop - n]
+        return self.prompt[start:] + self.generated[:stop - n]
+
+    @property
     def done(self) -> bool:
         return self.finish_reason is not None
 
@@ -192,7 +208,11 @@ class Scheduler:
         tracer=None,             # observability.trace.Tracer (None → no-op)
         track: str = "engine",
         attn_row_tile: int | None = None,  # the paged kernels' q tile, rows
-        carries_state: bool = False,  # some layer's state lives per slot
+        # a request may begin at position 0 alone: some layer's state, or a
+        # window layer's ring of keys, lives per slot
+        carries_state: bool = False,
+        # the window of the attention layers that keep a ring per slot
+        attn_window: int | None = None,
     ):
         # lifecycle tracing (observability/trace.py): the null tracer makes
         # every emit a constant-time no-op, so the untraced hot path is
@@ -217,6 +237,7 @@ class Scheduler:
         # where the engine's attention kernels cut a slot's run of rows
         # (ops/paged_attention.row_tile); alone, a run is never cut
         self.attn_row_tile = attn_row_tile or token_budget
+        self.attn_window = attn_window
         if admission_policy not in ("fifo", "prefix-hit"):
             raise ValueError(f"unknown admission_policy {admission_policy!r}")
         if admission_policy == "prefix-hit" and not (
@@ -255,10 +276,11 @@ class Scheduler:
         self.n_cancelled = 0
         self.n_cow = 0
         self.n_prefix_hits = 0        # admissions that adopted cached pages
-        # The model carries a per-slot recurrent state (serving/kv_pages.py
-        # init_state). A request may then begin only at position 0: adopted
-        # pages would skip rows whose state nobody kept, so every prefix hit
-        # is CUT to none and counted here.
+        # The model carries a per-slot recurrent state or a window layer's
+        # ring of keys (serving/kv_pages.py init_state, init_rings). A request
+        # may then begin only at position 0: adopted pages would skip rows
+        # whose state, or whose window's keys, nobody kept, so every prefix
+        # hit is CUT to none and counted here.
         self.carries_state = carries_state
         self.n_prefix_hits_cut = 0    # admissions whose radix hit was cut
         self.prefill_skipped = 0      # prompt tokens never re-prefilled
@@ -314,16 +336,24 @@ class Scheduler:
         (segment, page) blocks each paged-attention call of its step
         walks, `attn_segments` runs of one slot's rows and
         `attn_live_blocks` pages they attend to in all; against rows x
-        pages_per_slot, the share of a (row, page) grid that is left."""
-        segments = live_blocks = 0
+        pages_per_slot, the share of a (row, page) grid that is left. A
+        model with window layers says both kinds' live blocks,
+        `full_blocks` and `window_blocks`."""
+        segments = live_blocks = window_blocks = 0
         if plan is not None:
             is_start, is_last = segment_bounds(
                 np, plan.slot, plan.pos, self.attn_row_tile
             )
             segments = int(is_start.sum())
-            live_blocks = int(
-                (plan.pos[is_last] // self.page_size + 1).sum()
-            )
+            last_page = plan.pos[is_last] // self.page_size
+            live_blocks = int((last_page + 1).sum())
+            if self.attn_window:
+                # a window layer's list starts at the page of the segment's
+                # first in-window key (ops/paged_attention.row_segments)
+                first_page = np.maximum(
+                    plan.pos[is_start] - self.attn_window + 1, 0
+                ) // self.page_size
+                window_blocks = int((last_page - first_page + 1).sum())
         stats = {
             "free_pages": self.alloc.num_free,
             "resident": len(self.running),
@@ -331,6 +361,11 @@ class Scheduler:
             "attn_segments": segments,
             "attn_live_blocks": live_blocks,
         }
+        if self.attn_window:
+            # the live blocks of each kind's list: a full layer walks every
+            # page up to the row, a window layer the few the window touches
+            stats["full_blocks"] = live_blocks
+            stats["window_blocks"] = window_blocks
         if self.carries_state:
             # runs of one slot's rows at consecutive positions: what each
             # state-space layer's scan starts or continues this turn
@@ -368,7 +403,7 @@ class Scheduler:
         decode page of slack, minus adopted pages, plus one for the pending
         copy-on-write split when the first write lands in a shared page."""
         return (
-            pages_for(len(req.known) + 1, self.page_size)
+            pages_for(req.known_len + 1, self.page_size)
             - len(match.pages)
             + (1 if match.cow_pending else 0)
         )
@@ -454,7 +489,7 @@ class Scheduler:
             match = self._admissible(req, avail)
             if match is None:
                 continue
-            ratio = match.fed / max(len(req.known), 1)
+            ratio = match.fed / max(req.known_len, 1)
             key = (ratio, -i)  # tie → submission order
             if best is None or key > best[0]:
                 best = (key, i, req, match)
@@ -606,7 +641,7 @@ class Scheduler:
         avail = self.alloc.num_free + (
             self.prefix.reclaimable() if self.prefix is not None else 0
         )
-        need_total = pages_for(len(req.known) + 1, ps)
+        need_total = pages_for(req.known_len + 1, ps)
         if k:
             pinned = sum(
                 1 for p in matched[:k] if self.alloc.refcount(p) == 1
@@ -669,7 +704,7 @@ class Scheduler:
             req.donated_pages = 0
             self.tracer.instant(
                 "request.evacuate", track=self.track, rid=req.rid,
-                known=len(req.known),
+                known=req.known_len,
             )
         return out
 
@@ -811,7 +846,7 @@ class Scheduler:
         # carry them) and deadlines keep ticking, but their generation
         # holds until the serve loop unpauses them.
         order = [s for s in self._admit_order if s not in self.paused]
-        decode = [s for s in order if len(self.running[s].known) - self.running[s].fed == 1]
+        decode = [s for s in order if self.running[s].known_len - self.running[s].fed == 1]
         prefill = [s for s in order if s not in decode]
         # decode rows not yet handed out: an earlier slot's draft block may
         # never eat a later decode slot's ONE guaranteed row (stable order
@@ -824,7 +859,7 @@ class Scheduler:
                 decode_left -= 1
             if req is None or row >= T:
                 continue
-            pending = len(req.known) - req.fed
+            pending = req.known_len - req.fed
             c = min(pending, T - row, self.prefill_chunk)
             if c <= 0:
                 continue
@@ -844,7 +879,7 @@ class Scheduler:
                     plan.cow_src[slot], plan.cow_dst[slot] = pair
                     self.n_cow += 1
             planned.add(slot)
-            samples = req.fed + c == len(req.known)
+            samples = req.fed + c == req.known_len
             # speculative block: a sampling (decode-class) slot extends its
             # chunk with up to K drafted rows. Pages for the drafts come
             # from the free list / prefix-cache reclaim only — NEVER
@@ -884,14 +919,26 @@ class Scheduler:
                 ):
                     drafts.pop()
             k = len(drafts)
+            # the slot's run of rows, c of its known tokens then k drafts. A
+            # chunk is filled whole (a row at a time, each asking `req.known`
+            # anew, cost 7 ms a 512-row plan at contexts of thousands of
+            # tokens); a lone decode row is five stores
             table = self.alloc.table(slot)
-            for j in range(c + k):
-                p = req.fed + j
-                plan.tok[row + j] = req.known[p] if j < c else drafts[j - c]
-                plan.slot[row + j] = slot
-                plan.pos[row + j] = p
-                plan.page[row + j] = table[p // self.page_size]
-                plan.off[row + j] = p % self.page_size
+            if c + k == 1:
+                p = req.fed
+                plan.tok[row] = req.known_slice(p, p + 1)[0]
+                plan.slot[row] = slot
+                plan.pos[row] = p
+                plan.page[row] = table[p // self.page_size]
+                plan.off[row] = p % self.page_size
+            else:
+                rows = slice(row, row + c + k)
+                p = np.arange(req.fed, req.fed + c + k)
+                plan.tok[rows] = req.known_slice(req.fed, req.fed + c) + drafts
+                plan.slot[rows] = slot
+                plan.pos[rows] = p
+                plan.page[rows] = np.asarray(table, np.int32)[p // self.page_size]
+                plan.off[rows] = p % self.page_size
             if samples:
                 plan.sample_tok[slot] = row + c - 1
             if self.spec is not None and samples:

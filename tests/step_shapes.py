@@ -2,19 +2,17 @@
 whole serve step at its real size for a described chip without allocating a
 weight: `object.__new__(ServingEngine)` with the attributes `_step_impl`
 reads, the parameters as the per-layer trees `split_layer_stacks` would give,
-the pool and the per-slot state as `init_pool` / `init_state` would."""
+the pool and the per-slot state (the window layers' rings behind it) as
+`init_pool` / `init_state` / `init_rings` would."""
 
 import jax
 import jax.numpy as jnp
 
-from automodel_tpu.inference.generate import _dense_mlp
-from automodel_tpu.models.llm.decoder import layer_operators, layer_windows
 from automodel_tpu.models.registry import get_model_spec
 from automodel_tpu.ops.paged_attention import row_tile
-from automodel_tpu.ops.rope import rope_frequencies
 from automodel_tpu.serving import ServingConfig, ServingEngine
 from automodel_tpu.serving.engine import LAYER_STACKS
-from automodel_tpu.serving.kv_pages import init_pool, init_state
+from automodel_tpu.serving.kv_pages import init_pool, init_rings, init_state
 
 NOT_HF_KEYS = ("source", "reduced", "assumed", "published", "stands_for",
                "reference", "serve_dtype", "serving", "attn_impl",
@@ -23,7 +21,8 @@ NOT_HF_KEYS = ("source", "reduced", "assumed", "published", "stands_for",
 
 def engine_of_shapes(config: dict, serving: dict, sharding):
     """(engine, the step's arguments as ShapeDtypeStructs on `sharding`) for
-    a dense decoder's configuration file `config` (benchmark/configs)."""
+    a GQA decoder's configuration file `config` (benchmark/configs), dense
+    or with experts."""
     hf = {k: v for k, v in config.items() if k not in NOT_HF_KEYS}
     hf["architectures"] = config["architectures"]
     dtype = jnp.dtype(config["serve_dtype"])
@@ -50,21 +49,12 @@ def engine_of_shapes(config: dict, serving: dict, sharding):
     eng = object.__new__(ServingEngine)
     eng.cfg, eng.serve_cfg = cfg, sc
     eng._kv_quant, eng._mesh, eng._spec = False, None, None
-    eng.is_moe, eng.is_mla = False, cfg.attention_type == "mla"
+    eng.is_moe = getattr(cfg, "moe", None) is not None
+    eng.is_mla = cfg.attention_type == "mla"
     eng.holds_state = cfg.holds_state
     eng._attn_row_tile = row_tile(
         sc.token_budget, cfg.num_heads * 2 * cfg.resolved_head_dim)
-    L = cfg.num_layers
-    eng._stacks = [("layers", _dense_mlp, L)]
-    ops = layer_operators(cfg)
-    eng._stack_ops = [ops if ops is not None else (("attention", None),) * L]
-    eng._stack_attn = [sum(k == "attention" for k, _ in eng._stack_ops[0])]
-    eng._stack_windows = [jnp.asarray(
-        [w or 0 for w in layer_windows(cfg, L)], jnp.int32)]
-    eng._any_window = False
-    eng._inv_freq = rope_frequencies(
-        cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling) if cfg.use_rope else None
-    eng._freq_for_win = lambda win: eng._inv_freq
+    eng._plan_layers()
 
     def as_shapes(tree):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
@@ -72,7 +62,11 @@ def engine_of_shapes(config: dict, serving: dict, sharding):
 
     pool = as_shapes(jax.eval_shape(lambda: init_pool(
         cfg, eng._stack_attn, sc.num_pages, sc.page_size)))
-    state = as_shapes(jax.eval_shape(lambda: init_state(cfg, sc.max_slots)))
+    ssm = jax.eval_shape(lambda: init_state(cfg, sc.max_slots))
+    eng._num_ssm = len(ssm)
+    state = as_shapes(ssm + jax.eval_shape(lambda: init_rings(
+        cfg, cfg.num_passes * sum(eng._stack_rings), sc.max_slots,
+        eng._ring_pages, sc.page_size)))
     batch = as_shapes(jax.eval_shape(
         lambda: eng._plan_batch(eng.empty_plan())))
     args = (params, pool, batch) + ((state,) if state else ())

@@ -132,14 +132,20 @@ def test_pallas_mla_kernel_matches_reference():
 
 
 def test_dispatch_is_strict_about_kernel_unsupported_features():
-    """Windows/sinks are outside the kernel: an explicit impl='pallas'
-    raises instead of quietly running the reference; 'auto' (off-TPU: the
-    reference by rule) still serves the call."""
+    """A TRACED window and sinks are outside the kernel: an explicit
+    impl='pallas' raises instead of quietly running the reference; 'auto'
+    (off-TPU: the reference by rule) still serves the call. A window that is
+    a static of the call is the kernel's."""
     q, keys, values, kp, vp, pt, pos = _paged_setup(seed=5)
-    with pytest.raises(NotImplementedError, match="sliding windows"):
+    with pytest.raises(NotImplementedError, match="a traced sliding window"):
         ragged_paged_attention(
             q, kp, vp, pt, pos, scale=0.25, window=jnp.int32(4), impl="pallas",
         )
+    np.testing.assert_allclose(
+        np.asarray(ragged_paged_attention(
+            q, kp, vp, pt, pos, scale=0.25, window=4, impl="pallas")),
+        np.asarray(ragged_paged_attention_xla(
+            q, kp, vp, pt, pos, scale=0.25, window=4)), atol=1e-5)
     with pytest.raises(NotImplementedError, match="attention sinks"):
         ragged_paged_attention(
             q, kp, vp, pt, pos, scale=0.25, impl="pallas",

@@ -46,6 +46,10 @@ PARITY_TESTS = {
     # writes it out (benchmark/reference/jamba.py), the head tied
     "jamba": ("test_jamba.py",
               "test_forward_matches_reference_with_the_tied_head"),
+    # against the layer equations the plain reference writes out
+    # (benchmark/reference/exaone_moe.py), every expert held
+    "exaone_moe": ("test_exaone_moe.py",
+                   "test_forward_matches_reference_with_all_experts_held"),
 }
 
 #: Known gaps — families with functional tests (adapter roundtrips, recipe

@@ -74,6 +74,7 @@ WIDTHS = {
     "grouped 4:1": (48, 32, 8, 128, 84, 64, 10, 24),
     "one key/value head": (48, 8, 1, 128, 84, 64, 10, 24),
     "jamba2_3b: 20 heads over one": (256, 20, 1, 128, 3072, 64, 24, 128),
+    "k_exaone: 64 heads over 8": (1024, 64, 8, 128, 4096, 128, 64, 64),
 }
 
 
@@ -116,6 +117,43 @@ def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths,
         args = (q, pages, pages, tables, pos, *seg)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "paged_attention_gqa" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_gqa_with_a_window_compiles_for_the_chip(
+        one_chip, tpu_branch, quant):
+    """K-EXAONE's window layers' call at the cell's geometry: 1,024 rows of
+    64 heads over 8 of 128, a window of 128 tokens, the cache a ring of 8
+    pages of 128 tokens for each of 64 slots and the trash slot; the block
+    list (64 + 1024 / 32) segments x 8 pages of scalar memory, not x 64."""
+    from automodel_tpu.ops.paged_attention import (
+        RowSegments, max_row_segments, row_tile,
+    )
+
+    T, Hq, Hkv, D, ps, P, S, R, W = 1024, 64, 8, 128, 128, 64, 64, 8, 128
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    q = s((T, Hq, D), jnp.bfloat16)
+    ring = s(((S + 1) * R, ps, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
+    tables, pos = s((T, P), jnp.int32), s((T,), jnp.int32)
+    tile = row_tile(T, 2 * Hq * D)
+    most = max_row_segments(T, S, tile)
+    assert (tile, most) == (32, 96)
+    seg = (s((6, most * R), jnp.int32), s((), jnp.int32))
+    kw = dict(scale=D ** -0.5, window=W)
+    if quant:
+        scales = s(((S + 1) * R, ps), jnp.float32)
+        fn = lambda q, k, v, ks, vs, pt, pos, b, c: tpu_branch.paged_attention_quant_kernel(  # noqa: E731
+            q, k, v, ks, vs, pt, pos, segments=RowSegments(tile, b, c), **kw)
+        args = (q, ring, ring, scales, scales, tables, pos, *seg)
+    else:
+        fn = lambda q, k, v, pt, pos, b, c: tpu_branch.paged_attention_kernel(  # noqa: E731
+            q, k, v, pt, pos, segments=RowSegments(tile, b, c), **kw)
+        args = (q, ring, ring, tables, pos, *seg)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "paged_attention_window_gqa" in compiled.as_text()
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -277,6 +315,43 @@ def test_state_space_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
     # nothing is padded: a (5120, 16) state would take eight times its bytes
     assert mem.argument_size_in_bytes < 7.9e9
     assert mem.temp_size_in_bytes < 1.5e9
+
+
+def test_window_and_share_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
+    """K-EXAONE-236B-A23B's one-chip share at its cell's geometry, from
+    shapes alone (no weight is allocated): the dense layer and 7 expert layers
+    in ONE program whose arguments are the 7.73 GB of weights, the 4.30 GB
+    pool of the 2 full layers and the 1.64 GB of rings of the 6 window
+    layers, the last two donated and aliased to the outputs. The paged kernel
+    runs in every layer, under its window name in six; the three products of
+    the 8 HELD experts are `grouped_matmul` calls in each of 7 layers."""
+    import json
+
+    from tests.step_shapes import engine_of_shapes
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "k_exaone_236b_a23b_serve_v5e1.json")) as f:
+        config = json.load(f)
+    eng, args = engine_of_shapes(config, config["serving"], one_chip)
+    assert eng._ring_pages == 8 and eng._stack_rings == [1, 5]
+    assert eng._stack_attn == [0, 2] and len(args[3]) == 6
+    assert eng.cfg.moe.num_held == 8 and eng.cfg.moe.n_routed_experts == 128
+    lowered = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args)
+    text = lowered.as_text()
+    assert text.count("paged_attention_gqa") == 2
+    assert text.count("paged_attention_window_gqa") == 6
+    assert text.count("grouped_matmul") == 21 and "ragged_dot" not in text
+    mem = lowered.compile().memory_analysis()
+    page = 128 * 8 * 128 * 2
+    pool = 2 * 2 * 4097 * page
+    rings = 6 * 2 * 65 * 8 * page
+    assert (pool, rings) == (4_296_015_872, 1_635_778_560)
+    assert mem.alias_size_in_bytes >= pool + rings
+    # nothing is padded: weights 7.73 GB + pool + rings, and the plan
+    assert mem.argument_size_in_bytes < 7.74e9 + pool + rings + 1e7
+    assert mem.temp_size_in_bytes < 0.6e9
 
 
 def test_grouped_matmul_compiles_inside_a_shard_map_over_four_chips(
