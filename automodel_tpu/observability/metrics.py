@@ -327,6 +327,9 @@ METRIC_CATALOG = (
     # a serve step's ragged selective scan (ops/selective_scan.py; trace
     # time, process-global registry)
     ("selective_scan_calls_total", "counter", "traced ragged-selective-scan call sites (labeled by impl=pallas|xla and reason)"),
+    # the paged GQA kernel's body for a decode row (ops/pallas/
+    # ragged_paged_attention.py; trace time, process-global registry)
+    ("paged_attention_one_row_body_total", "counter", "traced paged GQA kernel call sites (labeled by scores=mxu|vpu: how a one-row segment's block is scored)"),
 )
 
 #: Process-global registry for components without an engine in hand

@@ -20,11 +20,17 @@ A segment's pages stream in order with the usual online-softmax (m, l,
 acc) VMEM scratch carried across them (the flash_attention.py recipe),
 reset on its first page and stored on its last. The q and output blocks
 are the segment's aligned tile: consecutive segments of one tile revisit
-the same output block and each stores its own rows alone. A segment of ONE
-row (a decode row) runs a one-row body; a longer one computes the tile's
-rows at once, each row masked at its own position, the rows outside the
-segment wholly. Rows of no segment come out zero (the wrapper zeroes them
-behind the call).
+the same output block and each stores its own rows alone. A segment of
+several rows computes the tile's rows at once, each row masked at its own
+position, the rows outside the segment wholly. A segment of ONE row (a
+decode row) runs a one-row body, and the GQA kernel has two, chosen by the
+call's static head counts alone (`one_row_body`): where a key/value head
+serves ONE query head the keys times the query summed over the lanes, on
+the VPU (the MXU would want every head's keys turned for one query row);
+where it serves SEVERAL, one product of the row's (Hq, D) queries against
+the page read as the (ps x Hkv, D) matrix it is in memory, the other
+key/value heads' columns masked out of the softmax. Rows of no segment come
+out zero (the wrapper zeroes them behind the call).
 
 Without descriptors (`segments=None`: tests, chip_smoke.py) every row is
 its own segment. Covers GQA (kv-head sharing, no KV repeat) and
@@ -154,14 +160,15 @@ def _rows_as_segments(page_tables, positions, page_size, row_width,
 
 
 def _segment_call(kernel, name, interpret, segments, positions, queries,
-                  pages, scales, out_width, scratch):
+                  pages, scales, out_width, scratch, page_size):
     """`pallas_call` over the step's blocks. `queries` are (T, H, w) row
-    arrays blocked by the block's tile, `pages` (N, ps, ...) pools and
-    `scales` their (N, ps) per-row scales blocked by the block's page, the
-    output (T, H, out_width) blocked like the queries."""
+    arrays blocked by the block's tile, `pages` (N, ...) pools of
+    `page_size` tokens a page and `scales` their (N, ps) per-row scales
+    blocked by the block's page, the output (T, H, out_width) blocked like
+    the queries."""
     tile, blocks, count = segments
     T, H, _ = queries[0].shape
-    ps = pages[0].shape[1]
+    ps = page_size
 
     def row_map(w, blocks):
         return (blocks[BLOCK_TILE, w], 0, 0)
@@ -199,11 +206,20 @@ def _segment_call(kernel, name, interpret, segments, positions, queries,
 
 
 # -- GQA ----------------------------------------------------------------------
+def one_row_body(Hq: int, Hkv: int) -> str:
+    """How a GQA call of these head counts scores a ONE-ROW segment's block
+    (a decode row's): "mxu" where a key/value head serves several query
+    heads, "vpu" where it serves one. The call's static shape decides and
+    nothing else; `_gqa_kernel` says why each."""
+    return "mxu" if Hq // Hkv > 1 else "vpu"
+
+
 def _gqa_kernel(
     blocks_ref,  # (6, W) scalar-prefetch blocks (RowSegments.blocks)
     q_ref,     # (tile, Hq, D)
-    k_ref,     # (1, ps, Hkv, D)   bf16, or int8 with scales
-    v_ref,     # (1, ps, Hkv, Dv)
+    k_ref,     # (1, ps, Hkv, D)   bf16, or int8 with scales; groups > 1:
+               # the same page as a matrix, (1, ps x Hkv, D)
+    v_ref,     # (1, ps, Hkv, Dv); groups > 1: (1, ps x Hkv, Dv)
     *rest,     # [ks_ref, vs_ref (1, 1, ps) f32 per-row scales of THIS page,]
                # out_ref (tile, Hq, Dv), one row's m/l/acc, the tile's
                # head-major q and m/l/acc
@@ -212,13 +228,16 @@ def _gqa_kernel(
     """bf16 and int8 pages alike. The tile body scores (Hkv, groups x
     tile, D) queries, the tile's rows turned head-major once a segment,
     against the page's (ps, Hkv, D) keys on the MXU, batched over the
-    key/value heads; the one-row body scores on the VPU
-    (`one_row_scores`). int8: the per-page scale rows ride the SAME
-    page-table entry as the payload, and dequantization is algebraic per
-    page: the K scale multiplies each kv slot's score column, the V scale
-    folds into the softmax weights before the value product — the int8
-    blocks are cast for the products, never materialized dequantized in
-    HBM."""
+    key/value heads. The one-row body depends on `groups`, the query heads
+    a key/value head serves (`one_row_body`): one, and it scores on the VPU
+    (`one_row_vpu`); several, and the page IS a matrix the row's queries
+    multiply with nothing to turn (`one_row_mxu`), which is why such a call
+    hands the pages in as (ps x Hkv, w) matrices. int8: the per-page scale
+    rows ride the SAME page-table entry as the payload, and dequantization
+    is algebraic per page: the K scale multiplies each kv slot's score
+    column, the V scale folds into the softmax weights before the value
+    product — the int8 blocks are cast for the products, never materialized
+    dequantized in HBM."""
     if quant:
         ks_ref, vs_ref, *rest = rest
     out_ref, m1, l1, acc1, qt, mt, lt, acct = rest
@@ -227,77 +246,126 @@ def _gqa_kernel(
     one_row = length == 1
     many_rows = length > 1
     Hq = q_ref.shape[1]
-    ps, Hkv, Dv = v_ref.shape[1:]
+    Hkv, ps, Dv = Hq // groups, page_size, v_ref.shape[-1]
     kv_idx = key0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
 
-    def finished(s):
+    def finished(s, k_scale=None):
         """Raw scores (..., ps) scaled and capped; int8: the K scale of
         each kv slot first, before any soft-cap nonlinearity."""
         if quant:
-            s = s * ks_ref[0]
+            s = s * (ks_ref[0] if k_scale is None else k_scale)
         s = s * scale
         if soft_cap is not None:
             s = soft_cap * jnp.tanh(s / soft_cap)
         return s
 
+    def by_head(page_ref):
+        """The page as the tile body's batched products read it, and the
+        axis its key/value heads lie on: (ps, Hkv, w) as an ungrouped call
+        hands it in, or made of a grouped call's (ps x Hkv, w) matrix."""
+        page = page_ref[0]
+        if groups == 1:
+            return page, 1
+        if Hkv == 1:
+            return page[None], 0
+        return page.reshape(ps, Hkv, page.shape[-1]), 1
+
     def tile_scores(q):
         """q (Hkv, R, D) → (Hkv, R, ps) f32 on the MXU."""
-        k = k_ref[0]                     # (ps, Hkv, D)
+        k, heads = by_head(k_ref)
         if quant:
             q, k = q.astype(jnp.float32), k.astype(jnp.float32)
         return finished(jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (1,))),
+            q, k, (((2,), (2,)), ((0,), (heads,))),
             preferred_element_type=jnp.float32,
         ))
-
-    def one_row_scores():
-        """(Hq, ps) f32 scores of the block's one row, on the VPU: a
-        product with the keys and a sum over D for each of the `groups`
-        query heads a key/value head serves. The MXU wants every head's
-        (ps, D) keys turned to (D, ps) first, and for ONE query row that
-        turning is most of the block: 2.9 us a block where this takes
-        1.25 and the page's fetch alone 0.97 (measured on a TPU v5e, 16
-        heads over 16 of 128, 64-token pages: PERF.md, PR 30)."""
-        k = k_ref[0].astype(jnp.float32)  # (ps, Hkv, D)
-        q = q_ref[off].astype(jnp.float32).reshape(Hkv, groups, -1)
-        per_group = []
-        for g in range(groups):
-            # query head g of every key/value head
-            s = jnp.sum(k * q[:, g][None], axis=-1)  # (ps, Hkv)
-            per_group.append(jnp.swapaxes(s, 0, 1))
-        if groups == 1:
-            return finished(per_group[0])
-        return finished(jnp.stack(per_group, axis=1).reshape(Hq, ps))
 
     def weighted_values(p):
         """p (Hkv, R, ps) → (Hkv, R, Dv); the V dequant folds into the
         weights: (p * vs) @ v_int8 == p @ v_fp."""
-        v = v_ref[0]                     # (ps, Hkv, Dv)
+        v, heads = by_head(v_ref)
         if quant:
             p, v = p * vs_ref[...], v.astype(jnp.float32)
         else:
             p = p.astype(v.dtype)
         return jax.lax.dot_general(
-            p, v, (((2,), (0,)), ((0,), (1,))),
+            p, v, (((2,), (1 - heads,)), ((0,), (heads,))),
             preferred_element_type=jnp.float32,
         )
 
     # -- a decode row ---------------------------------------------------------
+    def one_row_vpu():
+        """groups == 1. (Hq, ps) f32 scores on the VPU: a product with the
+        keys and a sum over D. The MXU wants every head's (ps, D) keys
+        turned to (D, ps) first, and for ONE query row a head that turning
+        is most of the block: 2.9 us a block where this takes 1.25 and the
+        page's fetch alone 0.97 (measured on a TPU v5e, 16 heads over 16 of
+        128, 64-token pages: PERF.md, PR 30). The softmax runs on (Hq, ps)
+        scores: on (Hkv, 1, ps) Mosaic is handed a compare with a one-wide
+        sublane axis, which the TPU compiler refuses (LLO_CHECK
+        ProducesVreg)."""
+        k = k_ref[0].astype(jnp.float32)  # (ps, Hkv, D)
+        # (as (Hkv, 1, D) and sliced again: the ops of the body the chip
+        # measured, whose jaxpr tests/unit/test_tpu_compile.py pins)
+        q = q_ref[off].astype(jnp.float32).reshape(Hkv, 1, -1)
+        s = jnp.sum(k * q[:, 0][None], axis=-1)  # (ps, Hkv)
+        _softmax_page(
+            finished(jnp.swapaxes(s, 0, 1)), _attends(kv_idx, pos0, window),
+            m1, l1, acc1,
+            lambda p: weighted_values(p.reshape(Hkv, 1, ps)).reshape(Hq, Dv),
+        )
+
+    def one_row_mxu():
+        """groups > 1. The page lies in memory as a (ps x Hkv, D) matrix,
+        row t x Hkv + h the key of token t and key/value head h, so the
+        row's (Hq, D) queries score against ALL of it in one product with
+        nothing to turn, and the columns of a query head's OTHER key/value
+        heads leave the softmax by the mask: `p @ V` over the flat values
+        is then each head's own sum. Hkv times the MXU's work on a unit
+        that idles in this body; on the VPU the same scores were `groups`
+        passes over the page (measured on a TPU v5e, the kernel alone,
+        PERF.md PR 37: 4.6 us a block -> 0.53 at 20 heads over 1 of 128
+        with 64-token pages; 1.4 -> 0.8 at 2 groups a head; a call of 2,010
+        blocks, 1,650 of them one-row, 7.7 -> 4.8 ms at 64 over 8 with
+        128-token pages, where the product batched over the key/value
+        heads, which turns keys and values head-major as the tile body
+        does, read 10.2)."""
+        q, k, v = q_ref[off], k_ref[0], v_ref[0]
+        if quant:
+            q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        column = jax.lax.broadcasted_iota(jnp.int32, (1, ps * Hkv), 1)
+        mask = _attends(key0 + column // Hkv, pos0, window)
+        if Hkv > 1:
+            head = jax.lax.broadcasted_iota(jnp.int32, (Hq, 1), 0) // groups
+            mask = jnp.logical_and(mask, column % Hkv == head)
+
+        def of_rows(per_key):
+            """(1, ps) of the page's tokens → (1, ps x Hkv) of the
+            matrix's rows, exactly: a 0/1 product in full precision."""
+            if Hkv == 1:
+                return per_key
+            token = jax.lax.broadcasted_iota(jnp.int32, (ps, ps * Hkv), 0)
+            return jnp.dot(
+                per_key, (column // Hkv == token).astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+        def weighted(p):
+            p = p * of_rows(vs_ref[0]) if quant else p.astype(v.dtype)
+            return jnp.dot(p, v, preferred_element_type=jnp.float32)
+
+        _softmax_page(
+            finished(s, of_rows(ks_ref[0]) if quant else None), mask,
+            m1, l1, acc1, weighted)
+
     @pl.when(jnp.logical_and(one_row, first_page))
     def _reset_one():
         _reset(m1, l1, acc1)
 
-    @pl.when(one_row)
-    def _one_row():
-        # the softmax runs on (Hq, ps) scores: on (Hkv, groups, ps) a model
-        # without grouping (groups == 1: one query head per key/value
-        # head) hands Mosaic a compare with a one-wide sublane axis, which
-        # the TPU compiler refuses (LLO_CHECK ProducesVreg)
-        _softmax_page(
-            one_row_scores(), _attends(kv_idx, pos0, window), m1, l1, acc1,
-            lambda p: weighted_values(
-                p.reshape(Hkv, groups, ps)).reshape(Hq, Dv),
-        )
+    pl.when(one_row)(one_row_mxu if groups > 1 else one_row_vpu)
 
     @pl.when(jnp.logical_and(one_row, last_page))
     def _store_one():
@@ -335,13 +403,20 @@ def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
     """Jitted (inlined into its caller) for its cache alone: a step traces
     one call per layer and pass, and every one after the first is the
     first's jaxpr."""
-    Hq, Hkv = q.shape[1], k_pages.shape[2]
+    Hq, (N, ps, Hkv, _) = q.shape[1], k_pages.shape
     groups = Hq // Hkv
     Dv = v_pages.shape[-1]
     qp = _pad_last(q, LANE)
     kp = _pad_last(k_pages, LANE)
     vp = _pad_last(v_pages, LANE)
     Dp, Dvp = qp.shape[-1], vp.shape[-1]
+    if one_row_body(Hq, Hkv) == "mxu":
+        # the page as the matrix `one_row_mxu` multiplies: a view, the pool
+        # is laid out so. (With ONE key/value head the four-dimensional page
+        # has a one-wide axis second-to-last, for which XLA copied the whole
+        # pool into a two-fold padded layout before every call.)
+        kp = kp.reshape(N, ps * Hkv, Dp)
+        vp = vp.reshape(N, ps * Hkv, Dvp)
     f32 = jnp.float32
     scratch = [
         pltpu.VMEM((Hq, LANE), f32),
@@ -358,7 +433,7 @@ def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
     )
     out = _segment_call(
         kernel, name, interpret, RowSegments(tile, blocks, count), positions,
-        [qp], [kp, vp], scales, Dvp, scratch,
+        [qp], [kp, vp], scales, Dvp, scratch, ps,
     )
     return out[..., :Dv]
 
@@ -374,6 +449,13 @@ def _gqa(q, k_pages, v_pages, scales, page_tables, positions, *, scale,
         segments = _rows_as_segments(
             page_tables, positions, k_pages.shape[1],
             q.shape[1] * (q.shape[2] + v_pages.shape[-1]), window)
+    from automodel_tpu.observability.metrics import default_registry
+
+    # trace time: once a call site (`_gqa_call`'s cache would tick once a
+    # shape), so an operator and a test see which body a model got
+    default_registry().counter(
+        "paged_attention_one_row_body_total",
+        scores=one_row_body(q.shape[1], k_pages.shape[2])).inc()
     return _gqa_call(
         q, k_pages, v_pages, tuple(scales), positions,
         segments.blocks, segments.count, tile=segments.tile,
@@ -526,6 +608,7 @@ def _mla_call(q_abs, q_rope, c_pages, kr_pages, scales, positions, blocks,
     return _segment_call(
         kernel, name, interpret, RowSegments(tile, blocks, count), positions,
         [q_abs, q_rope], [c_pages, kr_pages], scales, r, scratch,
+        c_pages.shape[1],
     )
 
 

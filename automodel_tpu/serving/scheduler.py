@@ -336,10 +336,13 @@ class Scheduler:
         (segment, page) blocks each paged-attention call of its step
         walks, `attn_segments` runs of one slot's rows and
         `attn_live_blocks` pages they attend to in all; against rows x
-        pages_per_slot, the share of a (row, page) grid that is left. A
-        model with window layers says both kinds' live blocks,
-        `full_blocks` and `window_blocks`."""
-        segments = live_blocks = window_blocks = 0
+        pages_per_slot, the share of a (row, page) grid that is left;
+        `attn_one_row_blocks` of them belong to runs of ONE row (decode
+        rows), whose block the GQA kernel scores in a body of its own
+        (`paged_attention_one_row_body_total` says which). A model with
+        window layers says both kinds' live blocks, `full_blocks` and
+        `window_blocks`."""
+        segments = live_blocks = one_row_blocks = window_blocks = 0
         if plan is not None:
             is_start, is_last = segment_bounds(
                 np, plan.slot, plan.pos, self.attn_row_tile
@@ -347,6 +350,8 @@ class Scheduler:
             segments = int(is_start.sum())
             last_page = plan.pos[is_last] // self.page_size
             live_blocks = int((last_page + 1).sum())
+            # a run of ONE row ends where it starts
+            one_row_blocks = int((last_page[is_start[is_last]] + 1).sum())
             if self.attn_window:
                 # a window layer's list starts at the page of the segment's
                 # first in-window key (ops/paged_attention.row_segments)
@@ -360,6 +365,7 @@ class Scheduler:
             "preempted": self.n_preemptions - preemptions_before,
             "attn_segments": segments,
             "attn_live_blocks": live_blocks,
+            "attn_one_row_blocks": one_row_blocks,
         }
         if self.attn_window:
             # the live blocks of each kind's list: a full layer walks every

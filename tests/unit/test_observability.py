@@ -271,6 +271,8 @@ def test_tracing_on_off_parity_and_compile_once(params):
     plans = [e.args for e in spans if e.name == "step.plan" and "rows" in e.args]
     assert all(1 <= p["attn_segments"] <= p["rows"] for p in plans)
     assert all(p["attn_live_blocks"] >= p["attn_segments"] for p in plans)
+    assert all(0 <= p["attn_one_row_blocks"] <= p["attn_live_blocks"]
+               for p in plans)
     # with neither sink nothing is recorded
     assert base_eng.obs.tracer is NULL_TRACER and NULL_TRACER.events == ()
     reg = eng.obs.registry.snapshot()
@@ -284,7 +286,7 @@ def test_tracing_on_off_parity_and_compile_once(params):
 
 
 PLAN_ARGS = {"free_pages", "resident", "preempted", "attn_segments",
-             "attn_live_blocks", "rows", "samples"}
+             "attn_live_blocks", "attn_one_row_blocks", "rows", "samples"}
 
 
 def _stream_all(fe, reqs):

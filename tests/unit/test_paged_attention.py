@@ -212,12 +212,12 @@ def _ragged_plan(runs, share=None, seed=0, min_rows=16, slots=PLAN_SLOTS):
     return slot, pos, tables[np.maximum(slot, 0)], N
 
 
-def _segments_of(slot, pos, pt, max_segments=PLAN_SEGMENTS):
+def _segments_of(slot, pos, pt, max_segments=PLAN_SEGMENTS, window=None):
     from automodel_tpu.ops.paged_attention import row_segments
 
     return row_segments(
         jnp.asarray(slot), jnp.asarray(pos), jnp.asarray(pt), page_size=PS,
-        tile=TILE, max_segments=max_segments,
+        tile=TILE, max_segments=max_segments, window=window,
     )
 
 
@@ -239,11 +239,18 @@ def _jitted(kernel_name, **kw):
         *args, segments=RowSegments(TILE, blocks, count), **kw))
 
 
-@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
-@pytest.mark.parametrize(
-    "heads", [(16, 16), (8, 2)], ids=["no grouping 16:16", "grouped 4:1"])
-@pytest.mark.parametrize("plan", PLANS.values(), ids=PLANS.keys())
-def test_pallas_gqa_segment_grid_matches_reference(plan, heads, quant):
+#: (query heads, key/value heads): a one-row segment's block scores on the
+#: VPU where a key/value head serves ONE query head and as a product against
+#: the page read as a (ps x Hkv, D) matrix where it serves several
+HEADS = {
+    "no grouping 16:16": (16, 16),
+    "grouped 4:1": (8, 2),
+    "20 heads over one": (20, 1),
+    "8 groups a head 16:2": (16, 2),
+}
+
+
+def _gqa_against_reference(plan, heads, quant, window=None):
     slot, pos, pt, N = _ragged_plan(**plan)
     Hq, Hkv = heads
     rng = np.random.default_rng(7)
@@ -251,19 +258,79 @@ def test_pallas_gqa_segment_grid_matches_reference(plan, heads, quant):
     kp = jnp.asarray(rng.normal(size=(N + 1, PS, Hkv, 16)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(N + 1, PS, Hkv, 16)), jnp.float32)
     pt, pos = jnp.asarray(pt), jnp.asarray(pos)
-    _tile, *segments = _segments_of(slot, pos, pt)
+    _tile, *segments = _segments_of(slot, pos, pt, window=window)
     if quant:
         (kp, ks), (vp, vs) = _quantized(kp), _quantized(vp)
         want = ragged_paged_attention_xla(
-            q, kp, vp, pt, pos, scale=0.25, k_scales=ks, v_scales=vs)
-        got = _jitted("paged_attention_quant_kernel", scale=0.25)(
-            *segments, q, kp, vp, ks, vs, pt, pos)
+            q, kp, vp, pt, pos, scale=0.25, window=window,
+            k_scales=ks, v_scales=vs)
+        got = _jitted("paged_attention_quant_kernel", scale=0.25,
+                      window=window)(*segments, q, kp, vp, ks, vs, pt, pos)
     else:
-        want = ragged_paged_attention_xla(q, kp, vp, pt, pos, scale=0.25)
-        got = _jitted("paged_attention_kernel", scale=0.25)(
+        want = ragged_paged_attention_xla(
+            q, kp, vp, pt, pos, scale=0.25, window=window)
+        got = _jitted("paged_attention_kernel", scale=0.25, window=window)(
             *segments, q, kp, vp, pt, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     assert not np.asarray(got)[np.asarray(pos) < 0].any()  # pads: exactly 0
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("heads", HEADS.values(), ids=HEADS.keys())
+@pytest.mark.parametrize("plan", PLANS.values(), ids=PLANS.keys())
+def test_pallas_gqa_segment_grid_matches_reference(plan, heads, quant):
+    _gqa_against_reference(plan, heads, quant)
+
+
+#: plans whose rows lie further into their sequences than either window
+WINDOW_PLANS = ("decode rows only",
+                "a chunk longer than the tile (split at the tile)",
+                "pad rows in the middle and at the end",
+                "a speculative run: pending token and drafts")
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize(
+    "window", [3, 6], ids=["a window inside a page", "a window over two pages"])
+@pytest.mark.parametrize("heads", HEADS.values(), ids=HEADS.keys())
+@pytest.mark.parametrize("plan", WINDOW_PLANS)
+def test_pallas_gqa_segment_grid_with_a_window_matches_reference(
+        plan, heads, window, quant):
+    """The same grid under a sliding window of 3 and of 6 tokens over
+    4-token pages: the block list starts at the first in-window page and
+    both bodies mask what lies further back."""
+    _gqa_against_reference(PLANS[plan], heads, quant, window)
+
+
+def test_the_counter_says_which_body_a_decode_row_got():
+    """`paged_attention_one_row_body_total{scores}` ticks once a traced
+    call site: `mxu` where a key/value head serves several query heads,
+    `vpu` where it serves one; the rule reads the call's shape alone."""
+    from automodel_tpu.observability.metrics import default_registry
+    from automodel_tpu.ops.pallas import ragged_paged_attention as rpa
+
+    def ticks():
+        return {body: default_registry().counter(
+            "paged_attention_one_row_body_total", scores=body).value
+            for body in ("mxu", "vpu")}
+
+    slot, pos, pt, N = _ragged_plan(**PLANS["decode rows only"])
+    seen = {}
+    for name, (Hq, Hkv) in HEADS.items():
+        q = jnp.ones((len(slot), Hq, 16), jnp.float32)
+        pages = jnp.ones((N + 1, PS, Hkv, 16), jnp.float32)
+        before = ticks()
+        jax.jit(functools.partial(
+            rpa.paged_attention_kernel, scale=0.25, window=3,
+            segments=_segments_of(slot, pos, pt, window=3))).lower(
+                q, pages, pages, jnp.asarray(pt), jnp.asarray(pos))
+        seen[name] = {b: n - before[b] for b, n in ticks().items()}
+    assert seen == {
+        "no grouping 16:16": {"mxu": 0, "vpu": 1},
+        "grouped 4:1": {"mxu": 1, "vpu": 0},
+        "20 heads over one": {"mxu": 1, "vpu": 0},
+        "8 groups a head 16:2": {"mxu": 1, "vpu": 0},
+    }
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
@@ -407,14 +474,47 @@ def test_turn_stats_count_the_grid_the_step_walks():
             plan.slot, plan.pos, plan.page_tables[np.maximum(plan.slot, 0)],
             max_segments=16)
         assert stats["attn_live_blocks"] == int(segments.count)
-        assert stats["attn_segments"] == (
-            _blocks_of(segments)["column"] == 0).sum()
+        b = _blocks_of(segments)
+        assert stats["attn_segments"] == (b["column"] == 0).sum()
+        assert stats["attn_one_row_blocks"] == (b["length"] == 1).sum()
         seen.append((stats["attn_segments"], stats["attn_live_blocks"]))
         sched.update(plan, np.zeros(4, np.int32), step)
     # 3 rows of the first prompt (1 page); 12 of the second, cut at the
     # tile into 5 and 7 (2 + 3 pages); 1 of the third (1 page)
     assert seen[0] == (4, 7)
     assert sched.turn_stats(0)["attn_segments"] == 0  # no plan, no grid
+
+
+def test_turn_stats_count_the_blocks_of_one_row_runs():
+    """`attn_one_row_blocks`: of a plan of decode rows, a chunk and pad
+    rows, the live blocks of the runs of ONE row, which the GQA kernel
+    scores in a body of its own; a chunk's last row alone in its tile is
+    such a run too."""
+    from automodel_tpu.serving.scheduler import Request, Scheduler
+
+    sched = Scheduler(
+        num_pages=32, page_size=PS, max_slots=4, pages_per_slot=8,
+        token_budget=16, prefill_chunk=12, attn_row_tile=TILE)
+    for rid, n in enumerate((5, 2, 9)):
+        sched.submit(Request(prompt=list(range(1, n + 1)), max_new_tokens=8,
+                             rid=rid))
+    plan = sched.schedule(0)           # three chunks: 5 + 2 + 9 rows
+    stats = sched.turn_stats(0, plan)
+    # rows 0-4, 5-6, then 7 ALONE before the tile's end, 8-15: the ninth
+    # prompt's first row is a run of one row (1 page)
+    assert (stats["attn_segments"], stats["attn_one_row_blocks"]) == (4, 1)
+    sched.update(plan, np.zeros(4, np.int32), 0)
+    plan = sched.schedule(1)           # three decode rows and 13 pad rows
+    assert (plan.pos >= 0).sum() == 3 and (plan.pos < 0).sum() == 13
+    stats = sched.turn_stats(0, plan)
+    # positions 5, 2 and 9 over 4-token pages: 2 + 1 + 3 pages
+    assert stats["attn_segments"] == 3
+    assert stats["attn_one_row_blocks"] == stats["attn_live_blocks"] == 6
+    segments = _segments_of(
+        plan.slot, plan.pos, plan.page_tables[np.maximum(plan.slot, 0)],
+        max_segments=16)
+    assert (_blocks_of(segments)["length"] == 1).sum() == 6
+    assert sched.turn_stats(0)["attn_one_row_blocks"] == 0
 
 
 def test_pallas_gqa_under_tp_takes_the_segments_replicated():

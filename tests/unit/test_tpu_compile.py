@@ -67,6 +67,15 @@ def _shapes(sharding, *trees):
         trees)
 
 
+def _one_row_bodies():
+    """What `paged_attention_one_row_body_total` reads now, by label."""
+    from automodel_tpu.observability.metrics import default_registry
+
+    return {body: default_registry().counter(
+        "paged_attention_one_row_body_total", scores=body).value
+        for body in ("mxu", "vpu")}
+
+
 #: (rows, heads, key/value heads, head width, pages, page size, pages a
 #: slot, slots)
 WIDTHS = {
@@ -75,6 +84,8 @@ WIDTHS = {
     "one key/value head": (48, 8, 1, 128, 84, 64, 10, 24),
     "jamba2_3b: 20 heads over one": (256, 20, 1, 128, 3072, 64, 24, 128),
     "k_exaone: 64 heads over 8": (1024, 64, 8, 128, 4096, 128, 64, 64),
+    "k_exaone at tp 4, a rank's: 16 heads over 2": (
+        1024, 16, 2, 128, 4096, 128, 64, 64),
 }
 
 
@@ -93,14 +104,10 @@ def _segments(rows, slots, pages_a_slot, row_width, shape):
     return tile, most, shapes, lambda b, c: RowSegments(tile, b, c)
 
 
-@pytest.mark.parametrize("widths", WIDTHS.values(), ids=WIDTHS.keys())
-@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths, quant):
+def _gqa_call(rpa, widths, quant, s):
+    """(function, its arguments as `s(shape, dtype)` shapes) of the paged
+    GQA call at `widths`, its row segments derived as the engine does."""
     T, Hq, Hkv, D, N, ps, P, S = widths
-
-    def s(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
     q = s((T, Hq, D), jnp.bfloat16)
     pages = s((N + 1, ps, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
     tables, pos = s((T, P), jnp.int32), s((T,), jnp.int32)
@@ -108,15 +115,71 @@ def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths,
     assert (tile, most) == (32, min(T, S + T // 32))
     if quant:
         scales = s((N + 1, ps), jnp.float32)
-        fn = lambda q, k, v, ks, vs, pt, pos, *seg: tpu_branch.paged_attention_quant_kernel(  # noqa: E731
+        fn = lambda q, k, v, ks, vs, pt, pos, *seg: rpa.paged_attention_quant_kernel(  # noqa: E731
             q, k, v, ks, vs, pt, pos, scale=D ** -0.5, segments=segments(*seg))
-        args = (q, pages, pages, scales, scales, tables, pos, *seg)
-    else:
-        fn = lambda q, k, v, pt, pos, *seg: tpu_branch.paged_attention_kernel(  # noqa: E731
-            q, k, v, pt, pos, scale=D ** -0.5, segments=segments(*seg))
-        args = (q, pages, pages, tables, pos, *seg)
+        return fn, (q, pages, pages, scales, scales, tables, pos, *seg)
+    fn = lambda q, k, v, pt, pos, *seg: rpa.paged_attention_kernel(  # noqa: E731
+        q, k, v, pt, pos, scale=D ** -0.5, segments=segments(*seg))
+    return fn, (q, pages, pages, tables, pos, *seg)
+
+
+@pytest.mark.parametrize("widths", WIDTHS.values(), ids=WIDTHS.keys())
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_paged_attention_gqa_compiles_for_the_chip(one_chip, tpu_branch, widths, quant):
+    _T, Hq, Hkv, _D, N, ps, _P, _S = widths
+    fn, args = _gqa_call(
+        tpu_branch, widths, quant,
+        lambda shape, dtype: jax.ShapeDtypeStruct(
+            shape, dtype, sharding=one_chip))
+    before = _one_row_bodies()
     compiled = jax.jit(fn).lower(*args).compile()
     assert "paged_attention_gqa" in compiled.as_text()
+    body = "mxu" if Hq > Hkv else "vpu"
+    assert _one_row_bodies() == {**before, body: before[body] + 1}
+    # the page handed in as a (ps x Hkv, D) matrix is a view of the pool,
+    # never a copy of it (int8 pages of 2 key/value heads apart, which XLA
+    # pads four-fold and relays for either body, the parent's too)
+    if not (quant and 1 < Hkv < 4):
+        assert not [ln for ln in compiled.as_text().splitlines()
+                    if " copy(" in ln
+                    and f"[{N + 1},{ps}" in ln.split(" copy(")[0]]
+
+
+#: sha256 of the GQA kernel's body (the jaxpr inside its `pallas_call`) at
+#: Ouro-2.6B's shape, 16 heads over 16, as PR 36's tree (7a1e9a3) traced it
+#: with jax 0.9.0, over bf16 and over int8 pages. A model WITHOUT grouping
+#: keeps the one-row body PR 30 measured for it: a PR that changes that body
+#: on purpose updates the digests and says what the chip read. (The jaxpr
+#: carries no source line; the lowered text does, and an edit above the
+#: kernel would move a hash of that.)
+UNGROUPED_BODY_SHA256 = {
+    False: "798b8aaae39c11a6e35adb721e94187787ab8c4d5458dfe5552e49fd1b83a354",
+    True: "bb8a9559db10f85f676b82327e6987fcefbc37a9377e06167b57b7bd18492f5f",
+}
+
+
+def _pallas_call_of(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for inner in eqn.params.values():
+            found = hasattr(inner, "jaxpr") and _pallas_call_of(inner.jaxpr)
+            if found:
+                return found
+    return None
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+def test_the_ungrouped_kernel_body_is_unchanged(tpu_branch, quant):
+    import hashlib
+
+    fn, args = _gqa_call(tpu_branch, WIDTHS["ouro_2_6b: no grouping"], quant,
+                         jax.ShapeDtypeStruct)
+    call = _pallas_call_of(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert call.params["name"] == "paged_attention_gqa" + "_int8" * quant
+    body = str(call.params["jaxpr"])
+    assert hashlib.sha256(body.encode()).hexdigest() == (
+        UNGROUPED_BODY_SHA256[quant])
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
@@ -272,10 +335,13 @@ def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
         token_budget=8))
 
     batch = eng._plan_batch(eng.empty_plan())
+    before = _one_row_bodies()
     lowered = jax.jit(eng._step_impl, donate_argnums=(1,)).lower(
         *_shapes(one_chip, eng.params, eng.pool, batch))
     assert lowered.as_text().count("paged_attention_gqa") == (
         cfg.num_passes * cfg.num_layers) == 12
+    # 4 heads over 4: a decode row scores on the VPU, as at Ouro's size
+    assert _one_row_bodies() == {**before, "vpu": before["vpu"] + 12}
     lowered.compile()
 
 
@@ -303,12 +369,19 @@ def test_state_space_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
     kernels = default_registry().counter(
         "selective_scan_calls_total", impl="pallas",
         reason="float32 state on a TPU")
-    before = kernels.value
+    before, bodies = kernels.value, _one_row_bodies()
     lowered = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args)
     assert lowered.as_text().count("paged_attention_gqa") == 2
     assert lowered.as_text().count("selective_scan") == 26
     assert kernels.value - before == 26
-    mem = lowered.compile().memory_analysis()
+    # 20 heads over ONE key/value head: a decode row scores on the MXU
+    assert _one_row_bodies() == {**bodies, "mxu": bodies["mxu"] + 2}
+    compiled = lowered.compile()
+    # and the kernel reads each pool as it lies: with the one-wide head axis
+    # second-to-last XLA copied all four into a padded layout every step
+    assert not [ln for ln in compiled.as_text().splitlines()
+                if " copy(" in ln and "[3073,64," in ln.split(" copy(")[0]]
+    mem = compiled.memory_analysis()
     state = 26 * 129 * (16 * 5120 * 4 + 3 * 5120 * 2)
     pool = 2 * 2 * 3073 * 64 * 128 * 2
     assert mem.alias_size_in_bytes >= state + pool
@@ -338,10 +411,13 @@ def test_window_and_share_step_compiles_whole_for_the_chip(one_chip, tpu_branch)
     assert eng._ring_pages == 8 and eng._stack_rings == [1, 5]
     assert eng._stack_attn == [0, 2] and len(args[3]) == 6
     assert eng.cfg.moe.num_held == 8 and eng.cfg.moe.n_routed_experts == 128
+    bodies = _one_row_bodies()
     lowered = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args)
     text = lowered.as_text()
     assert text.count("paged_attention_gqa") == 2
     assert text.count("paged_attention_window_gqa") == 6
+    # 64 heads over 8, full and window layers alike: the MXU body
+    assert _one_row_bodies() == {**bodies, "mxu": bodies["mxu"] + 8}
     assert text.count("grouped_matmul") == 21 and "ragged_dot" not in text
     mem = lowered.compile().memory_analysis()
     page = 128 * 8 * 128 * 2
