@@ -298,7 +298,6 @@ METRIC_CATALOG = (
     ("frontend_running", "gauge", "requests resident in slots"),
     ("frontend_waiting", "gauge", "requests queued for admission"),
     ("frontend_paused", "gauge", "slots paused for stream backpressure"),
-    ("frontend_itl_ewma_ms", "gauge", "decayed inter-token latency estimate (ms)"),
     ("request_ttft_ms", "histogram", "time to first token (ms)"),
     ("request_itl_ms", "histogram", "inter-token latency (ms)"),
     # serving resilience (serving/resilience.py: health board, recovery,

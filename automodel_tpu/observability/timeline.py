@@ -1,14 +1,18 @@
 """Per-request phase timelines reconstructed from trace events.
 
 Answers the question the raw stats cannot: *where did this request's TTFT
-go?* The tracer records lifecycle markers (submit → admit → handoff
-extract → handoff admit → first_token) and engine `step.run` spans; this
-module partitions each request's [submit, first_token] wall interval at
-those marker boundaries, so the phase components SUM TO TTFT EXACTLY by
-construction:
+go?* The tracer records lifecycle markers (submit → admit → first_row →
+handoff extract → handoff admit → first_token) and engine `step.run`
+spans; this module partitions each request's [submit, first_token] wall
+interval at those marker boundaries, so the phase components SUM TO TTFT
+EXACTLY by construction:
 
     queue      submit → first admission (waiting for a slot)
-    prefill    admission → handoff extract (disagg) or the committing
+    rows_wait  admission → the first plan that holds a row of it
+               (`request.first_row`: waiting for a row of a step's token
+               budget; the profiler's `serve.request.first_row` marker
+               and the benchmark's queue wait end at the same point)
+    prefill    first row → handoff extract (disagg) or the committing
                step's start (monolithic): prompt chunking time
     transfer   handoff extract → decode-side admission (disagg KV move)
     step       remainder up to first_token: the device step(s) that
@@ -35,6 +39,7 @@ class RequestTimeline:
     rid: int
     t_submit: float | None = None
     t_admit: float | None = None          # first admission anywhere
+    t_first_row: float | None = None      # first plan holding a row of it
     t_extract: float | None = None        # disagg: prefill-side extraction
     t_handoff_admit: float | None = None  # disagg: decode-side admission
     t_first: float | None = None          # first committed token
@@ -69,6 +74,9 @@ def build_timelines(events) -> dict[int, RequestTimeline]:
         elif n == "request.admit":
             if tl.t_admit is None:
                 tl.t_admit = e.ts
+        elif n == "request.first_row":
+            if tl.t_first_row is None:
+                tl.t_first_row = e.ts
         elif n == "request.handoff_extract":
             if tl.t_extract is None:
                 tl.t_extract = e.ts
@@ -116,24 +124,30 @@ def attribute_ttft(tl: RequestTimeline, step_spans) -> dict | None:
     if tl.t_submit is None or tl.t_first is None:
         return None
     t0 = tl.t_submit
-    t_admit = min(max(tl.t_admit if tl.t_admit is not None else t0, t0),
-                  tl.t_first)
+
+    def clamp(t, lo):
+        return min(max(t if t is not None else lo, lo), tl.t_first)
+
+    t_admit = clamp(tl.t_admit, t0)
+    t_row = clamp(tl.t_first_row, t_admit)
     disagg = tl.t_extract is not None and tl.t_handoff_admit is not None
     if disagg:
-        tx0 = min(max(tl.t_extract, t_admit), tl.t_first)
-        tx1 = min(max(tl.t_handoff_admit, tx0), tl.t_first)
+        tx0 = clamp(tl.t_extract, t_row)
+        tx1 = clamp(tl.t_handoff_admit, tx0)
         step_start = tx1
     else:
         tx0 = tx1 = None
         # the committing step: last step.run span ending at/before t_first
-        # that started after admission; its start splits prefill from step
-        step_start = t_admit
+        # that started after the first row; its start splits prefill from
+        # step
+        step_start = t_row
         for a, b in step_spans:
-            if a >= t_admit and b <= tl.t_first + 1e-9:
+            if a >= t_row and b <= tl.t_first + 1e-9:
                 step_start = max(step_start, a)
     phases = {
         "queue": (t0, t_admit),
-        "prefill": (t_admit, tx0 if disagg else step_start),
+        "rows_wait": (t_admit, t_row),
+        "prefill": (t_row, tx0 if disagg else step_start),
         "transfer": (tx0, tx1) if disagg else None,
         "step": (tx1 if disagg else step_start, tl.t_first),
     }

@@ -7,8 +7,12 @@ int args), so whenever a profiler session is running — the benchmark's
 trace — the program's spans lie in the `/host:CPU` plane on the profiler's
 own clock, beside the device's ops. `ObservabilityConfig.enabled` only
 decides whether the second sink, the in-memory buffer below (exports,
-flight recorder, timelines), is kept. Instants are not mirrored
-(`request.commit` fires dozens of times a step).
+flight recorder, timelines), is kept. Of the instants only the request
+markers of `PROFILED_INSTANTS` are mirrored, as zero-length
+`serve.request.*` annotations (`request.commit` fires dozens of times a
+step): their stats are `rid` and the wall-clock args the caller measured
+(`wall=`), which go to the profiler alone, so the buffer's args, and
+`digest()`, stay deterministic.
 
 Every in-memory event carries BOTH clocks: wall time (`time.perf_counter`,
 exported in microseconds for Perfetto) and a step clock (`step`), because
@@ -50,6 +54,11 @@ from jax.profiler import TraceAnnotation
 
 #: prefix of every span's name in the profiler's trace
 PROFILER_PREFIX = "serve."
+#: the lifecycle instants that also go to the profiler's trace: first
+#: admission to a slot, the first plan that holds a row of the request, its
+#: first committed token (no `engine_step` stat: the scheduler's clock is
+#: the frontend's turn, not the engine's step)
+PROFILED_INSTANTS = ("request.admit", "request.first_row", "request.first_token")
 
 
 class TraceEvent:
@@ -81,6 +90,16 @@ class TraceEvent:
 def _annotation(name, step, args):
     """The profiler-side sink of one span (span args are ints)."""
     return TraceAnnotation(PROFILER_PREFIX + name, engine_step=step, **args)
+
+
+def _marker(name, rid, wall):
+    """The profiler-side sink of one instant: a zero-length annotation for
+    a name of `PROFILED_INSTANTS` whose caller measured its `wall` args
+    (a dict of ints, perhaps empty; None: this occurrence stays in memory,
+    as a re-admission after a preemption does)."""
+    if wall is not None and name in PROFILED_INSTANTS:
+        with TraceAnnotation(PROFILER_PREFIX + name, rid=rid, **wall):
+            pass
 
 
 class _SpanCtx:
@@ -121,17 +140,19 @@ class _SpanCtx:
 
 
 class NullTracer:
-    """The tracer without a buffer: an instant is a no-op and a span is
-    the profiler's annotation alone (well under a microsecond when no
-    profiler session is running), so the instrumented hot loops cost a
-    few microseconds a step when tracing is off and nothing device-side
-    ever depends on tracing either way."""
+    """The tracer without a buffer: an instant is the profiler's marker
+    alone where it is one of `PROFILED_INSTANTS` (a no-op otherwise) and a
+    span the profiler's annotation alone (well under a microsecond each
+    when no profiler session is running), so the instrumented hot loops
+    cost a few microseconds a step when tracing is off and nothing
+    device-side ever depends on tracing either way."""
 
     enabled = False
     events = ()
 
-    def instant(self, name, *, track="engine", step=-1, rid=-1, **args):
-        pass
+    def instant(self, name, *, track="engine", step=-1, rid=-1, wall=None,
+                **args):
+        _marker(name, rid, wall)
 
     def span(self, name, *, track="engine", step=-1, rid=-1, **args):
         return _annotation(name, step, args)
@@ -162,7 +183,9 @@ class Tracer:
     def _record(self, ev: TraceEvent) -> None:
         self.events.append(ev)
 
-    def instant(self, name, *, track="engine", step=-1, rid=-1, **args):
+    def instant(self, name, *, track="engine", step=-1, rid=-1, wall=None,
+                **args):
+        _marker(name, rid, wall)
         self._record(TraceEvent(
             name, "i", time.perf_counter(), 0.0, step, track, rid, args
         ))

@@ -312,8 +312,9 @@ class ServeRecipe(TrainFinetuneRecipeForNextTokenPrediction):
             res = server.serve_batch(reqs, metric_logger=serve_logger)
         if obs.enabled:
             # end-of-run exports: Perfetto/JSONL trace, the Prometheus
-            # snapshot, and the TTFT/ITL attribution block (phase
-            # components sum to the measured median TTFT by construction)
+            # snapshot, and the TTFT/ITL attribution block (queue,
+            # rows_wait, prefill, transfer, step, backpressure: they sum to
+            # the measured median TTFT by construction), printed below too
             from automodel_tpu.observability import attribution_summary
 
             run_dir = cfg.get("run_dir", ".")

@@ -1207,8 +1207,9 @@ class ServingEngine:
         reg.counter("serve_plan_samples_total").inc(plan.n_samples)
         # step.run's three children split what the host does around the
         # device's step: the plan's uploads, the enqueue of the jitted
-        # step (a compile lands here), and the read-back, which blocks
-        # until the device is done. All four carry this step's number.
+        # step (a compile lands here), and the read-back, itself in two:
+        # the wait until the host learns the device is done, and the
+        # copies to numpy. All six carry this step's number.
         span = functools.partial(
             self.obs.tracer.span, track=self.track, step=self.steps_run
         )
@@ -1229,7 +1230,10 @@ class ServingEngine:
                 *out, self.state = out
             self.steps_run += 1
             with span("step.readback"):
-                return tuple(np.asarray(x) for x in out[1:])
+                with span("step.wait"):
+                    jax.block_until_ready(out[1:])
+                with span("step.fetch"):
+                    return tuple(np.asarray(x) for x in out[1:])
 
     def _step_args(self, batch: dict) -> tuple:
         """The jitted step's arguments: the per-slot state last, where the

@@ -31,9 +31,8 @@ One asyncio drive task owns one engine's serve loop:
   turn, so overload turns into fast "shed" rejections instead of
   requests silently queueing to timeout while holding their place. The
   decision is a pure function of (step index, queue state, request), so
-  identical arrival traces shed identical sets; the wall-clock ITL EWMA
-  is measured alongside for reporting and for converting step-unit
-  deadlines to seconds, but never enters the decision.
+  identical arrival traces shed identical sets; no wall clock enters
+  the decision.
 
 - **Cancellation.** `cancel(rid)` takes effect at the top of the next
   turn — before the next plan is built — releasing the slot's pages
@@ -98,8 +97,6 @@ from automodel_tpu.serving.scheduler import Request, Scheduler
 #: headroom factor on the steps-to-first-token estimate (shed when
 #: step + safety * est_steps >= deadline); >1 would shed earlier
 SHED_SAFETY = 1.0
-#: wall-clock inter-token-latency EWMA decay (reporting only)
-ITL_DECAY = 0.9
 
 
 @dataclasses.dataclass(frozen=True)
@@ -291,7 +288,7 @@ class FrontendBase:
         self.n_shed = 0
         self.n_rejected = 0
         self.n_recovered = 0                     # requeued here after a death
-        self.itl_ewma_s: float | None = None   # wall ITL (reporting only)
+        self._last_emit_t: float | None = None   # the last turn that committed
 
     # -- client API ---------------------------------------------------------
     def submit(self, req: Request, *, deadline_in: int | None = None
@@ -305,6 +302,9 @@ class FrontendBase:
         if req.rid < 0:
             req.rid = self._next_rid
         self._next_rid = max(self._next_rid, req.rid + 1)
+        # its entry into the program: TTFT and the request markers'
+        # `since_submit_us` count from here, as the client does
+        req.arrived_t = time.perf_counter()
         stream = TokenStream(req)
         self.n_submitted += 1
         self.obs.registry.counter(
@@ -401,6 +401,7 @@ class FrontendBase:
     async def _idle_turn(self) -> None:
         """A turn that planned nothing. Deadline expiry inside schedule()
         may still have finished work, so streams are served first."""
+        self._last_emit_t = None
         self._emit()
         self._advance()
         if self._closed and self._has_work:
@@ -420,19 +421,18 @@ class FrontendBase:
     def _pending_deadlines(self) -> list:
         return [s.next_deadline for s in self._scheds]
 
-    def _note_itl(self, dt: float, n_new: int) -> None:
-        """One step's wall seconds over the tokens it committed: the ITL
-        histogram and the EWMA beside it (reporting only)."""
+    def _note_itl(self, n_new: int) -> None:
+        """Once a turn that committed tokens: the wall time since the last
+        such turn, the gap every decoding stream sees between two tokens
+        (an idle turn starts the count anew)."""
         if not n_new:
             return
-        itl = dt / n_new
-        self.obs.registry.histogram(
-            "request_itl_ms", "inter-token latency (ms)"
-        ).observe(itl * 1e3)
-        self.itl_ewma_s = (
-            itl if self.itl_ewma_s is None
-            else ITL_DECAY * self.itl_ewma_s + (1 - ITL_DECAY) * itl
-        )
+        now = time.perf_counter()
+        if self._last_emit_t is not None:
+            self.obs.registry.histogram(
+                "request_itl_ms", "inter-token latency (ms)"
+            ).observe((now - self._last_emit_t) * 1e3)
+        self._last_emit_t = now
 
     def _advance(self) -> None:
         self.step_idx += 1
@@ -475,7 +475,6 @@ class FrontendBase:
             req, stream, deadline_in = self._arrivals.get_nowait()
             self._active[req.rid] = (req, stream)
             self._emitted[req.rid] = 0
-            req.arrived_t = time.perf_counter()
             if deadline_in is not None:
                 req.deadline = self.step_idx + deadline_in
             if self._closed or self._draining:
@@ -568,8 +567,7 @@ class FrontendBase:
         `sched`'s token budget first; a request that cannot clear that
         by its deadline would only occupy pool pages and die, so it sheds
         at the door. Pure step arithmetic — identical traces shed
-        identical sets (the wall-clock ITL EWMA is reported next to it
-        but never consulted)."""
+        identical sets."""
         if req.deadline is None:
             return True
         pending = req.known_len - req.fed
@@ -681,11 +679,6 @@ class FrontendBase:
                   ).set(waiting)
         reg.gauge("frontend_paused", "slots paused for stream backpressure"
                   ).set(sum(len(s.paused) for s in scheds))
-        if self.itl_ewma_s is not None:
-            reg.gauge(
-                "frontend_itl_ewma_ms",
-                "decayed inter-token latency estimate (ms)",
-            ).set(self.itl_ewma_s * 1e3)
         reasons: dict = {}
         for s in scheds:
             for r in s.finished:
@@ -703,10 +696,6 @@ class FrontendBase:
             "timed_out": sum(s.n_timed_out for s in scheds),
             "running": running,
             "waiting": waiting,
-            "itl_ewma_ms": (
-                round(self.itl_ewma_s * 1e3, 4)
-                if self.itl_ewma_s is not None else None
-            ),
         }
 
 
@@ -827,7 +816,7 @@ class OnlineFrontend(FrontendBase):
                     self.sched, plan, out, self.step_idx
                 )
             self.steps_run += 1
-            self._note_itl(dt, n_new)
+            self._note_itl(n_new)
             with span("frontend.emit"):
                 self._emit()
                 self._advance()
@@ -1014,7 +1003,7 @@ class DisaggOnlineFrontend(FrontendBase):
                     rids.discard(req.rid)
                     self.inflight.append(_Handoff(req, n_tok, src, ("d", j)))
             self.steps_run += 1
-            self._note_itl(dt, n_new)
+            self._note_itl(n_new)
             self._emit()
             self._advance()
 

@@ -67,6 +67,7 @@ memory — it emits a `StepPlan` of numpy arrays the engine uploads.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 
 import numpy as np
@@ -110,10 +111,14 @@ class Request:
     donated_pages: int = 0      # full pages already offered to the tree
     prefix_cut: bool = False    # a radix hit was cut (the model holds state)
     # wall-clock latency stamps (serve-loop-owned — the loop is the only
-    # layer that knows when a step's arrival window actually opened):
+    # layer that knows when a request entered it: `FrontendBase.submit`
+    # online, the step its arrival window opened offline):
     # ttft_s stays -1 for requests that never committed a token
-    arrived_t: float = -1.0     # wall time the request became servable
+    arrived_t: float = -1.0     # wall time the request entered the program
     ttft_s: float = -1.0        # time to first committed token (seconds)
+    # wall time of the first plan that held a row of it (schedule() stamps
+    # it once: the end of its queue; -1 until then)
+    first_row_t: float = -1.0
     # adaptive speculation: EWMA of per-block acceptance (accepted/drafted)
     # for THIS request; starts optimistic so the first blocks draft at full
     # K and the estimate is earned from real verifier feedback
@@ -184,6 +189,12 @@ class StepPlan:
     @property
     def n_samples(self) -> int:
         return sum(1 for *_, s in self.scheduled if s)
+
+
+def _since_us(t0: float, now: float, key: str) -> dict:
+    """`{key: microseconds from t0 to now}` for a request marker's profiler
+    args, or {} where the stamp was never set."""
+    return {key: int((now - t0) * 1e6)} if t0 >= 0 else {}
 
 
 class Scheduler:
@@ -426,7 +437,8 @@ class Scheduler:
             )
             self.running[slot] = req
             self._admit_order.append(slot)
-            if req.admitted_at < 0:
+            first = req.admitted_at < 0
+            if first:
                 req.admitted_at = step_idx
             if match.pages:
                 # radix hit: the matched prefix's pages go straight into the
@@ -443,6 +455,8 @@ class Scheduler:
             self.tracer.instant(
                 "request.admit", track=self.track, step=step_idx,
                 rid=req.rid, slot=slot, prefix_fed=match.fed,
+                wall=_since_us(req.arrived_t, time.perf_counter(),
+                               "since_submit_us") if first else None,
             )
         # FIFO admission (default): if the head doesn't fit, nothing behind
         # it jumps the queue (no starvation of long prompts). Under
@@ -957,6 +971,15 @@ class Scheduler:
             plan.seed[slot] = req.seed
             plan.scheduled.append((slot, c, samples))
             row += c + k
+            if req.first_row_t < 0:
+                # the end of its queue, as the harness times it: the first
+                # plan that holds a row of the request
+                req.first_row_t = time.perf_counter()
+                self.tracer.instant(
+                    "request.first_row", track=self.track, step=step_idx,
+                    rid=req.rid, wall=_since_us(
+                        req.arrived_t, req.first_row_t, "since_submit_us"),
+                )
         for slot, req in self.running.items():
             t = self.alloc.table(slot)
             plan.page_tables[slot, : len(t)] = t
@@ -1008,9 +1031,14 @@ class Scheduler:
                     break
             if n_commit:
                 if len(req.generated) == n_commit:
+                    now = time.perf_counter()
                     self.tracer.instant(
                         "request.first_token", track=self.track,
-                        step=step_idx, rid=req.rid,
+                        step=step_idx, rid=req.rid, wall={
+                            **_since_us(req.arrived_t, now, "since_submit_us"),
+                            **_since_us(req.first_row_t, now,
+                                        "since_first_row_us"),
+                        },
                     )
                 self.tracer.instant(
                     "request.commit", track=self.track, step=step_idx,

@@ -317,6 +317,40 @@ def test_no_deadline_means_no_shedding():
         assert out == _ref(params, p, 3)
 
 
+@BOTH_FRONTENDS
+def test_itl_is_the_period_between_committing_turns(kind):
+    """`request_itl_ms` observes once a turn that committed tokens the wall
+    time since the last such turn, whatever the tokens a turn commits (not
+    a step's time over its tokens), and an idle turn starts the count anew."""
+    import time
+
+    params = _params()
+    fe = _frontend(kind, params)
+
+    async def run():
+        fe.start()
+        out = await fe.submit(Request(prompt=[1, 2, 3], max_new_tokens=6)).collect()
+        await fe.close()
+        return out
+
+    assert len(asyncio.run(run())) == 6
+    itl = fe.obs.registry.snapshot()["request_itl_ms"]
+    # six tokens, one a turn: five periods between them on one engine; the
+    # disaggregated loop has a hand-off turn that commits nothing
+    assert itl["count"] == 5 if kind == "online" else 1 <= itl["count"] <= 5
+    assert "itl_ewma_ms" not in fe.stats()
+    before = itl["count"], itl["sum"]
+    fe._note_itl(3)
+    time.sleep(0.02)
+    fe._note_itl(3)
+    fe._note_itl(0)             # a turn that committed nothing: no period
+    asyncio.run(fe._idle_turn())
+    fe._note_itl(1)             # the first after an idle turn: no period
+    itl = fe.obs.registry.snapshot()["request_itl_ms"]
+    assert itl["count"] == before[0] + 1
+    assert itl["sum"] - before[1] >= 20.0
+
+
 # ---------------------------------------------------------------------------
 # /metrics endpoint
 # ---------------------------------------------------------------------------

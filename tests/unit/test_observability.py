@@ -248,23 +248,27 @@ def test_tracing_on_off_parity_and_compile_once(params):
     assert base["stats"]["compiled_signatures"] == 1
     names = {e.name for e in eng.obs.tracer.events}
     assert {"step.plan", "step.run", "step.upload", "step.dispatch",
-            "step.readback", "step.absorb", "request.submit",
-            "request.admit", "request.first_token", "request.done"} <= names
+            "step.readback", "step.wait", "step.fetch", "step.absorb",
+            "request.submit", "request.admit", "request.first_row",
+            "request.first_token", "request.done"} <= names
     # one step clock: every span of a turn carries the number its
     # step.run carries, and step.run's children nest inside it
     spans = [e for e in eng.obs.tracer.events if e.ph == "X"]
     runs = {e.step: e for e in spans if e.name == "step.run"}
     assert sorted(runs) == list(range(eng.steps_run))
-    for name in ("step.absorb", "step.upload", "step.dispatch",
-                 "step.readback"):
+    for name in ("step.absorb",) + RUN_SPANS:
         assert sorted(e.step for e in spans if e.name == name) == sorted(runs)
     # (a turn that could plan nothing has a step.plan without rows)
     assert sorted(e.step for e in spans if e.name == "step.plan"
                   and "rows" in e.args) == sorted(runs)
+    readbacks = {e.step: e for e in spans if e.name == "step.readback"}
     for e in spans:
-        if e.name in ("step.upload", "step.dispatch", "step.readback"):
+        if e.name in RUN_SPANS:
             run = runs[e.step]
             assert run.ts <= e.ts and e.ts + e.dur <= run.ts + run.dur
+        if e.name in ("step.wait", "step.fetch"):
+            rb = readbacks[e.step]
+            assert rb.ts <= e.ts and e.ts + e.dur <= rb.ts + rb.dur
     assert all(set(r.args) == {"rows", "samples"} for r in runs.values())
     # a planned turn says what grid its step's paged-attention calls walk:
     # at most a segment a real row, at least a live page a segment
@@ -285,6 +289,8 @@ def test_tracing_on_off_parity_and_compile_once(params):
     assert reg["serve_step_ms"]["count"] == reg["serve_steps_total"]
 
 
+RUN_SPANS = ("step.upload", "step.dispatch", "step.readback", "step.wait",
+             "step.fetch")
 PLAN_ARGS = {"free_pages", "resident", "preempted", "attn_segments",
              "attn_live_blocks", "attn_one_row_blocks", "rows", "samples"}
 
@@ -382,6 +388,11 @@ def test_digest_stable_across_identical_load_tests(params):
         report = run_load_test(eng, lt, fc)
         assert report["completed"] == 8
         digests.append(eng.obs.tracer.digest())
+        events = eng.obs.tracer.events
+        # the new marker once a request; no wall-clock arg in the buffer
+        assert sorted(e.rid for e in events
+                      if e.name == "request.first_row") == list(range(8))
+        assert not any(k.startswith("since_") for e in events for k in e.args)
     assert digests[0] == digests[1]
 
 
@@ -407,8 +418,9 @@ def test_disagg_timeline_phases_sum_to_ttft(params):
         att = attribute_ttft(tl, spans)
         if att is None:
             continue
-        total = (att["queue_ms"] + att["prefill_ms"] + att["transfer_ms"]
-                 + att["step_ms"] + att["backpressure_ms"])
+        total = (att["queue_ms"] + att["rows_wait_ms"] + att["prefill_ms"]
+                 + att["transfer_ms"] + att["step_ms"]
+                 + att["backpressure_ms"])
         assert total == pytest.approx(att["ttft_ms"], abs=1e-6)
         assert tl.t_extract is not None and tl.t_handoff_admit is not None
         checked += 1
@@ -416,6 +428,55 @@ def test_disagg_timeline_phases_sum_to_ttft(params):
     summary = attribution_summary(events)
     assert summary["with_first_token"] == 4
     assert summary["ttft_p50"]["transfer_ms"] >= 0.0
+
+
+def _timeline_events(marks):
+    """In-memory instants for rid 0 at the given wall seconds."""
+    from automodel_tpu.observability.trace import TraceEvent
+
+    return [TraceEvent(name, "i", ts, 0.0, -1, "engine", 0, {})
+            for name, ts in marks]
+
+
+def test_rows_wait_splits_admission_from_the_first_row():
+    """submit 1.0, admit 1.5, first row 1.9, the committing step 2.2-2.4,
+    first token 2.5: queue 500 ms, rows_wait 400, prefill 300, step 300."""
+    events = _timeline_events([
+        ("frontend.submit", 1.0), ("request.admit", 1.5),
+        ("request.first_row", 1.9), ("request.first_token", 2.5)])
+    (tl,) = build_timelines(events).values()
+    att = attribute_ttft(tl, [(2.0, 2.1), (2.2, 2.4)])
+    assert att == pytest.approx({
+        "queue_ms": 500.0, "rows_wait_ms": 400.0, "prefill_ms": 300.0,
+        "transfer_ms": 0.0, "step_ms": 300.0, "backpressure_ms": 0.0,
+        "ttft_ms": 1500.0})
+    # a trace without the marker (older runs) gives the interval to prefill
+    old = build_timelines([e for e in events
+                           if e.name != "request.first_row"])[0]
+    att = attribute_ttft(old, [(2.0, 2.1), (2.2, 2.4)])
+    assert att["rows_wait_ms"] == 0.0 and att["prefill_ms"] == pytest.approx(700.0)
+
+
+def test_online_ttft_phases_sum_and_carry_rows_wait(params):
+    """The online loop with the buffer kept, more requests than slots: every
+    request's phases, rows_wait among them, sum to its TTFT exactly, and
+    `attribution_summary` carries the phase."""
+    eng = ServingEngine(
+        own(params), CFG, _sc(observability=ObservabilityConfig(enabled=True)))
+    _online(eng, lens=(5, 9, 3, 7, 6))
+    events = list(eng.obs.tracer.events)
+    spans = sorted((e.ts, e.ts + e.dur) for e in events
+                   if e.ph == "X" and e.name == "step.run")
+    tls = build_timelines(events)
+    assert len(tls) == 5
+    for tl in tls.values():
+        assert tl.t_admit <= tl.t_first_row <= tl.t_first
+        att = attribute_ttft(tl, spans)
+        parts = ("queue_ms", "rows_wait_ms", "prefill_ms", "transfer_ms",
+                 "step_ms", "backpressure_ms")
+        assert sum(att[k] for k in parts) == pytest.approx(att["ttft_ms"], abs=1e-6)
+        assert min(att[k] for k in parts) >= 0.0
+    assert "rows_wait_ms" in attribution_summary(events)["ttft_p50"]
 
 
 def test_flight_recorder_dumps_on_injected_crash(params, tmp_path):
@@ -470,7 +531,8 @@ def test_observability_export_writes_both_faces(tmp_path):
 # -- the two sinks: profiler session and in-memory buffer --------------------
 
 TURN_SPANS = ("frontend.intake", "step.plan", "step.absorb", "frontend.emit")
-RUN_SPANS = ("step.upload", "step.dispatch", "step.readback")
+MARKERS = ("serve.request.admit", "serve.request.first_row",
+           "serve.request.first_token")
 
 
 def _online(eng, lens=(5, 9, 3), max_new=6):
@@ -534,6 +596,8 @@ def test_online_spans_reach_the_profiler_with_observability_off(online_untraced)
     assert eng.obs.tracer is NULL_TRACER and eng.steps_run > 3
     by_step = {}
     for name, line, t0, t1, stats in events:
+        if name in MARKERS:
+            continue
         by_step.setdefault(stats["engine_step"], []).append(
             (name[len("serve."):], line, t0, t1, stats))
     for step in range(eng.steps_run):
@@ -544,6 +608,11 @@ def test_online_spans_reach_the_profiler_with_observability_off(online_untraced)
             (child,) = [e for e in evs if e[0] == name]
             assert child[1] == run[1], "same thread line"
             assert run[2] <= child[2] and child[3] <= run[3], name
+        # the read-back in two halves: the wait for the device, the copies
+        (rb,) = [e for e in evs if e[0] == "step.readback"]
+        (wait,) = [e for e in evs if e[0] == "step.wait"]
+        (fetch,) = [e for e in evs if e[0] == "step.fetch"]
+        assert rb[2] <= wait[2] <= wait[3] <= fetch[2] <= fetch[3] <= rb[3]
         # the turn that planned this step (idle turns plan nothing and
         # carry no rows), and the one absorb / emit after it
         loop_lines = set()
@@ -557,8 +626,19 @@ def test_online_spans_reach_the_profiler_with_observability_off(online_untraced)
         assert plan[3] <= run[2], "planned before it ran"
         (absorb,) = [e for e in evs if e[0] == "step.absorb"]
         assert run[3] <= absorb[2], "absorbed after it ran"
-    # no instant is mirrored: a commit a token would swamp the trace
-    assert not [e for e in events if e[0].startswith("serve.request.")]
+    # of the instants only the three request markers are mirrored (a commit
+    # a token would swamp the trace), once a request, with the request's id
+    # and its wall-clock args and no step number
+    marks = [e for e in events if e[0].startswith("serve.request.")]
+    assert {e[0] for e in marks} == set(MARKERS)
+    for name in MARKERS:
+        mine = [e[4] for e in marks if e[0] == name]
+        assert sorted(st["rid"] for st in mine) == list(range(3)), name
+        assert all("engine_step" not in st and st["since_submit_us"] >= 0
+                   for st in mine)
+    assert all(e[3] - e[2] < 1e6 for e in marks), "zero-length annotations"
+    assert all("since_first_row_us" in e[4] for e in marks
+               if e[0] == "serve.request.first_token")
 
 
 def test_online_spans_with_observability_on_match_and_validate(
@@ -575,8 +655,19 @@ def test_online_spans_with_observability_on_match_and_validate(
     spans = [e for e in eng.obs.tracer.events if e.ph == "X"]
     in_memory = sorted((e.name, e.step) for e in spans)
     in_profile = sorted((n[len("serve."):], st["engine_step"])
-                        for n, _l, _a, _b, st in events)
+                        for n, _l, _a, _b, st in events if n not in MARKERS)
     assert in_memory == in_profile
+    # the markers: the buffer's instants of the same names, rid for rid;
+    # their wall-clock args went to the profiler alone
+    marks = {"serve." + e.name for e in eng.obs.tracer.events
+             if e.ph == "i"} & set(MARKERS)
+    assert marks == set(MARKERS)
+    assert sorted((e.name, e.rid) for e in eng.obs.tracer.events
+                  if "serve." + e.name in MARKERS) == sorted(
+        (n[len("serve."):], st["rid"]) for n, _l, _a, _b, st in events
+        if n in MARKERS)
+    assert not any(k.startswith("since_") for e in eng.obs.tracer.events
+                   for k in e.args)
     # the untraced run made the same spans, step for step (idle turns, whose
     # number the wall clock decides, add intake and plan spans only)
     per_step = {"serve." + n for n in
