@@ -157,7 +157,6 @@ def paged_serve_step():
     baseline). The prefix-hit path rides the
     SAME program — COW is the bounded copy block pinned here."""
     import jax
-    import jax.numpy as jnp
 
     from automodel_tpu.models.llm import decoder
     from automodel_tpu.serving.engine import ServingConfig, ServingEngine
@@ -169,17 +168,7 @@ def paged_serve_step():
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=8,
     ))
-    T, S, P = 8, 2, 4
-    batch = {k: jnp.zeros(T, jnp.int32) for k in ("tok", "slot", "pos", "page", "off")}
-    batch.update(
-        page_tables=jnp.zeros((S, P), jnp.int32),
-        sample_tok=jnp.zeros(S, jnp.int32),
-        temp=jnp.zeros(S, jnp.float32),
-        seed=jnp.zeros(S, jnp.int32),
-        cow_src=jnp.zeros(S, jnp.int32),
-        cow_dst=jnp.zeros(S, jnp.int32),
-    )
-    compiled = eng._step.lower(eng.params, eng.pool, batch).compile()
+    compiled = eng.lower_step().compile()
     return compiled, None
 
 
@@ -191,7 +180,6 @@ def spec_serve_step():
     — a lowering that drops the verify-row gather would silently verify
     nothing."""
     import jax
-    import jax.numpy as jnp
 
     from automodel_tpu.models.llm import decoder
     from automodel_tpu.serving.engine import ServingConfig, ServingEngine
@@ -206,19 +194,7 @@ def spec_serve_step():
         token_budget=8,
         speculative=SpeculativeConfig(enabled=True, draft_len=K),
     ))
-    T, S, P = 8, 2, 4
-    batch = {k: jnp.zeros(T, jnp.int32) for k in ("tok", "slot", "pos", "page", "off")}
-    batch.update(
-        page_tables=jnp.zeros((S, P), jnp.int32),
-        sample_tok=jnp.zeros(S, jnp.int32),
-        temp=jnp.zeros(S, jnp.float32),
-        seed=jnp.zeros(S, jnp.int32),
-        cow_src=jnp.zeros(S, jnp.int32),
-        cow_dst=jnp.zeros(S, jnp.int32),
-        verify_rows=jnp.zeros((S, K + 1), jnp.int32),
-        spec_len=jnp.zeros(S, jnp.int32),
-    )
-    compiled = eng._step.lower(eng.params, eng.pool, batch).compile()
+    compiled = eng.lower_step().compile()
     return compiled, None
 
 
@@ -233,7 +209,6 @@ def sharded_serve_step():
     per-layer all-gather/reduce-scatter budget is the baseline's pinned
     collective table (two-sided ratchet)."""
     import jax
-    import jax.numpy as jnp
 
     from automodel_tpu.distributed import MeshConfig
     from automodel_tpu.models.llm import decoder
@@ -247,24 +222,7 @@ def sharded_serve_step():
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=8,
     ), mesh_ctx=ctx)
-    T, S, P = 8, 2, 4
-    rep = ctx.replicated()
-    batch = {
-        k: jax.device_put(jnp.zeros(T, jnp.int32), rep)
-        for k in ("tok", "slot", "pos", "page", "off")
-    }
-    batch.update({
-        k: jax.device_put(v, rep)
-        for k, v in dict(
-            page_tables=jnp.zeros((S, P), jnp.int32),
-            sample_tok=jnp.zeros(S, jnp.int32),
-            temp=jnp.zeros(S, jnp.float32),
-            seed=jnp.zeros(S, jnp.int32),
-            cow_src=jnp.zeros(S, jnp.int32),
-            cow_dst=jnp.zeros(S, jnp.int32),
-        ).items()
-    })
-    compiled = eng._step.lower(eng.params, eng.pool, batch).compile()
+    compiled = eng.lower_step().compile()
     return compiled, dict(ctx.sizes)
 
 
@@ -279,7 +237,6 @@ def prefill_step():
     class — disaggregation changes WHERE phases run, never what the step
     compiles to."""
     import jax
-    import jax.numpy as jnp
 
     from automodel_tpu.models.llm import decoder
     from automodel_tpu.serving.engine import ServingConfig, ServingEngine
@@ -291,17 +248,7 @@ def prefill_step():
         page_size=4, num_pages=16, max_slots=2, pages_per_slot=4,
         token_budget=16,
     ))
-    T, S, P = 16, 2, 4
-    batch = {k: jnp.zeros(T, jnp.int32) for k in ("tok", "slot", "pos", "page", "off")}
-    batch.update(
-        page_tables=jnp.zeros((S, P), jnp.int32),
-        sample_tok=jnp.zeros(S, jnp.int32),
-        temp=jnp.zeros(S, jnp.float32),
-        seed=jnp.zeros(S, jnp.int32),
-        cow_src=jnp.zeros(S, jnp.int32),
-        cow_dst=jnp.zeros(S, jnp.int32),
-    )
-    compiled = eng._step.lower(eng.params, eng.pool, batch).compile()
+    compiled = eng.lower_step().compile()
     return compiled, None
 
 
@@ -339,7 +286,6 @@ def quant_serve_step():
     a quantization path that round-trips through bf16 casts would show up
     here before it shows up as a tolerance failure."""
     import jax
-    import jax.numpy as jnp
 
     from automodel_tpu.models.llm import decoder
     from automodel_tpu.serving.engine import ServingConfig, ServingEngine
@@ -352,17 +298,7 @@ def quant_serve_step():
         token_budget=8,
         kv_cache_dtype="int8", serve_precision="int8",
     ))
-    T, S, P = 8, 2, 4
-    batch = {k: jnp.zeros(T, jnp.int32) for k in ("tok", "slot", "pos", "page", "off")}
-    batch.update(
-        page_tables=jnp.zeros((S, P), jnp.int32),
-        sample_tok=jnp.zeros(S, jnp.int32),
-        temp=jnp.zeros(S, jnp.float32),
-        seed=jnp.zeros(S, jnp.int32),
-        cow_src=jnp.zeros(S, jnp.int32),
-        cow_dst=jnp.zeros(S, jnp.int32),
-    )
-    compiled = eng._step.lower(eng.params, eng.pool, batch).compile()
+    compiled = eng.lower_step().compile()
     return compiled, None
 
 

@@ -252,6 +252,7 @@ METRIC_CATALOG = (
     ("serve_plan_tokens_total", "counter", "tokens fed through step plans"),
     ("serve_plan_samples_total", "counter", "sample rows active in step plans"),
     ("serve_step_ms", "histogram", "device step wall time (ms)"),
+    ("serve_step_transfers_total", "counter", "host-device transfers issued by serve steps (labeled by direction=h2d|d2h)"),
     # engine serve_batch outcomes
     ("serve_new_tokens_total", "counter", "tokens committed to requests"),
     ("serve_requests_total", "counter", "requests finished by the engine"),

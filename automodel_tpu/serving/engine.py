@@ -5,13 +5,15 @@ synchronous offline path): requests of any length join and leave a running
 batch freely. The device-side step function has ONE compiled signature for
 the whole serving run —
 
-    step(params, pool, batch) -> (pool, sampled_tokens, logprobs)
+    step(params, pool, plan) -> (pool, outputs)
 
-where `batch` is the fixed-shape `StepPlan` the scheduler packs (a flat
+where `plan` is the fixed-shape `StepPlan` the scheduler builds (a flat
 `token_budget`-row ragged token batch: decode rows of many requests
-interleaved with chunked-prefill rows), `pool` is the paged KV cache
-(kv_pages.py; donated, so the update is in-place buffer reuse), and the
-sampled token per slot comes back for the host scheduler to absorb. No
+interleaved with chunked-prefill rows) packed into ONE int32 vector, `pool`
+is the paged KV cache (kv_pages.py; donated, so the update is in-place
+buffer reuse), and `outputs` is ONE int32 vector too, the sampled token and
+its log-probability per slot, for the host scheduler to absorb: one transfer
+each way a step. No
 shape in the step depends on which requests are active, how long they are,
 or how many pages they hold — requests joining/leaving NEVER recompile
 (pinned by the jit cache-miss counter test in tier-1).
@@ -24,7 +26,7 @@ A layer that names another mixer than attention (a Mamba-1 state-space layer,
 models/llm/mamba.py) runs the ragged selective scan (ops/selective_scan.py)
 over a per-SLOT state that lives in a tree of its own beside the pool
 (`ServingEngine.state`, kv_pages.init_state; donated and aliased like the
-pool): `step(params, pool, batch, state) -> (pool, tokens, logprobs, state)`.
+pool): `step(params, pool, plan, state) -> (pool, outputs, state)`.
 A GQA attention layer with a WINDOW keeps no pool pages either: its keys and
 values live in a ring of a few pages per slot (kv_pages.init_rings), in the
 same state tree behind the state-space entries, and the same paged kernel
@@ -308,6 +310,56 @@ def split_layer_stacks(params: dict, dtype) -> dict:
     return out
 
 
+def packed_layout(fields) -> tuple:
+    """((name, offset, shape, dtype), ...) of `fields` ((name, shape, dtype)
+    pairs in order) laid end to end in ONE int32 vector, a float32 field as
+    its bits: what crosses between host and device in a single transfer."""
+    layout, off = [], 0
+    for name, shape, dtype in fields:
+        layout.append((name, off, tuple(shape), np.dtype(dtype)))
+        off += int(np.prod(shape))
+    return tuple(layout)
+
+
+def _extent(field) -> slice:
+    _name, off, shape, _dtype = field
+    return slice(off, off + int(np.prod(shape)))
+
+
+def pack_host(layout, values) -> np.ndarray:
+    """`values` (name -> array) as the one int32 vector of `layout`."""
+    buf = np.empty(_extent(layout[-1]).stop, np.int32)
+    for field in layout:
+        buf[_extent(field)] = np.asarray(
+            values[field[0]], field[3]).view(np.int32).ravel()
+    return buf
+
+
+def unpack_host(layout, buf: np.ndarray) -> tuple:
+    """The fields of `layout` as numpy views of the fetched vector `buf`."""
+    return tuple(buf[_extent(f)].view(f[3]).reshape(f[2]) for f in layout)
+
+
+def pack_device(arrays) -> jax.Array:
+    """Traced arrays (int32 or float32) as one int32 vector, in order."""
+    return jnp.concatenate([
+        (a if a.dtype == jnp.int32
+         else jax.lax.bitcast_convert_type(a, jnp.int32)).reshape(-1)
+        for a in arrays
+    ])
+
+
+def unpack_device(layout, buf: jax.Array) -> dict:
+    """The uploaded vector `buf` sliced back into the fields of `layout`."""
+    out = {}
+    for field in layout:
+        name, _off, shape, dtype = field
+        x = buf[_extent(field)].reshape(shape)
+        out[name] = (x if dtype == np.int32
+                     else jax.lax.bitcast_convert_type(x, dtype))
+    return out
+
+
 class ServingEngine:
     """Paged-cache continuous-batching engine for the generic decoder
     families (TransformerConfig / MoETransformerConfig): GQA or MLA attention
@@ -521,21 +573,14 @@ class ServingEngine:
             # contract the cache-miss counter tests pin per replica
             rep = self._mesh.replicated()
             psh = self._pool_shardings
-            batch_keys = [
-                "tok", "slot", "pos", "page", "off", "page_tables",
-                "sample_tok", "temp", "seed", "cow_src", "cow_dst",
-            ]
-            if self._spec is not None:
-                batch_keys += ["verify_rows", "spec_len"]
-            out_sh: list = [psh, rep, rep]
-            if self._spec is not None:
+            # the plan and the packed outputs are one replicated vector each
+            out_sh: list = [psh, rep]
+            if self._needs_hidden in ("frontier", "rows"):
                 out_sh.append(rep)
-                if self._needs_hidden in ("frontier", "rows"):
-                    out_sh.append(rep)
             in_sh: list = [
                 jax.tree.map(lambda p: p.sharding, self.params),
                 psh,
-                {k: rep for k in batch_keys},
+                rep,
             ]
             if self.state:
                 ssh = state_shardings(cfg, self._mesh) + jax.tree.map(
@@ -920,9 +965,12 @@ class ServingEngine:
         # under serve.layers and under none of its sublayers is the
         # hidden state's sharding constraint: nothing off-mesh.
         #
+        # the plan arrives as ONE int32 vector (`_plan_batch`): sliced back
+        # into its fields here, the temperatures bit-cast to float32
+        with jax.named_scope("serve.unpack"):
+            b = unpack_device(self._plan_layout, b)
         # per-token page-table rows: pads index slot 0's table but their
         # position is -1, so they attend to nothing
-        b = dict(b)
         b["pt_tok"] = b["page_tables"][jnp.maximum(b["slot"], 0)]
         # the rows grouped into runs of one slot, once for every attention
         # call of the step: the Pallas kernels' unit of work (None, and
@@ -1031,6 +1079,11 @@ class ServingEngine:
 
         with jax.named_scope("serve.head"):
             out = self._head(params, new_pool, h, b)
+        # tokens, log-probabilities (and acceptances) go back as ONE int32
+        # vector, the float32 values bit-cast; hidden feedback stays its own
+        n = len(self._out_layout)
+        with jax.named_scope("serve.pack"):
+            out = (out[0], pack_device(out[1:1 + n])) + out[1 + n:]
         # the state last, where there is one: a decoder of attention alone
         # lowers to the program it always was
         return out + (tuple(new_state),) if state else out
@@ -1155,33 +1208,40 @@ class ServingEngine:
         serving run — the fixed-shape contract)."""
         return self._step._cache_size()
 
-    def _plan_batch(self, plan: StepPlan) -> dict:
-        """StepPlan → the jitted step's batch dict (the ONE sanctioned
-        host→device upload per step; replicated under a mesh)."""
+    @functools.cached_property
+    def _plan_layout(self) -> tuple:
+        """Where each StepPlan array lies in the one vector the step takes:
+        the engine's fixed geometry, read off `empty_plan`."""
+        plan = self.empty_plan()
+        return packed_layout(
+            (f.name, a.shape, a.dtype) for f in dataclasses.fields(plan)
+            if isinstance(a := getattr(plan, f.name), np.ndarray)
+        )
+
+    @functools.cached_property
+    def _out_layout(self) -> tuple:
+        """Where the step's tokens, log-probabilities and (speculation)
+        acceptances lie in the one vector it returns."""
+        S = self.serve_cfg.max_slots
+        if self._spec is None:
+            return packed_layout(
+                [("tokens", (S,), np.int32), ("logprobs", (S,), np.float32)])
+        K1 = self._spec.draft_len + 1
+        return packed_layout([
+            ("tokens", (S, K1), np.int32), ("logprobs", (S, K1), np.float32),
+            ("accept", (S,), np.int32),
+        ])
+
+    def _plan_batch(self, plan: StepPlan) -> jax.Array:
+        """StepPlan → the jitted step's one int32 plan vector (the ONE
+        sanctioned host→device upload per step, a single transfer whatever
+        the fields; replicated under a mesh)."""
+        buf = pack_host(self._plan_layout, vars(plan))
         if self._mesh is None:
-            up = jnp.asarray
-        else:
-            # plan arrays upload replicated onto the engine's mesh (the
-            # host scheduler is mesh-oblivious: page IDs are global)
-            rep = self._mesh.replicated()
-            up = lambda a: jax.device_put(np.asarray(a), rep)  # noqa: E731
-        batch = {
-            "tok": up(plan.tok),
-            "slot": up(plan.slot),
-            "pos": up(plan.pos),
-            "page": up(plan.page),
-            "off": up(plan.off),
-            "page_tables": up(plan.page_tables),
-            "sample_tok": up(plan.sample_tok),
-            "temp": up(plan.temp),
-            "seed": up(plan.seed),
-            "cow_src": up(plan.cow_src),
-            "cow_dst": up(plan.cow_dst),
-        }
-        if self._spec is not None:
-            batch["verify_rows"] = up(plan.verify_rows)
-            batch["spec_len"] = up(plan.spec_len)
-        return batch
+            return jnp.asarray(buf)
+        # replicated onto the engine's mesh (the host scheduler is
+        # mesh-oblivious: page IDs are global)
+        return jax.device_put(buf, self._mesh.replicated())
 
     def run_step(self, plan: StepPlan):
         """Upload one StepPlan, run the jitted step, return numpy outputs:
@@ -1206,16 +1266,17 @@ class ServingEngine:
         reg.counter("serve_plan_tokens_total").inc(plan.n_tokens)
         reg.counter("serve_plan_samples_total").inc(plan.n_samples)
         # step.run's three children split what the host does around the
-        # device's step: the plan's uploads, the enqueue of the jitted
+        # device's step: the plan's upload, the enqueue of the jitted
         # step (a compile lands here), and the read-back, itself in two:
         # the wait until the host learns the device is done, and the
-        # copies to numpy. All six carry this step's number.
+        # copy to numpy. All six carry this step's number.
         span = functools.partial(
             self.obs.tracer.span, track=self.track, step=self.steps_run
         )
         with span("step.run", rows=plan.n_tokens, samples=plan.n_samples):
             with span("step.upload"):
                 batch = self._plan_batch(plan)
+                reg.counter("serve_step_transfers_total", direction="h2d").inc()
             # the StepPlan upload above is the ONE sanctioned host→device
             # copy per step; with guard_transfers the step invocation runs
             # under transfer_guard("disallow") so any other transfer raises
@@ -1225,17 +1286,27 @@ class ServingEngine:
                         out = self._step(*self._step_args(batch))
                 else:
                     out = self._step(*self._step_args(batch))
-            self.pool = out[0]
-            if self.state:
-                *out, self.state = out
+                self.pool = out[0]
+                if self.state:
+                    *out, self.state = out
+                # the packed outputs (and hidden feedback, where the step
+                # returns it) start their copies now: each then waits on
+                # the device, not on the host's turn to ask
+                fetched = out[1:]
+                for x in fetched:
+                    x.copy_to_host_async()
+                reg.counter(
+                    "serve_step_transfers_total", direction="d2h",
+                ).inc(len(fetched))
             self.steps_run += 1
             with span("step.readback"):
                 with span("step.wait"):
-                    jax.block_until_ready(out[1:])
+                    jax.block_until_ready(fetched)
                 with span("step.fetch"):
-                    return tuple(np.asarray(x) for x in out[1:])
+                    packed, *hidden = (np.asarray(x) for x in fetched)
+                    return unpack_host(self._out_layout, packed) + tuple(hidden)
 
-    def _step_args(self, batch: dict) -> tuple:
+    def _step_args(self, batch: jax.Array) -> tuple:
         """The jitted step's arguments: the per-slot state last, where the
         model holds one."""
         args = (self.params, self.pool, batch)

@@ -164,18 +164,13 @@ def test_step_cow_copies_scale_rows(params):
         (k.at[2].set(9), v, ks.at[2].set(0.125), vs)
         for k, v, ks, vs in eng.pool[0]
     )
-    T, S, P, trash = 8, 2, 4, 16
-    batch = {key: jnp.full(T, trash if key == "page" else 0, jnp.int32)
-             for key in ("tok", "slot", "pos", "page", "off")}
-    batch.update(
-        page_tables=jnp.full((S, P), trash, jnp.int32),
-        sample_tok=jnp.zeros(S, jnp.int32),
-        temp=jnp.zeros(S, jnp.float32),
-        seed=jnp.zeros(S, jnp.int32),
-        cow_src=jnp.asarray([2, trash], jnp.int32),
-        cow_dst=jnp.asarray([5, trash], jnp.int32),
-    )
-    new_pool, _, _ = eng._step(eng.params, eng.pool, batch)
+    trash = 16
+    plan = eng.empty_plan()
+    plan.slot[:], plan.pos[:], plan.page[:] = 0, 0, trash
+    plan.page_tables[:] = trash
+    plan.sample_tok[:] = 0
+    plan.cow_src[:], plan.cow_dst[:] = [2, trash], [5, trash]
+    new_pool, _ = eng._step(*eng._step_args(eng._plan_batch(plan)))
     for k2, _, ks2, _ in new_pool[0]:
         assert bool(jnp.all(k2[5] == 9))
         assert bool(jnp.all(ks2[5] == 0.125))

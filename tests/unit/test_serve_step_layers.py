@@ -192,12 +192,17 @@ def test_router_splits_once_for_both_classes():
 
 
 # -- (c) the looped step -------------------------------------------------------
-#: sha256 of the lowered (StableHLO) serve step of CFG at GEO, as the commit
-#: before looped decoders (a49ab3f) lowered it with jax 0.9.0 on the CPU. The
-#: walk over passes must leave a one-pass model's program as it was: a PR
-#: that changes this step on purpose updates the digest and says why.
+#: sha256 of the lowered (StableHLO) serve step of CFG at GEO with jax 0.9.0
+#: on the CPU. The walk over passes must leave a one-pass model's program as
+#: it was: a PR that changes this step on purpose updates the digest and says
+#: why. Re-derived by PR 39 for the step's SIGNATURE, not its walk: the plan
+#: arrives as one int32 vector (eleven parameters became one, sliced back in
+#: thirteen ops at the top) and the tokens and log-probabilities leave as one
+#: (a bitcast and a concatenate at the end); with value names and private
+#: function numbers masked, every other line of the text is the one that
+#: a49ab3f's digest 1e354697... pinned (PRs 29-38).
 ONE_PASS_STEP_SHA256 = (
-    "1e3546975fa1431d0ac1541b0af9d90f5e0e154454af4b5cfe15bca09662d43c")
+    "6d2dd2154b6f895124f60d8cd1ab526c89fb55b44f89004fed1415dec6d741e7")
 
 
 def test_one_pass_step_text_is_unchanged():
