@@ -806,10 +806,112 @@ class JambaAdapter:
         return out
 
 
+@dataclasses.dataclass
+class MiMoAdapter:
+    """MiMoV2FlashForCausalLM <-> the expert decoder whose attention names
+    its operator (`layer_ops`): `dense_layers` / `moe_layers` hold each
+    layer's two norms and its MLP, `attn_layers` / `window_attn_layers` the
+    attention by kind (a window layer's with its `attention_sink_bias`).
+    The tree holds the held experts alone: entry j is the checkpoint's
+    expert `first_held_expert + j`. Linears transposed."""
+
+    cfg: Any  # MoETransformerConfig
+
+    NORMS = [
+        ("input_layernorm.weight", ("input_norm", "scale"), False),
+        ("post_attention_layernorm.weight", ("post_attn_norm", "scale"), False),
+    ]
+    DENSE = [(f"mlp.{p}.weight", (p, "kernel"), True)
+             for p in ("gate_proj", "up_proj", "down_proj")]
+    ATTENTION = [(f"self_attn.{p}.weight", (p, "kernel"), True)
+                 for p in ("q_proj", "k_proj", "v_proj", "o_proj")]
+    TOP = [
+        ("model.embed_tokens.weight", ("embed", "embedding"), False),
+        ("model.norm.weight", ("final_norm", "scale"), False),
+        ("lm_head.weight", ("lm_head", "kernel"), True),
+    ]
+
+    def _by_layer(self):
+        """(layer i, stack key, index in the stack, entries), experts apart."""
+        from automodel_tpu.models.llm.decoder import (
+            OPERATOR_STACKS, kind_config, layer_operators,
+        )
+
+        fk = self.cfg.first_k_dense
+        for i, (kind, j) in enumerate(layer_operators(self.cfg)):
+            dense = i < fk
+            layer = self.NORMS + (self.DENSE if dense else [
+                ("mlp.gate.weight", ("moe", "gate", "weight"), True)])
+            if not dense and self.cfg.moe.gate_bias_update_speed > 0:
+                layer = layer + [("mlp.gate.e_score_correction_bias",
+                                  ("moe", "gate", "e_score_bias"), False)]
+            if not dense and self.cfg.moe.n_shared_experts:
+                layer = layer + [
+                    (f"mlp.shared_experts.{p}.weight", ("moe", "shared", p, "kernel"), True)
+                    for p in ("gate_proj", "up_proj", "down_proj")]
+            yield i, ("dense_layers", i) if dense else ("moe_layers", i - fk), layer
+            attn = self.ATTENTION + ([
+                ("self_attn.attention_sink_bias", ("sinks",), False)]
+                if kind_config(self.cfg, kind).attention_sinks else [])
+            yield i, (OPERATOR_STACKS[kind], j), attn
+
+    def _experts(self):
+        """(layer i, index in `moe_layers`, held expert j, hf name, proj)."""
+        fk, moe = self.cfg.first_k_dense, self.cfg.moe
+        for li in range(self.cfg.num_layers - fk):
+            for j in range(moe.num_held):
+                e = moe.first_held_expert + j
+                for p in ("gate_proj", "up_proj", "down_proj"):
+                    yield li, j, f"model.layers.{fk + li}.mlp.experts.{e}.{p}.weight", p
+
+    def _top(self):
+        return self.TOP[:2] if self.cfg.tie_word_embeddings else self.TOP
+
+    def to_hf(self, params: Mapping) -> Iterator[tuple[str, np.ndarray]]:
+        for name, path, transpose in self._top():
+            x = np.asarray(_get(params, path))
+            yield name, (_t(x) if transpose else x)
+        for i, (stack, idx), entries in self._by_layer():
+            for suffix, path, transpose in entries:
+                x = np.asarray(_get(params[stack], path)[idx])
+                yield f"model.layers.{i}.{suffix}", (_t(x) if transpose else x)
+        experts = params["moe_layers"]["moe"]["experts"]
+        for li, j, name, p in self._experts():
+            yield name, _t(np.asarray(experts[p]["kernel"][li, j]))
+
+    def from_hf(self, read: Reader, shardings: Any = None) -> dict:
+        out: dict = {}
+        rows: dict = {}  # (stack,) + path -> {index in the stack: array}
+
+        def put(path, value):
+            sh = _get(shardings, path) if shardings is not None else None
+            _set(out, path, jax.device_put(value, sh) if sh is not None else value)
+
+        def one(name, transpose):
+            return _t(read(name)) if transpose else np.asarray(read(name))
+
+        for name, path, transpose in self._top():
+            put(path, one(name, transpose))
+        for i, (stack, idx), entries in self._by_layer():
+            for suffix, path, transpose in entries:
+                rows.setdefault((stack,) + path, {})[idx] = one(
+                    f"model.layers.{i}.{suffix}", transpose)
+        for path, by_index in rows.items():
+            put(path, np.stack([by_index[j] for j in range(len(by_index))]))
+        experts: dict = {}
+        for li, j, name, p in self._experts():
+            experts.setdefault(p, {}).setdefault(li, []).append(one(name, True))
+        for p, by_layer in experts.items():
+            put(("moe_layers", "moe", "experts", p, "kernel"),
+                np.stack([np.stack(by_layer[li]) for li in sorted(by_layer)]))
+        return out
+
+
 ADAPTERS = {
     "dense_decoder": DenseDecoderAdapter,
     "moe_decoder": MoEDecoderAdapter,
     "jamba": JambaAdapter,
+    "mimo": MiMoAdapter,
 }
 
 

@@ -296,7 +296,7 @@ def generate(
     from automodel_tpu.models.moe_lm.het_moe import HetMoEConfig
 
     if isinstance(cfg, HetMoEConfig):
-        # heterogeneous engine (step3p5/mimo/minimax-m3): per-layer python-
+        # heterogeneous engine (step3p5/minimax-m3): per-layer python-
         # loop decode with its own cache layout (incl. sparse index caches)
         assert rope_angles is None and deepstack_embeds is None
         from automodel_tpu.inference.het_generate import het_generate

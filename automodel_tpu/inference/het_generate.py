@@ -1,6 +1,6 @@
 """KV-cache generation for the heterogeneous MoE engine (het_moe).
 
-The het engine (step3p5 / mimo-v2-flash / minimax-m3 and the minimax-m3-vl
+The het engine (step3p5 / minimax-m3 and the minimax-m3-vl
 text side) keeps per-layer python-loop heterogeneity — per-layer attention
 geometries, dense/MoE MLPs, and (M3) block-sparse DSA layers — so the
 generic `inference.generate` layer-scan cannot drive it. This module mirrors
@@ -41,11 +41,11 @@ def _qkv(x, lp, ai, g, cfg, positions, inv_freq):
     prec = cfg.linear_precision
     q = _mm(x, lp["q_proj"]["kernel"][ai], prec).reshape(B, S, g.num_heads, g.head_dim)
     k = _mm(x, lp["k_proj"]["kernel"][ai], prec).reshape(B, S, g.num_kv_heads, g.head_dim)
-    v = _mm(x, lp["v_proj"]["kernel"][ai], prec).reshape(B, S, g.num_kv_heads, g.vd)
+    v = _mm(x, lp["v_proj"]["kernel"][ai], prec).reshape(B, S, g.num_kv_heads, g.head_dim)
     if cfg.attention_bias:
         q = q + lp["q_proj"]["bias"][ai].reshape(1, 1, g.num_heads, g.head_dim)
         k = k + lp["k_proj"]["bias"][ai].reshape(1, 1, g.num_kv_heads, g.head_dim)
-        v = v + lp["v_proj"]["bias"][ai].reshape(1, 1, g.num_kv_heads, g.vd)
+        v = v + lp["v_proj"]["bias"][ai].reshape(1, 1, g.num_kv_heads, g.head_dim)
     if cfg.qk_norm:
         q = rms_norm(q, lp["q_norm"]["scale"][ai], cfg.rms_norm_eps, cfg.zero_centered_norm)
         k = rms_norm(k, lp["k_norm"]["scale"][ai], cfg.rms_norm_eps, cfg.zero_centered_norm)
@@ -77,19 +77,10 @@ def _cached_attention(q, keys, values, positions, attend_len, g, cfg, keep=None)
     return s, values
 
 
-def _softmax_out(s, values, sinks, B, Sq, g):
-    Hkv = values.shape[2]
-    G = s.shape[2]
-    if sinks is not None:
-        sink = jnp.broadcast_to(
-            sinks.astype(jnp.float32).reshape(1, Hkv, G, 1, 1), s.shape[:4] + (1,)
-        )
-        s = jnp.concatenate([s, sink], axis=-1)
+def _softmax_out(s, values, B, Sq, g):
     p = jax.nn.softmax(s, axis=-1)
-    if sinks is not None:
-        p = p[..., :-1]
     o = jnp.einsum("bkgst,btkd->bskgd", p.astype(values.dtype), values)
-    return o.reshape(B, Sq, g.num_heads * g.vd)
+    return o.reshape(B, Sq, g.num_heads * g.head_dim)
 
 
 @partial(jax.jit, static_argnames=("cfg", "gen"))
@@ -170,13 +161,12 @@ def het_generate(
             s, values = _cached_attention(
                 q, ck, cv, positions, attend_len, g, cfg, keep=keep
             )
-            sinks = lp["sinks"][ai] if g.sinks else None
-            attn = _softmax_out(s, values, sinks, h.shape[0], h.shape[1], g)
+            attn = _softmax_out(s, values, h.shape[0], h.shape[1], g)
             if cfg.head_gate:
                 gate = jax.nn.sigmoid(x @ lp["g_proj"]["kernel"][ai])
                 gr = jnp.repeat(
-                    gate[..., None], g.vd, axis=-1
-                ).reshape(h.shape[0], h.shape[1], g.num_heads * g.vd)
+                    gate[..., None], g.head_dim, axis=-1
+                ).reshape(h.shape[0], h.shape[1], g.num_heads * g.head_dim)
                 attn = attn * gr.astype(attn.dtype)
             out = attn @ lp["o_proj"]["kernel"][ai]
             if cfg.attention_bias and "bias" in lp["o_proj"]:
@@ -211,7 +201,7 @@ def het_generate(
         is_sparse = bool(cfg.sparse_attn and cfg.sparse_attn[li])
         c = (
             jnp.zeros((B, T, g.num_kv_heads, g.head_dim), cfg.dtype),
-            jnp.zeros((B, T, g.num_kv_heads, g.vd), cfg.dtype),
+            jnp.zeros((B, T, g.num_kv_heads, g.head_dim), cfg.dtype),
         )
         if is_sparse:
             c = c + (jnp.zeros((B, T, cfg.sparse_index_dim), cfg.dtype),)

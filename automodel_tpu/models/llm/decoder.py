@@ -116,6 +116,18 @@ class TransformerConfig:
     normalized_lm_head: bool = False
     # gpt-oss: learnable per-head sink logits in the softmax denominator
     attention_sinks: bool = False
+    # the width of a GQA head's VALUES where it differs from its keys'
+    # (MiMo-V2-Flash: keys 192, values 128); None = the key width
+    v_head_dim: Optional[int] = None
+    # a constant the values are multiplied by (MiMo-V2-Flash's
+    # `attention_value_scale`): attention is linear in V, so it stands right
+    # behind the value projection, in every layer
+    attention_value_scale: float = 1.0
+    # the attention of the layers with a window, where it differs from the
+    # full layers' (layer_ops "window_attention", its own operator stack):
+    # (field, value) pairs that `kind_config` puts in its place, of
+    # `WINDOW_FIELDS` (MiMo-V2-Flash's swa_* keys)
+    window_attention: tuple = ()
     o_proj_bias: bool = False  # gpt-oss biases ALL four attention projections
     # attention flavor: "gqa" (default) or "mla" (DeepSeek latent attention)
     attention_type: str = "gqa"
@@ -181,6 +193,10 @@ class TransformerConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def resolved_v_head_dim(self) -> int:
+        return self.v_head_dim or self.resolved_head_dim
+
+    @property
     def rope_dim(self) -> int:
         if self.attention_type == "mla":
             return self.mla_qk_rope_head_dim
@@ -199,7 +215,7 @@ class TransformerConfig:
     def holds_state(self) -> bool:
         """Whether some layer carries a recurrent state from token to token
         (what a serving engine must keep per slot, beside the pages)."""
-        return any(op != "attention" for op in self.layer_ops or ())
+        return any(op not in ATTENTION_OPS for op in self.layer_ops or ())
 
     def attn_params_per_layer(self) -> int:
         """Projection parameter count of one attention block."""
@@ -218,8 +234,9 @@ class TransformerConfig:
             )
             kv = H * (self.mla_kv_lora_rank + dr) + self.mla_kv_lora_rank * n * (dn + dv)
             return q + kv + n * dv * H
-        D = self.resolved_head_dim
-        return H * (self.num_heads + 2 * self.num_kv_heads) * D + self.num_heads * D * H
+        D, Dv = self.resolved_head_dim, self.resolved_v_head_dim
+        return (H * (self.num_heads + self.num_kv_heads) * D
+                + H * self.num_kv_heads * Dv + self.num_heads * Dv * H)
 
     def flops_per_token(self, seq_len: int) -> float:
         """Training FLOPs/token (fwd+bwd ≈ 6*N + attention term) for MFU."""
@@ -233,7 +250,7 @@ class TransformerConfig:
         if self.layer_ops is not None:
             from automodel_tpu.models.llm.mamba import mamba_params_per_layer
 
-            n_attn = sum(op == "attention" for op in self.layer_ops)
+            n_attn = sum(op in ATTENTION_OPS for op in self.layer_ops)
             mixers_instead = (self.num_layers - n_attn) * (
                 mamba_params_per_layer(self) - self.attn_params_per_layer()
             )
@@ -264,8 +281,33 @@ def layer_windows(cfg: "TransformerConfig", num_layers: int | None = None) -> tu
 
 
 #: the parameter tree's key for each kind of operator's stack (a model with
-#: `layer_ops`); "attention" in a model without them lives in the layers
-OPERATOR_STACKS = {"attention": "attn_layers", "mamba": "mamba_layers"}
+#: `layer_ops`); "attention" in a model without them lives in the layers.
+#: "window_attention" is the attention of the layers with a window where its
+#: geometry is not the full layers' (`TransformerConfig.window_attention`)
+OPERATOR_STACKS = {"attention": "attn_layers", "mamba": "mamba_layers",
+                   "window_attention": "window_attn_layers"}
+
+#: the operators that are attention (over pages or a ring when served); every
+#: other one carries a state from token to token
+ATTENTION_OPS = ("attention", "window_attention")
+
+#: what a window layer's attention may set apart from the full layers'
+WINDOW_FIELDS = ("num_heads", "num_kv_heads", "v_head_dim", "attention_sinks")
+
+
+def kind_config(cfg: "TransformerConfig", kind: str) -> "TransformerConfig":
+    """The configuration an operator of `kind` runs with: the full layers'
+    own, or for "window_attention" theirs with `cfg.window_attention` in
+    place. A head WIDTH of its own is refused: the rotary table is one."""
+    if kind != "window_attention" or not cfg.window_attention:
+        return cfg
+    fields = dict(cfg.window_attention)
+    if set(fields) - set(WINDOW_FIELDS):
+        raise NotImplementedError(
+            f"a window layer's {sorted(set(fields) - set(WINDOW_FIELDS))} "
+            "apart from the full layers'")
+    return dataclasses.replace(
+        cfg, head_dim=cfg.resolved_head_dim, window_attention=(), **fields)
 
 
 def layer_operators(cfg: "TransformerConfig") -> tuple:
@@ -344,21 +386,21 @@ def init_attention_layers(cfg: TransformerConfig, rng: jax.Array, L: int) -> dic
         from automodel_tpu.models.llm.mla import init_mla_layers
 
         return init_mla_layers(cfg, rng, L)
-    D = cfg.resolved_head_dim
+    D, Dv = cfg.resolved_head_dim, cfg.resolved_v_head_dim
     H = cfg.hidden_size
     ks = jax.random.split(rng, 4)
     layers = {
         "input_norm": {"scale": jnp.ones((L, H))},
         "q_proj": {"kernel": _stack(dense_init, ks[0], (H, cfg.num_heads * D), L)},
         "k_proj": {"kernel": _stack(dense_init, ks[1], (H, cfg.num_kv_heads * D), L)},
-        "v_proj": {"kernel": _stack(dense_init, ks[2], (H, cfg.num_kv_heads * D), L)},
-        "o_proj": {"kernel": _stack(dense_init, ks[3], (cfg.num_heads * D, H), L)},
+        "v_proj": {"kernel": _stack(dense_init, ks[2], (H, cfg.num_kv_heads * Dv), L)},
+        "o_proj": {"kernel": _stack(dense_init, ks[3], (cfg.num_heads * Dv, H), L)},
         "post_attn_norm": {"scale": jnp.ones((L, H))},
     }
     if cfg.attention_bias:
         layers["q_proj"]["bias"] = jnp.zeros((L, cfg.num_heads * D))
         layers["k_proj"]["bias"] = jnp.zeros((L, cfg.num_kv_heads * D))
-        layers["v_proj"]["bias"] = jnp.zeros((L, cfg.num_kv_heads * D))
+        layers["v_proj"]["bias"] = jnp.zeros((L, cfg.num_kv_heads * Dv))
     if cfg.o_proj_bias:
         layers["o_proj"]["bias"] = jnp.zeros((L, H))
     if cfg.qk_norm:
@@ -413,11 +455,57 @@ _LAYER_NORMS = ("input_norm", "post_attn_norm")
 def _check_layer_ops(cfg: TransformerConfig) -> None:
     if cfg.layer_ops is None:
         return
-    if cfg.attention_type != "gqa" or cfg.num_passes != 1 or cfg.sliding_window:
+    if cfg.attention_type != "gqa" or cfg.num_passes != 1:
         raise NotImplementedError(
-            "layers that name their mixer (layer_ops) with MLA, more than "
-            "one pass or sliding windows"
+            "layers that name their mixer (layer_ops) with MLA or more than "
+            "one pass"
         )
+    # a window is the "window_attention" operator's, and only its
+    windowed = tuple(w is not None for w in layer_windows(cfg))
+    if windowed != tuple(op == "window_attention" for op in cfg.layer_ops):
+        raise NotImplementedError(
+            "layers that name their mixer (layer_ops) where the layers with "
+            "a sliding window are not the \"window_attention\" ones"
+        )
+
+
+def init_operator_stacks(cfg: TransformerConfig, ops: tuple, attn_key,
+                         mamba_key) -> dict:
+    """Each kind of operator's stack of a model whose layers name their
+    mixer, entry j the j-th layer of that kind: the attention kinds without
+    the layer's two norms (those stay with the layers), each at its kind's
+    geometry (`kind_config`)."""
+    from automodel_tpu.models.llm.mamba import init_mamba_layers
+
+    out = {}
+    for kind, stack in OPERATOR_STACKS.items():
+        n = sum(op == kind for op, _ in ops)
+        if not n:
+            continue
+        if kind == "mamba":
+            out[stack] = init_mamba_layers(cfg, mamba_key, n)
+            continue
+        key = attn_key if kind == "attention" else jax.random.fold_in(attn_key, 1)
+        attn = init_attention_layers(kind_config(cfg, kind), key, n)
+        out[stack] = {k: v for k, v in attn.items() if k not in _LAYER_NORMS}
+    return out
+
+
+def operator_stack_specs(cfg: TransformerConfig, ops: tuple) -> dict:
+    """`init_operator_stacks`' logical axes."""
+    from automodel_tpu.models.llm.mamba import mamba_layer_specs
+
+    kinds = {op for op, _ in ops}
+    out = {}
+    for kind, stack in OPERATOR_STACKS.items():
+        if kind not in kinds:
+            continue
+        if kind == "mamba":
+            out[stack] = mamba_layer_specs(cfg)
+            continue
+        attn = attention_layer_specs(kind_config(cfg, kind))
+        out[stack] = {k: v for k, v in attn.items() if k not in _LAYER_NORMS}
+    return out
 
 
 def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
@@ -432,17 +520,8 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
         layers = init_attention_layers(cfg, ks[0], L)
     else:
         # every layer's two norms here; each kind's operators a stack apart
-        from automodel_tpu.models.llm.mamba import init_mamba_layers
-
         layers = {name: {"scale": jnp.ones((L, H))} for name in _LAYER_NORMS}
-        n_attn = sum(op == "attention" for op, _ in ops)
-        if n_attn:
-            attn = init_attention_layers(cfg, ks[0], n_attn)
-            operators["attn_layers"] = {
-                k: v for k, v in attn.items() if k not in _LAYER_NORMS
-            }
-        if L - n_attn:
-            operators["mamba_layers"] = init_mamba_layers(cfg, ks[1], L - n_attn)
+        operators = init_operator_stacks(cfg, ops, ks[0], ks[1])
     layers.update(
         {
             "gate_proj": {"kernel": _stack(dense_init, ks[4], (H, I), L)},
@@ -472,15 +551,7 @@ def param_specs(cfg: TransformerConfig) -> dict:
     layers = attention_layer_specs(cfg)
     operators = {}
     if ops is not None:
-        from automodel_tpu.models.llm.mamba import mamba_layer_specs
-
-        kinds = {op for op, _ in ops}
-        if "attention" in kinds:
-            operators["attn_layers"] = {
-                k: v for k, v in layers.items() if k not in _LAYER_NORMS
-            }
-        if "mamba" in kinds:
-            operators["mamba_layers"] = mamba_layer_specs(cfg)
+        operators = operator_stack_specs(cfg, ops)
         layers = {k: layers[k] for k in _LAYER_NORMS}
     layers.update(
         {
@@ -938,19 +1009,24 @@ def _walk_named_layers(params, cfg, h, positions, segment_ids, inv_freq,
             "sensitive: no ring, no permuted layout) or with aux-hidden capture"
         )
 
-    def layer(h, lp, kind):
-        if kind == "attention":
-            h = attention_block(h, lp, cfg, positions, segment_ids, inv_freq,
-                                constrain, None, mesh_ctx)
+    freq_for = make_freq_for(cfg, inv_freq)
+
+    def layer(h, lp, kind, window):
+        if kind in ATTENTION_OPS:
+            h = attention_block(h, lp, kind_config(cfg, kind), positions,
+                                segment_ids, freq_for(window), constrain,
+                                window, mesh_ctx)
         else:
             h = mamba_block(h, lp, cfg, positions, constrain)
         return mlp_block(h, lp, cfg, constrain, mesh_ctx)
 
-    for i, (kind, j) in enumerate(layer_operators(cfg)):
+    for i, ((kind, j), window) in enumerate(
+            zip(layer_operators(cfg), layer_windows(cfg))):
         lp = jax.tree.map(lambda a: a[i], params["layers"])
         lp.update(jax.tree.map(lambda a: a[j], params[OPERATOR_STACKS[kind]]))
         h = maybe_remat(
-            lambda h, lp, kind=kind: layer(h, lp, kind), cfg.remat_policy
+            lambda h, lp, kind=kind, window=window: layer(h, lp, kind, window),
+            cfg.remat_policy,
         )(h, lp)
     return h
 
@@ -992,7 +1068,9 @@ def project_qkv(x, lp, cfg: TransformerConfig, positions, inv_freq):
         k = rms_norm(k, lp["k_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
     q = q.reshape(B, S, cfg.num_heads, D)
     k = k.reshape(B, S, cfg.num_kv_heads, D)
-    v = v.reshape(B, S, cfg.num_kv_heads, D)
+    v = v.reshape(B, S, cfg.num_kv_heads, cfg.resolved_v_head_dim)
+    if cfg.attention_value_scale != 1.0:
+        v = v * jnp.asarray(cfg.attention_value_scale, v.dtype)
     if cfg.qk_norm and not cfg.qk_norm_after_rope:
         q = rms_norm(q, lp["q_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
         k = rms_norm(k, lp["k_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
@@ -1099,7 +1177,7 @@ def attention_block(h, lp, cfg: TransformerConfig, positions, segment_ids, inv_f
             impl=cfg.attn_impl,
             mesh_ctx=None if manual else mesh_ctx,
         )
-    attn = attn.reshape(B, S, cfg.num_heads * D)
+    attn = attn.reshape(B, S, cfg.num_heads * cfg.resolved_v_head_dim)
     from automodel_tpu.ops.quant import matmul as _mm
 
     attn_out = _mm(attn, lp["o_proj"]["kernel"], cfg.linear_precision)

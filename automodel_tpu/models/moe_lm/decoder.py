@@ -25,14 +25,21 @@ from automodel_tpu.models.common.layers import (
     scan_layers_windowed,
 )
 from automodel_tpu.models.llm.decoder import (
+    _LAYER_NORMS,
+    OPERATOR_STACKS,
     TransformerConfig,
+    _check_layer_ops,
     _stack,
     attention_block,
     attention_layer_specs,
     init_attention_layers,
+    init_operator_stacks,
+    kind_config,
+    layer_operators,
     layer_windows,
     make_freq_for,
     mlp_block,
+    operator_stack_specs,
     unembed,
     _make_constrain,
 )
@@ -78,7 +85,12 @@ class MoETransformerConfig(TransformerConfig):
         """Activated-params FLOPs/token for MFU (routed experts count k/E)."""
         D = self.resolved_head_dim
         H = self.hidden_size
-        attn_params = self.attn_params_per_layer()
+        # a layer's attention at its own kind's geometry (`kind_config`)
+        attn_total = sum(
+            kind_config(self, kind).attn_params_per_layer()
+            for kind, _ in layer_operators(self)
+        ) if self.layer_ops is not None else (
+            self.num_layers * self.attn_params_per_layer())
         dense_mlp = 3 * H * self.intermediate_size
         moe_mlp = (
             3 * H * self.moe.moe_intermediate_size * self.moe.experts_per_token
@@ -87,7 +99,7 @@ class MoETransformerConfig(TransformerConfig):
         )
         n_active = (
             self.vocab_size * H * (1 if self.tie_word_embeddings else 2)
-            + self.num_layers * attn_params
+            + attn_total
             + self.first_k_dense * dense_mlp
             + self.num_moe_layers * moe_mlp
         )
@@ -95,17 +107,40 @@ class MoETransformerConfig(TransformerConfig):
         return 6.0 * n_active + attn_flops
 
 
+def _layer_attention(cfg: MoETransformerConfig, rng, L: int) -> dict:
+    """What a stack of `L` layers holds of attention: the whole attention
+    block, or, where the layers name their operator (`layer_ops`: window
+    layers with a geometry of their own), the two norms alone, the
+    operators lying in their kinds' stacks."""
+    if cfg.layer_ops is None:
+        return init_attention_layers(cfg, rng, L)
+    return {name: {"scale": jnp.ones((L, cfg.hidden_size))}
+            for name in _LAYER_NORMS}
+
+
+def _layer_attention_specs(cfg: MoETransformerConfig) -> dict:
+    specs = attention_layer_specs(cfg)
+    if cfg.layer_ops is None:
+        return specs
+    return {name: specs[name] for name in _LAYER_NORMS}
+
+
 def init(cfg: MoETransformerConfig, rng: jax.Array) -> dict:
+    _check_layer_ops(cfg)
     H, I = cfg.hidden_size, cfg.intermediate_size
     ks = jax.random.split(rng, 6)
     params: dict = {
         "embed": {"embedding": embed_init(ks[0], (cfg.vocab_size, H))},
         "final_norm": {"scale": jnp.ones((H,))},
     }
+    ops = layer_operators(cfg)
+    if ops is not None:
+        params.update(init_operator_stacks(
+            cfg, ops, jax.random.fold_in(rng, 5), jax.random.fold_in(rng, 6)))
     if cfg.first_k_dense > 0:
         L = cfg.first_k_dense
         kg, ku, kd = jax.random.split(ks[2], 3)
-        dense_layers = init_attention_layers(cfg, ks[1], L)
+        dense_layers = _layer_attention(cfg, ks[1], L)
         dense_layers.update(
             {
                 "gate_proj": {"kernel": _stack(dense_init, kg, (H, I), L)},
@@ -115,7 +150,7 @@ def init(cfg: MoETransformerConfig, rng: jax.Array) -> dict:
         )
         params["dense_layers"] = dense_layers
     Lm = cfg.num_moe_layers
-    moe_layers = init_attention_layers(cfg, ks[3], Lm)
+    moe_layers = _layer_attention(cfg, ks[3], Lm)
     moe_keys = jax.random.split(ks[4], Lm)
     moe_stacked = jax.tree.map(
         lambda *xs: jnp.stack(xs), *[init_moe(cfg.moe, H, k) for k in moe_keys]
@@ -141,11 +176,14 @@ def param_specs(cfg: MoETransformerConfig) -> dict:
         "up_proj": {"kernel": ("layers", "embed", "mlp")},
         "down_proj": {"kernel": ("layers", "mlp", "embed")},
     }
+    ops = layer_operators(cfg)
+    if ops is not None:
+        specs.update(operator_stack_specs(cfg, ops))
     if cfg.first_k_dense > 0:
-        d = attention_layer_specs(cfg)
+        d = _layer_attention_specs(cfg)
         d.update(mlp_specs)
         specs["dense_layers"] = d
-    m = attention_layer_specs(cfg)
+    m = _layer_attention_specs(cfg)
     # prepend the stacked-layers axis to every moe param spec
     m["moe"] = jax.tree.map(
         lambda s: ("layers",) + s,
@@ -365,6 +403,27 @@ def forward(
     windows = layer_windows(cfg)
     Lm, E = cfg.num_moe_layers, cfg.moe.n_routed_experts
 
+    if cfg.layer_ops is not None:
+        if (return_routing or routing_override is not None
+                or return_aux_hidden is not None or deepstack_embeds is not None
+                or rope_angles is not None
+                or (mesh_ctx is not None and mesh_ctx.sizes["pp"] > 1)):
+            raise NotImplementedError(
+                "an expert decoder whose attention names its operator "
+                "(layer_ops) under the pp pipeline, with routing capture or "
+                "replay, aux-hidden capture, deepstack or MRoPE angles"
+            )
+        h, aux_loss, tokens_per_expert = _walk_named_layers(
+            params, cfg, h, positions, segment_ids, freq_for, constrain,
+            mesh_ctx, token_mask,
+        )
+        h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps,
+                     cfg.zero_centered_norm)
+        out = h if return_hidden else unembed(params, cfg, h)
+        if return_stats:
+            return out, aux_loss, {"tokens_per_expert": tokens_per_expert}
+        return out, aux_loss
+
     pp_ok = (
         mesh_ctx is not None
         and mesh_ctx.sizes["pp"] > 1
@@ -530,6 +589,51 @@ def forward(
             stats_out["routing"] = routing
         return out, aux_loss, stats_out
     return out, aux_loss
+
+
+def _walk_named_layers(params, cfg, h, positions, segment_ids, freq_for,
+                       constrain, mesh_ctx, token_mask):
+    """The layers of an expert decoder whose attention names its operator
+    (`layer_ops`: window layers with key/value heads or sinks of their own),
+    in a Python loop: layer i's norms and MLP from its stack (`dense_layers`
+    or `moe_layers`), its attention from its kind's stack at its kind's
+    geometry (`kind_config`). Returns (h, aux loss, tokens per expert
+    (num_moe_layers, E))."""
+    from automodel_tpu.models.common.layers import maybe_remat
+
+    fk = cfg.first_k_dense
+
+    def layer(h, lp, kind, window, dense):
+        h = attention_block(
+            h, lp, kind_config(cfg, kind), positions, segment_ids,
+            freq_for(window), constrain, window, mesh_ctx,
+        )
+        if dense:
+            return mlp_block(h, lp, cfg, constrain), jnp.float32(0.0), None
+        x = rms_norm(h, lp["post_attn_norm"]["scale"], cfg.rms_norm_eps,
+                     cfg.zero_centered_norm)
+        out, aux, stats = moe_forward(
+            lp["moe"], cfg.moe, x, constrain, token_mask=token_mask,
+            mesh_ctx=mesh_ctx,
+        )
+        h = constrain(h + out, ("act_batch", "act_seq", "act_embed"))
+        return h, aux, stats["tokens_per_expert"]
+
+    aux_loss, per_expert = jnp.float32(0.0), []
+    for i, ((kind, j), window) in enumerate(
+            zip(layer_operators(cfg), layer_windows(cfg))):
+        stack, li = ("dense_layers", i) if i < fk else ("moe_layers", i - fk)
+        lp = jax.tree.map(lambda a: a[li], params[stack])
+        lp.update(jax.tree.map(lambda a: a[j], params[OPERATOR_STACKS[kind]]))
+        h, aux, tokens = maybe_remat(
+            lambda h, lp, kind=kind, window=window, dense=i < fk: layer(
+                h, lp, kind, window, dense),
+            cfg.remat_policy,
+        )(h, lp)
+        aux_loss = aux_loss + aux
+        if tokens is not None:
+            per_expert.append(tokens)
+    return h, aux_loss, jnp.stack(per_expert)
 
 
 def apply_gate_bias_update(params: dict, cfg: MoETransformerConfig, tokens_per_expert) -> dict:

@@ -160,6 +160,91 @@ def exaone_moe_config(hf: Mapping[str, Any], **overrides) -> MoETransformerConfi
     return MoETransformerConfig(moe=moe_overrides or moe, first_k_dense=first_k, **kw)
 
 
+def mimo_v2_flash_config(hf: Mapping[str, Any], **overrides) -> MoETransformerConfig:
+    """MiMoV2FlashForCausalLM (MiMo-V2-Flash): GQA whose keys are `head_dim`
+    wide and values `v_head_dim` wide, the values scaled by
+    `attention_value_scale` in every layer; `hybrid_layer_pattern` (1 = a
+    layer with a window of `sliding_window` tokens) whose window layers
+    have their own key/value heads (`swa_num_key_value_heads`), their own
+    rotary theta (`swa_rope_theta`) and a learnable sink logit a head
+    (`add_swa_attention_sink_bias`; the full layers' `add_full_...`): their
+    attention is an operator stack of its own (`layer_ops`
+    "window_attention", `window_attention` its fields). Rotary embedding on
+    the first `partial_rotary_factor` of each head, half-split; a pre-norm
+    before each sublayer; dense layers where `moe_layer_freq` is 0 (leading
+    ones alone), then DeepSeek-style experts: sigmoid scores, a selection
+    bias (`noaux_tc`), the top-k renormalised and scaled by
+    `routed_scaling_factor` (null: 1), no shared expert unless
+    `n_shared_experts` says so.
+
+    `n_routed_experts` is how many experts the tree HOLDS; a configuration
+    that stands for one chip's share gives the router's published width
+    under `router_num_experts` (and the first held expert under
+    `first_held_expert`, default 0), as `exaone_moe_config` does. A window
+    layer's head WIDTH apart from the full layers' is refused by name, and
+    the multi-token-prediction layers are not built."""
+    kw = _base_kwargs(hf)
+    L = kw["num_layers"]
+    kw["rms_norm_eps"] = float(hf.get("layernorm_epsilon", hf.get("rms_norm_eps", 1e-5)))
+    kw["attention_bias"] = bool(hf.get("attention_bias", False))
+    kw["partial_rotary_factor"] = float(hf.get("partial_rotary_factor", 1.0))
+    kw["v_head_dim"] = int(hf.get("v_head_dim") or 0) or None
+    kw["attention_value_scale"] = float(hf.get("attention_value_scale") or 1.0)
+    kw["attention_sinks"] = bool(hf.get("add_full_attention_sink_bias", False))
+    pattern = hf.get("hybrid_layer_pattern")
+    if pattern is None or len(pattern) != L:
+        raise ValueError(
+            f"mimo_v2_flash needs a hybrid_layer_pattern of {L} entries")
+    if any(pattern):
+        kw["sliding_window"] = int(hf["sliding_window"])
+        kw["layer_types"] = tuple(
+            "sliding" if p == 1 else "global" for p in pattern)
+        kw["rope_local_theta"] = float(hf.get("swa_rope_theta", kw["rope_theta"]))
+    kw["layer_ops"] = tuple(
+        "window_attention" if p == 1 else "attention" for p in pattern)
+    head_dim = kw["head_dim"] or kw["hidden_size"] // kw["num_heads"]
+    if int(hf.get("swa_head_dim") or head_dim) != head_dim:
+        raise NotImplementedError(
+            "mimo_v2_flash: a window layer's head width (swa_head_dim "
+            f"{hf['swa_head_dim']}) apart from the full layers' ({head_dim})")
+    full = {"num_heads": kw["num_heads"], "num_kv_heads": kw["num_kv_heads"],
+            "v_head_dim": kw["v_head_dim"] or head_dim,
+            "attention_sinks": kw["attention_sinks"]}
+    window = {
+        "num_heads": int(hf.get("swa_num_attention_heads") or full["num_heads"]),
+        "num_kv_heads": int(hf.get("swa_num_key_value_heads") or full["num_kv_heads"]),
+        "v_head_dim": int(hf.get("swa_v_head_dim") or full["v_head_dim"]),
+        "attention_sinks": bool(hf.get("add_swa_attention_sink_bias", False)),
+    }
+    kw["window_attention"] = tuple(
+        (k, v) for k, v in window.items() if v != full[k])
+    freq = list(hf.get("moe_layer_freq") or [1] * L)
+    first_k = freq.index(1) if 1 in freq else L
+    if freq != [0] * first_k + [1] * (L - first_k):
+        raise NotImplementedError(
+            "mimo_v2_flash moe_layer_freq other than leading dense layers "
+            f"then expert ones: {freq}")
+    held = int(hf["n_routed_experts"])
+    routed = int(hf.get("router_num_experts", held))
+    moe = MoEConfig(
+        n_routed_experts=routed,
+        n_held_experts=None if held == routed else held,
+        first_held_expert=int(hf.get("first_held_expert", 0)),
+        n_shared_experts=int(hf.get("n_shared_experts") or 0),
+        experts_per_token=int(hf["num_experts_per_tok"]),
+        n_groups=int(hf.get("n_group", 1)),
+        topk_groups=int(hf.get("topk_group", 1)),
+        moe_intermediate_size=int(hf["moe_intermediate_size"]),
+        score_func="sigmoid" if hf.get("scoring_func", "sigmoid") == "sigmoid" else "softmax",
+        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        route_scale=float(hf.get("routed_scaling_factor") or 1.0),
+        gate_bias_update_speed=float(hf.get("bias_update_speed", 0.001)),
+    )
+    moe_overrides = overrides.pop("moe", None)
+    kw.update(overrides)
+    return MoETransformerConfig(moe=moe_overrides or moe, first_k_dense=first_k, **kw)
+
+
 def deepseek_v4_config(hf: Mapping[str, Any], **overrides) -> MoETransformerConfig:
     """DeepseekV4ForCausalLM: the V3 MoE+MLA body plus DSA — the lightning
     indexer's top-k sparse attention (reference: components/models/

@@ -1,10 +1,10 @@
-"""Step-3.5 and MiMo-V2-Flash family adapters for the heterogeneous MoE
-decoder (models/moe_lm/het_moe.py).
+"""Step-3.5 and MiniMax-M3 family adapters for the heterogeneous MoE
+decoder (models/moe_lm/het_moe.py). (MiMo-V2-Flash is served by the expert
+decoder: models/moe_lm/families.py `mimo_v2_flash_config`.)
 
 References: nemo_automodel/components/models/step3p5/ (model.py:235 MoE
 mapping, layers.py:183 attention, state_dict_adapter.py stacked-expert
-layout) and mimo_v2_flash/ (config.py hybrid_layer_pattern semantics,
-model.py:269 per-type sink biases, standard per-expert checkpoint layout).
+layout) and minimax_m3_vl/.
 """
 
 from __future__ import annotations
@@ -97,81 +97,6 @@ def step3p5_config(hf: Mapping[str, Any], **overrides) -> HetMoEConfig:
         share_expert_dim=int(share_dim),
         swiglu_limit=limit,
         rms_norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
-        tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
-    )
-    moe_overrides = overrides.pop("moe", None)
-    kw.update(overrides)  # unknown keys raise loudly in HetMoEConfig
-    if moe_overrides is not None:
-        kw["moe"] = moe_overrides
-    return HetMoEConfig(**kw)
-
-
-def mimo_v2_flash_config(hf: Mapping[str, Any], **overrides) -> HetMoEConfig:
-    """MiMoV2FlashForCausalLM: hybrid_layer_pattern (1 = sliding) with
-    swa_* head settings, per-type attention-sink biases, DeepSeek-style
-    sigmoid routing on every moe_layer_freq layer."""
-    L = int(hf["num_hidden_layers"])
-    heads = int(hf["num_attention_heads"])
-    kv = int(hf.get("num_key_value_heads", heads))
-    pattern = hf.get("hybrid_layer_pattern")
-    if pattern is None:
-        block = hf.get("hybrid_block_size")
-        if block:
-            pattern = [0 if ((i + 1) % int(block) == 0) else 1 for i in range(L)]
-        else:
-            pattern = [0 if (i % 6 == 0 or i == L - 1) else 1 for i in range(L)]
-    layer_types = tuple("sliding" if p == 1 else "global" for p in pattern)
-    freq = hf.get("moe_layer_freq")
-    if freq is None:
-        freq = [1] * L
-    head_dim = int(hf.get("head_dim", hf["hidden_size"] // heads))
-    v_dim = int(hf.get("v_head_dim", head_dim) or head_dim)
-    prf = float(hf.get("partial_rotary_factor", 1.0))
-    moe = MoEConfig(
-        n_routed_experts=int(hf["n_routed_experts"]),
-        n_shared_experts=int(hf.get("n_shared_experts") or 0),
-        experts_per_token=int(hf.get("num_experts_per_tok", 8)),
-        n_groups=int(hf.get("n_group", 1)),
-        topk_groups=int(hf.get("topk_group", 1)),
-        moe_intermediate_size=int(hf["moe_intermediate_size"]),
-        score_func="sigmoid" if hf.get("scoring_func", "sigmoid") == "sigmoid" else "softmax",
-        norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
-        route_scale=float(hf.get("routed_scaling_factor", 1.0) or 1.0),
-        gate_bias_update_speed=float(hf.get("bias_update_speed", 0.001)),
-    )
-    thetas = tuple(
-        float(hf.get("swa_rope_theta", 10000.0)) if lt == "sliding"
-        else float(hf.get("rope_theta", 5_000_000.0))
-        for lt in layer_types
-    )
-    kw = dict(
-        vocab_size=int(hf["vocab_size"]),
-        hidden_size=int(hf["hidden_size"]),
-        intermediate_size=int(hf["intermediate_size"]),
-        num_layers=L,
-        layer_types=layer_types,
-        global_attn=AttnGeom(
-            num_heads=heads, num_kv_heads=kv, head_dim=head_dim,
-            v_head_dim=v_dim,
-            sinks=bool(hf.get("add_full_attention_sink_bias", False)),
-        ),
-        sliding_attn=AttnGeom(
-            num_heads=int(hf.get("swa_num_attention_heads", heads)),
-            num_kv_heads=int(hf.get("swa_num_key_value_heads", kv)),
-            head_dim=int(hf.get("swa_head_dim", head_dim) or head_dim),
-            v_head_dim=int(hf.get("swa_v_head_dim", v_dim) or v_dim),
-            sliding_window=int(hf.get("sliding_window") or 128),
-            sinks=bool(hf.get("add_swa_attention_sink_bias", True)),
-        ),
-        qk_norm=False,
-        attention_bias=bool(hf.get("attention_bias", False)),
-        rope_thetas=thetas,
-        partial_rotary=(prf,) * L,
-        use_rope=(True,) * L,
-        mlp_kinds=tuple("moe" if f else "dense" for f in freq),
-        moe=moe,
-        share_expert_dim=0,  # shared experts live inside the MoE block
-        rms_norm_eps=float(hf.get("layernorm_epsilon", hf.get("rms_norm_eps", 1e-5))),
         tie_word_embeddings=bool(hf.get("tie_word_embeddings", False)),
     )
     moe_overrides = overrides.pop("moe", None)
@@ -278,9 +203,6 @@ class HetMoEAdapter:
     style="step3p5": self_attn.{q,k}_norm + g_proj; STACKED expert tensors
     moe.{gate,up,down}_proj.weight (E, I, H)/(E, H, I), router moe.gate.weight
     (E, H) + moe.router_bias, shared expert under share_expert.*.
-    style="mimo": standard per-expert mlp.experts.{e}.{proj}.weight, router
-    mlp.gate.weight + mlp.gate.e_score_correction_bias, per-layer
-    self_attn.attention_sink_bias, shared under mlp.shared_experts.*.
     style="minimax_m3": per-expert block_sparse_moe.experts.{e}.w1/w3/w2
     (gate/up/down), router block_sparse_moe.gate.weight +
     block_sparse_moe.e_score_correction_bias, shared experts under
@@ -322,8 +244,6 @@ class HetMoEAdapter:
             ]
         if self.cfg.head_gate:
             e.append(("self_attn.g_proj.weight", ("g_proj", "kernel"), True))
-        if g.sinks:
-            e.append(("self_attn.attention_sink_bias", ("sinks",), False))
         return e
 
     def to_hf(self, params):
@@ -384,7 +304,7 @@ class HetMoEAdapter:
                         yield base + f"block_sparse_moe.shared_experts.{proj}.weight", _t(
                             params["shared_mlp"][proj]["kernel"][mi]
                         )
-            elif self.style == "step3p5":
+            else:  # step3p5
                 yield base + "moe.gate.weight", _t(np.asarray(moe["gate"]["weight"][mi]))
                 if "e_score_bias" in moe["gate"]:
                     yield base + "moe.router_bias", np.asarray(
@@ -400,22 +320,6 @@ class HetMoEAdapter:
                     for proj in ("gate_proj", "up_proj", "down_proj"):
                         yield base + f"share_expert.{proj}.weight", _t(
                             params["shared_mlp"][proj]["kernel"][mi]
-                        )
-            else:  # mimo
-                yield base + "mlp.gate.weight", _t(np.asarray(moe["gate"]["weight"][mi]))
-                if "e_score_bias" in moe["gate"]:
-                    yield base + "mlp.gate.e_score_correction_bias", np.asarray(
-                        moe["gate"]["e_score_bias"][mi]
-                    )
-                for e in range(E):
-                    for proj in ("gate_proj", "up_proj", "down_proj"):
-                        yield base + f"mlp.experts.{e}.{proj}.weight", _t(
-                            np.asarray(moe["experts"][proj]["kernel"][mi, e])
-                        )
-                if cfg.moe.n_shared_experts:
-                    for proj in ("gate_proj", "up_proj", "down_proj"):
-                        yield base + f"mlp.shared_experts.{proj}.weight", _t(
-                            np.asarray(moe["shared"][proj]["kernel"][mi])
                         )
 
     def from_hf(self, read, shardings=None) -> dict:
@@ -521,7 +425,7 @@ class HetMoEAdapter:
                             )
                             for (li, *_r) in moe_rows
                         ]))
-            elif self.style == "step3p5":
+            else:  # step3p5
                 put(("moe", "gate", "weight"), np.stack([
                     one(f"model.layers.{li}.moe.gate.weight", True)
                     for (li, *_r) in moe_rows
@@ -543,30 +447,6 @@ class HetMoEAdapter:
                     for proj in ("gate_proj", "up_proj", "down_proj"):
                         put(("shared_mlp", proj, "kernel"), np.stack([
                             one(f"model.layers.{li}.share_expert.{proj}.weight", True)
-                            for (li, *_r) in moe_rows
-                        ]))
-            else:  # mimo
-                put(("moe", "gate", "weight"), np.stack([
-                    one(f"model.layers.{li}.mlp.gate.weight", True)
-                    for (li, *_r) in moe_rows
-                ]))
-                if cfg.moe.gate_bias_update_speed > 0:
-                    put(("moe", "gate", "e_score_bias"), np.stack([
-                        one(f"model.layers.{li}.mlp.gate.e_score_correction_bias", False)
-                        for (li, *_r) in moe_rows
-                    ]))
-                for proj in ("gate_proj", "up_proj", "down_proj"):
-                    put(("moe", "experts", proj, "kernel"), np.stack([
-                        np.stack([
-                            one(f"model.layers.{li}.mlp.experts.{e}.{proj}.weight", True)
-                            for e in range(E)
-                        ])
-                        for (li, *_r) in moe_rows
-                    ]))
-                if cfg.moe.n_shared_experts:
-                    for proj in ("gate_proj", "up_proj", "down_proj"):
-                        put(("moe", "shared", proj, "kernel"), np.stack([
-                            one(f"model.layers.{li}.mlp.shared_experts.{proj}.weight", True)
                             for (li, *_r) in moe_rows
                         ]))
         return params

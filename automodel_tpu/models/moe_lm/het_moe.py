@@ -1,24 +1,25 @@
-"""Heterogeneous-attention MoE decoder — the Step-3.5 / MiMo-V2-Flash engine.
+"""Heterogeneous-attention MoE decoder — the Step-3.5 / MiniMax-M3 engine.
 
-The analog of the reference's step3p5 (reference: nemo_automodel/components/
-models/step3p5/, 2581 LoC) and mimo_v2_flash (mimo_v2_flash/, 1107 LoC)
-families. Both interleave TWO attention geometries by `layer_types` — global
-layers and sliding-window layers with their OWN head counts (and, for MiMo,
-their own qk/v head dims and attention-sink biases) — over a decoder whose
-MLPs are per-layer dense or routed-MoE (+ a per-layer shared expert):
+The analog of the reference's step3p5 family (reference: nemo_automodel/
+components/models/step3p5/, 2581 LoC): TWO attention geometries interleaved
+by `layer_types` — global layers and sliding-window layers with their OWN
+head counts (and head dims) — over a decoder whose MLPs are per-layer dense or
+routed-MoE (+ a per-layer shared expert):
 
 - step3p5 (layers.py:183 `Step3p5Attention`): per-head qk-RMSNorm, optional
   head-wise sigmoid gate (g_proj), per-layer rope theta / partial rotary /
   NoPE layers (`use_rope_layers`), clamped swiglu MLPs with per-layer
   limits, arbitrary `moe_layers_enum` MoE placement, separate shared expert.
-- mimo_v2_flash (model.py): sliding layers carry swa_* head settings and a
-  learnable attention-sink bias; MoE with DeepSeek-style sigmoid routing.
+
+(MiMo-V2-Flash, window layers with key/value heads and sinks of their own,
+runs on the expert decoder that the serving engine serves:
+models/moe_lm/families.py `mimo_v2_flash_config`.)
 
 TPU design: stacked parameter groups per attention geometry and per MLP
 kind, a python loop over `layer_types` with running per-group indices (the
 models/hybrid/qwen3_next idiom — the heterogeneity is static config), all
 attention through ops/attention.dot_product_attention (flash on TPU,
-sinks/windows/MLA-ish asymmetric v dims native).
+windows native).
 """
 
 from __future__ import annotations
@@ -45,13 +46,7 @@ class AttnGeom:
     num_heads: int = 8
     num_kv_heads: int = 4
     head_dim: int = 64
-    v_head_dim: Optional[int] = None   # None → head_dim (MiMo swa differs)
     sliding_window: Optional[int] = None
-    sinks: bool = False                # learnable per-head sink bias (MiMo)
-
-    @property
-    def vd(self) -> int:
-        return self.v_head_dim or self.head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -122,7 +117,7 @@ class HetMoEConfig:
         for i, lt in enumerate(self.layer_types):
             g = self.geom(lt)
             total += H * g.head_dim * (g.num_heads + 2 * g.num_kv_heads)
-            total += g.num_heads * g.vd * H
+            total += g.num_heads * g.head_dim * H
             if self.mlp_kinds[i] == "moe":
                 total += 3 * H * self.moe.moe_intermediate_size * self.moe.experts_per_token
                 total += 3 * H * self.share_expert_dim
@@ -144,14 +139,14 @@ def _init_attn_group(cfg: HetMoEConfig, g: AttnGeom, rng, n: int) -> dict:
     p = {
         "q_proj": {"kernel": _stack(dense_init, ks[0], (H, g.num_heads * g.head_dim), n)},
         "k_proj": {"kernel": _stack(dense_init, ks[1], (H, g.num_kv_heads * g.head_dim), n)},
-        "v_proj": {"kernel": _stack(dense_init, ks[2], (H, g.num_kv_heads * g.vd), n)},
-        "o_proj": {"kernel": _stack(dense_init, ks[3], (g.num_heads * g.vd, H), n)},
+        "v_proj": {"kernel": _stack(dense_init, ks[2], (H, g.num_kv_heads * g.head_dim), n)},
+        "o_proj": {"kernel": _stack(dense_init, ks[3], (g.num_heads * g.head_dim, H), n)},
     }
     if cfg.attention_bias:
         for name, width in (
             ("q_proj", g.num_heads * g.head_dim),
             ("k_proj", g.num_kv_heads * g.head_dim),
-            ("v_proj", g.num_kv_heads * g.vd),
+            ("v_proj", g.num_kv_heads * g.head_dim),
             ("o_proj", H),
         ):
             p[name]["bias"] = jnp.zeros((n, width))
@@ -161,8 +156,6 @@ def _init_attn_group(cfg: HetMoEConfig, g: AttnGeom, rng, n: int) -> dict:
         p["k_norm"] = {"scale": norm1((n, g.head_dim))}
     if cfg.head_gate:
         p["g_proj"] = {"kernel": _stack(dense_init, ks[4], (H, g.num_heads), n)}
-    if g.sinks:
-        p["sinks"] = jnp.zeros((n, g.num_heads))
     return p
 
 
@@ -182,8 +175,6 @@ def _attn_group_specs(cfg: HetMoEConfig, g: AttnGeom) -> dict:
         p["k_norm"] = {"scale": ("layers", "norm")}
     if cfg.head_gate:
         p["g_proj"] = {"kernel": ("layers", "embed", None)}
-    if g.sinks:
-        p["sinks"] = ("layers", "heads")
     return p
 
 
@@ -500,11 +491,11 @@ def forward(
             x = rms_norm(h, params["input_norms"]["scale"][li], eps, zc)
             q = _mm(x, lp["q_proj"]["kernel"][ai], prec).reshape(B, S, g.num_heads, g.head_dim)
             k = _mm(x, lp["k_proj"]["kernel"][ai], prec).reshape(B, S, g.num_kv_heads, g.head_dim)
-            v = _mm(x, lp["v_proj"]["kernel"][ai], prec).reshape(B, S, g.num_kv_heads, g.vd)
+            v = _mm(x, lp["v_proj"]["kernel"][ai], prec).reshape(B, S, g.num_kv_heads, g.head_dim)
             if cfg.attention_bias:
                 q = q + lp["q_proj"]["bias"][ai].reshape(1, 1, g.num_heads, g.head_dim)
                 k = k + lp["k_proj"]["bias"][ai].reshape(1, 1, g.num_kv_heads, g.head_dim)
-                v = v + lp["v_proj"]["bias"][ai].reshape(1, 1, g.num_kv_heads, g.vd)
+                v = v + lp["v_proj"]["bias"][ai].reshape(1, 1, g.num_kv_heads, g.head_dim)
             if cfg.qk_norm:
                 q = rms_norm(q, lp["q_norm"]["scale"][ai], eps, zc)
                 k = rms_norm(k, lp["k_norm"]["scale"][ai], eps, zc)
@@ -519,16 +510,15 @@ def forward(
                 )
                 attn = _sparse_masked_attention(q, k, v, keep, g.head_dim ** -0.5)
             else:
-                sinks = lp["sinks"][ai] if g.sinks else None
                 attn = dot_product_attention(
                     q, k, v, causal=cfg.causal, segment_ids=segment_ids,
                     positions=positions, sliding_window=g.sliding_window,
-                    sinks=sinks, impl=cfg.attn_impl,
+                    impl=cfg.attn_impl,
                 )
             if cfg.head_gate:
                 gate = jax.nn.sigmoid(x @ lp["g_proj"]["kernel"][ai])
                 attn = attn * gate[..., :, None].astype(attn.dtype)
-            attn = attn.reshape(B, S, g.num_heads * g.vd)
+            attn = attn.reshape(B, S, g.num_heads * g.head_dim)
             out = _mm(attn, lp["o_proj"]["kernel"][ai], prec)
             if cfg.attention_bias and "bias" in lp["o_proj"]:
                 out = out + lp["o_proj"]["bias"][ai]

@@ -116,16 +116,18 @@ MODEL_ARCH_MAPPING: dict[str, ModelSpec] = {
         "gemma4_moe", gemma4_module.gemma4_moe_config, gemma4_module,
         adapter_name="gemma4_moe",
     ),
-    # Step-3.5 / MiMo-V2-Flash: heterogeneous sliding/global attention
-    # geometries over per-layer dense/MoE MLPs (reference: models/step3p5,
-    # models/mimo_v2_flash) — the het_moe engine
+    # Step-3.5: heterogeneous sliding/global attention geometries over
+    # per-layer dense/MoE MLPs (reference: models/step3p5) — the het_moe
+    # engine
     "Step3p5ForCausalLM": ModelSpec(
         "step3p5", het_families.step3p5_config, het_moe_module,
         adapter_name="het_moe", adapter_kwargs={"style": "step3p5"},
     ),
+    # MiMo-V2-Flash: the expert decoder with the window layers' attention an
+    # operator of its own (key/value heads, sinks), keys wider than values
     "MiMoV2FlashForCausalLM": ModelSpec(
-        "mimo_v2_flash", het_families.mimo_v2_flash_config, het_moe_module,
-        adapter_name="het_moe", adapter_kwargs={"style": "mimo"},
+        "mimo_v2_flash", moe_families.mimo_v2_flash_config, moe_decoder,
+        adapter_name="mimo",
     ),
     # Ling 2.0 (reference: models/ling_v2): deepseek-style routed MoE on
     # qk-normed partial-rope GQA; fused query_key_value checkpoint layout

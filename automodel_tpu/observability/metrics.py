@@ -330,6 +330,9 @@ METRIC_CATALOG = (
     # the paged GQA kernel's body for a decode row (ops/pallas/
     # ragged_paged_attention.py; trace time, process-global registry)
     ("paged_attention_one_row_body_total", "counter", "traced paged GQA kernel call sites (labeled by scores=mxu|vpu: how a one-row segment's block is scored)"),
+    # paged GQA calls with per-head sink logits (ops/paged_attention.py;
+    # trace time, process-global registry)
+    ("paged_attention_sink_total", "counter", "traced paged GQA call sites with attention sinks (labeled by impl=pallas|xla: where they lowered)"),
 )
 
 #: Process-global registry for components without an engine in hand

@@ -22,13 +22,16 @@ and counts every call it hands to the reference):
   one sequence, so a prefill chunk's rows fetch their pages once. The GQA
   kernel takes a sliding window as a static of the call (the block list
   then starts at the page of the segment's first in-window key and the mask
-  drops what lies further back); the MLA kernels take none, and neither
-  takes sinks (`_gqa_unsupported_reason` / `_mla_unsupported_reason` below
-  state the rules).
+  drops what lies further back) and per-head sink logits (a call with
+  them is named `paged_attention_sink_gqa`); the MLA kernels take neither
+  (`_gqa_unsupported_reason` / `_mla_unsupported_reason` below state the
+  rules).
 
 Layouts (see serving/kv_pages.py for the pool):
 
-- GQA:  k_pages/v_pages (N, ps, Hkv, D); q (T, Hq, D).
+- GQA:  k_pages (N, ps, Hkv, Dk), v_pages (N, ps, Hkv, Dv); q (T, Hq, D):
+  values may be narrower than keys, and keys wider than a lane tile lie at
+  a whole number of tiles (Dk >= D, zeros behind the key).
 - Quantized pools (serving kv_cache_dtype="int8"): the same page layouts
   hold int8 plus (N, ps) per-row scale arrays riding alongside; the
   reference dequantizes the gathered per-token view, the kernel variant
@@ -102,9 +105,14 @@ def ragged_paged_attention_xla(
     on the gathered per-token view in f32 — the CPU-testable oracle for
     the quantized Pallas kernel."""
     T, Hq, D = q.shape
-    N, ps, Hkv, _ = k_pages.shape
+    N, ps, Hkv, Dk = k_pages.shape
     P = page_tables.shape[1]
     G = Hq // Hkv
+    if Dk != D:
+        # keys stored wider than the head, zeros behind them
+        # (serving/kv_pages.key_width): the query's zeros meet them
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Dk - D)))
+        D = Dk
 
     # gather each token's pages → a contiguous per-token KV view
     keys = k_pages[page_tables].reshape(T, P * ps, Hkv, D)
@@ -372,10 +380,16 @@ def _gqa_unsupported_reason(q, k_pages, window, sinks, quant, tp):
     if window is not None and not isinstance(window, int):
         # the kernel's block list and mask take the window as a static
         return "a traced sliding window"
-    if sinks is not None:
-        return "attention sinks"
+    if sinks is not None and tp > 1:
+        # the tp shard_map wrapper hands the kernel no sinks
+        return "attention sinks under tp > 1"
     if Hq % Hkv != 0:
         return f"GQA needs Hq % Hkv == 0 (got {Hq} % {Hkv})"
+    if quant and k_pages.shape[-1] > 128:
+        # the int8 bodies widen a page to float32 for their products: at 64
+        # heads over 4 or 8 of 256-wide keys that passes the 16 MiB of VMEM
+        # a kernel is given (the chip's compiler, for a described v5e)
+        return "int8 pages of keys wider than a lane tile"
     if tp > 1 and quant:
         # scales replicate while heads shard; the quantized kernel has no
         # shard_map wrapper
@@ -416,9 +430,14 @@ def ragged_paged_attention(
     unsupported = _gqa_unsupported_reason(
         q, k_pages, window, sinks, quant, tp
     )
-    if resolve_kernel_impl(
-        impl, "pallas", unsupported, "paged_attention"
-    ) == "pallas":
+    chosen = resolve_kernel_impl(impl, "pallas", unsupported, "paged_attention")
+    if sinks is not None:
+        from automodel_tpu.observability.metrics import default_registry
+
+        # trace time: which calls with sinks lowered to the kernel
+        default_registry().counter(
+            "paged_attention_sink_total", impl=chosen).inc()
+    if chosen == "pallas":
         if tp > 1:
             return _pallas_gqa_tp(
                 mesh_ctx, q, k_pages, v_pages, page_tables, positions,
@@ -433,7 +452,7 @@ def ragged_paged_attention(
             return paged_attention_quant_kernel(
                 q, k_pages, v_pages, k_scales, v_scales,
                 page_tables, positions, scale=scale, soft_cap=soft_cap,
-                segments=segments, window=window,
+                segments=segments, window=window, sinks=sinks,
             )
         from automodel_tpu.ops.pallas.ragged_paged_attention import (
             paged_attention_kernel,
@@ -442,6 +461,7 @@ def ragged_paged_attention(
         return paged_attention_kernel(
             q, k_pages, v_pages, page_tables, positions,
             scale=scale, soft_cap=soft_cap, segments=segments, window=window,
+            sinks=sinks,
         )
     q = _annotate_tp(q, mesh_ctx, 1)              # head axis
     k_pages = _annotate_tp(k_pages, mesh_ctx, 2)

@@ -39,13 +39,18 @@ in latent space), over bf16 and int8 pages. The GQA kernels take a sliding
 window as a static of the call: the block list then begins at the page of
 the segment's first in-window key (`row_segments(window=...)`) and the mask
 drops every key `window` or more positions behind its row; such a call is
-named `paged_attention_window_gqa`. The MLA kernels
-take no window and none takes attention sinks: the dispatcher
-(ops/paged_attention.py) states the rules and never calls in here with them.
+named `paged_attention_window_gqa`. They take per-head sink logits too: a
+head's sink starts each segment's running maximum and sum (`_reset`), so it
+joins every row's softmax denominator and adds nothing to its output; such a
+call is named `paged_attention_sink_gqa`. Values may be narrower than keys.
+The MLA kernels take neither: the dispatcher (ops/paged_attention.py) states
+the rules and never calls in here with them.
 
 GQA head dims that are not lane (128) multiples are zero-padded host-side
 (pad lanes add zero logits / zero value columns — exact), which copies the
-pool on every call; the MLA latent/rope pages go in as they are. Runs on
+pool on every call: the serving pool stores a key wider than a lane tile at
+a whole number of tiles (serving/kv_pages.key_width), so that only the
+queries are padded; the MLA latent/rope pages go in as they are. Runs on
 CPU via interpret mode for unit-test parity against the XLA reference.
 """
 
@@ -103,9 +108,17 @@ def _attends(kv_idx, row_pos, window):
     return mask
 
 
-def _reset(m_ref, l_ref, acc_ref):
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+def _reset(m_ref, l_ref, acc_ref, sink=None):
+    """A segment's running state before its first page. With `sink` (the
+    rows' sink logits, broadcast like `m_ref`) the sink is already in the
+    maximum and the sum, one term e^(sink - m) = 1 that adds no value: it
+    joins the softmax's denominator alone."""
+    if sink is None:
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+    else:
+        m_ref[...] = sink
+        l_ref[...] = jnp.ones_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
@@ -160,12 +173,13 @@ def _rows_as_segments(page_tables, positions, page_size, row_width,
 
 
 def _segment_call(kernel, name, interpret, segments, positions, queries,
-                  pages, scales, out_width, scratch, page_size):
+                  pages, scales, out_width, scratch, page_size, whole=()):
     """`pallas_call` over the step's blocks. `queries` are (T, H, w) row
     arrays blocked by the block's tile, `pages` (N, ...) pools of
     `page_size` tokens a page and `scales` their (N, ps) per-row scales
-    blocked by the block's page, the output (T, H, out_width) blocked like
-    the queries."""
+    blocked by the block's page, `whole` arrays every block reads whole
+    (fetched once), the output (T, H, out_width) blocked like the
+    queries."""
     tile, blocks, count = segments
     T, H, _ = queries[0].shape
     ps = page_size
@@ -187,6 +201,8 @@ def _segment_call(kernel, name, interpret, segments, positions, queries,
             # rule that a block's second-to-last dim is a multiple of 8 or
             # the whole axis
             *(pl.BlockSpec((1, 1, ps), page_map(3)) for _ in scales),
+            *(pl.BlockSpec(x.shape, lambda w, blocks, n=x.ndim: (0,) * n)
+              for x in whole),
         ],
         out_specs=pl.BlockSpec((tile, H, out_width), row_map),
         scratch_shapes=scratch,
@@ -199,7 +215,7 @@ def _segment_call(kernel, name, interpret, segments, positions, queries,
         name=name,
     )(
         blocks, *queries, *pages,
-        *(s.astype(jnp.float32)[:, None, :] for s in scales),
+        *(s.astype(jnp.float32)[:, None, :] for s in scales), *whole,
     )
     # rows of no segment were never stored
     return jnp.where((positions >= 0)[:, None, None], out, 0)
@@ -221,9 +237,11 @@ def _gqa_kernel(
                # the same page as a matrix, (1, ps x Hkv, D)
     v_ref,     # (1, ps, Hkv, Dv); groups > 1: (1, ps x Hkv, Dv)
     *rest,     # [ks_ref, vs_ref (1, 1, ps) f32 per-row scales of THIS page,]
+               # [sink1_ref (Hq, LANE), sinkt_ref (Hkv, groups x tile, LANE)
+               # f32 sink logits as one row's and the tile's m lie,]
                # out_ref (tile, Hq, Dv), one row's m/l/acc, the tile's
                # head-major q and m/l/acc
-    scale, soft_cap, page_size, groups, tile, quant, window,
+    scale, soft_cap, page_size, groups, tile, quant, window, sinks,
 ):
     """bf16 and int8 pages alike. The tile body scores (Hkv, groups x
     tile, D) queries, the tile's rows turned head-major once a segment,
@@ -237,9 +255,12 @@ def _gqa_kernel(
     is algebraic per page: the K scale multiplies each kv slot's score
     column, the V scale folds into the softmax weights before the value
     product — the int8 blocks are cast for the products, never materialized
-    dequantized in HBM."""
+    dequantized in HBM. With `sinks` each head's sink logit starts a
+    segment's running maximum and sum (`_reset`) in both bodies."""
     if quant:
         ks_ref, vs_ref, *rest = rest
+    if sinks:
+        sink1_ref, sinkt_ref, *rest = rest
     out_ref, m1, l1, acc1, qt, mt, lt, acct = rest
     off, length, pos0, key0, first_page, last_page = _block(
         blocks_ref, page_size, window)
@@ -363,7 +384,7 @@ def _gqa_kernel(
 
     @pl.when(jnp.logical_and(one_row, first_page))
     def _reset_one():
-        _reset(m1, l1, acc1)
+        _reset(m1, l1, acc1, sink1_ref[...] if sinks else None)
 
     pl.when(one_row)(one_row_mxu if groups > 1 else one_row_vpu)
 
@@ -374,7 +395,7 @@ def _gqa_kernel(
     # -- a chunk's rows: the whole tile, head-major ---------------------------
     @pl.when(jnp.logical_and(many_rows, first_page))
     def _reset_tile():
-        _reset(mt, lt, acct)
+        _reset(mt, lt, acct, sinkt_ref[...] if sinks else None)
         # (tile, Hq, D) → (Hkv, groups x tile, D): score row g * tile + i
         # of a key/value head is its query head g of tile row i
         qt[...] = jnp.swapaxes(q_ref[...], 0, 1).reshape(qt.shape)
@@ -399,10 +420,10 @@ def _gqa_kernel(
     static_argnames=("tile", "scale", "soft_cap", "name", "interpret",
                      "window"))
 def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
-              tile, scale, soft_cap, name, interpret, window=None):
+              tile, scale, soft_cap, name, interpret, window=None, sinks=None):
     """Jitted (inlined into its caller) for its cache alone: a step traces
     one call per layer and pass, and every one after the first is the
-    first's jaxpr."""
+    first's jaxpr. `sinks` (Hq,): per-head sink logits, or None."""
     Hq, (N, ps, Hkv, _) = q.shape[1], k_pages.shape
     groups = Hq // Hkv
     Dv = v_pages.shape[-1]
@@ -427,23 +448,34 @@ def _gqa_call(q, k_pages, v_pages, scales, positions, blocks, count, *,
         pltpu.VMEM((Hkv, groups * tile, LANE), f32),
         pltpu.VMEM((Hkv, groups * tile, Dvp), f32),
     ]
+    whole = ()
+    if sinks is not None:
+        # the sinks as the two bodies' maxima lie: a row's (Hq, LANE), and
+        # the tile's score row g x tile + i of key/value head h, head
+        # h x groups + g's
+        s = jnp.broadcast_to(sinks.astype(f32)[:, None], (Hq, LANE))
+        whole = (s, jnp.broadcast_to(
+            s.reshape(Hkv, groups, 1, LANE), (Hkv, groups, tile, LANE),
+        ).reshape(Hkv, groups * tile, LANE))
     kernel = functools.partial(
         _gqa_kernel, scale=scale, soft_cap=soft_cap, groups=groups,
-        quant=len(scales) > 0, window=window,
+        quant=len(scales) > 0, window=window, sinks=sinks is not None,
     )
     out = _segment_call(
         kernel, name, interpret, RowSegments(tile, blocks, count), positions,
-        [qp], [kp, vp], scales, Dvp, scratch, ps,
+        [qp], [kp, vp], scales, Dvp, scratch, ps, whole,
     )
     return out[..., :Dv]
 
 
 def _gqa(q, k_pages, v_pages, scales, page_tables, positions, *, scale,
-         soft_cap, segments, name, window=None):
+         soft_cap, segments, name, window=None, sinks=None):
     window = window or None
-    if window:
-        # the same body under a name of its own: a trace and a lowered step
-        # tell a window layer's calls from a full layer's
+    if sinks is not None:
+        # the same body under names of their own: a trace and a lowered step
+        # tell a call with sinks, a window layer's and a full layer's apart
+        name = name.replace("paged_attention", "paged_attention_sink")
+    elif window:
         name = name.replace("paged_attention", "paged_attention_window")
     if segments is None:
         segments = _rows_as_segments(
@@ -460,7 +492,7 @@ def _gqa(q, k_pages, v_pages, scales, page_tables, positions, *, scale,
         q, k_pages, v_pages, tuple(scales), positions,
         segments.blocks, segments.count, tile=segments.tile,
         scale=float(scale), soft_cap=soft_cap, name=name,
-        interpret=_interpret(), window=window,
+        interpret=_interpret(), window=window, sinks=sinks,
     )
 
 
@@ -471,16 +503,19 @@ def paged_attention_kernel(
     soft_cap: float | None = None,
     segments: RowSegments | None = None,
     window: int | None = None,
+    sinks=None,
 ):
     """GQA ragged paged attention; q (T, Hq, D), pages (N, ps, Hkv, D[v]),
     `page_tables` (T, P) per ROW. `segments` groups the rows into runs of
     one sequence (`row_segments`, built with the same `window`); None:
     every row its own. `window` (static): a row attends to the keys fewer
-    than `window` positions behind it."""
+    than `window` positions behind it. `sinks` (Hq,): a logit a head in
+    each row's softmax denominator and nowhere else (the call is then
+    named `paged_attention_sink_gqa`)."""
     return _gqa(
         q, k_pages, v_pages, (), page_tables, positions,
         scale=scale, soft_cap=soft_cap, segments=segments,
-        name="paged_attention_gqa", window=window,
+        name="paged_attention_gqa", window=window, sinks=sinks,
     )
 
 
@@ -491,13 +526,14 @@ def paged_attention_quant_kernel(
     soft_cap: float | None = None,
     segments: RowSegments | None = None,
     window: int | None = None,
+    sinks=None,
 ):
     """GQA ragged paged attention over int8 pages with (N, ps) per-row
     scales; same contract as `paged_attention_kernel`."""
     return _gqa(
         q, k_pages, v_pages, (k_scales, v_scales), page_tables, positions,
         scale=scale, soft_cap=soft_cap, segments=segments,
-        name="paged_attention_gqa_int8", window=window,
+        name="paged_attention_gqa_int8", window=window, sinks=sinks,
     )
 
 

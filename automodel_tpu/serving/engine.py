@@ -93,8 +93,10 @@ from automodel_tpu.inference.generate import (
 from automodel_tpu.inference.sampling import filter_logits
 from automodel_tpu.models.common.layers import cast_params
 from automodel_tpu.models.llm.decoder import (
+    ATTENTION_OPS,
     OPERATOR_STACKS,
     _dense,
+    kind_config,
     layer_operators,
     layer_windows,
     make_freq_for,
@@ -371,8 +373,10 @@ class ServingEngine:
     request at a position other than 0 without having run the rows before
     it has no state, and no ring, to begin from: for a model that holds
     either (`begins_at_zero`) prefix hits are cut to none (counted),
-    speculation and a hand-off between pools are refused by name. The
-    heterogeneous engine (HetMoEConfig: attention layers of unlike geometry,
+    speculation and a hand-off between pools are refused by name. Window
+    layers whose attention has a geometry of its own (key/value heads, value
+    width, sinks: `decoder.kind_config`) keep rings at that geometry. The
+    heterogeneous engine (HetMoEConfig: Step-3.5's and MiniMax-M3's layers,
     sparse index caches) is not servable here."""
 
     def __init__(
@@ -402,10 +406,11 @@ class ServingEngine:
         if isinstance(cfg, HetMoEConfig):
             raise NotImplementedError(
                 "ServingEngine serves TransformerConfig / MoETransformerConfig "
-                "decoders: attention layers of ONE geometry over one page "
-                "pool, beside them layers whose state lives per slot; the het "
-                "engine's attention layers of unlike geometry and its index "
-                "caches need a step function and a pool of their own"
+                "decoders: full attention layers over one page pool, window "
+                "layers (at a geometry of their own, `window_attention`) over "
+                "rings, layers whose state lives per slot; the het engine's "
+                "per-layer geometries and index caches need a step function "
+                "and a pool of their own"
             )
         # serve-step linear precision: all decoder/generate linears already
         # route through ops/quant.matmul(x, kernel, cfg.linear_precision),
@@ -434,9 +439,6 @@ class ServingEngine:
         # some layer carries a state from token to token (kv_pages.init_state)
         self.holds_state = cfg.holds_state
         self._plan_layers()
-        # a request may begin at position 0 alone: what a slot carries
-        # (a recurrent state, a window layer's ring) cannot be adopted
-        self.begins_at_zero = self.holds_state or self._ring_pages > 0
         spec = serve_cfg.speculative
         if self.begins_at_zero and spec is not None and spec.enabled:
             raise NotImplementedError(
@@ -446,12 +448,6 @@ class ServingEngine:
                 "snapshot of it is kept), and a verify block's rows may "
                 "overwrite ring pages the pending row still reads"
             )
-        # rows of the paged kernels' q tile, from the step's rows and the
-        # elements of one row's attention queries and outputs
-        self._attn_row_tile = row_tile(serve_cfg.token_budget, cfg.num_heads * (
-            2 * cfg.mla_kv_lora_rank + cfg.mla_qk_rope_head_dim
-            if self.is_mla else 2 * cfg.resolved_head_dim
-        ))
         # tp/ep-sharded step (mesh_ctx set): the paged pool becomes a
         # mesh-sharded array (kv_pages.pool_axes) and GSPMD partitions the
         # ONE jitted step over the mesh — page IDs stay global, so the host
@@ -499,7 +495,8 @@ class ServingEngine:
         ssm_state = init_state(cfg, serve_cfg.max_slots, self._mesh)
         self._num_ssm = len(ssm_state)
         self.state = ssm_state + init_rings(
-            cfg, cfg.num_passes * sum(self._stack_rings), serve_cfg.max_slots,
+            kind_config(cfg, "window_attention"),
+            cfg.num_passes * sum(self._stack_rings), serve_cfg.max_slots,
             self._ring_pages, serve_cfg.page_size, self._mesh,
             serve_cfg.kv_cache_dtype,
         )
@@ -618,10 +615,11 @@ class ServingEngine:
         # per stack and layer (kind, index into that kind's operator stack),
         # the index None where attention's weights are the layer's own
         ops = layer_operators(cfg)
-        self._stack_ops = [
-            ops if ops is not None else (("attention", None),) * L
-            for *_, L in self._stacks
-        ]
+        self._stack_ops, off = [], 0
+        for *_, L in self._stacks:
+            self._stack_ops.append(
+                ops[off:off + L] if ops is not None else (("attention", None),) * L)
+            off += L
         # per stack and layer its window, a STATIC of the trace (None: full
         # attention): the step's loop is Python's, so the paged op takes the
         # window as a static of each call and a rotary table is chosen, or
@@ -644,12 +642,13 @@ class ServingEngine:
         # per stack: layers that read a ring, and pool entries a pass (one
         # per attention layer that does not)
         self._stack_rings = [
-            sum(kind == "attention" and bool(w) for (kind, _), w in zip(ops, wins))
+            sum(kind in ATTENTION_OPS and bool(w)
+                for (kind, _), w in zip(ops, wins))
             if rings else 0
             for ops, wins in zip(self._stack_ops, self._stack_windows)
         ]
         self._stack_attn = [
-            sum(kind == "attention" for kind, _ in ops) - n_rings
+            sum(kind in ATTENTION_OPS for kind, _ in ops) - n_rings
             for ops, n_rings in zip(self._stack_ops, self._stack_rings)
         ]
         # a model without rotary embedding (cfg.use_rope False) has no table;
@@ -658,6 +657,18 @@ class ServingEngine:
             cfg.rope_dim, cfg.rope_theta, cfg.rope_scaling
         ) if cfg.use_rope else None
         self._freq_for = make_freq_for(cfg, self._inv_freq)
+        # a request may begin at position 0 alone: what a slot carries
+        # (a recurrent state, a window layer's ring) cannot be adopted
+        self.begins_at_zero = cfg.holds_state or self._ring_pages > 0
+        # rows of the paged kernels' q tile, from the step's rows and the
+        # elements of one row's attention queries and outputs (the widest
+        # kind's, where window layers have a geometry of their own)
+        self._attn_row_tile = row_tile(sc.token_budget, cfg.num_heads * (
+            2 * cfg.mla_kv_lora_rank + cfg.mla_qk_rope_head_dim
+        ) if self.is_mla else max(
+            k.num_heads * (k.resolved_head_dim + k.resolved_v_head_dim)
+            for k in (cfg, kind_config(cfg, "window_attention"))
+        ))
 
     # -- mesh plumbing ------------------------------------------------------
     @staticmethod
@@ -809,7 +820,7 @@ class ServingEngine:
         return h + moe_out
 
     # -- device step --------------------------------------------------------
-    def _attn(self, h, lp, window, cache, b):
+    def _attn(self, h, lp, cfg, window, cache, b):
         """One attention sub-block over the paged pool; `cache` is one
         layer's page arrays — (k, v) fp, or (k, v, k_scale, v_scale)
         with kv_cache_dtype="int8", where new-token rows quantize IN-JIT at
@@ -817,9 +828,9 @@ class ServingEngine:
         behind the page gather. `window` is the layer's, static (None:
         full attention). With a window, in a model that keeps rings, `cache`
         is the layer's ring of pages per slot, not a pool entry, and the
-        rows' write pages, page tables and block list are the ring's.
+        rows' write pages, page tables and block list are the ring's. `cfg`
+        is the layer's operator's configuration (`decoder.kind_config`).
         Returns (post-residual h, written cache). h is (1, T, H)."""
-        cfg = self.cfg
         freq = self._freq_for(window)
         if window and self._ring_pages:
             page, tables, segments, write = (
@@ -879,6 +890,10 @@ class ServingEngine:
             return h, (pool_k, pool_v)
         # GQA
         q, k, v = project_qkv(x, lp, cfg, positions, freq)
+        if cache[0].shape[-1] != k.shape[-1]:
+            # keys lie in the pages at a whole number of lane tiles
+            # (kv_pages.key_width), zeros behind them
+            k = jnp.pad(k, [(0, 0)] * 3 + [(0, cache[0].shape[-1] - k.shape[-1])])
         scales_kw = {}
         if self._kv_quant:
             pool_k, pool_v, s_k, s_v = cache
@@ -1004,11 +1019,16 @@ class ServingEngine:
             )
         # copy-on-write splits first (≤ 1 per slot; idle entries copy the
         # trash page onto itself): a slot about to append into a page some
-        # other table or the radix tree still reads gets a private copy
-        with jax.named_scope("serve.cow"):
-            pool = jax.tree.map(
-                lambda a: a.at[b["cow_dst"]].set(a[b["cow_src"]]), pool
-            )
+        # other table or the radix tree still reads gets a private copy. A
+        # model whose requests begin at position 0 alone never shares a page
+        # (its prefix hits are cut, a hand-off refused): its step has no
+        # such block, whose scatter made the compiler lay a pool of fewer
+        # than 8 key/value heads a page out anew, twice a step, for nothing
+        if not self.begins_at_zero:
+            with jax.named_scope("serve.cow"):
+                pool = jax.tree.map(
+                    lambda a: a.at[b["cow_dst"]].set(a[b["cow_src"]]), pool
+                )
         # under a mesh: pool pinned to its pages-global / heads-sharded
         # layout through the COW block and the layers; hidden replicated so
         # every tp reduction lives inside the layer stack (no-ops off-mesh)
@@ -1051,17 +1071,18 @@ class ServingEngine:
                     for lp, win, (kind, j) in zip(params[pkey], wins, ops):
                         if j is not None:
                             lp = {**lp, **params[OPERATOR_STACKS[kind]][j]}
-                        if kind == "attention" and win and self._ring_pages:
+                        kcfg = kind_config(cfg, kind)
+                        if kind in ATTENTION_OPS and win and self._ring_pages:
                             with jax.named_scope("serve.attn"), \
                                     jax.named_scope("serve.attn.window"):
                                 h, new_state[r] = self._attn(
-                                    h, lp, win, state[r], b
+                                    h, lp, kcfg, win, state[r], b
                                 )
                             r += 1
-                        elif kind == "attention":
+                        elif kind in ATTENTION_OPS:
                             with jax.named_scope("serve.attn"), full_scope():
                                 h, cache = self._attn(
-                                    h, lp, win, next(caches), b
+                                    h, lp, kcfg, win, next(caches), b
                                 )
                             new_stack.append(cache)
                         else:

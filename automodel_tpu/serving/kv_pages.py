@@ -305,17 +305,40 @@ def _init_layer(shapes, dtype, num_pages, page_size, kv_cache_dtype):
     )
 
 
+#: lanes of the chip's vector registers and of its memory tiles
+LANE = 128
+
+
+def key_width(head_dim: int) -> int:
+    """The width a cached key is stored at: its own up to a lane tile, a
+    whole number of tiles above that, zeros behind the key (they add nothing
+    to a score). For a key of 192 the chip's compiler lays a (..., ps, Hkv,
+    192) page out with the TOKEN axis minor, which the paged kernel cannot
+    read without a copy of the whole pool every call; at 256 the page lies
+    as the kernel reads it where 8 key/value heads fill a tile's rows (with
+    4, the (ps x Hkv, 256) view the kernel takes is a relayout, not a view),
+    for a third more bytes of keys."""
+    return head_dim if head_dim <= LANE else -(-head_dim // LANE) * LANE
+
+
+def gqa_rows(cfg) -> tuple:
+    """One cached token's (key, value) rows of a GQA layer of `cfg`'s
+    geometry: (Hkv, key width), (Hkv, value width)."""
+    return ((cfg.num_kv_heads, key_width(cfg.resolved_head_dim)),
+            (cfg.num_kv_heads, cfg.resolved_v_head_dim))
+
+
 def init_gqa_pool(
     cfg, num_layers: int, num_pages: int, page_size: int,
     kv_cache_dtype: str | None = None,
 ):
     """One GQA stack: a (k, v) tuple of page arrays per layer (dtype/row
-    shapes from cfg — the cache-entry shapes of inference/generate.py's
-    `_cache_shapes`). kv_cache_dtype="int8" → (k, v, k_scale, v_scale):
-    int8 payloads at the SAME shapes plus the per-page scale arrays."""
-    row = (cfg.num_kv_heads, cfg.resolved_head_dim)
+    shapes from cfg, `gqa_rows`). kv_cache_dtype="int8" → (k, v, k_scale,
+    v_scale): int8 payloads at the SAME shapes plus the per-page scale
+    arrays."""
     return tuple(
-        _init_layer((row, row), cfg.dtype, num_pages, page_size, kv_cache_dtype)
+        _init_layer(gqa_rows(cfg), cfg.dtype, num_pages, page_size,
+                    kv_cache_dtype)
         for _ in range(num_layers)
     )
 
@@ -411,7 +434,9 @@ def state_shapes(cfg, max_slots: int) -> tuple:
 
 
 def num_state_layers(cfg) -> int:
-    return sum(op != "attention" for op in cfg.layer_ops or ())
+    from automodel_tpu.models.llm.decoder import ATTENTION_OPS
+
+    return sum(op not in ATTENTION_OPS for op in cfg.layer_ops or ())
 
 
 def state_shardings(cfg, mesh_ctx):
@@ -458,12 +483,12 @@ def init_rings(
 ) -> tuple:
     """The window layers' cache: per layer (and pass) the page arrays of a
     pool of (max_slots + 1) * `ring` pages, slot s's ring at pages s * ring
-    and on, the last slot the trash slot. Zeros, and nothing ever clears
-    them (see the module's text). Replicated on a mesh: an engine with
-    window layers refuses tp > 1."""
-    row = (cfg.num_kv_heads, cfg.resolved_head_dim)
+    and on, the last slot the trash slot, at the rows of `cfg`, the window
+    layers' own configuration (`decoder.kind_config`). Zeros, and nothing
+    ever clears them (see the module's text). Replicated on a mesh: an
+    engine with window layers refuses tp > 1."""
     rings = tuple(
-        _init_layer((row, row), cfg.dtype, (max_slots + 1) * ring - 1,
+        _init_layer(gqa_rows(cfg), cfg.dtype, (max_slots + 1) * ring - 1,
                     page_size, kv_cache_dtype)
         for _ in range(num_layers)
     )

@@ -8,8 +8,8 @@ the pool and the per-slot state (the window layers' rings behind it) as
 import jax
 import jax.numpy as jnp
 
+from automodel_tpu.models.llm.decoder import kind_config
 from automodel_tpu.models.registry import get_model_spec
-from automodel_tpu.ops.paged_attention import row_tile
 from automodel_tpu.serving import ServingConfig, ServingEngine
 from automodel_tpu.serving.engine import LAYER_STACKS
 from automodel_tpu.serving.kv_pages import init_pool, init_rings, init_state
@@ -52,8 +52,6 @@ def engine_of_shapes(config: dict, serving: dict, sharding):
     eng.is_moe = getattr(cfg, "moe", None) is not None
     eng.is_mla = cfg.attention_type == "mla"
     eng.holds_state = cfg.holds_state
-    eng._attn_row_tile = row_tile(
-        sc.token_budget, cfg.num_heads * 2 * cfg.resolved_head_dim)
     eng._plan_layers()
 
     def as_shapes(tree):
@@ -65,7 +63,8 @@ def engine_of_shapes(config: dict, serving: dict, sharding):
     ssm = jax.eval_shape(lambda: init_state(cfg, sc.max_slots))
     eng._num_ssm = len(ssm)
     state = as_shapes(ssm + jax.eval_shape(lambda: init_rings(
-        cfg, cfg.num_passes * sum(eng._stack_rings), sc.max_slots,
+        kind_config(cfg, "window_attention"),
+        cfg.num_passes * sum(eng._stack_rings), sc.max_slots,
         eng._ring_pages, sc.page_size)))
     batch = as_shapes(jax.eval_shape(
         lambda: eng._plan_batch(eng.empty_plan())))

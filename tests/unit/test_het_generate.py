@@ -1,6 +1,6 @@
 """het_generate: KV-cache decode parity for the heterogeneous MoE engine.
 
-The het engine (step3p5 / mimo-v2-flash / minimax-m3) decodes through
+The het engine (step3p5 / minimax-m3) decodes through
 `inference/het_generate.py` — per-layer python-loop caches including the
 block-sparse DSA index cache. Parity oracle: re-run the full het_moe
 forward for every new token (the discipline of test_generate.py).

@@ -116,7 +116,8 @@ FAMILIES = {
         "num_hidden_layers": 4, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 8, "v_head_dim": 8,
         "swa_num_attention_heads": 2, "swa_num_key_value_heads": 1,
-        "swa_head_dim": 16, "swa_v_head_dim": 8,
+        "swa_head_dim": 8, "swa_v_head_dim": 4,
+        "attention_value_scale": 0.707,
         "hybrid_layer_pattern": [0, 1, 1, 0],
         "sliding_window": 8,
         "rope_theta": 5000000.0, "swa_rope_theta": 10000.0,

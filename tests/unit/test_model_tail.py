@@ -378,11 +378,12 @@ MIMO_HF = {
     "num_hidden_layers": 4, "num_attention_heads": 4,
     "num_key_value_heads": 2, "head_dim": 8, "v_head_dim": 8,
     "swa_num_attention_heads": 2, "swa_num_key_value_heads": 1,
-    "swa_head_dim": 16, "swa_v_head_dim": 8,
+    "swa_head_dim": 8, "swa_v_head_dim": 4,
     "hybrid_layer_pattern": [0, 1, 1, 0],
     "sliding_window": 8,
     "rope_theta": 5000000.0, "swa_rope_theta": 10000.0,
     "partial_rotary_factor": 0.5,
+    "attention_value_scale": 0.707,
     "add_full_attention_sink_bias": False,
     "add_swa_attention_sink_bias": True,
     "n_routed_experts": 4, "num_experts_per_tok": 2,
@@ -426,22 +427,30 @@ def test_step3p5_forward_and_roundtrip():
 
 @pytest.mark.slow
 def test_mimo_v2_flash_forward_and_roundtrip():
+    """MiMo-V2-Flash on the expert decoder: the window layers' attention an
+    operator stack of its own (its key/value heads, value width and sinks),
+    the value scale read, the checkpoint's names both ways."""
     from automodel_tpu.checkpoint.hf_adapter import get_adapter
-    from automodel_tpu.models.moe_lm import het_moe
+    from automodel_tpu.models.moe_lm import decoder as moe_decoder
 
     spec = get_model_spec(MIMO_HF)
     cfg = spec.config_from_hf(MIMO_HF, dtype=jnp.float32, remat_policy="none")
+    assert spec.module is moe_decoder
     assert cfg.layer_types == ("global", "sliding", "sliding", "global")
-    assert cfg.mlp_kinds == ("dense", "moe", "moe", "moe")
-    assert cfg.sliding_attn.head_dim == 16 and cfg.sliding_attn.vd == 8
-    assert cfg.sliding_attn.sinks and not cfg.global_attn.sinks
-    params = het_moe.init(cfg, jax.random.key(0))
+    assert cfg.layer_ops == ("attention", "window_attention",
+                             "window_attention", "attention")
+    assert cfg.first_k_dense == 1 and cfg.attention_value_scale == 0.707
+    assert dict(cfg.window_attention) == {
+        "num_heads": 2, "num_kv_heads": 1, "v_head_dim": 4,
+        "attention_sinks": True}
+    assert not cfg.attention_sinks and cfg.rope_local_theta == 10000.0
+    params = moe_decoder.init(cfg, jax.random.key(0))
     # non-zero sinks so the path is exercised
-    params["s_attn"]["sinks"] = 0.3 + 0.1 * jax.random.normal(
-        jax.random.key(5), params["s_attn"]["sinks"].shape
+    params["window_attn_layers"]["sinks"] = 0.3 + 0.1 * jax.random.normal(
+        jax.random.key(5), params["window_attn_layers"]["sinks"].shape
     )
     ids = jax.random.randint(jax.random.key(1), (2, 16), 0, 128)
-    out, aux, stats = het_moe.forward(params, cfg, ids, return_stats=True)
+    out, aux, stats = moe_decoder.forward(params, cfg, ids, return_stats=True)
     assert np.isfinite(np.asarray(out)).all()
     assert stats["tokens_per_expert"].shape == (3, 4)
 
@@ -452,9 +461,11 @@ def test_mimo_v2_flash_forward_and_roundtrip():
     assert "model.layers.1.mlp.gate.e_score_correction_bias" in sd
     assert "model.layers.1.mlp.shared_experts.down_proj.weight" in sd
     assert "model.layers.0.mlp.gate_proj.weight" in sd  # dense layer
-    assert sd["model.layers.1.self_attn.k_proj.weight"].shape == (1 * 16, 32)
+    assert sd["model.layers.1.self_attn.k_proj.weight"].shape == (1 * 8, 32)
+    assert sd["model.layers.1.self_attn.v_proj.weight"].shape == (1 * 4, 32)
+    assert sd["model.layers.0.self_attn.v_proj.weight"].shape == (2 * 8, 32)
     p2 = ad.from_hf(lambda k: np.asarray(sd[k]))
-    o2, _, _ = het_moe.forward(
+    o2, _, _ = moe_decoder.forward(
         jax.tree.map(jnp.asarray, p2), cfg, ids, return_stats=True
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(o2), atol=1e-5)

@@ -132,10 +132,11 @@ def test_pallas_mla_kernel_matches_reference():
 
 
 def test_dispatch_is_strict_about_kernel_unsupported_features():
-    """A TRACED window and sinks are outside the kernel: an explicit
-    impl='pallas' raises instead of quietly running the reference; 'auto'
-    (off-TPU: the reference by rule) still serves the call. A window that is
-    a static of the call is the kernel's."""
+    """A TRACED window and int8 pages of keys wider than a lane tile are
+    outside the kernel: an explicit impl='pallas' raises instead of quietly
+    running the reference; 'auto' (off-TPU: the reference by rule) still
+    serves the call. A window that is a static of the call is the kernel's,
+    and so are sinks."""
     q, keys, values, kp, vp, pt, pos = _paged_setup(seed=5)
     with pytest.raises(NotImplementedError, match="a traced sliding window"):
         ragged_paged_attention(
@@ -146,10 +147,20 @@ def test_dispatch_is_strict_about_kernel_unsupported_features():
             q, kp, vp, pt, pos, scale=0.25, window=4, impl="pallas")),
         np.asarray(ragged_paged_attention_xla(
             q, kp, vp, pt, pos, scale=0.25, window=4)), atol=1e-5)
-    with pytest.raises(NotImplementedError, match="attention sinks"):
+    sinks = jnp.linspace(-1.0, 2.0, q.shape[1], dtype=jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(ragged_paged_attention(
+            q, kp, vp, pt, pos, scale=0.25, impl="pallas", sinks=sinks)),
+        np.asarray(ragged_paged_attention_xla(
+            q, kp, vp, pt, pos, scale=0.25, sinks=sinks)), atol=1e-5)
+    wide = jnp.zeros(kp.shape[:3] + (256,), jnp.int8)
+    scales = jnp.ones(kp.shape[:2], jnp.float32)
+    with pytest.raises(NotImplementedError,
+                       match="int8 pages of keys wider than a lane tile"):
         ragged_paged_attention(
-            q, kp, vp, pt, pos, scale=0.25, impl="pallas",
-            sinks=jnp.zeros((q.shape[1],), jnp.float32),
+            jnp.zeros(q.shape[:2] + (192,), q.dtype), wide, vp.astype(jnp.int8),
+            pt, pos, scale=0.25, impl="pallas", k_scales=scales,
+            v_scales=scales,
         )
     got = ragged_paged_attention(
         q, kp, vp, pt, pos, scale=0.25, window=jnp.int32(4), impl="auto",

@@ -50,6 +50,11 @@ PARITY_TESTS = {
     # (benchmark/reference/exaone_moe.py), every expert held
     "exaone_moe": ("test_exaone_moe.py",
                    "test_forward_matches_reference_with_all_experts_held"),
+    # against the layer equations the plain reference writes out
+    # (benchmark/reference/mimo_v2_flash.py: sinks, the value scale), every
+    # expert held; a pin beside it (test_model_pins.py)
+    "mimo_v2_flash": ("test_mimo_v2_flash.py",
+                      "test_forward_matches_reference_with_all_experts_held"),
 }
 
 #: Known gaps — families with functional tests (adapter roundtrips, recipe

@@ -430,6 +430,104 @@ def test_window_and_share_step_compiles_whole_for_the_chip(one_chip, tpu_branch)
     assert mem.temp_size_in_bytes < 0.6e9
 
 
+@pytest.mark.parametrize("kind", ["full", "window with sinks"])
+def test_paged_attention_with_sinks_and_unlike_widths_compiles_for_the_chip(
+        one_chip, tpu_branch, kind):
+    """MiMo-V2-Flash's calls at the cell's geometry: 256 rows of 64 query
+    heads, keys 192 wide (stored at 256, kv_pages.key_width) beside values
+    128 wide; the full layers' 4 key/value heads over a pool of 1,537 pages,
+    the window layers' 8 over a ring of 4 pages for each of 64 slots and the
+    trash slot, a window of 128 and a sink logit a head. Neither copies its
+    pages: at 192 the compiler lays a page out with its token axis minor, at
+    256 as the kernel reads it."""
+    from automodel_tpu.ops.paged_attention import (
+        RowSegments, max_row_segments, row_tile,
+    )
+
+    window = kind != "full"
+    T, Hq, D, Dv, ps, P, S = 256, 64, 192, 128, 128, 64, 64
+    Hkv, N, R = (8, 65 * 4, 4) if window else (4, 1537, P)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tile = row_tile(T, Hq * (D + Dv))
+    most = max_row_segments(T, S, tile)
+    assert (tile, most) == (32, 72)
+    args = (s((T, Hq, D), jnp.bfloat16), s((N, ps, Hkv, 256), jnp.bfloat16),
+            s((N, ps, Hkv, Dv), jnp.bfloat16), s((T, P), jnp.int32),
+            s((T,), jnp.int32), s((6, most * R), jnp.int32), s((), jnp.int32),
+            s((Hq,), jnp.float32))
+
+    def fn(q, k, v, pt, pos, b, c, sinks):
+        return tpu_branch.paged_attention_kernel(
+            q, k, v, pt, pos, scale=D ** -0.5, segments=RowSegments(tile, b, c),
+            window=128 if window else None, sinks=sinks if window else None)
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    name = "paged_attention_sink_gqa" if window else "paged_attention_gqa"
+    assert name in text and "paged_attention_window_gqa" not in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"[{N},{ps}" in ln.split(" copy(")[0]]
+
+
+def test_unlike_window_layers_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
+    """MiMo-V2-Flash's one-chip share at its cell's geometry, from shapes
+    alone: the dense layer and 10 expert layers in ONE program whose
+    arguments are the 10.84 GB of weights, the 1.21 GB pool of the 2 full
+    layers (4 key/value heads) and the 1.84 GB of rings of the 9 window
+    layers (8 key/value heads and sinks), the last two donated and aliased.
+    The paged kernel runs in every layer, under its sink name in nine; the
+    16 HELD experts' three products are `grouped_matmul` calls in each of
+    10 layers. No pool or ring is copied: the step of a model whose pages
+    are never shared has no copy-on-write block, whose scatter laid a pool
+    of 4 key/value heads a page out anew twice a step. What is left: the
+    full layers' KEYS (4 heads of 256 a token, tiled (4, 128) in the pool)
+    are relaid to the (ps x Hkv, 256) matrix the kernel's one-row body reads,
+    one `reshape` a full layer a step (the values, 128 wide, and the window
+    layers' 8 heads are bitcasts); PERF.md section 5 prices it."""
+    import json
+
+    from tests.step_shapes import engine_of_shapes
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo_v2_flash_serve_v5e1.json")) as f:
+        config = json.load(f)
+    eng, args = engine_of_shapes(config, config["serving"], one_chip)
+    assert eng._ring_pages == 4 and eng._stack_rings == [0, 9]
+    assert eng._stack_attn == [1, 1] and len(args[3]) == 9
+    assert eng.cfg.moe.num_held == 16 and eng.cfg.moe.n_routed_experts == 256
+    bodies = _one_row_bodies()
+    lowered = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args)
+    text = lowered.as_text()
+    assert text.count("paged_attention_gqa") == 2
+    assert text.count("paged_attention_sink_gqa") == 9
+    assert "serve.cow" not in text
+    # 64 heads over 4 and over 8: the MXU body
+    assert _one_row_bodies() == {**bodies, "mxu": bodies["mxu"] + 11}
+    assert text.count("grouped_matmul") == 30 and "ragged_dot" not in text
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    page = 128 * (256 + 128) * 2
+    pool = 2 * 1537 * 4 * page
+    rings = 9 * 65 * 4 * 8 * page
+    assert (pool, rings) == (1_208_745_984, 1_840_250_880)
+    assert mem.alias_size_in_bytes >= pool + rings
+    assert mem.argument_size_in_bytes < 10.845e9 + pool + rings + 1e7
+    assert mem.temp_size_in_bytes < 0.6e9
+    text = compiled.as_text()
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and ("[1537," in ln or "[260," in ln)]
+    relaid = [ln for ln in text.splitlines()
+              if " reshape(" in ln and "[1537,512,256]" in ln.split(" reshape(")[0]]
+    assert len(relaid) == 2
+    assert not [ln for ln in text.splitlines()
+                if " reshape(" in ln and "[260,1024," in ln.split(" reshape(")[0]]
+
+
 def test_grouped_matmul_compiles_inside_a_shard_map_over_four_chips(
         topo, tpu_branch):
     """The EP path's use: each chip's local experts over the rows it
