@@ -139,6 +139,24 @@ def dispatch_tensors(
     return dispatch, combine_w
 
 
+#: rows of the grouped matmul's row tile (`ops/grouped_matmul._TILE_ROWS`):
+#: the compact path of a share rounds its rows up to whole tiles
+_SHARE_ROW_TILE = 128
+
+
+def share_rows(cfg: MoEConfig, pairs: int) -> int | None:
+    """Rows of the COMPACT path of a layer that holds a share of its experts,
+    over `pairs` (row, top-k) pairs: twice the pairs its held experts expect
+    under even routing, rounded up to whole row tiles. None where the layer
+    holds all its experts, or where that bound is no fewer than `pairs`: the
+    layer then runs its whole path alone."""
+    if cfg.holds_all_experts:
+        return None
+    expected2 = -(-2 * pairs * cfg.num_held // cfg.n_routed_experts)
+    rows = -(-expected2 // _SHARE_ROW_TILE) * _SHARE_ROW_TILE
+    return rows if rows < pairs else None
+
+
 def experts_forward_dropless(
     params: dict,
     cfg: MoEConfig,
@@ -146,7 +164,9 @@ def experts_forward_dropless(
     weights: jnp.ndarray,  # (T, K)
     indices: jnp.ndarray,  # (T, K)
     mesh_ctx=None,
-) -> jnp.ndarray:
+    *,
+    with_live: bool = False,
+):
     """Dropless sort-based dispatch + ragged grouped GEMM.
 
     The megablox/`GroupedExpertsDeepEP` analog (reference: experts.py:651):
@@ -170,6 +190,17 @@ def experts_forward_dropless(
     belong to no group, the grouped matmul gives their rows zeros, and the
     token's result is the held experts' part alone, each with the weight the
     router gave it over ALL its chosen experts.
+
+    Such a layer's live pairs are few (8 held of 128 routed at top-8: a
+    sixteenth), and everything after the sort runs over the sorted pairs'
+    first `R` rows alone: the COMPACT path at `R = share_rows(cfg, T*K)`,
+    about twice the live pairs expected, when the live pairs fit in it, else
+    the WHOLE path at `R = T*K`, one `lax.cond` between the two. Both are
+    the same function of `R`, exact for any routing: no pair is dropped, and
+    a pair computes what it did, summed in the same order. A layer that
+    holds all its experts runs the whole path alone, traced as it always
+    was. `with_live=True` returns (out, the live pairs, whether the whole
+    path ran): int32 and bool scalars.
     """
     T, H = x.shape
     K = cfg.experts_per_token
@@ -182,32 +213,50 @@ def experts_forward_dropless(
         flat_expert = jnp.where((local >= 0) & (local < E), local, E)
     # stable sort groups rows by expert while keeping token order within
     sort_idx = jnp.argsort(flat_expert, stable=True)
-    token_of = sort_idx // K
-    expert_of = jnp.take(flat_expert, sort_idx)
-    xs = jnp.take(x, token_of, axis=0)  # (TK, H)
-    group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
 
-    # masked tokens carry the sentinel index E (see gate_forward) — clip once
-    # for the bias gathers; their rows are zero-weighted in the combine anyway
-    safe_expert = jnp.clip(expert_of, 0, E - 1)
-    gmm = functools.partial(grouped_matmul, mesh_ctx=mesh_ctx)
-    u = gmm(xs, params["up_proj"]["kernel"].astype(dtype), group_sizes)
-    if "bias" in params["up_proj"]:
-        u = u + jnp.take(params["up_proj"]["bias"].astype(dtype), safe_expert, axis=0)
-    if cfg.gated_experts:
-        g = gmm(xs, params["gate_proj"]["kernel"].astype(dtype), group_sizes)
-        if "bias" in params["gate_proj"]:
-            g = g + jnp.take(params["gate_proj"]["bias"].astype(dtype), safe_expert, axis=0)
-        h_in = gated_combine(g, u, cfg.expert_activation, cfg.swiglu_limit)
-    else:
-        h_in = _EXPERT_ACT[cfg.expert_activation](u)
-    y = gmm(h_in, params["down_proj"]["kernel"].astype(dtype), group_sizes)
-    if "bias" in params["down_proj"]:
-        y = y + jnp.take(params["down_proj"]["bias"].astype(dtype), safe_expert, axis=0)
+    def first_pairs(rows):
+        """The layer over the first `rows` sorted pairs: the held experts'
+        pairs lie first, the rows past them come out of the grouped
+        matmuls zero and add nothing."""
+        idx = sort_idx if rows == T * K else sort_idx[:rows]
+        token_of = idx // K
+        expert_of = jnp.take(flat_expert, idx)
+        xs = jnp.take(x, token_of, axis=0)  # (rows, H)
+        group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
 
-    w_sorted = jnp.take(weights.reshape(T * K), sort_idx, axis=0).astype(dtype)
-    contrib = y * w_sorted[:, None]
-    return jnp.zeros((T, H), dtype).at[token_of].add(contrib)
+        # masked tokens carry the sentinel index E (see gate_forward) — clip
+        # once for the bias gathers; their rows are zero-weighted in the
+        # combine anyway
+        safe_expert = jnp.clip(expert_of, 0, E - 1)
+        gmm = functools.partial(grouped_matmul, mesh_ctx=mesh_ctx)
+        u = gmm(xs, params["up_proj"]["kernel"].astype(dtype), group_sizes)
+        if "bias" in params["up_proj"]:
+            u = u + jnp.take(params["up_proj"]["bias"].astype(dtype), safe_expert, axis=0)
+        if cfg.gated_experts:
+            g = gmm(xs, params["gate_proj"]["kernel"].astype(dtype), group_sizes)
+            if "bias" in params["gate_proj"]:
+                g = g + jnp.take(params["gate_proj"]["bias"].astype(dtype), safe_expert, axis=0)
+            h_in = gated_combine(g, u, cfg.expert_activation, cfg.swiglu_limit)
+        else:
+            h_in = _EXPERT_ACT[cfg.expert_activation](u)
+        y = gmm(h_in, params["down_proj"]["kernel"].astype(dtype), group_sizes)
+        if "bias" in params["down_proj"]:
+            y = y + jnp.take(params["down_proj"]["bias"].astype(dtype), safe_expert, axis=0)
+
+        w_sorted = jnp.take(weights.reshape(T * K), idx, axis=0).astype(dtype)
+        contrib = y * w_sorted[:, None]
+        return jnp.zeros((T, H), dtype).at[token_of].add(contrib)
+
+    bound = share_rows(cfg, T * K)
+    if bound is None and not with_live:
+        return first_pairs(T * K)
+    live = jnp.sum(flat_expert < E, dtype=jnp.int32)
+    if bound is None:
+        return first_pairs(T * K), live, jnp.bool_(True)
+    whole = live > bound
+    out = jax.lax.cond(
+        whole, lambda: first_pairs(T * K), lambda: first_pairs(bound))
+    return (out, live, whole) if with_live else out
 
 
 def _raw_ragged_a2a(x, out, in_off, send_sz, out_off, recv_sz, axis_name):

@@ -85,7 +85,10 @@ def moe_forward(
     scope: str = "moe",  # named-scope prefix: the serve step passes its own
 ) -> tuple[jnp.ndarray, jnp.ndarray, dict]:
     """Returns (out (B,S,H), aux_loss scalar, stats). stats["indices"] is
-    the (T,K) selection — capture it for routing replay (R3). The three
+    the (T,K) selection — capture it for routing replay (R3); a layer that
+    holds a share of its experts adds `share_live`, its live (row, expert)
+    pairs, and `share_whole`, whether its dispatch ran the whole path
+    (`experts_forward_dropless`). The three
     sublayers run under `<scope>.route`, `.experts` and `.shared` named
     scopes (metadata only), so a profiler trace tells them apart."""
     B, S, H = x.shape
@@ -108,10 +111,16 @@ def moe_forward(
                 routed = experts_forward_dropless_ep(
                     params["experts"], cfg, flat, weights, indices, mesh_ctx
                 )
-            else:
+            elif cfg.holds_all_experts:
                 routed = experts_forward_dropless(
                     params["experts"], cfg, flat, weights, indices, mesh_ctx
                 )
+            else:
+                routed, live, whole = experts_forward_dropless(
+                    params["experts"], cfg, flat, weights, indices, mesh_ctx,
+                    with_live=True,
+                )
+                stats.update(share_live=live, share_whole=whole)
         else:
             capacity = compute_capacity(cfg, B * S)
             dispatch, combine = dispatch_tensors(cfg, indices, weights, capacity)

@@ -253,6 +253,8 @@ METRIC_CATALOG = (
     ("serve_plan_samples_total", "counter", "sample rows active in step plans"),
     ("serve_step_ms", "histogram", "device step wall time (ms)"),
     ("serve_step_transfers_total", "counter", "host-device transfers issued by serve steps (labeled by direction=h2d|d2h)"),
+    ("serve_moe_share_runs_total", "counter", "runs of expert layers that hold a share of their experts (labeled by path=compact|whole: over about twice the expected live pairs, or over every pair)"),
+    ("serve_moe_share_live_max", "gauge", "most live (row, held expert) pairs one expert layer had in the last step (layers that hold a share)"),
     # engine serve_batch outcomes
     ("serve_new_tokens_total", "counter", "tokens committed to requests"),
     ("serve_requests_total", "counter", "requests finished by the engine"),

@@ -351,6 +351,16 @@ def pack_device(arrays) -> jax.Array:
     ])
 
 
+def _share_words(share_runs) -> tuple:
+    """The step's two words on its expert layers that hold a share of their
+    experts (none in another model): how many ran the whole path, and the
+    most live (row, expert) pairs one of them had."""
+    if not share_runs:
+        return ()
+    live, whole = (jnp.stack(v) for v in zip(*share_runs))
+    return jnp.sum(whole, dtype=jnp.int32), jnp.max(live)
+
+
 def unpack_device(layout, buf: jax.Array) -> dict:
     """The uploaded vector `buf` sliced back into the fields of `layout`."""
     out = {}
@@ -799,7 +809,7 @@ class ServingEngine:
             return pool
         return jax.lax.with_sharding_constraint(pool, self._pool_shardings)
 
-    def _moe_mlp(self, h, lp, cfg):
+    def _moe_mlp(self, h, lp, cfg, b, share_runs: list):
         """MoE block of the step. Dropless dispatch always: the capacity
         dispatcher's bound depends on the token population, so a
         capacity-trained config would drop differently at serve time;
@@ -808,15 +818,24 @@ class ServingEngine:
         step) via the shard_map wrapper — the flat token batch shards over
         ep, expert weights enter sharded on ep only. Routing is
         deterministic in the logits, so EP changes where experts run,
-        never which tokens they see."""
+        never which tokens they see. A layer that holds a share of its
+        experts routes its pad rows (slot < 0) to no expert: they are all
+        alike, so they would all add the same held experts' pairs to its
+        live ones, which its compact path bounds; a real row's result is
+        unchanged. Such a layer appends (its live pairs, whether it ran the
+        whole path) to `share_runs`."""
         from automodel_tpu.moe.layer import moe_forward
 
         moe_cfg = dataclasses.replace(cfg.moe, dispatcher="dropless")
         x = rms_norm(h, lp["post_attn_norm"]["scale"], cfg.rms_norm_eps,
                      cfg.zero_centered_norm)
-        moe_out, _aux, _stats = moe_forward(
-            lp["moe"], moe_cfg, x, mesh_ctx=self._mesh, scope="serve.moe"
+        rows = None if moe_cfg.holds_all_experts else (b["slot"] >= 0)[None]
+        moe_out, _aux, stats = moe_forward(
+            lp["moe"], moe_cfg, x, token_mask=rows, mesh_ctx=self._mesh,
+            scope="serve.moe"
         )
+        if "share_live" in stats:
+            share_runs.append((stats["share_live"], stats["share_whole"]))
         return h + moe_out
 
     # -- device step --------------------------------------------------------
@@ -1051,6 +1070,7 @@ class ServingEngine:
         # `state`; the operator's weights then come from its kind's stack.
         new_pool = [[] for _ in self._stacks]
         new_state = list(state)
+        share_runs = []  # the expert layers that hold a share: _moe_mlp
         r = self._num_ssm
         # the full layers' own scope only beside window layers: a model
         # without them lowers as it always did
@@ -1091,7 +1111,10 @@ class ServingEngine:
                                     h, lp, state[j], b
                                 )
                         with jax.named_scope(mlp_scope):
-                            h = mlp_fn(h, lp, cfg)
+                            if mlp_fn is _dense_mlp:
+                                h = mlp_fn(h, lp, cfg)
+                            else:
+                                h = mlp_fn(h, lp, cfg, b, share_runs)
                         h = self._constrain_rep(h)
                 if t + 1 < cfg.num_passes:
                     h = rms_norm(h, params["final_norm"]["scale"],
@@ -1101,10 +1124,12 @@ class ServingEngine:
         with jax.named_scope("serve.head"):
             out = self._head(params, new_pool, h, b)
         # tokens, log-probabilities (and acceptances) go back as ONE int32
-        # vector, the float32 values bit-cast; hidden feedback stays its own
-        n = len(self._out_layout)
+        # vector, the float32 values bit-cast; hidden feedback stays its own.
+        # A model whose expert layers hold a share adds two words
         with jax.named_scope("serve.pack"):
-            out = (out[0], pack_device(out[1:1 + n])) + out[1 + n:]
+            words = _share_words(share_runs)
+            n = len(self._out_layout) - len(words)
+            out = (out[0], pack_device(out[1:1 + n] + words)) + out[1 + n:]
         # the state last, where there is one: a decoder of attention alone
         # lowers to the program it always was
         return out + (tuple(new_state),) if state else out
@@ -1242,16 +1267,26 @@ class ServingEngine:
     @functools.cached_property
     def _out_layout(self) -> tuple:
         """Where the step's tokens, log-probabilities and (speculation)
-        acceptances lie in the one vector it returns."""
+        acceptances lie in the one vector it returns; in a model whose
+        expert layers hold a share of their experts two words follow
+        (`_share_words`), which `run_step` counts and returns no further."""
         S = self.serve_cfg.max_slots
         if self._spec is None:
-            return packed_layout(
-                [("tokens", (S,), np.int32), ("logprobs", (S,), np.float32)])
-        K1 = self._spec.draft_len + 1
-        return packed_layout([
-            ("tokens", (S, K1), np.int32), ("logprobs", (S, K1), np.float32),
-            ("accept", (S,), np.int32),
-        ])
+            fields = [("tokens", (S,), np.int32), ("logprobs", (S,), np.float32)]
+        else:
+            K1 = self._spec.draft_len + 1
+            fields = [
+                ("tokens", (S, K1), np.int32), ("logprobs", (S, K1), np.float32),
+                ("accept", (S,), np.int32),
+            ]
+        if self._holds_expert_share:
+            fields += [("share_whole", (), np.int32),
+                       ("share_live_max", (), np.int32)]
+        return packed_layout(fields)
+
+    @functools.cached_property
+    def _holds_expert_share(self) -> bool:
+        return self.is_moe and not self.cfg.moe.holds_all_experts
 
     def _plan_batch(self, plan: StepPlan) -> jax.Array:
         """StepPlan → the jitted step's one int32 plan vector (the ONE
@@ -1325,7 +1360,20 @@ class ServingEngine:
                     jax.block_until_ready(fetched)
                 with span("step.fetch"):
                     packed, *hidden = (np.asarray(x) for x in fetched)
-                    return unpack_host(self._out_layout, packed) + tuple(hidden)
+                    out = unpack_host(self._out_layout, packed)
+            if self._holds_expert_share:
+                *out, whole, live_max = out
+                self._count_share_runs(int(whole), int(live_max))
+            return tuple(out) + tuple(hidden)
+
+    def _count_share_runs(self, whole: int, live_max: int) -> None:
+        """A step's expert layer-runs by the path they took, and the most
+        live pairs one of them had (`experts_forward_dropless`)."""
+        reg = self.obs.registry
+        reg.counter("serve_moe_share_runs_total", path="whole").inc(whole)
+        reg.counter("serve_moe_share_runs_total", path="compact").inc(
+            self.cfg.num_moe_layers - whole)
+        reg.gauge("serve_moe_share_live_max").set(live_max)
 
     def _step_args(self, batch: jax.Array) -> tuple:
         """The jitted step's arguments: the per-slot state last, where the

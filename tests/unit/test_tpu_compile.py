@@ -11,7 +11,9 @@ grouping (16 heads over 16 key/value heads, Ouro-2.6B's), where the causal
 mask's compare had a one-wide sublane axis.
 """
 
+import collections
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -74,6 +76,14 @@ def _one_row_bodies():
     return {body: default_registry().counter(
         "paged_attention_one_row_body_total", scores=body).value
         for body in ("mxu", "vpu")}
+
+
+def _grouped_matmul_rows(lowered_text: str) -> dict:
+    """{rows: calls} of the `grouped_matmul` kernels in a lowered step, by
+    each call's (rows, n) result."""
+    rows = [int(re.search(r"-> tensor<(\d+)x\d+xbf16>", ln).group(1))
+            for ln in lowered_text.splitlines() if "grouped_matmul" in ln]
+    return dict(collections.Counter(rows))
 
 
 #: (rows, heads, key/value heads, head width, pages, page size, pages a
@@ -397,7 +407,9 @@ def test_window_and_share_step_compiles_whole_for_the_chip(one_chip, tpu_branch)
     pool of the 2 full layers and the 1.64 GB of rings of the 6 window
     layers, the last two donated and aliased to the outputs. The paged kernel
     runs in every layer, under its window name in six; the three products of
-    the 8 HELD experts are `grouped_matmul` calls in each of 7 layers."""
+    the 8 HELD experts are `grouped_matmul` calls in each of 7 layers, in
+    both branches of the layer's `lax.cond` (the compact path and the whole
+    path, `experts_forward_dropless`)."""
     import json
 
     from tests.step_shapes import engine_of_shapes
@@ -418,7 +430,10 @@ def test_window_and_share_step_compiles_whole_for_the_chip(one_chip, tpu_branch)
     assert text.count("paged_attention_window_gqa") == 6
     # 64 heads over 8, full and window layers alike: the MXU body
     assert _one_row_bodies() == {**bodies, "mxu": bodies["mxu"] + 8}
-    assert text.count("grouped_matmul") == 21 and "ragged_dot" not in text
+    # the held experts' three products in each of 7 layers, once a branch:
+    # the compact path's over 1,024 sorted pairs, the whole path's over 8,192
+    assert text.count("grouped_matmul") == 42 and "ragged_dot" not in text
+    assert _grouped_matmul_rows(text) == {1024: 21, 8192: 21}
     mem = lowered.compile().memory_analysis()
     page = 128 * 8 * 128 * 2
     pool = 2 * 2 * 4097 * page
@@ -480,7 +495,8 @@ def test_unlike_window_layers_step_compiles_whole_for_the_chip(one_chip, tpu_bra
     layers (8 key/value heads and sinks), the last two donated and aliased.
     The paged kernel runs in every layer, under its sink name in nine; the
     16 HELD experts' three products are `grouped_matmul` calls in each of
-    10 layers. No pool or ring is copied: the step of a model whose pages
+    10 layers, in both branches of the layer's `lax.cond` (the compact path
+    and the whole path). No pool or ring is copied: the step of a model whose pages
     are never shared has no copy-on-write block, whose scatter laid a pool
     of 4 key/value heads a page out anew twice a step. What is left: the
     full layers' KEYS (4 heads of 256 a token, tiled (4, 128) in the pool)
@@ -508,7 +524,9 @@ def test_unlike_window_layers_step_compiles_whole_for_the_chip(one_chip, tpu_bra
     assert "serve.cow" not in text
     # 64 heads over 4 and over 8: the MXU body
     assert _one_row_bodies() == {**bodies, "mxu": bodies["mxu"] + 11}
-    assert text.count("grouped_matmul") == 30 and "ragged_dot" not in text
+    # 10 layers' three products, once a branch: over 256 pairs and 2,048
+    assert text.count("grouped_matmul") == 60 and "ragged_dot" not in text
+    assert _grouped_matmul_rows(text) == {256: 30, 2048: 30}
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     page = 128 * (256 + 128) * 2
