@@ -46,6 +46,7 @@ from automodel_tpu.models.common.layers import cast_params
 from automodel_tpu.models.llm.decoder import (
     TransformerConfig,
     _dense,
+    apply_norm,
     layer_windows,
     mlp_inner,
     project_qkv,
@@ -212,7 +213,7 @@ def _mla_attn_with_cache(h, lp, cfg, positions, inv_freq, cache_c, cache_kr,
 
 
 def _dense_mlp(h, lp, cfg):
-    x = rms_norm(h, lp["post_attn_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+    x = apply_norm(h, lp["post_attn_norm"], cfg)
     mlp_out = _mm(mlp_inner(x, lp, cfg), lp["down_proj"]["kernel"], cfg.linear_precision)
     if cfg.use_post_norms:
         mlp_out = rms_norm(mlp_out, lp["post_mlp_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)

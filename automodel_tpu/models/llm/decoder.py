@@ -31,7 +31,7 @@ from automodel_tpu.models.common.layers import (
     scan_layers_windowed,
 )
 from automodel_tpu.ops.attention import dot_product_attention
-from automodel_tpu.ops.norms import rms_norm
+from automodel_tpu.ops.norms import layer_norm, rms_norm
 from automodel_tpu.ops.rope import RopeScalingConfig, apply_rope, rope_frequencies
 
 #: Attention here is position-causal everywhere (ring attention under cp,
@@ -116,6 +116,18 @@ class TransformerConfig:
     normalized_lm_head: bool = False
     # gpt-oss: learnable per-head sink logits in the softmax denominator
     attention_sinks: bool = False
+    # "rms" (RMSNorm) or "layer" (LayerNorm with a bias; Phi-4-mini-flash):
+    # every layer's two norms and the final one, eps `rms_norm_eps`
+    norm_type: str = "rms"
+    # differential attention (arXiv:2410.05258) in every attention operator,
+    # its heads paired as DiffLlama pairs them (`diff_values`, `diff_combine`):
+    # query head i scores against key head i // G as in GQA and its softmax
+    # map weighs a value of 2 x head_dim made of two value heads; heads i and
+    # i + Hq/2 are one pair, o = (1 - l0) rms(O_i - lambda O_{i+Hq/2}) with a
+    # learned scale, lambda from four learned vectors and l0 from the layer
+    differential_attention: bool = False
+    # False: the Mamba-1 mixer without Jamba's RMSNorms on dt, B and C
+    mamba_inner_norms: bool = True
     # the width of a GQA head's VALUES where it differs from its keys'
     # (MiMo-V2-Flash: keys 192, values 128); None = the key width
     v_head_dim: Optional[int] = None
@@ -215,7 +227,7 @@ class TransformerConfig:
     def holds_state(self) -> bool:
         """Whether some layer carries a recurrent state from token to token
         (what a serving engine must keep per slot, beside the pages)."""
-        return any(op not in ATTENTION_OPS for op in self.layer_ops or ())
+        return any(op in STATE_OPS for op in self.layer_ops or ())
 
     def attn_params_per_layer(self) -> int:
         """Projection parameter count of one attention block."""
@@ -250,10 +262,17 @@ class TransformerConfig:
         if self.layer_ops is not None:
             from automodel_tpu.models.llm.mamba import mamba_params_per_layer
 
-            n_attn = sum(op in ATTENTION_OPS for op in self.layer_ops)
-            mixers_instead = (self.num_layers - n_attn) * (
-                mamba_params_per_layer(self) - self.attn_params_per_layer()
-            )
+            H, C = self.hidden_size, self.mamba_d_inner
+            instead = {
+                "mamba": mamba_params_per_layer(self),
+                "gmu": 2 * H * C,
+                "cross_attention": 2 * H * self.num_heads * self.resolved_head_dim,
+            }
+            n_attn = sum(op in ATTENTION_OPS + ("cross_attention",)
+                         for op in self.layer_ops)
+            mixers_instead = sum(
+                instead[op] - self.attn_params_per_layer()
+                for op in self.layer_ops if op in instead)
         n_params = (
             self.vocab_size * self.hidden_size * (1 if self.tie_word_embeddings else 2)
             + self.num_layers * layer_params + mixers_instead
@@ -285,14 +304,29 @@ def layer_windows(cfg: "TransformerConfig", num_layers: int | None = None) -> tu
 #: "window_attention" is the attention of the layers with a window where its
 #: geometry is not the full layers' (`TransformerConfig.window_attention`)
 OPERATOR_STACKS = {"attention": "attn_layers", "mamba": "mamba_layers",
-                   "window_attention": "window_attn_layers"}
+                   "window_attention": "window_attn_layers",
+                   "gmu": "gmu_layers", "cross_attention": "cross_attn_layers"}
 
-#: the operators that are attention (over pages or a ring when served); every
-#: other one carries a state from token to token
+#: the operators that are attention with keys and values of their own (over
+#: pages or a ring when served)
 ATTENTION_OPS = ("attention", "window_attention")
+
+#: the operators that carry a state from token to token (per slot when served)
+STATE_OPS = ("mamba",)
+
+#: the cross-decoder's operators (a decoder-hybrid-decoder, SambaY: arXiv
+#: 2507.06607): a gated memory unit, which gates the memory layer's output at
+#: its own row, and cross-attention, which computes queries alone and reads
+#: the keys and values of the last full-attention layer before it. Neither
+#: keeps a cache, and a row of either depends on nothing but its own hidden
+#: state, memory and that one cache
+CROSS_OPS = ("gmu", "cross_attention")
 
 #: what a window layer's attention may set apart from the full layers'
 WINDOW_FIELDS = ("num_heads", "num_kv_heads", "v_head_dim", "attention_sinks")
+
+#: differential attention's four learned vectors, each head_dim wide
+DIFF_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
 
 
 def kind_config(cfg: "TransformerConfig", kind: str) -> "TransformerConfig":
@@ -308,6 +342,72 @@ def kind_config(cfg: "TransformerConfig", kind: str) -> "TransformerConfig":
             "apart from the full layers'")
     return dataclasses.replace(
         cfg, head_dim=cfg.resolved_head_dim, window_attention=(), **fields)
+
+
+def cross_decoder(cfg: "TransformerConfig") -> tuple | None:
+    """(memory layer, shared-cache layer, first cross layer) of a
+    decoder-hybrid-decoder, or None: every layer from the first GMU or
+    cross-attention layer on is one of the two, the memory is the output of
+    the last Mamba layer before them (its scan's, before the gate) and the
+    cache the cross layers read is the last full-attention layer's."""
+    ops = cfg.layer_ops or ()
+    first = next((i for i, op in enumerate(ops) if op in CROSS_OPS), None)
+    if first is None:
+        return None
+    if any(op not in CROSS_OPS for op in ops[first:]):
+        raise NotImplementedError(
+            "a layer other than a GMU or cross-attention behind the first one")
+    before = ops[:first]
+    memory = max((i for i, op in enumerate(before) if op == "mamba"), default=None)
+    shared = max((i for i, op in enumerate(before) if op == "attention"),
+                 default=None)
+    if ("gmu" in ops[first:] and memory is None) or (
+            "cross_attention" in ops[first:] and shared is None):
+        raise NotImplementedError(
+            "a GMU with no Mamba layer before it, or cross-attention with no "
+            "full-attention layer before it")
+    return memory, shared, first
+
+
+def diff_lambda_init(layer: int) -> float:
+    """Differential attention's lambda_init at (0-based) `layer`."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+def diff_values(v):
+    """(..., Hkv, D) values -> (..., Hkv, 2D): what the query heads of key
+    head g weigh, value heads g mod Hkv/2 and Hkv/2 + g mod Hkv/2 side by
+    side (DiffLlama: the value heads' two halves concatenated, repeated over
+    the query heads' two halves). Query head i of pair (i, i + Hq/2) scores
+    against key head i // G; both heads of a GQA group weigh one value."""
+    half = v.shape[-2] // 2
+    lo, hi = v[..., :half, :], v[..., half:, :]
+    return jnp.concatenate([jnp.concatenate([lo, lo], -2),
+                            jnp.concatenate([hi, hi], -2)], -1)
+
+
+def diff_combine(o, lp, cfg: "TransformerConfig", layer: int):
+    """(..., Hq, 2D) weighted values -> (..., Hq x D): pair (i, i + Hq/2)
+    gives (1 - l0) rms(O_i - lambda O_{i+Hq/2}; sub_norm) (eps
+    `rms_norm_eps`, a learned scale), lambda = exp(lq1.lk1) - exp(lq2.lk2)
+    + l0, l0 = `diff_lambda_init(layer)`; in float32, back to o's dtype."""
+    f32 = jnp.float32
+    l0 = diff_lambda_init(layer)
+    lam = (jnp.exp(jnp.sum(lp["lambda_q1"].astype(f32) * lp["lambda_k1"].astype(f32)))
+           - jnp.exp(jnp.sum(lp["lambda_q2"].astype(f32) * lp["lambda_k2"].astype(f32)))
+           + l0)
+    half = o.shape[-2] // 2
+    d = o[..., :half, :].astype(f32) - lam * o[..., half:, :].astype(f32)
+    d = rms_norm(d, lp["sub_norm"]["scale"], cfg.rms_norm_eps) * (1.0 - l0)
+    return d.reshape(*o.shape[:-2], half * o.shape[-1]).astype(o.dtype)
+
+
+def apply_norm(x, p: dict, cfg: "TransformerConfig"):
+    """A layer's or the final norm, `p` its leaves: RMSNorm, or LayerNorm
+    with a bias where `cfg.norm_type` is "layer"."""
+    if cfg.norm_type == "layer":
+        return layer_norm(x, p["scale"], p["bias"], cfg.rms_norm_eps)
+    return rms_norm(x, p["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
 
 
 def layer_operators(cfg: "TransformerConfig") -> tuple:
@@ -414,6 +514,14 @@ def init_attention_layers(cfg: TransformerConfig, rng: jax.Array, L: int) -> dic
         layers["post_mlp_norm"] = {"scale": jnp.ones((L, H))}
     if cfg.attention_sinks:
         layers["sinks"] = jnp.zeros((L, cfg.num_heads))
+    if cfg.differential_attention:
+        for j, name in enumerate(DIFF_LAMBDAS):
+            layers[name] = 0.1 * jax.random.normal(
+                jax.random.fold_in(rng, 10 + j), (L, D))
+        layers["sub_norm"] = {"scale": jnp.ones((L, 2 * D))}
+    if cfg.norm_type == "layer":
+        for name in _LAYER_NORMS:
+            layers[name]["bias"] = jnp.zeros((L, H))
     return layers
 
 
@@ -444,6 +552,12 @@ def attention_layer_specs(cfg: TransformerConfig) -> dict:
         layers["post_mlp_norm"] = {"scale": ("layers", "norm")}
     if cfg.attention_sinks:
         layers["sinks"] = ("layers", "heads")
+    if cfg.differential_attention:
+        layers.update({name: ("layers", None) for name in DIFF_LAMBDAS})
+        layers["sub_norm"] = {"scale": ("layers", "norm")}
+    if cfg.norm_type == "layer":
+        for name in _LAYER_NORMS:
+            layers[name]["bias"] = ("layers", "norm")
     return layers
 
 
@@ -485,10 +599,26 @@ def init_operator_stacks(cfg: TransformerConfig, ops: tuple, attn_key,
         if kind == "mamba":
             out[stack] = init_mamba_layers(cfg, mamba_key, n)
             continue
-        key = attn_key if kind == "attention" else jax.random.fold_in(attn_key, 1)
+        if kind == "gmu":
+            H, C = cfg.hidden_size, cfg.mamba_d_inner
+            k1, k2 = jax.random.split(jax.random.fold_in(mamba_key, 1))
+            out[stack] = {
+                "in_proj": {"kernel": _stack(dense_init, k1, (H, C), n)},
+                "o_proj": {"kernel": _stack(dense_init, k2, (C, H), n)},
+            }
+            continue
+        key = attn_key if kind == "attention" else jax.random.fold_in(
+            attn_key, 1 if kind == "window_attention" else 2)
         attn = init_attention_layers(kind_config(cfg, kind), key, n)
-        out[stack] = {k: v for k, v in attn.items() if k not in _LAYER_NORMS}
+        out[stack] = {k: v for k, v in attn.items()
+                      if k not in _LAYER_NORMS + _missing_projections(kind)}
     return out
+
+
+def _missing_projections(kind: str) -> tuple:
+    """The projections an operator of `kind` lacks: cross-attention
+    computes queries alone."""
+    return ("k_proj", "v_proj") if kind == "cross_attention" else ()
 
 
 def operator_stack_specs(cfg: TransformerConfig, ops: tuple) -> dict:
@@ -503,8 +633,13 @@ def operator_stack_specs(cfg: TransformerConfig, ops: tuple) -> dict:
         if kind == "mamba":
             out[stack] = mamba_layer_specs(cfg)
             continue
+        if kind == "gmu":
+            out[stack] = {"in_proj": {"kernel": ("layers", "embed", "mlp")},
+                          "o_proj": {"kernel": ("layers", "mlp", "embed")}}
+            continue
         attn = attention_layer_specs(kind_config(cfg, kind))
-        out[stack] = {k: v for k, v in attn.items() if k not in _LAYER_NORMS}
+        out[stack] = {k: v for k, v in attn.items()
+                      if k not in _LAYER_NORMS + _missing_projections(kind)}
     return out
 
 
@@ -521,6 +656,9 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
     else:
         # every layer's two norms here; each kind's operators a stack apart
         layers = {name: {"scale": jnp.ones((L, H))} for name in _LAYER_NORMS}
+        if cfg.norm_type == "layer":
+            for name in _LAYER_NORMS:
+                layers[name]["bias"] = jnp.zeros((L, H))
         operators = init_operator_stacks(cfg, ops, ks[0], ks[1])
     layers.update(
         {
@@ -535,6 +673,8 @@ def init(cfg: TransformerConfig, rng: jax.Array) -> dict:
         **operators,
         "final_norm": {"scale": jnp.ones((H,))},
     }
+    if cfg.norm_type == "layer":
+        params["final_norm"]["bias"] = jnp.zeros((H,))
     if not cfg.tie_word_embeddings:
         params["lm_head"] = {"kernel": dense_init(jax.random.fold_in(rng, 99), (H, cfg.vocab_size))}
     if cfg.exit_gate:
@@ -566,6 +706,8 @@ def param_specs(cfg: TransformerConfig) -> dict:
         **operators,
         "final_norm": {"scale": ("norm",)},
     }
+    if cfg.norm_type == "layer":
+        specs["final_norm"]["bias"] = ("norm",)
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = {"kernel": ("embed", "vocab")}
     if cfg.exit_gate:
@@ -881,7 +1023,7 @@ def forward(
     def end_pass(h):
         """A pass's final norm (and, when asked, the exit gate's logit on
         the normed state, (B, S) fp32)."""
-        h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+        h = apply_norm(h, params["final_norm"], cfg)
         if return_gate_logits:
             g = params["exit_gate"]
             gate_logits.append(
@@ -1010,25 +1152,85 @@ def _walk_named_layers(params, cfg, h, positions, segment_ids, inv_freq,
         )
 
     freq_for = make_freq_for(cfg, inv_freq)
+    memory_layer, shared_layer, _ = cross_decoder(cfg) or (None, None, None)
+    # what the cross-decoder reads: the memory layer's scan output and the
+    # shared layer's keys and values
+    carried = {}
 
-    def layer(h, lp, kind, window):
-        if kind in ATTENTION_OPS:
+    def layer(h, lp, kind, window, i, carried):
+        out = {}
+        if cfg.differential_attention and kind in ATTENTION_OPS + (
+                "cross_attention",):
+            h, kv = diff_attention_block(
+                h, lp, kind_config(cfg, kind), positions, segment_ids,
+                freq_for(window), window, i, carried.get("kv"), mesh_ctx)
+            if i == shared_layer:
+                out["kv"] = kv
+        elif kind in ATTENTION_OPS:
             h = attention_block(h, lp, kind_config(cfg, kind), positions,
                                 segment_ids, freq_for(window), constrain,
                                 window, mesh_ctx)
+        elif kind == "gmu":
+            h = gmu_block(h, lp, cfg, carried["memory"])
+        elif kind == "cross_attention":
+            raise NotImplementedError(
+                "cross-attention without differential attention")
         else:
-            h = mamba_block(h, lp, cfg, positions, constrain)
-        return mlp_block(h, lp, cfg, constrain, mesh_ctx)
+            h, memory = mamba_block(h, lp, cfg, positions, constrain,
+                                    with_memory=True)
+            if i == memory_layer:
+                out["memory"] = memory
+        return mlp_block(h, lp, cfg, constrain, mesh_ctx), out
 
     for i, ((kind, j), window) in enumerate(
             zip(layer_operators(cfg), layer_windows(cfg))):
         lp = jax.tree.map(lambda a: a[i], params["layers"])
         lp.update(jax.tree.map(lambda a: a[j], params[OPERATOR_STACKS[kind]]))
-        h = maybe_remat(
-            lambda h, lp, kind=kind, window=window: layer(h, lp, kind, window),
+        h, out = maybe_remat(
+            lambda h, lp, carried, kind=kind, window=window, i=i: layer(
+                h, lp, kind, window, i, carried),
             cfg.remat_policy,
-        )(h, lp)
+        )(h, lp, carried)
+        carried.update(out)
     return h
+
+
+def gmu_block(h, lp, cfg: TransformerConfig, memory):
+    """Pre-norm gated memory unit with residual: h += (silu(x W1) * m) W2,
+    `memory` (.., C) float32 the memory layer's at the same rows."""
+    from automodel_tpu.ops.quant import matmul as _mm
+
+    x = apply_norm(h, lp["input_norm"], cfg)
+    gate = ACTIVATIONS[cfg.activation](
+        _mm(x, lp["in_proj"]["kernel"], cfg.linear_precision))
+    y = (gate.astype(jnp.float32) * memory).astype(h.dtype)
+    return h + _mm(y, lp["o_proj"]["kernel"], cfg.linear_precision)
+
+
+def diff_attention_block(h, lp, cfg: TransformerConfig, positions, segment_ids,
+                         inv_freq, window, layer: int, kv=None, mesh_ctx=None):
+    """Pre-norm differential attention with residual over whole sequences:
+    with `kv` None the layer's own keys and values, else (cross-attention)
+    queries alone against `kv`, another layer's (keys (B, S, Hkv, D), values
+    `diff_values`'). Returns (h, (k, v)) with what it attended to."""
+    from automodel_tpu.ops.quant import matmul as _mm
+
+    B, S, _ = h.shape
+    D = cfg.resolved_head_dim
+    x = apply_norm(h, lp["input_norm"], cfg)
+    if kv is None:
+        q, k, v = project_qkv(x, lp, cfg, positions, inv_freq)
+        kv = (k, diff_values(v))
+    else:
+        q = _dense(x, lp["q_proj"], cfg.linear_precision).reshape(
+            B, S, cfg.num_heads, D)
+    o = dot_product_attention(
+        q, kv[0], kv[1], causal=cfg.causal, segment_ids=segment_ids,
+        positions=positions, sliding_window=window, scale=cfg.attn_scale,
+        impl=cfg.attn_impl, mesh_ctx=mesh_ctx,
+    )
+    o = diff_combine(o, lp, cfg, layer)
+    return h + _dense(o, lp["o_proj"], cfg.linear_precision), kv
 
 
 def head_kernel(params: dict, cfg: TransformerConfig) -> jnp.ndarray:
@@ -1119,7 +1321,7 @@ def attention_block(h, lp, cfg: TransformerConfig, positions, segment_ids, inv_f
     B, S, _ = h.shape
 
     # -- attention ----------------------------------------------------------
-    x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+    x = apply_norm(h, lp["input_norm"], cfg)
     q, k, v = project_qkv(x, lp, cfg, positions, inv_freq)
     q = constrain(q, ("act_batch", "act_seq", "act_heads", None))
     k = constrain(k, ("act_batch", "act_seq", "act_kv_heads", None))
@@ -1198,7 +1400,7 @@ def mlp_block(h, lp, cfg: TransformerConfig, constrain, mesh_ctx=None, manual=Fa
     explicit tp — lp holds the I/tp slice; the down_proj partial is psum'd."""
     from automodel_tpu.ops.quant import matmul as _mm
 
-    x = rms_norm(h, lp["post_attn_norm"]["scale"], cfg.rms_norm_eps, cfg.zero_centered_norm)
+    x = apply_norm(h, lp["post_attn_norm"], cfg)
     mlp = constrain(mlp_inner(x, lp, cfg), ("act_batch", "act_seq", "act_mlp"))
     mlp_out = _mm(mlp, lp["down_proj"]["kernel"], cfg.linear_precision)
     if manual and mesh_ctx is not None and mesh_ctx.sizes["tp"] > 1:

@@ -269,6 +269,54 @@ def jamba_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def phi4flash_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
+    """Phi4FlashForCausalLM (Phi-4-mini-flash-reasoning; the SambaY
+    decoder-hybrid-decoder of arXiv:2507.06607), its layout derived from
+    `num_hidden_layers` L and `mb_per_layer` as the family's code derives it:
+    below L/2 (the self-decoder) a Mamba-1 mixer where i % mb_per_layer == 0
+    and attention with the `sliding_window` elsewhere; layer L/2 a Mamba
+    layer whose output before its gate is the MEMORY, L/2 + 1 full attention
+    whose keys and values are the one full-length cache; from L/2 + 2 on (the
+    cross-decoder) a gated memory unit where a Mamba layer would stand and
+    cross-attention, queries alone against that cache, elsewhere. Every
+    attention layer is differential (`TransformerConfig.differential_
+    attention`) with biased projections and no rotary embedding; the norms
+    are LayerNorms with a bias; the Mamba mixers have none on dt, B or C."""
+    L, every = int(hf["num_hidden_layers"]), int(hf.get("mb_per_layer", 2))
+    if every < 1 or L % 2 or L // 2 % every:
+        raise NotImplementedError(
+            f"Phi4FlashForCausalLM with num_hidden_layers={L}, mb_per_layer="
+            f"{every}: layer L/2 must be a Mamba layer")
+    if hf.get("mlp_bias") or hf.get("lm_head_bias"):
+        raise NotImplementedError("Phi4FlashForCausalLM with MLP or head biases")
+    ops = []
+    for i in range(L):
+        mamba = i % every == 0
+        if i < L // 2 + 2:
+            ops.append("mamba" if mamba else
+                       "attention" if i == L // 2 + 1 else "window_attention")
+        else:
+            ops.append("gmu" if mamba else "cross_attention")
+    kw = _base_kwargs(hf)
+    kw["rms_norm_eps"] = float(hf.get("layer_norm_eps", 1e-5))
+    kw["norm_type"] = "layer"
+    kw["layer_ops"] = tuple(ops)
+    kw["sliding_window"] = int(hf["sliding_window"])
+    kw["layer_types"] = tuple(
+        "sliding" if op == "window_attention" else "full" for op in ops)
+    kw["use_rope"] = False
+    kw["attention_bias"] = True
+    kw["o_proj_bias"] = True
+    kw["differential_attention"] = True
+    kw["mamba_inner_norms"] = False
+    kw["mamba_d_state"] = int(hf.get("mamba_d_state", 16))
+    kw["mamba_d_conv"] = int(hf.get("mamba_d_conv", 4))
+    kw["mamba_expand"] = int(hf.get("mamba_expand", 2))
+    kw["activation"] = {"silu": "silu", "gelu": "gelu"}[hf.get("hidden_act", "silu")]
+    kw.update(overrides)
+    return TransformerConfig(**kw)
+
+
 def baichuan_config(hf: Mapping[str, Any], **overrides) -> TransformerConfig:
     """BaichuanForCausalLM — Baichuan2 7B shape (reference: models/baichuan/
     model.py): llama-like MHA with a fused W_pack qkv projection (handled by

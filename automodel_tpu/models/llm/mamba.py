@@ -49,14 +49,17 @@ def init_mamba_layers(cfg, rng: jax.Array, L: int) -> dict:
     ks = jax.random.split(rng, 6)
     dt = jnp.exp(jax.random.uniform(
         ks[5], (L, C), F32, jnp.log(1e-3), jnp.log(1e-1)))
+    norms = {
+        "dt_norm": {"scale": jnp.ones((L, R))},
+        "b_norm": {"scale": jnp.ones((L, N))},
+        "c_norm": {"scale": jnp.ones((L, N))},
+    } if cfg.mamba_inner_norms else {}
     return {
         "in_proj": {"kernel": _stack(dense_init, ks[0], (H, 2 * C), L)},
         "conv": {"kernel": _stack(dense_init, ks[1], (K, C), L),
                  "bias": jnp.zeros((L, C))},
         "x_proj": {"kernel": _stack(dense_init, ks[2], (C, R + 2 * N), L)},
-        "dt_norm": {"scale": jnp.ones((L, R))},
-        "b_norm": {"scale": jnp.ones((L, N))},
-        "c_norm": {"scale": jnp.ones((L, N))},
+        **norms,
         "dt_proj": {"kernel": _stack(dense_init, ks[3], (R, C), L)},
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus's inverse
         "A_log": jnp.broadcast_to(
@@ -68,13 +71,16 @@ def init_mamba_layers(cfg, rng: jax.Array, L: int) -> dict:
 
 def mamba_layer_specs(cfg) -> dict:
     """The inner channels are the mixer's wide axis, cut like an MLP's."""
+    norms = {
+        "dt_norm": {"scale": ("layers", "norm")},
+        "b_norm": {"scale": ("layers", "norm")},
+        "c_norm": {"scale": ("layers", "norm")},
+    } if cfg.mamba_inner_norms else {}
     return {
         "in_proj": {"kernel": ("layers", "embed", "mlp")},
         "conv": {"kernel": ("layers", None, "mlp"), "bias": ("layers", "mlp")},
         "x_proj": {"kernel": ("layers", "mlp", None)},
-        "dt_norm": {"scale": ("layers", "norm")},
-        "b_norm": {"scale": ("layers", "norm")},
-        "c_norm": {"scale": ("layers", "norm")},
+        **norms,
         "dt_proj": {"kernel": ("layers", None, "mlp")},
         "dt_bias": ("layers", "mlp"),
         "A_log": ("layers", None, "mlp"),
@@ -86,7 +92,8 @@ def mamba_layer_specs(cfg) -> dict:
 def mamba_params_per_layer(cfg) -> int:
     H, C, N = cfg.hidden_size, cfg.mamba_d_inner, cfg.mamba_d_state
     K, R = cfg.mamba_d_conv, cfg.resolved_dt_rank
-    return (H * 2 * C + K * C + C + C * (R + 2 * N) + R + 2 * N
+    norms = R + 2 * N if cfg.mamba_inner_norms else 0
+    return (H * 2 * C + K * C + C + C * (R + 2 * N) + norms
             + R * C + C + N * C + C + C * H)
 
 
@@ -103,31 +110,38 @@ def mamba_selection(u, lp, cfg):
     eps = cfg.rms_norm_eps
     sel = _mm(u, lp["x_proj"]["kernel"], cfg.linear_precision)
     dt, b, c = jnp.split(sel, [R, R + N], axis=-1)
-    dt = rms_norm(dt, lp["dt_norm"]["scale"], eps)
-    b = rms_norm(b, lp["b_norm"]["scale"], eps)
-    c = rms_norm(c, lp["c_norm"]["scale"], eps)
+    if cfg.mamba_inner_norms:
+        dt = rms_norm(dt, lp["dt_norm"]["scale"], eps)
+        b = rms_norm(b, lp["b_norm"]["scale"], eps)
+        c = rms_norm(c, lp["c_norm"]["scale"], eps)
     dt = _mm(dt, lp["dt_proj"]["kernel"], cfg.linear_precision)
     delta = jax.nn.softplus(dt.astype(F32) + lp["dt_bias"].astype(F32))
     a = -jnp.exp(lp["A_log"].astype(F32))
     return delta, b.astype(F32), c.astype(F32), a
 
 
-def mamba_output(y, u, z, lp, cfg):
-    """(y + D * u) * silu(z) through o_proj. y (.., C) float32: the scan's."""
+def mamba_output(y, u, z, lp, cfg, with_memory: bool = False):
+    """(y + D * u) * silu(z) through o_proj. y (.., C) float32: the scan's.
+    `with_memory`: (that, y + D * u) — the output before the gate, float32,
+    what a decoder-hybrid-decoder's memory layer hands its GMUs."""
     y = y + lp["D"].astype(F32) * u.astype(F32)
-    y = (y * jax.nn.silu(z.astype(F32))).astype(u.dtype)
-    return _mm(y, lp["o_proj"]["kernel"], cfg.linear_precision)
+    out = _mm((y * jax.nn.silu(z.astype(F32))).astype(u.dtype),
+              lp["o_proj"]["kernel"], cfg.linear_precision)
+    return (out, y) if with_memory else out
 
 
-def mamba_block(h, lp, cfg, positions, constrain):
+def mamba_block(h, lp, cfg, positions, constrain, with_memory: bool = False):
     """Pre-norm mixer with residual over whole sequences h (B, S, H), every
-    document from a zero state (`positions` restart at 0 in a packed row)."""
-    x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_norm_eps,
-                 cfg.zero_centered_norm)
+    document from a zero state (`positions` restart at 0 in a packed row).
+    `with_memory`: (h, the mixer's output before the gate)."""
+    from automodel_tpu.models.llm.decoder import apply_norm
+
+    x = apply_norm(h, lp["input_norm"], cfg)
     u, z = mamba_inputs(x, lp, cfg)
     u = jax.nn.silu(causal_conv(
         u, lp["conv"]["kernel"], lp["conv"]["bias"], positions)).astype(h.dtype)
     delta, b, c, a = mamba_selection(u, lp, cfg)
     y = selective_scan(u, delta, a, b, c, positions)
-    h = h + mamba_output(y, u, z, lp, cfg)
-    return constrain(h, ("act_batch", "act_seq", "act_embed"))
+    out, memory = mamba_output(y, u, z, lp, cfg, with_memory=True)
+    h = constrain(h + out, ("act_batch", "act_seq", "act_embed"))
+    return (h, memory) if with_memory else h

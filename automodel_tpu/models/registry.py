@@ -184,6 +184,12 @@ MODEL_ARCH_MAPPING: dict[str, ModelSpec] = {
     "JambaForCausalLM": ModelSpec(
         "jamba", families.jamba_config, decoder, adapter_name="jamba",
     ),
+    # Phi-4-mini-flash (SambaY): Mamba-1 and window layers, one full layer
+    # whose cache the cross-decoder's layers read, gated memory units;
+    # differential attention throughout (no checkpoint adapter yet)
+    "Phi4FlashForCausalLM": ModelSpec(
+        "phi4flash", families.phi4flash_config, decoder,
+    ),
     "BaichuanForCausalLM": ModelSpec(
         "baichuan", families.baichuan_config, decoder,
         adapter_kwargs={"style": "baichuan"},

@@ -335,6 +335,9 @@ METRIC_CATALOG = (
     # paged GQA calls with per-head sink logits (ops/paged_attention.py;
     # trace time, process-global registry)
     ("paged_attention_sink_total", "counter", "traced paged GQA call sites with attention sinks (labeled by impl=pallas|xla: where they lowered)"),
+    # paged differential-attention calls (ops/paged_attention.py
+    # ragged_paged_diff_attention; a decoder-hybrid-decoder's layers)
+    ("paged_attention_diff_total", "counter", "traced paged differential-attention call sites (labeled by impl=pallas|xla: where they lowered)"),
 )
 
 #: Process-global registry for components without an engine in hand

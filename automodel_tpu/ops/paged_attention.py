@@ -474,6 +474,75 @@ def ragged_paged_attention(
     return _annotate_tp(out, mesh_ctx, 1)
 
 
+def diff_row_width(Hq: int, Hkv: int, D: int) -> int:
+    """A differential call's elements a row for `row_tile`: its scores run
+    against a whole (ps, Hkv x D) page and its products give Hkv x D values
+    a head before two value heads are picked, so a tile's VMEM grows with
+    the page's width, not the head's."""
+    return Hq * (Hkv * D + 4 * D)
+
+
+#: the head width the differential kernel takes: two heads to a lane tile
+DIFF_HEAD_DIM = 64
+
+
+def _diff_unsupported_reason(q, k_pages, window, tp):
+    Hq, D = q.shape[1:]
+    Hkv = k_pages.shape[-1] // D
+    if window is not None and not isinstance(window, int):
+        return "a traced sliding window"
+    if tp > 1:
+        return "differential attention under tp > 1"
+    if D != DIFF_HEAD_DIM or Hkv % 4 or Hq % Hkv:
+        # two heads to a lane tile, the value heads' two halves each a whole
+        # number of tiles, a query head's pair in the other half
+        return f"differential heads of {D} ({Hq} over {Hkv})"
+    return None
+
+
+def ragged_paged_diff_attention(
+    q, k_pages, v_pages, page_tables, positions,
+    *,
+    scale: float,
+    window=None,
+    impl: str = "auto",
+    mesh_ctx=None,
+    segments: RowSegments | None = None,
+):
+    """Differential attention's maps over MERGED pages, (N, ps, Hkv x D) keys
+    and values (serving/kv_pages.py: the heads side by side in a page's minor
+    dimension, no lane padding at D = 64). q (T, Hq, D); query head i scores
+    against key head i // (Hq / Hkv), and its map weighs the 2D-wide value of
+    `decoder.diff_values`, read out of the page it already holds: no second
+    copy of the values. Returns (T, Hq, 2D), the pairs not yet combined
+    (`decoder.diff_combine`). Same dispatch contract as the GQA entry; the
+    kernel's call is named `paged_attention_diff_gqa`, or
+    `paged_attention_diff_window_gqa` with a window."""
+    from automodel_tpu.ops.attention import resolve_kernel_impl
+    from automodel_tpu.observability.metrics import default_registry
+
+    unsupported = _diff_unsupported_reason(q, k_pages, window, _tp_size(mesh_ctx))
+    chosen = resolve_kernel_impl(impl, "pallas", unsupported, "paged_attention")
+    # trace time: which differential calls lowered to the kernel
+    default_registry().counter("paged_attention_diff_total", impl=chosen).inc()
+    if chosen == "pallas":
+        from automodel_tpu.ops.pallas.ragged_paged_attention import (
+            paged_attention_diff_kernel,
+        )
+
+        return paged_attention_diff_kernel(
+            q, k_pages, v_pages, page_tables, positions, scale=scale,
+            segments=segments, window=window)
+    from automodel_tpu.models.llm.decoder import diff_values
+
+    N, ps, _ = k_pages.shape
+    D = q.shape[-1]
+    return ragged_paged_attention_xla(
+        q, k_pages.reshape(N, ps, -1, D),
+        diff_values(v_pages.reshape(N, ps, -1, D)), page_tables, positions,
+        scale=scale, window=window)
+
+
 def _mla_unsupported_reason(window, tp):
     """Why the Pallas MLA kernels cannot take this call, or None."""
     if window is not None:

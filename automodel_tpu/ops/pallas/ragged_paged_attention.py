@@ -537,6 +537,182 @@ def paged_attention_quant_kernel(
     )
 
 
+# -- differential attention --------------------------------------------------
+def _diff_kernel(
+    blocks_ref,  # (6, W) scalar-prefetch blocks (RowSegments.blocks)
+    q_ref,     # (tile, Hq, LANE): each query at its key head's half of a tile
+    k_ref,     # (1, ps, Hkv x D) a merged page: head h at lanes h x D
+    v_ref,     # (1, ps, Hkv x D)
+    out_ref,   # (tile, Hq, 2D): each head's two value heads side by side
+    m1, l1, acc1, qt, mt, lt, acct,  # one row's m/l/acc, the tile's q and m/l/acc
+    *, scale, page_size, groups, tile, window,
+):
+    """Differential attention's maps (`ops/paged_attention.ragged_paged_diff_
+    attention`) over merged pages of heads 64 wide, two to a lane tile. A row
+    of query heads scores against the WHOLE page in one product: each query
+    is spread to its key head's lane tile (`spread`), zeros elsewhere, so the
+    other heads' keys add nothing, and no page is cut at a half tile. The
+    maps weigh the whole page of values too, and of each row's product the
+    tiles of its two value heads are kept (`weighted_values`); their halves
+    are picked when the segment is stored (`values_of`). The pairing is
+    DiffLlama's (`decoder.diff_values`): key head g = i // groups, value
+    heads g mod Hkv/2 and Hkv/2 + g mod Hkv/2, both in the same half of a
+    tile. The maps are not combined here: that is the caller's."""
+    off, length, pos0, key0, first_page, last_page = _block(
+        blocks_ref, page_size, window)
+    one_row = length == 1
+    many_rows = length > 1
+    Hq = q_ref.shape[1]
+    ps = page_size
+    tiles = k_ref.shape[-1] // LANE
+    half_kv = tiles  # Hkv / 2: two heads to a tile
+    f32 = jnp.float32
+    kv_idx = key0 + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+
+    def heads(rows, per_head):
+        """(rows, 1): the query head of each score row."""
+        return jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // per_head
+
+    def spread(q, head):
+        """(R, LANE) queries -> (R, page width): at their key head's tile."""
+        ktile = head // groups // 2
+        return jnp.concatenate(
+            [jnp.where(ktile == c, q, jnp.zeros_like(q)) for c in range(tiles)],
+            axis=-1)
+
+    def scores(q_big):
+        return jax.lax.dot_general(
+            q_big, k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=f32) * scale
+
+    def weighted_values(p, head):
+        """p (R, ps) -> (R, 2 LANE): of the map times the whole page, the
+        tiles that hold each row's two value heads."""
+        pv = jnp.dot(p.astype(v_ref.dtype), v_ref[0], preferred_element_type=f32)
+        a = head // groups % half_kv
+        out = []
+        for tile_of, cs in ((a // 2, range(tiles // 2)),
+                            ((half_kv + a) // 2, range(tiles // 2, tiles))):
+            picked = jnp.zeros((pv.shape[0], LANE), f32)
+            for c in cs:
+                picked = jnp.where(tile_of == c, pv[:, c * LANE:(c + 1) * LANE],
+                                   picked)
+            out.append(picked)
+        return jnp.concatenate(out, axis=-1)
+
+    def values_of(acc, head):
+        """(R, 2 LANE) -> (R, LANE): each row's two value heads, side by
+        side, from the halves of the tiles they lie in."""
+        odd = head // groups % half_kv % 2 == 1
+        first, second = acc[:, :LANE], acc[:, LANE:]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
+        lo = jnp.where(odd, pltpu.roll(first, LANE // 2, 1), first)
+        hi = jnp.where(odd, second, pltpu.roll(second, LANE // 2, 1))
+        return jnp.where(lane < LANE // 2, lo, hi)
+
+    # -- a decode row ---------------------------------------------------------
+    @pl.when(jnp.logical_and(one_row, first_page))
+    def _reset_one():
+        _reset(m1, l1, acc1)
+
+    @pl.when(one_row)
+    def _one_row():
+        head = heads(Hq, 1)
+        _softmax_page(
+            scores(spread(q_ref[off], head)), _attends(kv_idx, pos0, window),
+            m1, l1, acc1, lambda p: weighted_values(p, head))
+
+    @pl.when(jnp.logical_and(one_row, last_page))
+    def _store_one():
+        out_ref[off] = values_of(
+            _normalized(l1, acc1), heads(Hq, 1)).astype(out_ref.dtype)
+
+    # -- a chunk's rows: the whole tile, head-major ---------------------------
+    @pl.when(jnp.logical_and(many_rows, first_page))
+    def _reset_tile():
+        _reset(mt, lt, acct)
+        rows = jnp.swapaxes(q_ref[...], 0, 1).reshape(Hq * tile, LANE)
+        qt[...] = spread(rows, heads(Hq * tile, tile))
+
+    @pl.when(many_rows)
+    def _tile_rows():
+        tile_row = jax.lax.rem(jax.lax.broadcasted_iota(
+            jnp.int32, (Hq * tile, 1), 0), tile)
+        mask = _attends(
+            kv_idx, _tile_row_positions(tile_row, off, length, pos0), window)
+        head = heads(Hq * tile, tile)
+        _softmax_page(scores(qt[...]), mask, mt, lt, acct,
+                      lambda p: weighted_values(p, head))
+
+    @pl.when(jnp.logical_and(many_rows, last_page))
+    def _store_tile():
+        rows = values_of(_normalized(lt, acct), heads(Hq * tile, tile))
+        _store_segment_rows(
+            out_ref, jnp.swapaxes(rows.reshape(Hq, tile, LANE), 0, 1),
+            off, length)
+
+
+@functools.partial(
+    jax.jit, inline=True,
+    static_argnames=("tile", "scale", "name", "interpret", "window"))
+def _diff_call(q, k_pages, v_pages, positions, blocks, count, *, tile, scale,
+               name, interpret, window=None):
+    """Jitted for its cache alone, as `_gqa_call`."""
+    Hq, D = q.shape[1:]
+    groups = Hq // (k_pages.shape[-1] // D)
+    # each query at its key head's half of a lane tile (two heads to a tile)
+    odd = (jnp.arange(Hq) // groups % 2 == 1)[None, :, None]
+    zeros = jnp.zeros_like(q)
+    q = jnp.where(odd, jnp.concatenate([zeros, q], -1),
+                  jnp.concatenate([q, zeros], -1))
+    f32 = jnp.float32
+    scratch = [
+        pltpu.VMEM((Hq, LANE), f32),
+        pltpu.VMEM((Hq, LANE), f32),
+        pltpu.VMEM((Hq, 2 * LANE), f32),
+        pltpu.VMEM((Hq * tile, k_pages.shape[-1]), q.dtype),
+        pltpu.VMEM((Hq * tile, LANE), f32),
+        pltpu.VMEM((Hq * tile, LANE), f32),
+        pltpu.VMEM((Hq * tile, 2 * LANE), f32),
+    ]
+    kernel = functools.partial(
+        _diff_kernel, scale=scale, groups=groups, window=window)
+    return _segment_call(
+        kernel, name, interpret, RowSegments(tile, blocks, count), positions,
+        [q], [k_pages, v_pages], (), 2 * D, scratch, k_pages.shape[1],
+    )
+
+
+def paged_attention_diff_kernel(
+    q, k_pages, v_pages, page_tables, positions,
+    *,
+    scale: float,
+    segments: RowSegments | None = None,
+    window: int | None = None,
+):
+    """Differential attention's maps over merged pages: q (T, Hq, 64),
+    pages (N, ps, Hkv x 64), `page_tables` (T, P) per ROW; returns (T, Hq,
+    128), each head's map times its two value heads. `segments` and
+    `window` as in `paged_attention_kernel`; the call is named
+    `paged_attention_diff_gqa`, or `paged_attention_diff_window_gqa` with a
+    window."""
+    from automodel_tpu.ops.paged_attention import diff_row_width
+
+    window = window or None
+    Hq, D = q.shape[1:]
+    if segments is None:
+        segments = _rows_as_segments(
+            page_tables, positions, k_pages.shape[1],
+            diff_row_width(Hq, k_pages.shape[-1] // D, D), window)
+    name = ("paged_attention_diff_window_gqa" if window
+            else "paged_attention_diff_gqa")
+    return _diff_call(
+        q, k_pages, v_pages, positions, segments.blocks, segments.count,
+        tile=segments.tile, scale=float(scale), name=name,
+        interpret=_interpret(), window=window,
+    )
+
+
 # -- absorbed MLA -------------------------------------------------------------
 def _mla_kernel(
     blocks_ref,  # (6, W) scalar-prefetch blocks (RowSegments.blocks)

@@ -96,6 +96,10 @@ from automodel_tpu.models.llm.decoder import (
     ATTENTION_OPS,
     OPERATOR_STACKS,
     _dense,
+    apply_norm,
+    cross_decoder,
+    diff_combine,
+    gmu_block,
     kind_config,
     layer_operators,
     layer_windows,
@@ -109,7 +113,9 @@ from automodel_tpu.models.llm.mamba import (
     mamba_selection,
 )
 from automodel_tpu.ops.paged_attention import (
+    diff_row_width,
     ragged_paged_attention,
+    ragged_paged_diff_attention,
     ragged_paged_mla_attention,
     row_tile,
     step_row_segments,
@@ -436,6 +442,10 @@ class ServingEngine:
         # quantized engines each compile their one program; fp stays
         # byte-identical to the quantization-unaware engine)
         self._kv_quant = serve_cfg.kv_cache_dtype is not None
+        if self._kv_quant and getattr(cfg, "differential_attention", False):
+            raise NotImplementedError(
+                "int8 key/value pages under differential attention: its "
+                "kernel reads merged bf16 pages and takes no scales")
         # observability bundle: routers pass ONE shared bundle to every
         # engine (distinct track names) so a single tracer/registry sees
         # the whole request lifecycle across replica classes; standalone
@@ -678,7 +688,16 @@ class ServingEngine:
         ) if self.is_mla else max(
             k.num_heads * (k.resolved_head_dim + k.resolved_v_head_dim)
             for k in (cfg, kind_config(cfg, "window_attention"))
-        ))
+        ) if not cfg.differential_attention else diff_row_width(
+            cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim))
+        # a decoder-hybrid-decoder (decoder.cross_decoder): (memory layer,
+        # shared-cache layer, first cross layer); from the first cross layer
+        # on the step runs over the S sample rows alone, whose cross-attention
+        # calls read the shared layer's pages through segments of their own
+        self._cross = cross_decoder(cfg)
+        self._cross_row_tile = row_tile(sc.max_slots, diff_row_width(
+            cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim)
+        ) if self._cross else None
 
     # -- mesh plumbing ------------------------------------------------------
     @staticmethod
@@ -839,7 +858,7 @@ class ServingEngine:
         return h + moe_out
 
     # -- device step --------------------------------------------------------
-    def _attn(self, h, lp, cfg, window, cache, b):
+    def _attn(self, h, lp, cfg, window, cache, b, layer: int = 0):
         """One attention sub-block over the paged pool; `cache` is one
         layer's page arrays — (k, v) fp, or (k, v, k_scale, v_scale)
         with kv_cache_dtype="int8", where new-token rows quantize IN-JIT at
@@ -849,7 +868,8 @@ class ServingEngine:
         is the layer's ring of pages per slot, not a pool entry, and the
         rows' write pages, page tables and block list are the ring's. `cfg`
         is the layer's operator's configuration (`decoder.kind_config`).
-        Returns (post-residual h, written cache). h is (1, T, H)."""
+        With differential attention (`_diff_attn`) `layer` is the layer's
+        index. Returns (post-residual h, written cache). h is (1, T, H)."""
         freq = self._freq_for(window)
         if window and self._ring_pages:
             page, tables, segments, write = (
@@ -859,8 +879,19 @@ class ServingEngine:
             page, tables, segments, write = (
                 b["page"], b["pt_tok"], b["segments"], "serve.pool_write")
         positions = jnp.maximum(b["pos"], 0)[None]  # (1, T); pads clamped
-        x = rms_norm(h, lp["input_norm"]["scale"], cfg.rms_norm_eps,
-                     cfg.zero_centered_norm)
+        x = apply_norm(h, lp["input_norm"], cfg)
+        if cfg.differential_attention:
+            q, k, v = project_qkv(x, lp, cfg, positions, freq)
+            pool_k, pool_v = cache
+            with jax.named_scope(write):
+                # merged pages (kv_pages.gqa_rows): a token's heads side by side
+                pool_k = pool_k.at[page, b["off"]].set(
+                    k[0].reshape(k.shape[1], -1).astype(pool_k.dtype))
+                pool_v = pool_v.at[page, b["off"]].set(
+                    v[0].reshape(v.shape[1], -1).astype(pool_v.dtype))
+            return self._diff_attn(h, q[0], lp, cfg, (pool_k, pool_v), tables,
+                                   b["pos"], segments, window, layer), (
+                pool_k, pool_v)
         if self.is_mla:
             n = cfg.num_heads
             dn, dr = cfg.mla_qk_nope_head_dim, cfg.mla_qk_rope_head_dim
@@ -955,17 +986,47 @@ class ServingEngine:
             return h + attn_out, (pool_k, pool_v, s_k, s_v)
         return h + attn_out, (pool_k, pool_v)
 
-    def _ssm(self, h, lp, cache, b):
+    def _diff_attn(self, h, q, lp, cfg, cache, tables, pos, segments, window,
+                   layer):
+        """h + differential attention's output: q (R, Hq, D) of the rows of h
+        (1, R, H) against the pages of `cache`, through `tables` and `pos` of
+        those rows and their `segments`; the pairs combined outside the
+        kernel (`decoder.diff_combine`)."""
+        o = ragged_paged_diff_attention(
+            q, *cache, tables, pos,
+            scale=cfg.attn_scale if cfg.attn_scale is not None
+            else cfg.resolved_head_dim ** -0.5,
+            window=window, mesh_ctx=self._mesh, segments=segments,
+        )
+        o = diff_combine(o, lp, cfg, layer)
+        return h + _dense(o[None], lp["o_proj"], cfg.linear_precision)
+
+    def _cross_attn(self, h, lp, cfg, cache, b, layer):
+        """A cross-attention layer over the sample rows h (1, S, H): queries
+        alone, against the shared layer's pages `cache`, each row a segment
+        of its own (`b["cross_*"]`, once a step)."""
+        x = apply_norm(h, lp["input_norm"], cfg)
+        q = _dense(x, lp["q_proj"], cfg.linear_precision)
+        q = q[0].reshape(q.shape[1], cfg.num_heads, cfg.resolved_head_dim)
+        return self._diff_attn(h, q, lp, cfg, cache, b["cross_pt"],
+                               b["cross_pos"], b["cross_segments"], None, layer)
+
+    def _gmu(self, h, lp, memory):
+        """A gated memory unit over the sample rows h (1, S, H): h +=
+        (silu(x W1) * m) W2, `memory` (S, C) the memory layer's at the rows."""
+        return gmu_block(h, lp, self.cfg, memory[None])
+
+    def _ssm(self, h, lp, cache, b, with_memory: bool = False):
         """One state-space mixer (models/llm/mamba.py) over the step's ragged
         rows; `cache` is the layer's `(conv, ssm)` per-slot state. Each run
         of one slot's rows continues what the slot carried in, or starts
         from zeros at position 0 (ops/selective_scan.py). Returns
-        (post-residual h, written state). h is (1, T, H)."""
+        (post-residual h, written state), and `with_memory` the mixer's output
+        before its gate, (T, C) float32, too. h is (1, T, H)."""
         cfg = self.cfg
         conv_state, ssm_state = cache
         with jax.named_scope("serve.ssm.proj"):
-            x = rms_norm(h[0], lp["input_norm"]["scale"], cfg.rms_norm_eps,
-                         cfg.zero_centered_norm)
+            x = apply_norm(h[0], lp["input_norm"], cfg)
             u, z = mamba_inputs(x, lp, cfg)
         with jax.named_scope("serve.ssm.conv"):
             u, conv_state = ragged_conv(
@@ -981,7 +1042,10 @@ class ServingEngine:
                 mesh_ctx=self._mesh,
             )
         with jax.named_scope("serve.ssm.proj"):
-            out = mamba_output(y, u, z, lp, cfg)
+            out = mamba_output(y, u, z, lp, cfg, with_memory)
+        if with_memory:
+            out, memory = out
+            return h + out[None], (conv_state, ssm_state), memory
         return h + out[None], (conv_state, ssm_state)
 
     def _step_impl(self, params, pool, b, state=()):
@@ -1036,6 +1100,21 @@ class ServingEngine:
                 max_slots=sc.max_slots, window=self.cfg.sliding_window,
                 max_pages=R,
             )
+        if self._cross:
+            # the cross-decoder's rows, once for all its layers: each slot's
+            # sample row (none: a pad row) with its position and page table,
+            # each a segment of its own over the shared layer's pages
+            sc = self.serve_cfg
+            sampled = b["sample_tok"] >= 0
+            idx = jnp.clip(b["sample_tok"], 0, b["pos"].shape[0] - 1)
+            b["cross_rows"] = idx
+            b["cross_pos"] = jnp.where(sampled, b["pos"][idx], -1)
+            b["cross_pt"] = b["pt_tok"][idx]
+            b["cross_segments"] = step_row_segments(
+                jnp.where(sampled, b["slot"][idx], -1), b["cross_pos"],
+                b["cross_pt"], page_size=sc.page_size,
+                tile=self._cross_row_tile, max_slots=sc.max_slots,
+            )
         # copy-on-write splits first (≤ 1 per slot; idle entries copy the
         # trash page onto itself): a slot about to append into a page some
         # other table or the radix tree still reads gets a private copy. A
@@ -1072,6 +1151,11 @@ class ServingEngine:
         new_state = list(state)
         share_runs = []  # the expert layers that hold a share: _moe_mlp
         r = self._num_ssm
+        # a decoder-hybrid-decoder's memory (the memory layer's mixer output
+        # before its gate) and the shared layer's written pages
+        memory_layer, shared_layer, first_cross = self._cross or (None,) * 3
+        memory = shared = None
+        i = 0  # the layer's index in the model
         # the full layers' own scope only beside window layers: a model
         # without them lowers as it always did
         full_scope = functools.partial(
@@ -1092,37 +1176,61 @@ class ServingEngine:
                         if j is not None:
                             lp = {**lp, **params[OPERATOR_STACKS[kind]][j]}
                         kcfg = kind_config(cfg, kind)
+                        if i == first_cross:
+                            # the rest of the model runs over the sample rows
+                            h = h[:, b["cross_rows"]]
+                            memory = memory[b["cross_rows"]]
+                        cross_scope = functools.partial(
+                            jax.named_scope, "serve.cross"
+                        ) if first_cross is not None and i >= first_cross \
+                            else contextlib.nullcontext
                         if kind in ATTENTION_OPS and win and self._ring_pages:
                             with jax.named_scope("serve.attn"), \
                                     jax.named_scope("serve.attn.window"):
                                 h, new_state[r] = self._attn(
-                                    h, lp, kcfg, win, state[r], b
+                                    h, lp, kcfg, win, state[r], b, layer=i
                                 )
                             r += 1
                         elif kind in ATTENTION_OPS:
                             with jax.named_scope("serve.attn"), full_scope():
                                 h, cache = self._attn(
-                                    h, lp, kcfg, win, next(caches), b
+                                    h, lp, kcfg, win, next(caches), b, layer=i
                                 )
                             new_stack.append(cache)
+                            if i == shared_layer:
+                                shared = cache
+                        elif kind == "gmu":
+                            with cross_scope(), jax.named_scope("serve.gmu"):
+                                h = self._gmu(h, lp, memory)
+                        elif kind == "cross_attention":
+                            with cross_scope(), jax.named_scope("serve.attn"), \
+                                    jax.named_scope("serve.attn.cross"):
+                                h = self._cross_attn(h, lp, kcfg, shared, b, i)
+                        elif i == memory_layer:
+                            with jax.named_scope("serve.ssm"):
+                                h, new_state[j], memory = self._ssm(
+                                    h, lp, state[j], b, with_memory=True
+                                )
                         else:
                             with jax.named_scope("serve.ssm"):
                                 h, new_state[j] = self._ssm(
                                     h, lp, state[j], b
                                 )
-                        with jax.named_scope(mlp_scope):
+                        with cross_scope(), jax.named_scope(mlp_scope):
                             if mlp_fn is _dense_mlp:
                                 h = mlp_fn(h, lp, cfg)
                             else:
                                 h = mlp_fn(h, lp, cfg, b, share_runs)
                         h = self._constrain_rep(h)
+                        i += 1
                 if t + 1 < cfg.num_passes:
                     h = rms_norm(h, params["final_norm"]["scale"],
                                  cfg.rms_norm_eps, cfg.zero_centered_norm)
         new_pool = self._constrain_pool([tuple(st) for st in new_pool])
 
         with jax.named_scope("serve.head"):
-            out = self._head(params, new_pool, h, b)
+            out = self._head(params, new_pool, h, b,
+                             sampled=first_cross is not None)
         # tokens, log-probabilities (and acceptances) go back as ONE int32
         # vector, the float32 values bit-cast; hidden feedback stays its own.
         # A model whose expert layers hold a share adds two words
@@ -1134,17 +1242,17 @@ class ServingEngine:
         # lowers to the program it always was
         return out + (tuple(new_state),) if state else out
 
-    def _head(self, params, new_pool, h, b):
-        """Final norm, unembed of the sample rows, sampling, log-softmax."""
+    def _head(self, params, new_pool, h, b, sampled: bool = False):
+        """Final norm, unembed of the sample rows, sampling, log-softmax.
+        `sampled`: h holds the sample rows already, (1, S, H)."""
         cfg = self.cfg
-        h = rms_norm(h, params["final_norm"]["scale"], cfg.rms_norm_eps,
-                     cfg.zero_centered_norm)
+        h = apply_norm(h, params["final_norm"], cfg)
         if self._spec is not None:
             return self._spec_verify_tail(params, new_pool, h, b)
         # sample rows: each slot's last scheduled token (or a junk row when
         # sample_tok < 0 — the host ignores those slots)
-        idx = jnp.clip(b["sample_tok"], 0, h.shape[1] - 1)
-        h_s = h[0, idx]                            # (S, H)
+        idx = jnp.clip(b["sample_tok"], 0, b["pos"].shape[0] - 1)
+        h_s = h[0] if sampled else h[0, idx]       # (S, H)
         logits = unembed(params, cfg, h_s[None])[0]  # (S, V) fp32
         logits = self._constrain_rep(logits)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1474,11 +1582,21 @@ class ServingEngine:
             stats = sched.turn_stats(preempted, plan)
             if plan is not None:
                 stats.update(rows=plan.n_tokens, samples=plan.n_samples)
+                if self._cross:
+                    stats.update(self._cross_stats(plan))
             span.set_metadata(**stats)
             reg = self.obs.registry
             reg.gauge("serve_attn_segments").set(stats["attn_segments"])
             reg.gauge("serve_attn_live_blocks").set(stats["attn_live_blocks"])
         return plan
+
+    def _cross_stats(self, plan: StepPlan) -> dict:
+        """A decoder-hybrid-decoder's turn: `cross_rows`, the sample rows its
+        cross-decoder runs over, and `cross_blocks`, the (row, page) blocks
+        one cross-attention call over the shared layer's pages walks."""
+        rows = plan.sample_tok[plan.sample_tok >= 0]
+        pages = plan.pos[rows] // self.serve_cfg.page_size + 1
+        return {"cross_rows": int(rows.size), "cross_blocks": int(pages.sum())}
 
     def run_one_step(
         self, sched: Scheduler, step_idx: int,

@@ -323,7 +323,14 @@ def key_width(head_dim: int) -> int:
 
 def gqa_rows(cfg) -> tuple:
     """One cached token's (key, value) rows of a GQA layer of `cfg`'s
-    geometry: (Hkv, key width), (Hkv, value width)."""
+    geometry: (Hkv, key width), (Hkv, value width). With differential
+    attention the heads lie side by side in one row each, (Hkv x D,): at a
+    head of 64 a (ps, Hkv, 64) page is laid out with its token axis minor,
+    which the kernel cannot read without a copy of the pool every call; a
+    (ps, Hkv x 64) page is lane-dense and holds the same bytes."""
+    if getattr(cfg, "differential_attention", False):
+        width = cfg.num_kv_heads * cfg.resolved_head_dim
+        return (width,), (width,)
     return ((cfg.num_kv_heads, key_width(cfg.resolved_head_dim)),
             (cfg.num_kv_heads, cfg.resolved_v_head_dim))
 
@@ -377,6 +384,8 @@ def pool_axes(cfg, kv_cache_dtype: str | None = None) -> tuple:
     """
     if cfg.attention_type == "mla":
         data = ((None, None, "tp"), (None, None, None))
+    elif getattr(cfg, "differential_attention", False):
+        data = ((None, None, "tp"), (None, None, "tp"))
     else:
         data = ((None, None, "tp", None), (None, None, "tp", None))
     if kv_cache_dtype is None:
@@ -434,9 +443,9 @@ def state_shapes(cfg, max_slots: int) -> tuple:
 
 
 def num_state_layers(cfg) -> int:
-    from automodel_tpu.models.llm.decoder import ATTENTION_OPS
+    from automodel_tpu.models.llm.decoder import STATE_OPS
 
-    return sum(op not in ATTENTION_OPS for op in cfg.layer_ops or ())
+    return sum(op in STATE_OPS for op in cfg.layer_ops or ())
 
 
 def state_shardings(cfg, mesh_ctx):

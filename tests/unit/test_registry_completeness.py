@@ -55,6 +55,11 @@ PARITY_TESTS = {
     # expert held; a pin beside it (test_model_pins.py)
     "mimo_v2_flash": ("test_mimo_v2_flash.py",
                       "test_forward_matches_reference_with_all_experts_held"),
+    # against the layer equations the plain reference writes out
+    # (benchmark/reference/phi4_flash.py: differential attention as DiffLlama
+    # pairs its heads, the GMUs, the cross layers), the head tied
+    "phi4flash": ("test_phi4_flash.py",
+                  "test_forward_matches_reference_with_the_tied_head"),
 }
 
 #: Known gaps — families with functional tests (adapter roundtrips, recipe

@@ -546,6 +546,134 @@ def test_unlike_window_layers_step_compiles_whole_for_the_chip(one_chip, tpu_bra
                 if " reshape(" in ln and "[260,1024," in ln.split(" reshape(")[0]]
 
 
+@pytest.mark.parametrize("call", ["full over 128 rows", "window over 128 rows",
+                                  "cross over 64 sample rows"])
+def test_paged_attention_diff_compiles_for_the_chip(one_chip, tpu_branch, call):
+    """Phi-4-mini-flash's differential calls at the cell's geometry: 40 query
+    heads of 64 over 20 key/value heads, pages of 128 tokens holding a
+    token's heads side by side (1,280 lanes: at (128, 20, 64) the compiler
+    lays a page out with its token axis minor); the full layer's pool of
+    4,097 pages, the window layers' ring of 6 pages for each of 64 slots and
+    the trash slot. No call copies its pages."""
+    from automodel_tpu.ops.paged_attention import (
+        RowSegments, diff_row_width, max_row_segments, row_tile,
+    )
+
+    window = 512 if call.startswith("window") else None
+    T = 64 if call.startswith("cross") else 128
+    Hq, Hkv, D, ps, P, S = 40, 20, 64, 128, 64, 64
+    N, R = (65 * 6, 6) if window else (4097, P)
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    tile = row_tile(T, diff_row_width(Hq, Hkv, D))
+    most = max_row_segments(T, S, tile)
+    assert tile == 16
+    args = (s((T, Hq, D), jnp.bfloat16), s((N, ps, Hkv * D), jnp.bfloat16),
+            s((N, ps, Hkv * D), jnp.bfloat16), s((T, P), jnp.int32),
+            s((T,), jnp.int32), s((6, most * R), jnp.int32), s((), jnp.int32))
+
+    def fn(q, k, v, pt, pos, b, c):
+        return tpu_branch.paged_attention_diff_kernel(
+            q, k, v, pt, pos, scale=D ** -0.5, segments=RowSegments(tile, b, c),
+            window=window)
+
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    name = ("paged_attention_diff_window_gqa" if window
+            else "paged_attention_diff_gqa")
+    assert name in text
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"[{N},{ps}" in ln.split(" copy(")[0]]
+
+
+def test_decoder_hybrid_decoder_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
+    """Phi-4-mini-flash-reasoning whole at its cell's geometry, from shapes
+    alone: 32 layers in ONE program whose arguments are the 8.73 GB of
+    weights (the head untied), the 2.69 GB pool of the ONE full layer, the
+    2.04 GB of rings of the 8 window layers and the 0.21 GB of state of the 9
+    Mamba layers, the last three donated and aliased. The full layer's call
+    and the 7 cross layers' read the one pool, under one name; the window
+    layers' calls carry their own; a `selective_scan` in each Mamba layer.
+    A token costs 5,120 bytes of the pool (keys and values, 20 heads of 64,
+    bf16), the compile's own arguments: no page is padded, and no pool or
+    ring is copied."""
+    import json
+
+    from tests.step_shapes import engine_of_shapes
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "phi4_mini_flash_serve_v5e1.json")) as f:
+        config = json.load(f)
+    eng, args = engine_of_shapes(config, config["serving"], one_chip)
+    assert eng._ring_pages == 6 and eng._stack_rings == [8]
+    assert eng._stack_attn == [1] and len(args[3]) == 9 + 8
+    assert eng._cross == (16, 17, 18)
+    lowered = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args)
+    text = lowered.as_text()
+    assert text.count("paged_attention_diff_gqa") == 8
+    assert text.count("paged_attention_diff_window_gqa") == 8
+    assert text.count("selective_scan") == 9
+    assert "serve.cow" not in text
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    token = 2 * 20 * 64 * 2
+    pool = 4097 * 128 * token
+    rings = 8 * 65 * 6 * 128 * token
+    state = 9 * 65 * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert (token, pool, rings) == (5120, 2_685_009_920, 2_044_723_200)
+    assert mem.alias_size_in_bytes >= pool + rings + state
+    weights = 2 * 4_364_726_784
+    assert mem.argument_size_in_bytes < weights + pool + rings + state + 1e7
+    assert mem.temp_size_in_bytes < 0.3e9
+    text = compiled.as_text()
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and ("[4097," in ln or "[390," in ln)]
+
+
+#: sha256 of each accepted cell's whole serve step as lowered for the chip,
+#: source locations left out: the step the parent of the PR that brought the
+#: decoder-hybrid-decoder lowered, byte for byte. A change to a step's program
+#: changes its pin; one that leaves the program alone (host code, another
+#: model's path) does not
+LOWERED_STEPS = {
+    "moonlight_16b_a3b_serve_v5e1":
+        "7e14e3041411e18d942835dd99dd18d899f75013ff97d0f54ca3ea8ab43033f7",
+    "ouro_2_6b_serve_v5e1":
+        "39a7ee4b49041adc08159f5aab4dcbe3c4e0ebaeb81088a62e9a32a0ff77a0f6",
+    "jamba2_3b_serve_v5e1":
+        "9fa6c6619cda7833d2e556b89690ac0375fb252f1f3fbf3df8ace7a69946b0ec",
+    "k_exaone_236b_a23b_serve_v5e1":
+        "afe762160da4eb9d386be0af67fca15f402d067c5071ef08f5476dec50fba2a4",
+    "mimo_v2_flash_serve_v5e1":
+        "934aff71f9c26cb9a481779c0890b7f6cc64368d15dd8c059026b5a2c660fdfa",
+}
+
+
+@pytest.mark.parametrize("name", LOWERED_STEPS)
+def test_the_other_cells_lowered_steps_are_as_they_were(one_chip, tpu_branch, name):
+    import hashlib
+    import json
+
+    from tests.step_shapes import engine_of_shapes
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs", f"{name}.json")) as f:
+        config = json.load(f)
+    eng, args = engine_of_shapes(config, config["serving"], one_chip)
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    try:
+        text = jax.jit(eng._step_impl, donate_argnums=(1, 3)).lower(*args).as_text()
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+    assert hashlib.sha256(text.encode()).hexdigest() == LOWERED_STEPS[name]
+
+
 def test_grouped_matmul_compiles_inside_a_shard_map_over_four_chips(
         topo, tpu_branch):
     """The EP path's use: each chip's local experts over the rows it
