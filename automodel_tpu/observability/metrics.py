@@ -279,6 +279,7 @@ METRIC_CATALOG = (
     ("serve_prefix_hits_cut_total", "counter", "admissions whose cached-prefix match was cut to none (the model holds a recurrent state, or a window layer's ring, per slot)"),
     ("serve_prefill_skipped_tokens_total", "counter", "prompt tokens skipped via prefix reuse"),
     ("serve_cow_copies_total", "counter", "copy-on-write page copies"),
+    ("serve_cow_steps_total", "counter", "serve steps of a model whose step holds the copy-on-write block (labeled by path=copy|skip: the plan split at least one page, whose pages alone were copied, or none)"),
     # speculative decoding
     ("serve_spec_drafted_total", "counter", "draft tokens proposed"),
     ("serve_spec_accepted_total", "counter", "draft tokens accepted"),

@@ -133,6 +133,7 @@ from automodel_tpu.resilience.faults import fault_hit
 from automodel_tpu.serving.kv_pages import (
     PageAllocator,
     apply_defrag,
+    copy_split_pages,
     init_pool,
     init_rings,
     init_state,
@@ -1115,18 +1116,16 @@ class ServingEngine:
                 b["cross_pt"], page_size=sc.page_size,
                 tile=self._cross_row_tile, max_slots=sc.max_slots,
             )
-        # copy-on-write splits first (≤ 1 per slot; idle entries copy the
-        # trash page onto itself): a slot about to append into a page some
-        # other table or the radix tree still reads gets a private copy. A
-        # model whose requests begin at position 0 alone never shares a page
-        # (its prefix hits are cut, a hand-off refused): its step has no
-        # such block, whose scatter made the compiler lay a pool of fewer
-        # than 8 key/value heads a page out anew, twice a step, for nothing
+        # copy-on-write splits first (≤ 1 per slot): a slot about to append
+        # into a page some other table or the radix tree still reads gets a
+        # private copy. Only the plan's splits are copied; a step with none
+        # (no prefix cache, or no shared page to append into) copies
+        # nothing. A model whose requests begin at position 0 alone never
+        # shares a page (its prefix hits are cut, a hand-off refused): its
+        # step has no such block
         if not self.begins_at_zero:
             with jax.named_scope("serve.cow"):
-                pool = jax.tree.map(
-                    lambda a: a.at[b["cow_dst"]].set(a[b["cow_src"]]), pool
-                )
+                pool = copy_split_pages(pool, b["cow_src"], b["cow_dst"])
         # under a mesh: pool pinned to its pages-global / heads-sharded
         # layout through the COW block and the layers; hidden replicated so
         # every tp reduction lives inside the layer stack (no-ops off-mesh)
@@ -1429,6 +1428,13 @@ class ServingEngine:
         reg.counter("serve_steps_total").inc()
         reg.counter("serve_plan_tokens_total").inc(plan.n_tokens)
         reg.counter("serve_plan_samples_total").inc(plan.n_samples)
+        if not self.begins_at_zero:
+            # the copy-on-write block's work: "copy" where the plan splits a
+            # page (those pages alone are copied), "skip" where it splits none
+            split = bool(np.any(plan.cow_src != plan.cow_dst))
+            reg.counter(
+                "serve_cow_steps_total", path="copy" if split else "skip",
+            ).inc()
         # step.run's three children split what the host does around the
         # device's step: the plan's upload, the enqueue of the jitted
         # step (a compile lands here), and the read-back, itself in two:

@@ -282,6 +282,28 @@ def apply_defrag(pool, src: jnp.ndarray):
     return jax.tree.map(lambda a: a[full], pool)
 
 
+def copy_split_pages(pool, src: jnp.ndarray, dst: jnp.ndarray):
+    """A step's copy-on-write splits on a pool pytree: for each entry with
+    `src != dst`, page `src` is copied onto page `dst` in every array (the
+    page axis is axis 0). Idle entries (trash onto trash) copy nothing, so a
+    step without a split does no work here; with k splits, k pages an array.
+
+    The splits copy one after another in the order of their entries (the
+    scheduler's order of making them): a page one split reads may be freed
+    and handed out again as a LATER split's fresh page within the step,
+    never the reverse. The loop carries the pool, which XLA updates in
+    place."""
+    split = src != dst
+    order = jnp.argsort(~split, stable=True)  # the splits first, in order
+
+    def copy(i, pool):
+        s, d = src[order[i]], dst[order[i]]
+        return jax.tree.map(lambda a: jax.lax.dynamic_update_slice_in_dim(
+            a, jax.lax.dynamic_slice_in_dim(a, s, 1), d, 0), pool)
+
+    return jax.lax.fori_loop(0, split.sum(), copy, pool)
+
+
 def pool_trash_index(pool) -> int:
     """The trash page index = num_pages (pages axis is num_pages + 1)."""
     return jax.tree.leaves(pool)[0].shape[0] - 1

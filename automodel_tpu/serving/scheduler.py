@@ -166,7 +166,8 @@ class StepPlan:
     sample_tok: np.ndarray   # (S,) int32 row to sample from, -1 = no sample
     temp: np.ndarray         # (S,) float32 per-slot temperature
     seed: np.ndarray         # (S,) int32 per-slot base seed
-    # copy-on-write page copies (≤ 1 per slot per step; trash→trash = no-op)
+    # copy-on-write page copies (≤ 1 per slot per step), first entries in
+    # the order the scheduler made them; trash→trash = no-op
     cow_src: np.ndarray = None  # (S,) int32 source page
     cow_dst: np.ndarray = None  # (S,) int32 destination page
     # speculative decoding (None unless the engine runs with it enabled):
@@ -858,7 +859,7 @@ class Scheduler:
         if self.spec is not None:
             plan.verify_rows = np.zeros((S, self.spec.draft_len + 1), np.int32)
             plan.spec_len = np.zeros(S, np.int32)
-        row = 0
+        row = splits = 0
         planned = set()
         # decode rows first (pending == 1), then prefill chunks; within each
         # class oldest admit first. Paused slots (stream backpressure) get
@@ -896,7 +897,10 @@ class Scheduler:
                     continue
                 pair = self.alloc.cow(slot, first_page)
                 if pair is not None:  # the ladder may have dropped the share
-                    plan.cow_src[slot], plan.cow_dst[slot] = pair
+                    # entries in the order made: a page this split reads
+                    # may be freed and handed to a later split as its copy
+                    plan.cow_src[splits], plan.cow_dst[splits] = pair
+                    splits += 1
                     self.n_cow += 1
             planned.add(slot)
             samples = req.fed + c == req.known_len

@@ -184,6 +184,31 @@ def test_warm_vs_cold_parity_overlapping_stream():
     assert stats["tokens_fed"] < cold["stats"]["tokens_fed"]
 
 
+def test_cow_steps_are_counted_by_whether_the_plan_splits_a_page():
+    """`serve_cow_steps_total{path}` over the overlapping stream: without
+    the cache no page is ever shared, so every step skips the copy; with it
+    the steps whose plan splits a page copy, the others skip."""
+    params = decoder.init(CFG, jax.random.key(0))
+    (sys_p,) = _ragged(1, [9])
+    prompts = [sys_p + t for t in _ragged(2, [3, 5])] + [sys_p[:6] + [61, 62]]
+    geo = dict(page_size=4, num_pages=32, max_slots=3, pages_per_slot=8,
+               token_budget=8, prefill_chunk=4)
+    for cache in (None, ENABLED):
+        engine = ServingEngine(own(params), CFG,
+                               ServingConfig(**geo, prefix_cache=cache))
+        res = engine.serve_batch([
+            Request(prompt=list(p), max_new_tokens=4, arrival=a)
+            for p, a in zip(prompts, [0, 3, 5])])
+        reg = engine.obs.registry
+        copy, skip = (reg.counter("serve_cow_steps_total", path=p).value
+                      for p in ("copy", "skip"))
+        assert copy + skip == res["stats"]["steps"]
+        if cache is None:
+            assert copy == 0
+        else:
+            assert 1 <= copy <= res["stats"]["cow_copies"]
+
+
 def test_preempted_request_readmits_through_its_own_donation():
     """Preempt-and-requeue with the cache on: the victim's donated pages
     turn its recompute-style re-prefill into a radix hit, and outputs still

@@ -195,14 +195,14 @@ def test_router_splits_once_for_both_classes():
 #: sha256 of the lowered (StableHLO) serve step of CFG at GEO with jax 0.9.0
 #: on the CPU. The walk over passes must leave a one-pass model's program as
 #: it was: a PR that changes this step on purpose updates the digest and says
-#: why. Re-derived by PR 39 for the step's SIGNATURE, not its walk: the plan
-#: arrives as one int32 vector (eleven parameters became one, sliced back in
-#: thirteen ops at the top) and the tokens and log-probabilities leave as one
-#: (a bitcast and a concatenate at the end); with value names and private
-#: function numbers masked, every other line of the text is the one that
-#: a49ab3f's digest 1e354697... pinned (PRs 29-38).
+#: why. Last re-derived for the copy-on-write block alone: a `while` over the
+#: plan's split pages (a dynamic slice and update a pool array a trip, after
+#: a stable argsort that puts the splits first) where a gather and a scatter
+#: a pool array over every slot's entry were; with value names, locations and
+#: private function names masked, every other line of the text is the one
+#: the digest before it pinned.
 ONE_PASS_STEP_SHA256 = (
-    "6d2dd2154b6f895124f60d8cd1ab526c89fb55b44f89004fed1415dec6d741e7")
+    "a13714b3dec097c369ec05e59fc38bff00b209c1ec9beec10b944e9d8969ae01")
 
 
 def test_one_pass_step_text_is_unchanged():
@@ -241,9 +241,9 @@ def test_looped_step_walks_the_same_weights_over_its_own_cache_entries():
                          re.S).group(1).count("%arg")
 
     assert n_args(text[P]) - n_args(text[1]) == 2 * L * (P - 1)
-    # every pass does a pass's work: the pool writes and copy-on-write
-    # scatters, the page gathers and (the head's one aside) the matrix
-    # products of the one-pass step, P times over
+    # every pass does a pass's work: the pool writes' scatters, the page
+    # gathers and (the head's one aside) the matrix products of the one-pass
+    # step, P times over
     assert _ops(text[1], "scatter") > 0
     assert _ops(text[P], "scatter") == P * _ops(text[1], "scatter")
     assert _ops(text[P], "dot_general") - 1 == P * (_ops(text[1], "dot_general") - 1)

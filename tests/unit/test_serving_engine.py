@@ -419,6 +419,45 @@ def test_looped_decoder_logits_match_reference_through_preemption():
     assert np.median(low_errs) > 10 * LOGPROB_TOL
 
 
+TRASH = 16  # the toy looped engine's trash page: num_pages
+
+
+@pytest.mark.parametrize("src,dst", [
+    ([TRASH] * 4, [TRASH] * 4),                     # no split
+    ([3, TRASH, TRASH, TRASH], [9, TRASH, TRASH, TRASH]),  # one split
+    ([1, 1, 2, 3], [8, 9, 10, 11]),                 # a split in every slot
+    ([3, 1, TRASH, TRASH], [7, 3, TRASH, TRASH]),   # a page copied, then
+], ids=["none", "one", "every_slot", "copied_then_split_into"])  # split into
+def test_step_copies_the_split_pages_as_one_scatter_would(src, dst):
+    """The looped engine's step over a plan of pad rows and the given
+    copy-on-write entries: every pool array equals the scatter
+    `a.at[dst].set(a[src])` of the pool it was given, page for page (the
+    trash page aside, which the pad rows write). A plan without a split
+    leaves the pool as it was; the last case's second split overwrites the
+    page its first copies, as a page freed and handed out again within the
+    step is."""
+    from tests import ouro_case
+
+    cfg = ouro_case.config()
+    eng = ServingEngine(own(ouro_case.init_params(cfg)), cfg, ServingConfig(
+        page_size=4, num_pages=TRASH, max_slots=4, pages_per_slot=4,
+        token_budget=8))
+    leaves, tree = jax.tree.flatten(eng.pool)
+    rng = np.random.default_rng(0)
+    before = [rng.standard_normal(a.shape).astype(a.dtype) for a in leaves]
+    eng.pool = jax.tree.unflatten(tree, [jnp.asarray(a) for a in before])
+    plan = eng.empty_plan()
+    plan.page[:] = TRASH
+    plan.cow_src[:], plan.cow_dst[:] = src, dst
+    pool, _ = eng._step(*eng._step_args(eng._plan_batch(plan)))
+    after = jax.tree.leaves(pool)
+    assert len(after) == len(before) == 2 * cfg.num_passes * cfg.num_layers
+    for a, got in zip(before, after):
+        want = a.copy()
+        want[dst] = a[src]
+        np.testing.assert_array_equal(np.asarray(got)[:TRASH], want[:TRASH])
+
+
 def test_request_larger_than_the_whole_pool_is_refused_by_name():
     """The looped cell's geometry: 10 pages of 64 tokens a slot. In a pool of
     84 pages a 640-token request is admissible; a pool of 8 could never hold

@@ -355,6 +355,43 @@ def test_looped_step_lowered_for_the_chip_holds_a_kernel_per_pass_and_layer(
     lowered.compile()
 
 
+def test_looped_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
+    """Ouro-2.6B's serve step at its cell's geometry, from shapes alone: 48
+    layers walked 4 times in ONE program whose 8.56 GB pool (384 arrays, a
+    key and a value array for each pass and layer, 85 pages of 256 KiB) is
+    donated and aliased to the output. The copy-on-write block is a loop over
+    the plan's split pages that updates the pool in place: no scatter under
+    `serve.cow`, no copy of a pool array, and no more temporaries than the
+    block's scatter over every slot took (1,618,685,952 bytes) plus 64 MiB.
+    At 87 % of the chip's memory a pool copied through the loop would not
+    fit."""
+    import json
+
+    from tests.step_shapes import engine_of_shapes
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "ouro_2_6b_serve_v5e1.json")) as f:
+        config = json.load(f)
+    eng, args = engine_of_shapes(config, config["serving"], one_chip)
+    page = 64 * 16 * 128 * 2
+    assert len(args[1][0]) == 4 * 48 and page == 256 * 1024
+    compiled = jax.jit(eng._step_impl, donate_argnums=(1,)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    cow = [ln for ln in text.splitlines() if "serve.cow/" in ln]
+    assert any(" while(" in ln for ln in cow)
+    assert not any(" scatter(" in ln for ln in cow)
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and "[85,64,16,128]" in ln.split(" copy(")[0]]
+    mem = compiled.memory_analysis()
+    pool = 2 * 4 * 48 * 85 * page
+    assert pool == 8_556_380_160
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < 1_618_685_952 + 64 * 2**20
+
+
 def test_state_space_step_compiles_whole_for_the_chip(one_chip, tpu_branch):
     """AI21-Jamba2-3B's serve step at its cell's geometry, from shapes alone
     (no weight is allocated): all 28 layers, 26 of them a `selective_scan`
@@ -635,21 +672,24 @@ def test_decoder_hybrid_decoder_step_compiles_whole_for_the_chip(one_chip, tpu_b
 
 
 #: sha256 of each accepted cell's whole serve step as lowered for the chip,
-#: source locations left out: the step the parent of the PR that brought the
-#: decoder-hybrid-decoder lowered, byte for byte. A change to a step's program
-#: changes its pin; one that leaves the program alone (host code, another
-#: model's path) does not
+#: source locations left out, byte for byte. Moonlight's and Ouro's are the
+#: steps whose copy-on-write block loops over the plan's split pages alone;
+#: the others' steps have no such block and are as they were before it. A
+#: change to a step's program changes its pin; one that leaves the program
+#: alone (host code, another model's path) does not
 LOWERED_STEPS = {
     "moonlight_16b_a3b_serve_v5e1":
-        "7e14e3041411e18d942835dd99dd18d899f75013ff97d0f54ca3ea8ab43033f7",
+        "abd6e5e70cbca1903029ad6d474d8edacb9f8d81b05f4824f334b64a3603e7cc",
     "ouro_2_6b_serve_v5e1":
-        "39a7ee4b49041adc08159f5aab4dcbe3c4e0ebaeb81088a62e9a32a0ff77a0f6",
+        "cedfd082488b3f3d8771343cb9c940f42a87af510ae4bbf72f686344d917bff8",
     "jamba2_3b_serve_v5e1":
         "9fa6c6619cda7833d2e556b89690ac0375fb252f1f3fbf3df8ace7a69946b0ec",
     "k_exaone_236b_a23b_serve_v5e1":
         "afe762160da4eb9d386be0af67fca15f402d067c5071ef08f5476dec50fba2a4",
     "mimo_v2_flash_serve_v5e1":
         "934aff71f9c26cb9a481779c0890b7f6cc64368d15dd8c059026b5a2c660fdfa",
+    "phi4_mini_flash_serve_v5e1":
+        "4c1c75f35001bc315db5039d1292ff394342c6b255bd390d6afd652478a5d64c",
 }
 
 
